@@ -120,8 +120,8 @@ def resolve_positional(spec: "SpecInfo",
     """(resolved axis names, problems) of a spec's positional entries
     against an ordered mesh-axis tuple. With no order known, nothing
     resolves and nothing is flagged; the -1-repeated and
-    out-of-range error cases mirror the runtime resolver
-    (parallel/mesh.resolve_spec)."""
+    out-of-range error cases are flagged here because the installed
+    jax silently drops a positional 0 (``P(0)`` -> replicated)."""
     problems: List[str] = []
     if sum(1 for i in spec.pos_entries if i == -1) > 1:
         problems.append("-1 appears more than once in one PartitionSpec")

@@ -572,10 +572,11 @@ def _shard_mesh(ndev: int):
 def _h_grouped_reduce(ndev: int):
     import numpy as np
 
+    import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from filodb_tpu.parallel.mesh import _grouped_reduce, _shard_map
+    from filodb_tpu.parallel.mesh import _grouped_reduce
     rng = np.random.default_rng(_SEED + 4)
     S, T, G = 16, 12, 4
     local = rng.normal(0, 1e3, (S, T))
@@ -587,7 +588,7 @@ def _h_grouped_reduce(ndev: int):
     for agg in ("sum", "avg"):
         def body(loc, g):
             return _grouped_reduce(loc, g, G, agg)
-        f = _shard_map(
+        f = jax.shard_map(
             body, mesh=mesh, in_specs=(P("shard", None), P("shard")),
             out_specs=P(), check_vma=False)
         outs.append(np.asarray(f(jnp.asarray(local),

@@ -104,14 +104,11 @@ def arg_sig(args) -> Tuple:
 
 def cost_from_compiled(compiled) -> Optional[Dict[str, float]]:
     """FLOPs / bytes-accessed from a ``Compiled``'s cost_analysis
-    (dict in new jax, [dict] in 0.4.x; None when the backend doesn't
-    provide one)."""
+    (None when the backend doesn't provide one)."""
     try:
         ca = compiled.cost_analysis()
     except Exception:   # noqa: BLE001 — cost is best-effort telemetry
         return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
     if not isinstance(ca, dict):
         return None
     out: Dict[str, float] = {}

@@ -35,99 +35,6 @@ jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
 
-# jax moved shard_map to the top level (and renamed check_rep -> check_vma)
-# after 0.4.x; accept either so the mesh executor runs on both
-if hasattr(jax, "shard_map"):
-    _shard_map_raw = jax.shard_map
-else:                                                   # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def _shard_map_raw(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_legacy(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma)
-
-
-def resolve_spec(mesh: "Mesh", spec):
-    """Expand positional PartitionSpec indices against ``mesh``.
-
-    Library-level shard_map code (tilestore/shardstore evaluators) names
-    mesh axes POSITIONALLY — ``P(0)`` is the first mesh axis, ``P(1)``
-    the second, a single ``-1`` the tuple of all axes not otherwise
-    mentioned (dropped when empty) — so the evaluator bodies stay
-    agnostic to users' axis naming conventions. Newer jax resolves these
-    natively; this resolver implements the same semantics on every
-    version this repo supports. Out-of-range indices and a repeated
-    ``-1`` raise ValueError, mirroring the native behavior."""
-    if spec is None:
-        return spec
-    names = tuple(mesh.axis_names)
-    entries = tuple(spec)
-
-    def subaxes(e):
-        return tuple(e) if isinstance(e, (tuple, list)) else (e,)
-
-    if not any(isinstance(x, int) for e in entries for x in subaxes(e)):
-        return spec
-
-    def name_of(i: int) -> str:
-        if not -len(names) <= i < len(names):
-            raise ValueError(
-                f"positional PartitionSpec index {i} out of range for "
-                f"mesh axes {names}")
-        return names[i]
-
-    mentioned = set()
-    for e in entries:
-        for x in subaxes(e):
-            if isinstance(x, str):
-                mentioned.add(x)
-            elif isinstance(x, int) and x != -1:
-                mentioned.add(name_of(x))
-    neg = sum(1 for e in entries for x in subaxes(e)
-              if isinstance(x, int) and x == -1)
-    if neg > 1:
-        raise ValueError("at most one -1 may appear in a PartitionSpec")
-    remaining = tuple(n for n in names if n not in mentioned)
-    out = []
-    for e in entries:
-        if isinstance(e, int):
-            if e == -1:
-                out.append(remaining if remaining else None)
-            else:
-                out.append(name_of(e))
-        elif isinstance(e, (tuple, list)):
-            sub = []
-            for x in e:
-                if isinstance(x, int):
-                    sub.extend(remaining if x == -1 else (name_of(x),))
-                else:
-                    sub.append(x)
-            out.append(tuple(sub))
-        else:
-            out.append(e)
-    return P(*out)
-
-
-def _resolve_spec_tree(mesh, specs):
-    """resolve_spec over a specs pytree (tuples/lists/dicts of P/None)."""
-    if specs is None or isinstance(specs, P):
-        return resolve_spec(mesh, specs)
-    if isinstance(specs, (tuple, list)):
-        return tuple(_resolve_spec_tree(mesh, s) for s in specs)
-    if isinstance(specs, dict):
-        return {k: _resolve_spec_tree(mesh, v) for k, v in specs.items()}
-    return specs
-
-
-def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """shard_map with positional-PartitionSpec resolution: mesh-agnostic
-    library specs (P(0), P(None, 1), P(-1)) expand against the call's
-    mesh before lowering."""
-    return _shard_map_raw(f, mesh=mesh,
-                          in_specs=_resolve_spec_tree(mesh, in_specs),
-                          out_specs=_resolve_spec_tree(mesh, out_specs),
-                          check_vma=check_vma)
-
 from filodb_tpu.lint.caches import cache_registry
 from filodb_tpu.lint.contracts import kernel_contract
 from filodb_tpu.lint.numerics import order_insensitive
@@ -199,7 +106,7 @@ def _grouped_reduce_check():
     devs = np.asarray(jax.devices()[:1]).reshape(1, 1)
     mesh = Mesh(devs, ("shard", "time"))
     S, T, G = 8, 16, 4
-    f = _shard_map(
+    f = jax.shard_map(
         lambda loc, g: _grouped_reduce(loc, g, G, "sum"),
         mesh=mesh, in_specs=(P("shard", None), P("shard")),
         out_specs=P(), check_vma=False)
@@ -297,7 +204,7 @@ class MeshExecutor:
         def run(func, agg, num_groups, nsteps_local, w_bound, ts, vals,
                 lens, gids, w0s, w0e, step, scalar):
             @functools.partial(
-                _shard_map, mesh=mesh,
+                jax.shard_map, mesh=mesh,
                 in_specs=(P("shard", None, None), P("shard", None, None),
                           P("shard", None), P("shard", None),
                           P(), P(), P(), P()),
@@ -368,7 +275,7 @@ class MeshExecutor:
         def run(func, num_groups, k, bottom, nsteps_local, w_bound, ts,
                 vals, lens, gids, w0s, w0e, step, scalar):
             @functools.partial(
-                _shard_map, mesh=mesh,
+                jax.shard_map, mesh=mesh,
                 in_specs=(P("shard", None, None), P("shard", None, None),
                           P("shard", None), P("shard", None),
                           P(), P(), P(), P()),

@@ -19,10 +19,11 @@ lower through ``shard_map``:
   * grouped aggregation keeps the one-hot [S, G] matmul + ``psum``
     collective of the scatter-gather path (mesh._grouped_reduce) but
     feeds it from the resident tiles;
-  * PartitionSpecs are POSITIONAL (mesh.resolve_spec): ``P(None, 0)``
-    = replicated slots x first-mesh-axis series, ``P(1, 0)`` = steps on
-    the second axis x series on the first — the evaluator code never
-    names an axis, so it runs unchanged on any user mesh shape;
+  * PartitionSpecs name the mesh's axes through ``mesh.axis_names``
+    (first axis = series shards, second = output steps):
+    ``P(None, s_axis)`` = replicated slots x sharded series,
+    ``P(t_axis, s_axis)`` = steps x series — the evaluator code never
+    hard-codes an axis name, so it runs unchanged on any user mesh;
   * cross-flush tile refreshes are ZERO-COPY in HBM: the slot channels
     are capacity-padded and a flush appends its new slot columns via a
     ``donate_argnums`` jit (``_append_step``) — the donated buffers are
@@ -59,8 +60,7 @@ from filodb_tpu.lint.capacity import (capacity, drop_resident,
 from filodb_tpu.lint.contracts import kernel_contract
 from filodb_tpu.lint.locks import guarded_by
 from filodb_tpu.lint.numerics import order_insensitive, precision
-from filodb_tpu.parallel.mesh import (_grouped_reduce, _shard_map, make_mesh,
-                                      resolve_spec)
+from filodb_tpu.parallel.mesh import _grouped_reduce, make_mesh
 
 # cache inventory (graftlint): the sharded-evaluator dispatch table
 # memoizes compiled shard_map programs keyed purely on (kernel family,
@@ -158,7 +158,7 @@ def _build_counter_eval(mesh: Mesh, func: str, nsteps_local: int,
     the single-device evaluate_counters_t_batch family."""
     from filodb_tpu.query.tilestore import _eval_counter_fast
 
-    t_axis = mesh.axis_names[1]
+    s_axis, t_axis = mesh.axis_names[:2]
 
     def counter_body(tsr, vv, n, base, dt, w0s, w0e, step):
         # this device's slice of the output step grid rides the time
@@ -176,21 +176,21 @@ def _build_counter_eval(mesh: Mesh, func: str, nsteps_local: int,
     if batch:
         @jax.jit
         def run_b(tsr, vv, n, base, dt, w0s, w0e, step):
-            inner = _shard_map(
+            inner = jax.shard_map(
                 counter_body, mesh=mesh,
-                in_specs=(P(None, 0), P(None, 0), P(), P(), P(),
+                in_specs=(P(None, s_axis), P(None, s_axis), P(), P(), P(),
                           P(None), P(None), P()),
-                out_specs=P(None, 1, 0))
+                out_specs=P(None, t_axis, s_axis))
             return inner(tsr, vv, n, base, dt, w0s, w0e, step)
         return run_b
 
     @jax.jit
     def run(tsr, vv, n, base, dt, w0s, w0e, step):
-        inner = _shard_map(
+        inner = jax.shard_map(
             counter_body, mesh=mesh,
-            in_specs=(P(None, 0), P(None, 0), P(), P(), P(),
+            in_specs=(P(None, s_axis), P(None, s_axis), P(), P(), P(),
                       P(), P(), P()),
-            out_specs=P(1, 0))
+            out_specs=P(t_axis, s_axis))
         return inner(tsr, vv, n, base, dt, w0s, w0e, step)
     return run
 
@@ -203,8 +203,8 @@ def _build_aligned_eval(mesh: Mesh, func: str, nsteps_local: int,
     ``arr_keys`` is the channel-set signature ((name, ndim), ...)."""
     from filodb_tpu.query.tilestore import _eval_core
 
-    t_axis = mesh.axis_names[1]
-    arr_specs = {k: (P(0) if nd == 1 else P(0, None))
+    s_axis, t_axis = mesh.axis_names[:2]
+    arr_specs = {k: (P(s_axis) if nd == 1 else P(s_axis, None))
                  for k, nd in arr_keys}
 
     def aligned_body(arrs, n, base, dt, w0s, w0e, step):
@@ -220,20 +220,20 @@ def _build_aligned_eval(mesh: Mesh, func: str, nsteps_local: int,
     if batch:
         @jax.jit
         def run_b(arrs, n, base, dt, w0s, w0e, step):
-            inner = _shard_map(
+            inner = jax.shard_map(
                 aligned_body, mesh=mesh,
                 in_specs=(arr_specs, P(), P(), P(),
                           P(None), P(None), P()),
-                out_specs=P(None, 0, 1))
+                out_specs=P(None, s_axis, t_axis))
             return inner(arrs, n, base, dt, w0s, w0e, step)
         return run_b
 
     @jax.jit
     def run(arrs, n, base, dt, w0s, w0e, step):
-        inner = _shard_map(
+        inner = jax.shard_map(
             aligned_body, mesh=mesh,
             in_specs=(arr_specs, P(), P(), P(), P(), P(), P()),
-            out_specs=P(0, 1))
+            out_specs=P(s_axis, t_axis))
         return inner(arrs, n, base, dt, w0s, w0e, step)
     return run
 
@@ -253,8 +253,7 @@ def _build_grouped_pair_eval(mesh: Mesh, func: str, nsteps_local: int,
     counts > 0, exactly the Pallas group-sum kernel's return shape."""
     from filodb_tpu.query.tilestore import _eval_counter_fast
 
-    s_axis = mesh.axis_names[0]
-    t_axis = mesh.axis_names[1]
+    s_axis, t_axis = mesh.axis_names[:2]
 
     def grouped_pair_body(tsr, vv, gids, n, base, dt, w0s, w0e, step):
         t_off = (jax.lax.axis_index(t_axis).astype(jnp.int64)
@@ -272,11 +271,11 @@ def _build_grouped_pair_eval(mesh: Mesh, func: str, nsteps_local: int,
 
     @jax.jit
     def run(tsr, vv, gids, n, base, dt, w0s, w0e, step):
-        inner = _shard_map(
+        inner = jax.shard_map(
             grouped_pair_body, mesh=mesh,
-            in_specs=(P(None, 0), P(None, 0), P(0), P(), P(), P(), P(),
-                      P(), P()),
-            out_specs=(P(1, None), P(1, None)))
+            in_specs=(P(None, s_axis), P(None, s_axis), P(s_axis),
+                      P(), P(), P(), P(), P(), P()),
+            out_specs=(P(t_axis, None), P(t_axis, None)))
         return inner(tsr, vv, gids, n, base, dt, w0s, w0e, step)
     return run
 
@@ -289,7 +288,7 @@ def _build_grouped_eval(mesh: Mesh, func: str, nsteps_local: int,
     collective) -> [G, T]."""
     from filodb_tpu.query.tilestore import _eval_counter_fast
 
-    t_axis = mesh.axis_names[1]
+    s_axis, t_axis = mesh.axis_names[:2]
 
     @functools.partial(jax.jit, static_argnames=("agg",))
     def run(agg, tsr, vv, gids, n, base, dt, w0s, w0e, step):
@@ -302,11 +301,11 @@ def _build_grouped_eval(mesh: Mesh, func: str, nsteps_local: int,
                                        w0e + t_off, step)
             return _grouped_reduce(local.T.astype(jnp.float64), gids,
                                    num_groups, agg)
-        inner = _shard_map(
+        inner = jax.shard_map(
             grouped_body, mesh=mesh,
-            in_specs=(P(None, 0), P(None, 0), P(0), P(), P(), P(), P(),
-                      P(), P()),
-            out_specs=P(None, 1))
+            in_specs=(P(None, s_axis), P(None, s_axis), P(s_axis),
+                      P(), P(), P(), P(), P(), P()),
+            out_specs=P(None, t_axis))
         return inner(tsr, vv, gids, n, base, dt, w0s, w0e, step)
     return run
 
@@ -350,7 +349,7 @@ class ShardedTiles:
         self.S_pad = -(-S // n_shard) * n_shard
         self.cap = _next_pow2(N, 64)
         self.n_filled = N
-        col = NamedSharding(mesh, resolve_spec(mesh, P(None, 0)))
+        col = NamedSharding(mesh, P(None, mesh.axis_names[0]))
         self._col_sharding = col
 
         def place(host_nx_s, dtype):
@@ -463,9 +462,9 @@ class ShardedTiles:
         key = tuple(sorted(arrs))
         placed = self._aligned.get(key)
         if placed is None:
-            row = NamedSharding(self.mesh, resolve_spec(self.mesh, P(0)))
-            row2 = NamedSharding(self.mesh,
-                                 resolve_spec(self.mesh, P(0, None)))
+            s_axis = self.mesh.axis_names[0]
+            row = NamedSharding(self.mesh, P(s_axis))
+            row2 = NamedSharding(self.mesh, P(s_axis, None))
             placed = {}
             for k, a in arrs.items():
                 h = np.asarray(a)
@@ -520,7 +519,7 @@ class ShardedTiles:
         t_local, w0s, w0e, step = self._grid(steps, window_ms, offset_ms)
         g = np.full(self.S_pad, -1, dtype=np.int32)   # -1 = padding rows
         g[:self.S] = np.asarray(gids, dtype=np.int32)
-        row = NamedSharding(self.mesh, resolve_spec(self.mesh, P(0)))
+        row = NamedSharding(self.mesh, P(self.mesh.axis_names[0]))
         vv = self._cv if func in ("rate", "increase") else self._v
         args = (self._tsr, vv, jax.device_put(g, row),
                 np.int64(self.n_filled), np.int64(self.base_ms),
@@ -542,7 +541,7 @@ class ShardedTiles:
         t_local, w0s, w0e, step = self._grid(steps, window_ms, offset_ms)
         g = np.full(self.S_pad, -1, dtype=np.int32)
         g[:self.S] = np.asarray(gids, dtype=np.int32)
-        row = NamedSharding(self.mesh, resolve_spec(self.mesh, P(0)))
+        row = NamedSharding(self.mesh, P(self.mesh.axis_names[0]))
         vv = self._cv if func in ("rate", "increase") else self._v
         args = (self._tsr, vv, jax.device_put(g, row),
                 np.int64(self.n_filled), np.int64(self.base_ms),

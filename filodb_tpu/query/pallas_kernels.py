@@ -35,13 +35,6 @@ from jax.experimental.pallas import tpu as pltpu
 from filodb_tpu.lint.contracts import ANY, SEM, SMEM, Block, kernel_contract
 from filodb_tpu.lint.numerics import precision
 
-# jax dropped / moved the top-level enable_x64 context manager across
-# versions; resolve whichever this install provides
-if hasattr(jax, "enable_x64"):
-    _enable_x64 = jax.enable_x64
-else:                                                   # jax <= 0.4.x
-    from jax.experimental import enable_x64 as _enable_x64
-
 # int32 sentinel for padded samples: beyond any valid relative timestamp
 TR_PAD = np.int32(2**31 - 1)
 
@@ -545,7 +538,7 @@ def counter_groupsum(func: str, st: int, dspan: int, hi_mode: int,
         num_scalar_prefetch=1,
         grid=(n_s,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((1, 8, _GS_SS), lambda si, p: (si, 0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((_GS_SS, G), lambda si, p: (si, 0),
@@ -581,7 +574,7 @@ def counter_groupsum(func: str, st: int, dspan: int, hi_mode: int,
             interpret=interpret,
         )(params, v_p, base, onehot)
 
-    with _enable_x64(False):
+    with jax.enable_x64(False):
         sums, cnts = body(params, v_p, base, onehot)
     return sums[:nsteps], cnts[:nsteps]
 
@@ -724,7 +717,7 @@ def window_extract(tr: jnp.ndarray, pay: jnp.ndarray,
                             memory_space=pltpu.VMEM)
     # trace the kernel in 32-bit mode: under jax_enable_x64, index-map and
     # literal constants become i64, which Mosaic cannot legalize
-    with _enable_x64(False):
+    with jax.enable_x64(False):
         outs = pl.pallas_call(
             functools.partial(_extract_kernel, C),
             grid=grid,

@@ -59,6 +59,23 @@ def test_counter_parity_bitwise_across_device_counts(ndev, tp, func):
     assert np.array_equal(got, ref, equal_nan=True)
 
 
+@pytest.mark.parametrize("ndev,tp", [(4, 1), (4, 2)])
+def test_placed_store_is_sharded_not_replicated(ndev, tp):
+    """A replicated store gives the same per-series answers as a sharded
+    one, so parity cannot see it: pin the placement itself. The spec
+    names the shard axis and every device holds S_pad / n_shard series
+    of each resident channel."""
+    mesh = _mesh(ndev, tp)
+    st = ShardedTileEvaluator(mesh).place(_tiles(S=13))
+    s_axis = mesh.axis_names[0]
+    n_shard = mesh.shape[s_axis]
+    for chan in (st._tsr, st._v, st._cv):
+        assert chan.sharding.spec == jax.sharding.PartitionSpec(None, s_axis)
+        assert len(chan.addressable_shards) == ndev
+        for sh in chan.addressable_shards:
+            assert sh.data.shape == (st.cap, st.S_pad // n_shard)
+
+
 def test_counter_parity_instant_and_offset():
     tiles = _tiles()
     ev = ShardedTileEvaluator(_mesh(8, 2))
@@ -283,8 +300,7 @@ def test_append_step_is_donated():
     output reuses its sharding."""
     mesh = _mesh(2, 1)
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from filodb_tpu.parallel.mesh import resolve_spec
-    col = NamedSharding(mesh, resolve_spec(mesh, P(None, 0)))
+    col = NamedSharding(mesh, P(None, mesh.axis_names[0]))
     import jax.numpy as jnp
     tsr = jax.device_put(jnp.zeros((64, 8), jnp.int32), col)
     v = jax.device_put(jnp.ones((64, 8)), col)
