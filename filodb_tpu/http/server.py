@@ -1939,6 +1939,10 @@ class FiloHttpServer:
         "filodb_tile_cache_entries": "Device tile-cache entries",
         "filodb_tile_builds_total": "Device tile (re)builds",
         "filodb_tile_cache_hits_total": "Device tile-cache hits",
+        "filodb_fused_aggs_total":
+            "Queries served by the fused group-sum kernel",
+        "filodb_mesh_dispatches_total":
+            "Dispatches served from the mesh-resident sharded store",
         "filodb_exec_cache_hits_total": "Compiled-executable reuse hits",
         "filodb_exec_cache_misses_total": "Compiled-executable retraces",
         "filodb_exec_cache_entries": "Distinct compiled kernel shapes",
@@ -2173,6 +2177,12 @@ class FiloHttpServer:
                  getattr(self.backend, "tile_builds", 0))
             emit("tile_cache_hits_total", {},
                  getattr(self.backend, "tile_hits", 0))
+            # which path served: fused group-sum kernel dispatches and
+            # sharded (mesh-resident) dispatches
+            emit("fused_aggs_total", {},
+                 getattr(self.backend, "fused_aggs", 0))
+            emit("mesh_dispatches_total", {},
+                 getattr(self.backend, "mesh_dispatches", 0))
             # serving fast path: compiled-executable reuse (shape
             # buckets) + micro-batcher occupancy
             exec_stats = getattr(self.backend, "executable_cache_stats",

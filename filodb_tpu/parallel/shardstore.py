@@ -61,6 +61,7 @@ from filodb_tpu.lint.contracts import kernel_contract
 from filodb_tpu.lint.locks import guarded_by
 from filodb_tpu.lint.numerics import order_insensitive, precision
 from filodb_tpu.parallel.mesh import _grouped_reduce, make_mesh
+from filodb_tpu.query.cumsum import cumsum_f64
 
 # cache inventory (graftlint): the sharded-evaluator dispatch table
 # memoizes compiled shard_map programs keyed purely on (kernel family,
@@ -114,7 +115,7 @@ def _append_step(tsr, v, cv, new_tsr, new_v, n_filled):
     corr0 = jax.lax.dynamic_slice_in_dim(cv, n_filled - 1, 1, axis=0) - prev0
     prevs = jnp.concatenate([prev0, new_v[:-1]], axis=0)
     drop = new_v < prevs
-    new_cv = new_v + jnp.cumsum(jnp.where(drop, prevs, 0.0), axis=0) + corr0
+    new_cv = new_v + cumsum_f64(jnp.where(drop, prevs, 0.0), axis=0) + corr0
     tsr = jax.lax.dynamic_update_slice_in_dim(tsr, new_tsr, n_filled, axis=0)
     v = jax.lax.dynamic_update_slice_in_dim(v, new_v, n_filled, axis=0)
     cv = jax.lax.dynamic_update_slice_in_dim(cv, new_cv, n_filled, axis=0)

@@ -41,6 +41,7 @@ import jax.numpy as jnp  # noqa: E402
 from filodb_tpu.lint.capacity import capacity
 from filodb_tpu.lint.contracts import kernel_contract
 from filodb_tpu.lint.numerics import order_insensitive, precision  # noqa: F401
+from filodb_tpu.query.cumsum import cumsum_f64
 from filodb_tpu.query.model import RawSeries
 
 # functions servable from aligned tiles (everything endpoint- or
@@ -125,7 +126,7 @@ class AlignedTiles:
             prev = jnp.concatenate([jnp.full_like(prev[:, :1], jnp.nan),
                                     prev], axis=1)
             drop = valid & (v < prev) & ~jnp.isnan(prev)
-            c = v + jnp.cumsum(jnp.where(drop, prev, 0.0), axis=1)
+            c = v + cumsum_f64(jnp.where(drop, prev, 0.0), axis=1)
             c = jnp.where(valid, c, 0.0)
         elif name in ("ev_change", "ev_reset"):
             # event vs previous valid sample, attributed to the later one
@@ -199,7 +200,7 @@ class AlignedTiles:
         ps[:, k+1] = sum of slots 0..k. Shape [S, N+1]."""
         c = self._ps.get(name)
         if c is None:
-            cs = jnp.cumsum(self.channel(name), axis=1)
+            cs = cumsum_f64(self.channel(name), axis=1)
             c = jnp.concatenate([jnp.zeros_like(cs[:, :1]), cs], axis=1)
             self._ps[name] = c
         return c
@@ -1304,7 +1305,6 @@ def groupsum_counters(tiles: AlignedTiles, func: str, steps: np.ndarray,
     # frontier — accumulators + nbuf x nstreams x mlen scratch + the
     # onehot/base input blocks — and an oversized query must fall back
     # to the general path HERE, not explode at Mosaic compile time
-    nstreams = pk._gs_nstreams(st, hi_mode, lo_mode)
     if pk._gs_pipeline(st, dspan, hi_mode, lo_mode, nsteps, G) is None:
         return None              # no admissible (tt, nbuf) within VMEM
     S_pad = -(-S // pk._GS_SS) * pk._GS_SS
@@ -1313,21 +1313,13 @@ def groupsum_counters(tiles: AlignedTiles, func: str, steps: np.ndarray,
     onehot = jnp.asarray(onehot, jnp.float32)
     if S_pad != S:
         onehot = jnp.pad(onehot, ((0, S_pad - S), (0, 0)))
-    try:
-        return pk.counter_groupsum(
-            func, st, dspan, hi_mode, lo_mode, v_p, base, onehot,
-            k_l0, w0e - tiles.base_ms, window_ms, step, nsteps,
-            interpret=interpret)
-    except Exception:
-        # backstop for shapes the budget model misses: a Mosaic
-        # compile/lowering failure downgrades to the general path
-        # instead of killing the query
-        import logging
-        logging.getLogger(__name__).warning(
-            "fused group-sum kernel failed to compile "
-            "(T=%d G=%d streams=%d); falling back to the general path",
-            nsteps, G, nstreams, exc_info=True)
-        return None
+    # a kernel the chip's compiler refuses fails the query with the
+    # compiler's message: the decided-in-advance route to the general
+    # path is the _gs_pipeline budget check above, never an except
+    return pk.counter_groupsum(
+        func, st, dspan, hi_mode, lo_mode, v_p, base, onehot,
+        k_l0, w0e - tiles.base_ms, window_ms, step, nsteps,
+        interpret=interpret)
 
 
 import functools as _functools

@@ -50,6 +50,7 @@ from filodb_tpu.lint.threads import thread_root
 from filodb_tpu.obs import devprof
 from filodb_tpu.obs import metrics as obs_metrics
 from filodb_tpu.obs import trace as obs_trace
+from filodb_tpu.query.cumsum import cumsum_f64
 from filodb_tpu.query.model import GridResult, RangeParams, RawSeries
 
 _DEV_HELP = ("Wall seconds per device dispatch (kernel submission + "
@@ -231,7 +232,7 @@ def _take(arr, idx):
 def _prefix(x):
     """[S, N] -> [S, N+1] exclusive prefix sums."""
     return jnp.concatenate(
-        [jnp.zeros((x.shape[0], 1), x.dtype), jnp.cumsum(x, axis=1)], axis=1)
+        [jnp.zeros((x.shape[0], 1), x.dtype), cumsum_f64(x, axis=1)], axis=1)
 
 
 def _correction(vals, lens):
@@ -241,7 +242,7 @@ def _correction(vals, lens):
     prev = jnp.concatenate([vals[:, :1], vals[:, :-1]], axis=1)
     dropped = (vals < prev) & valid & (idx[None, :] > 0)
     drops = jnp.where(dropped, prev, 0.0)
-    return jnp.cumsum(drops, axis=1)
+    return cumsum_f64(drops, axis=1)
 
 
 @precision(

@@ -111,6 +111,28 @@ def _rate_query(port):
             for r in body["data"]["result"]}
 
 
+def test_backend_construction_failure_exits_nonzero(tmp_path):
+    """No silent oracle fallback: a node whose device backend cannot be
+    built dies with the reason on stderr instead of serving from numpy."""
+    cfg_path = tmp_path / "server.json"
+    cfg_path.write_text(json.dumps({"num-shards": 1, "port": 0}))
+    script = (
+        "import sys\n"
+        "from filodb_tpu.query import tpu\n"
+        "def boom(self, *a, **k):\n"
+        "    raise RuntimeError('no device for you')\n"
+        "tpu.TpuBackend.__init__ = boom\n"
+        "from filodb_tpu.standalone import server\n"
+        f"sys.exit(server.main(['--config', {str(cfg_path)!r}]))\n")
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    res = subprocess.run([sys.executable, "-c", script], cwd=str(REPO),
+                         env=env, capture_output=True, timeout=120)
+    assert res.returncode != 0
+    assert b"no device for you" in res.stderr
+    assert res.stdout.strip() == b""          # no startup line was printed
+
+
 def test_kill_minus_9_restart_replays_to_identical_results(tmp_path):
     cfg = {
         "num-shards": 2, "groups-per-shard": 2, "port": 0,
@@ -127,6 +149,10 @@ def test_kill_minus_9_restart_replays_to_identical_results(tmp_path):
         ports = _read_ports(proc)
         port, gw_port = ports["port"], ports["gateway_port"]
         assert gw_port is not None
+        # the node says what it runs on, as jax reports it
+        dev = ports["device"]
+        assert dev["platform"] == "cpu" and dev["count"] >= 1
+        assert isinstance(dev["kind"], str) and dev["kind"]
 
         # shards come up ACTIVE (empty streams -> trivial recovery)
         _poll(lambda: ((lambda b: (len(b["data"]) == 2 and all(
