@@ -71,6 +71,17 @@ def _ffill_idx(valid: jnp.ndarray) -> jnp.ndarray:
            "channels (ones/cv/prefix sums/transposes) are lazy "
            "per-function warm caches over the same slot count, not "
            "part of the cold footprint")
+def _counter_corrected(v, valid, ff_v):
+    """Counter-reset corrected value channel [S, N]: every sample plus
+    the running sum of the values the counter dropped from (``ff_v`` =
+    forward-filled previous valid values)."""
+    prev = jnp.concatenate([jnp.full_like(ff_v[:, :1], jnp.nan),
+                            ff_v[:, :-1]], axis=1)
+    drop = valid & (v < prev) & ~jnp.isnan(prev)
+    c = v + cumsum_f64(jnp.where(drop, prev, 0.0), axis=1)
+    return jnp.where(valid, c, 0.0)
+
+
 class AlignedTiles:
     """One cohort of series sharing cadence dt, as device tiles."""
 
@@ -122,12 +133,7 @@ class AlignedTiles:
         elif name == "ts":
             c = jnp.where(valid, self.ts, 0.0)
         elif name == "cv":                      # counter-reset corrected
-            prev = self.ff("v")[:, :-1]
-            prev = jnp.concatenate([jnp.full_like(prev[:, :1], jnp.nan),
-                                    prev], axis=1)
-            drop = valid & (v < prev) & ~jnp.isnan(prev)
-            c = v + cumsum_f64(jnp.where(drop, prev, 0.0), axis=1)
-            c = jnp.where(valid, c, 0.0)
+            c = _counter_corrected(v, valid, self.ff("v"))
         elif name in ("ev_change", "ev_reset"):
             # event vs previous valid sample, attributed to the later one
             # (AggrOverTimeFunctions ChangesChunkedFunction semantics)

@@ -1,0 +1,247 @@
+"""Ask the chip's compiler before the chip: the main path's device
+programs compile for one *described* TPU v5e (no chip attached), from
+shapes only, at the sizes ``chip_smoke.py`` uses (8,192 series x 720
+samples, 16 groups).
+
+These are compiles, never chip runs: they say nothing about results or
+times. They catch what interpret mode cannot — a kernel Mosaic refuses,
+a program that does not fit, a sharding that silently replicates, and
+the f64 cumulative sum whose reduce-window form took the TPU compiler
+minutes (query/cumsum.py).
+
+The topology is described inside a module-scoped fixture and nowhere
+else: only one process may load the TPU library, and every xdist worker
+imports every test file. Keep all such tests in THIS file.
+"""
+
+import functools
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from filodb_tpu.query import pallas_kernels as pk
+from filodb_tpu.query import tilestore as tst
+from filodb_tpu.query import tpu
+from filodb_tpu.query.cumsum import cumsum_f64
+
+S, N, G = 8192, 720, 16            # chip_smoke.py's default store
+S_HOST = pk._GS_SS                 # series of the host-side stand-in tiles
+BASE, DT = 1_600_000_000_000, 10_000
+W, STEP, T = 300_000, 60_000, 100  # 5m windows, 1m steps, ~100 steps
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:      # noqa: BLE001 — any failure means "no compiler here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-device compile is written to the persistent cache but
+    # can never be read back without the chip: keep it off for this file
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _tiles(jitter_ms):
+    """Stand-in tiles built here on the CPU, only to ask the dispatchers
+    which arrays they pass: S_HOST series (one kernel lane tile) — the
+    tests widen every series dimension to S before compiling."""
+    rng = np.random.default_rng(11)
+    ts = BASE + np.arange(N, dtype=np.float64)[None, :] * DT
+    if jitter_ms:
+        ts = ts + rng.integers(-jitter_ms, jitter_ms + 1, (S_HOST, N))
+    else:
+        ts = np.broadcast_to(ts, (S_HOST, N))
+    vals = np.cumsum(rng.integers(0, 50, (S_HOST, N)).astype(np.float64),
+                     axis=1)
+    return tst.AlignedTiles([{"i": str(i)} for i in range(S_HOST)], BASE,
+                            DT, np.ones((S_HOST, N), bool), ts, vals)
+
+
+@pytest.fixture(scope="module")
+def jittered():
+    return _tiles(2000)
+
+
+def _steps():
+    return BASE + 600_000 + np.arange(T, dtype=np.int64) * STEP
+
+
+def _shapes(tree, sharding):
+    """Arrays/scalars -> ShapeDtypeStructs on the described device, with
+    every S_HOST-sized (series) dimension widened to the real S."""
+    def sds(a):
+        a = np.asarray(a) if not hasattr(a, "shape") else a
+        shape = tuple(S if d == S_HOST else d for d in a.shape)
+        return jax.ShapeDtypeStruct(shape, a.dtype, sharding=sharding)
+    return jax.tree_util.tree_map(sds, tree)
+
+
+def _compile(fn, *shapes):
+    t0 = time.monotonic()
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    return compiled, time.monotonic() - t0
+
+
+# -- (b) the f64 cumulative sum, first of all ---------------------------------
+
+@pytest.mark.parametrize("shape,axis", [((S, N), 1), ((64, S), 0),
+                                        ((4096, 4096), 1)])
+def test_cumsum_f64_compiles_in_seconds(one_chip, shape, axis):
+    x = jax.ShapeDtypeStruct(shape, jnp.float64, sharding=one_chip)
+    compiled, secs = _compile(lambda v: cumsum_f64(v, axis), x)
+    assert secs < 30, f"f64 cumulative sum took {secs:.0f}s to compile"
+    # still f64, and no reduce-window crept back in
+    assert "f64" in compiled.as_text() or "u32" in compiled.as_text()
+    assert "reduce-window" not in compiled.as_text()
+
+
+def test_cumsum_f64_is_sequential_f64():
+    """Left-to-right f64 adds: bit-for-bit np.cumsum on any backend."""
+    x = np.random.default_rng(0).normal(0, 1e9, (37, 721))
+    for axis in (0, 1):
+        got = np.asarray(jax.jit(functools.partial(cumsum_f64, axis=axis))(x))
+        assert got.dtype == np.float64
+        np.testing.assert_array_equal(got, np.cumsum(x, axis=axis))
+
+
+def test_tile_build_corrected_channel_compiles(one_chip):
+    v = jax.ShapeDtypeStruct((S, N), jnp.float64, sharding=one_chip)
+    valid = jax.ShapeDtypeStruct((S, N), jnp.bool_, sharding=one_chip)
+    _, secs = _compile(tst._counter_corrected, v, valid, v)
+    assert secs < 30
+
+
+def test_pallas_rate_impl_compiles_end_to_end(one_chip):
+    """The irregular-cadence rate path a non-CPU backend switches to:
+    counter correction + exact 3xf32 split + the Pallas boundary-extract
+    kernel + the f64 extrapolation, as ONE program."""
+    s, n, t = 4096, 4096, 512
+    sh = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    i64 = sh((), jnp.int64)
+    lowered = tpu._pallas_rate_impl.lower(
+        "rate", t, False, sh((s, n), jnp.int64), sh((s, n), jnp.float64),
+        sh((s,), jnp.int32), i64, i64, i64)
+    t0 = time.monotonic()
+    compiled = lowered.compile()
+    assert time.monotonic() - t0 < 60
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- (a) the fused group-sum kernel, with the dispatcher's own args -----------
+
+@pytest.mark.parametrize("jitter_ms,nstreams", [(0, 1), (2000, 3)])
+def test_counter_groupsum_compiles(one_chip, monkeypatch, jittered,
+                                   jitter_ms, nstreams):
+    tiles = jittered if jitter_ms else _tiles(0)
+    onehot = np.zeros((S_HOST, G), np.float32)
+    onehot[np.arange(S_HOST), np.arange(S_HOST) % G] = 1.0
+    seen = {}
+
+    def capture(*args, **kw):
+        seen["args"], seen["kw"] = args, kw
+        return None
+    monkeypatch.setattr(pk, "counter_groupsum", capture)
+    assert tst.groupsum_counters(tiles, "rate", _steps(), W, onehot) is None
+    monkeypatch.undo()
+    func, st, dspan, hi, lo, v_p, base, oh = seen["args"][:8]
+    scalars = seen["args"][8:]
+    assert pk._gs_nstreams(st, hi, lo) == nstreams
+
+    def run(v_p, base, oh):
+        return pk.counter_groupsum(func, st, dspan, hi, lo, v_p, base, oh,
+                                   *scalars)
+    # the kernel's series dimension is its grid: n_s lane tiles of _GS_SS
+    n_s = S // pk._GS_SS
+    assert v_p.shape[0] == base.shape[0] == 1
+    sh = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled, _ = _compile(
+        run, sh((n_s,) + v_p.shape[1:], v_p.dtype),
+        sh((n_s,) + base.shape[1:], base.dtype),
+        sh((S, G), jnp.float32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- (c) the per-series aligned evaluators, as tilestore jits them ------------
+
+def _grid_args():
+    steps = _steps()
+    w0e = int(steps[0])
+    return (np.int64(N), np.int64(BASE), np.int64(DT), np.int64(w0e - W),
+            np.int64(w0e), np.int64(STEP))
+
+
+def test_eval_counter_fast_compiles(one_chip, jittered):
+    arrs = tst._tiles_arrays_fast(jittered, "rate")
+    fn = functools.partial(tst._eval_counter_fast, "rate", T)
+    _compile(fn, *_shapes((arrs,) + _grid_args(), one_chip))
+
+
+def test_eval_counter_slide_compiles(one_chip, jittered):
+    st = STEP // DT
+    arrs = tst._tiles_arrays_slide(jittered, "rate", st)
+    fn = functools.partial(tst._eval_counter_slide, "rate", T, st)
+    _compile(fn, *_shapes((arrs,) + _grid_args(), one_chip))
+
+
+def test_eval_core_gauge_family_compiles(one_chip, jittered):
+    """avg_over_time: the aligned gauge family (f64 prefix sums)."""
+    arrs = tst._tiles_arrays(jittered, "avg_over_time")
+    fn = functools.partial(tst._eval_core, "avg_over_time", T)
+    _compile(fn, *_shapes((arrs,) + _grid_args(), one_chip))
+
+
+def test_packed_gather_max_over_time_compiles(one_chip):
+    """max_over_time is an order statistic: it rides the packed gather
+    kernel, not the aligned tiles — at the smoke's <= 64-series subset."""
+    s, n, t_bucket, w_bound = 64, 1024, 128, 32
+    sh = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    i64 = sh((), jnp.int64)
+    tpu._window_gather.lower(
+        "max_over_time", w_bound, sh((s, n), jnp.int64),
+        sh((s, n), jnp.float64), sh((s,), jnp.int32), i64, i64, i64,
+        t_bucket, 0.0).compile()
+
+
+# -- (d) the path across chips: sharded, with a collective --------------------
+
+def test_grouped_pair_on_four_chips_is_sharded_with_collective(topo):
+    from filodb_tpu.parallel.shardstore import _build_grouped_pair_eval
+    mesh = Mesh(np.asarray(topo.devices).reshape(4, 1), ("shard", "time"))
+    cap = 1024                                   # pow2 slot capacity
+    col = NamedSharding(mesh, P(None, "shard"))
+    row = NamedSharding(mesh, P("shard"))
+    rep = NamedSharding(mesh, P())
+    i64 = jax.ShapeDtypeStruct((), jnp.int64, sharding=rep)
+    args = (jax.ShapeDtypeStruct((cap, S), jnp.int32, sharding=col),
+            jax.ShapeDtypeStruct((cap, S), jnp.float64, sharding=col),
+            jax.ShapeDtypeStruct((S,), jnp.int32, sharding=row),
+            i64, i64, i64, i64, i64, i64)
+    compiled = _build_grouped_pair_eval(mesh, "rate", T, G).lower(
+        *args).compile()
+    # the psum over the shard axis: an all-reduce, which this compiler
+    # spells for emulated f64 as an async all-gather + local adds
+    text = compiled.as_text()
+    assert any(c in text for c in ("all-reduce", "all-gather")), \
+        "no cross-chip collective in the grouped program"
+    whole = cap * S * (4 + 8) + S * 4
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    assert 0.2 * whole < per_device < 0.3 * whole, (per_device, whole)
