@@ -134,6 +134,9 @@ class PartKey:
     # Layout: u16 schema_id, u16 numPairs, then per pair (u16 klen, bytes,
     # u16 vlen, bytes), UTF-8.
     def to_bytes(self) -> bytes:
+        cached = self.__dict__.get("_bytes")    # immutable: encode once
+        if cached is not None:
+            return cached
         out = bytearray(struct.pack("<HH", self.schema_id, len(self.labels)))
         for k, v in self.labels:
             kb, vb = k.encode(), v.encode()
@@ -141,10 +144,27 @@ class PartKey:
             out.extend(kb)
             out.extend(struct.pack("<H", len(vb)))
             out.extend(vb)
-        return bytes(out)
+        object.__setattr__(self, "_bytes", bytes(out))
+        return self._bytes
 
     @staticmethod
     def from_bytes(buf: bytes) -> "PartKey":
+        """Decode the canonical form. Interned by content: a WAL row of a
+        series seen before costs one dict lookup and yields the SAME
+        object (the identity fast path of run detection and the
+        partition map)."""
+        buf = bytes(buf)
+        pk = _PK_INTERN.get(buf)
+        if pk is None:
+            pk = PartKey._parse(buf)
+            object.__setattr__(pk, "_bytes", buf)
+            if len(_PK_INTERN) >= _PK_INTERN_MAX:
+                _PK_INTERN.clear()
+            _PK_INTERN[buf] = pk
+        return pk
+
+    @staticmethod
+    def _parse(buf: bytes) -> "PartKey":
         schema_id, npairs = struct.unpack_from("<HH", buf, 0)
         off = 4
         pairs = []
@@ -159,6 +179,11 @@ class PartKey:
             off += vlen
             pairs.append((k, v))
         return PartKey(schema_id, tuple(pairs))
+
+
+# canonical bytes -> PartKey (bounded; cleared wholesale when full)
+_PK_INTERN: Dict[bytes, PartKey] = {}
+_PK_INTERN_MAX = 2_000_000
 
 
 # ---------------------------------------------------------------------------
@@ -284,6 +309,16 @@ class RecordBuilder:
         cont = self._containers.setdefault(schema_name, RecordContainer(schema))
         cont.add(pk, timestamp, *values)
         return pk
+
+    def add_keyed(self, schema_name: str, part_key: PartKey,
+                  timestamp: int, *values) -> None:
+        """add_sample for a caller that already holds the series'
+        PartKey (the gateway's per-series route cache)."""
+        cont = self._containers.get(schema_name)
+        if cont is None:
+            cont = self._containers[schema_name] = RecordContainer(
+                self.schemas.by_name(schema_name))
+        cont.add(part_key, timestamp, *values)
 
     def containers(self) -> List[RecordContainer]:
         out = [c for c in self._containers.values() if len(c)]

@@ -54,6 +54,8 @@ class GatewayServer:
         self.batch_lines = batch_lines
         self.ws, self.ns = ws, ns
         self.part_schema = PartitionSchema()
+        # (line identity, field name) -> (schema name, PartKey, shard)
+        self._routes: Dict = {}
         self._stats_lock = threading.Lock()
         self.lines_ingested = 0
         self.lines_rejected = 0
@@ -89,10 +91,42 @@ class GatewayServer:
         self._thread: Optional[threading.Thread] = None
 
     # -- routing -----------------------------------------------------------
+    # single-field lines without escapes or quotes — what a scraper sends
+    _FAST_FIELDS = frozenset({"counter", "gauge", "value"})
+    _ROUTE_CACHE_MAX = 2_000_000
+
     def _route_line(self, line: str, builders: Dict[int, RecordBuilder]
                     ) -> bool:
         """Parse one line, append each resulting sample to its shard's
-        builder (GatewayServer.scala:120 shardKeyHash->ingestionShard)."""
+        builder (GatewayServer.scala:120 shardKeyHash->ingestionShard).
+
+        A series' identity (measurement + tags) decides its schema,
+        part key and shard, and never changes: the common single-field
+        line resolves them once per series (``_routes``) and afterwards
+        parses only the value and the timestamp."""
+        parts = line.split(" ")
+        if len(parts) == 3 and "\\" not in line and '"' not in line:
+            ident, field, ts_raw = parts
+            fname, _, fval = field.partition("=")
+            if fname in self._FAST_FIELDS and "," not in fval:
+                try:
+                    route = self._routes.get((ident, fname))
+                    if route is None:
+                        route = self._resolve(line, ident, fname)
+                    value = float(fval[:-1] if fval.endswith("i") else fval)
+                    ts = int(ts_raw) // 1_000_000
+                except ValueError:
+                    with self._stats_lock:
+                        self.lines_rejected += 1
+                    return False
+                schema_name, pk, shard = route
+                b = builders.get(shard)
+                if b is None:
+                    b = builders[shard] = RecordBuilder(self.schemas)
+                b.add_keyed(schema_name, pk, ts, value)
+                with self._stats_lock:
+                    self.lines_ingested += 1
+                return True
         try:
             rec = parse_line(line)
             samples = input_records(rec, self.ws, self.ns)
@@ -101,21 +135,35 @@ class GatewayServer:
                 self.lines_rejected += 1
             return False
         for schema_name, labels, ts, values in samples:
-            schema = self.schemas.by_name(schema_name)
-            pk = PartKey.make(schema, labels)
-            if self.spread_provider is not None:
-                spread = self.spread_provider.spread_for_labels(
-                    labels, self.part_schema.non_metric_shard_key_columns)
-            else:
-                spread = self.spread
-            shard = ingestion_shard(pk.shard_key_hash(self.part_schema),
-                                    pk.part_hash(), spread,
-                                    self.num_shards)
+            _, _, shard = self._route(schema_name, labels)
             b = builders.setdefault(shard, RecordBuilder(self.schemas))
             b.add_sample(schema_name, labels, ts, *values)
         with self._stats_lock:
             self.lines_ingested += 1
         return True
+
+    def _route(self, schema_name: str, labels: Dict[str, str]):
+        """(schema name, part key, ingestion shard) of one series."""
+        pk = PartKey.make(self.schemas.by_name(schema_name), labels)
+        if self.spread_provider is not None:
+            spread = self.spread_provider.spread_for_labels(
+                labels, self.part_schema.non_metric_shard_key_columns)
+        else:
+            spread = self.spread
+        shard = ingestion_shard(pk.shard_key_hash(self.part_schema),
+                                pk.part_hash(), spread, self.num_shards)
+        return schema_name, pk, shard
+
+    def _resolve(self, line: str, ident: str, fname: str):
+        """First sight of a series on the fast path: resolve its route
+        through the general parser and remember it."""
+        samples = input_records(parse_line(line), self.ws, self.ns)
+        (schema_name, labels, _, _), = samples
+        route = self._route(schema_name, labels)
+        if len(self._routes) >= self._ROUTE_CACHE_MAX:
+            self._routes.clear()
+        self._routes[(ident, fname)] = route
+        return route
 
     def _publish(self, builders: Dict[int, RecordBuilder],
                  raise_on_error: bool = False) -> None:

@@ -11,6 +11,7 @@ fallback when no compiler is available.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import sys
@@ -26,6 +27,23 @@ _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
+def _src_hash() -> str:
+    with open(_SRC, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+def _is_fresh(lib_path: str) -> bool:
+    """The library beside a hash file naming the source it was built
+    from. Not mtime: a copy or a checkout does not keep it, and the
+    library is git-ignored, so only the hash ties it to the committed
+    source (a library without its hash file is rebuilt)."""
+    try:
+        with open(lib_path + ".sha256") as f:
+            return os.path.exists(lib_path) and f.read().strip() == _src_hash()
+    except OSError:
+        return False
+
+
 def _build(lib_path: str) -> bool:
     """Compile the codec; atomic rename so concurrent builders are safe."""
     fd, tmp = tempfile.mkstemp(suffix=".so", dir=_DIR)
@@ -35,6 +53,9 @@ def _build(lib_path: str) -> bool:
             ["g++", "-O3", "-shared", "-fPIC", "-o", tmp, _SRC],
             check=True, capture_output=True, timeout=120)
         os.replace(tmp, lib_path)
+        with open(tmp + ".sha256", "w") as f:
+            f.write(_src_hash())
+        os.replace(tmp + ".sha256", lib_path + ".sha256")
         return True
     except (OSError, subprocess.SubprocessError):
         try:
@@ -53,10 +74,8 @@ def load_nibblepack() -> Optional[ctypes.CDLL]:
             return _lib
         _tried = True
         lib_path = os.path.join(_DIR, _LIB_NAME)
-        fresh = (os.path.exists(lib_path)
-                 and os.path.getmtime(lib_path) >= os.path.getmtime(_SRC))
         # graftlint: disable=lock-blocking-reachable (one-time native build on first use; the lock exists to prevent duplicate concurrent compiles)
-        if not fresh and not _build(lib_path):
+        if not _is_fresh(lib_path) and not _build(lib_path):
             return None
         try:
             lib = ctypes.CDLL(lib_path)
