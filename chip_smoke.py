@@ -40,6 +40,7 @@ import subprocess
 import sys
 import tempfile
 import time
+import traceback
 import urllib.error
 import urllib.parse
 import urllib.request
@@ -214,6 +215,20 @@ DEVICE_FAMILIES = ("filodb_fused_aggs_total", "filodb_mesh_dispatches_total",
                    "filodb_tile_builds_total", "filodb_tile_cache_hits_total")
 
 
+def served_by(delta):
+    """Which device path the counters say served a query."""
+    paths = []
+    if delta.get("filodb_mesh_dispatches_total"):
+        paths.append("mesh-resident sharded store")
+    elif delta.get("filodb_fused_aggs_total"):
+        paths.append("fused group-sum kernel")
+    if delta.get("filodb_device_execute_seconds_count"):
+        tiles = (delta.get("filodb_tile_builds_total")
+                 or delta.get("filodb_tile_cache_hits_total"))
+        paths.append("aligned tiles" if tiles else "packed kernels")
+    return paths or ["none: host only"]
+
+
 def device_delta(before, after):
     return {f: after.get(f, 0) - before.get(f, 0) for f in DEVICE_FAMILIES
             if after.get(f, 0) != before.get(f, 0)}
@@ -365,7 +380,8 @@ def run_query(port, name, query, want, start_s, end_s, step_s, rtol,
           "series": len(want), "steps": len(steps_s),
           "cold_s": secs[0], "warm_s": secs[1],
           "max_rel_err": max(errs), "rtol": rtol,
-          "device_counters": delta, "ok": ok})
+          "served_by": served_by(delta), "device_counters": delta,
+          "ok": ok})
 
 
 EXEC = ("filodb_device_execute_seconds_count", "filodb_exec_cache_hits_total",
@@ -476,17 +492,17 @@ def main():
            "data-dir": os.path.join(workdir, "data"),
            "stream-dir": os.path.join(workdir, "streams"),
            "flush-interval-s": FLUSH_S,
-           # a cold node compiles every program on its first queries
+           # a cold node compiles every program on its first queries;
+           # the all-series `sum by` scans the whole store by design
            "query-timeout-s": 900.0,
+           "query-sample-limit": 0, "query-series-limit": 0,
            "mesh-enabled": args.chips > 1}
     device, proc = None, start_node(cfg, workdir)
     try:
         t0 = time.monotonic()
         line = read_startup(proc, 300)
         if line is None:
-            check("node", False, "no startup line; see stderr below")
-            with open(os.path.join(workdir, "node.stderr")) as f:
-                sys.stderr.write(f.read()[-4000:])
+            check("node", False, "the node printed no startup line")
             return finish(device)
         device = line.get("device")
         port, gw_port = line["port"], line["gateway_port"]
@@ -519,8 +535,15 @@ def main():
                   "rows_ingested": m.get("filodb_rows_ingested")})
             now_phases(port, world)
         check("node", proc.poll() is None, "the node died during the run")
+    except Exception as e:      # noqa: BLE001 — any phase's error fails the run
+        traceback.print_exc()
+        check("run", False, f"{type(e).__name__}: {e}"[:500])
     finally:
         stop_node(proc)
+        if _failed:
+            with open(os.path.join(workdir, "node.stderr")) as f:
+                sys.stderr.write("---- node stderr (tail) ----\n"
+                                 + f.read()[-6000:] + "\n")
         shutil.rmtree(workdir, ignore_errors=True)
     emit({"phase": "done", "seconds": time.monotonic() - t_all,
           "failed": _failed})

@@ -199,6 +199,9 @@ class IngestRecord:
     values: Tuple  # data column values in schema order (floats / hist arrays)
 
 
+@single_writer("a RecordContainer is filled by ONE thread: the producer "
+               "that owns its RecordBuilder, or the WAL reader decoding "
+               "it; consumers only read it after the hand-off")
 @dataclass
 class RecordContainer:
     """A batch of ingest records for one schema — the unit handed to the

@@ -31,7 +31,7 @@ from filodb_tpu.ingest.stream import IngestionStream
 
 
 @guarded_by("_stats_lock", "lines_ingested", "lines_rejected",
-            "batches_dropped")
+            "batches_dropped", "_routes")
 class GatewayServer:
     """TCP ingest edge, one instance per gateway process.
 
@@ -110,7 +110,8 @@ class GatewayServer:
             fname, _, fval = field.partition("=")
             if fname in self._FAST_FIELDS and "," not in fval:
                 try:
-                    route = self._routes.get((ident, fname))
+                    with self._stats_lock:
+                        route = self._routes.get((ident, fname))
                     if route is None:
                         route = self._resolve(line, ident, fname)
                     value = float(fval[:-1] if fval.endswith("i") else fval)
@@ -160,9 +161,10 @@ class GatewayServer:
         samples = input_records(parse_line(line), self.ws, self.ns)
         (schema_name, labels, _, _), = samples
         route = self._route(schema_name, labels)
-        if len(self._routes) >= self._ROUTE_CACHE_MAX:
-            self._routes.clear()
-        self._routes[(ident, fname)] = route
+        with self._stats_lock:
+            if len(self._routes) >= self._ROUTE_CACHE_MAX:
+                self._routes.clear()
+            self._routes[(ident, fname)] = route
         return route
 
     def _publish(self, builders: Dict[int, RecordBuilder],

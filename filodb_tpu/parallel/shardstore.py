@@ -148,8 +148,8 @@ def _sharded_counter_check():
     check=_sharded_counter_check,
     rel_time_bits=31, span_guard="ShardedTiles.query_fits",
     notes="slot-major counter fast path lowered over the ('shard','time')"
-          " mesh from device-resident sharded tiles; positional "
-          "PartitionSpecs, per-device step-grid slices via axis_index; "
+          " mesh from device-resident sharded tiles; PartitionSpecs "
+          "name mesh.axis_names, per-device step-grid slices via axis_index; "
           "bit-for-bit the single-device _eval_counter_fast values")
 def _build_counter_eval(mesh: Mesh, func: str, nsteps_local: int,
                         batch: int):

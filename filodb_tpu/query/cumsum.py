@@ -19,11 +19,14 @@ def cumsum_f64(x: jnp.ndarray, axis: int) -> jnp.ndarray:
     """Inclusive cumulative sum of ``x`` along ``axis`` as a sequential
     scan (one carried row, ``x.shape[axis]`` steps)."""
     rows = jnp.moveaxis(x, axis, 0)
+    if rows.shape[0] == 0:
+        return x
 
     def step(carry, row):
         carry = carry + row
         return carry, carry
 
-    _, out = jax.lax.scan(step, jnp.zeros(rows.shape[1:], x.dtype), rows,
-                          unroll=8)
-    return jnp.moveaxis(out, 0, axis)
+    # the carry starts as the first row (not as fresh zeros): it then has
+    # x's own type under shard_map / vmap, whatever axes x varies over
+    _, out = jax.lax.scan(step, rows[0], rows[1:], unroll=8)
+    return jnp.moveaxis(jnp.concatenate([rows[:1], out], axis=0), 0, axis)

@@ -2,7 +2,8 @@
 via tilestore.groupsum_counters): parity vs the per-series transposed
 evaluator + numpy grouping on jittered huge-counter data with resets,
 plus dispatcher fallbacks. Runs in interpret mode on the CPU test mesh;
-the real-TPU compile path is exercised by bench.py.
+the real-TPU compile is asked of the chip's compiler in
+tests/test_tpu_compile.py and run on the chip by chip_smoke.py.
 
 (Reference semantics: rangefn/RateFunctions.scala:23-79 extrapolated
 rate; the grouping matches exec/AggrOverRangeVectors sum-by.)"""

@@ -1,4 +1,6 @@
-"""Pallas window-extract kernel parity tests (interpret mode on CPU).
+"""Pallas window-extract kernel parity tests (interpret mode on CPU; the
+real-TPU compile is asked of the chip's compiler in
+tests/test_tpu_compile.py and run on the chip by chip_smoke.py).
 
 Brute-force oracle over random ragged series incl. duplicate timestamps,
 boundary-coincident samples and empty windows."""

@@ -18,6 +18,24 @@ from filodb_tpu.standalone.bus import (BusClient, SupervisorBus,
 from filodb_tpu.standalone.supervisor import split_quota, worker_config
 
 
+# -- launchers stay off JAX: a parent that touched it would hold the chip --
+
+@pytest.mark.parametrize("module", [
+    "filodb_tpu.standalone.supervisor", "chip_smoke", "bench_e2e"])
+def test_launcher_import_does_not_import_jax(module):
+    """The supervisor, the chip smoke and the e2e bench start node
+    processes that need the chip; importing them must leave JAX alone."""
+    import pathlib
+    import subprocess
+    import sys
+    repo = pathlib.Path(__file__).resolve().parent.parent
+    res = subprocess.run(
+        [sys.executable, "-c",
+         f"import sys, {module}; sys.exit('jax' in sys.modules)"],
+        cwd=str(repo), capture_output=True, timeout=120)
+    assert res.returncode == 0, res.stderr.decode()[-2000:]
+
+
 # -- admission quota: global across workers, not Nx ------------------------
 
 def test_split_quota_preserves_aggregate_bound():
