@@ -300,7 +300,8 @@ def prom_matrix(body):
 
 
 def ref_subset(world, query, sel, n, start_s, step_s, end_s, gauge=False):
-    """refeval over the selected series' first n scrapes -> {instance: row}."""
+    """refeval over the selected series' first n scrapes -> {instance: row};
+    start/end are seconds from T0, as everywhere in the query phases."""
     labels = world.g_labels if gauge else world.labels
     ts = world.g_ts if gauge else world.ts
     vals = world.g_vals if gauge else world.vals
@@ -309,8 +310,19 @@ def ref_subset(world, query, sel, n, start_s, step_s, end_s, gauge=False):
                          **labels[i]},
                         ts[i, :n].tolist(), vals[i, :n].tolist())
               for i in sel]
-    got = ref_eval(query, series, start_s, step_s, end_s)
+    t0_s = T0_MS // 1000
+    got = ref_eval(query, series, t0_s + start_s, step_s, t0_s + end_s)
     return {dict(k)["instance"]: np.array(row) for k, row in got.items()}
+
+
+def counter_subset(world, job, cadence):
+    """(PromQL, series indices) of one (job, cadence, rack 0) selection:
+    at most 64 series, small enough for the pure-Python reference."""
+    sel = [i for i in range(world.S) if world.job[i] == job
+           and world.rack[i] == 0
+           and world.jittered[i] == (cadence == "jitter")]
+    return (f'rate(http_requests_total{{job="job-{job:02d}",'
+            f'cadence="{cadence}",rack="r0"}}[5m])'), sel
 
 
 def ref_sum_by_job(world, n, start_s, step_s, end_s, window_ms):
@@ -330,7 +342,7 @@ def ref_sum_by_job(world, n, start_s, step_s, end_s, window_ms):
             for j in range(JOBS)}
 
 
-def compare(got, want, steps_s, rtol):
+def compare(got, want, steps_s):
     """Max relative error over every (series, step); inf on any mismatch
     of series set, step grid or NaN pattern."""
     if set(got) != set(want):
@@ -370,7 +382,7 @@ def run_query(port, name, query, want, start_s, end_s, step_s, rtol,
         t0 = time.monotonic()
         body = http_get(port, path, cache="false", **params)
         secs.append(time.monotonic() - t0)
-        errs.append(compare(prom_matrix(body), want, steps_s, rtol))
+        errs.append(compare(prom_matrix(body), want, steps_s))
     delta = device_delta(before, metrics(port))
     ok = check(name, max(errs) <= rtol,
                f"max relative error {max(errs):.3g} > {rtol:g}")
@@ -399,32 +411,25 @@ def query_phases(port, world, n_prefix, chips):
               start, end, 60, RTOL_F32,
               ["filodb_fused_aggs_total"] if chips == 1 else
               ["filodb_mesh_dispatches_total"])
-    for cadence in ("tick", "jitter"):
-        sel = [i for i in range(world.S) if world.job[i] == 3
-               and world.rack[i] == 0
-               and world.jittered[i] == (cadence == "jitter")]
-        q = (f'rate(http_requests_total{{job="job-03",cadence="{cadence}",'
-             f'rack="r0"}}[5m])')
+    # four chips: the cross-chip path and what it is compared with, only
+    for cadence in ("tick", "jitter") if chips == 1 else ("tick",):
+        q, sel = counter_subset(world, 3, cadence)
         run_query(port, f"rate_{cadence}_series", q,
-                  ref_subset(world, q, sel, n_prefix, T0_MS // 1000 + start,
-                             60, T0_MS // 1000 + end),
+                  ref_subset(world, q, sel, n_prefix, start, 60, end),
                   start, end, 60, RTOL_F32,
                   EXEC if chips == 1 else ["filodb_mesh_dispatches_total"])
-        if chips != 1:
-            return                      # the cross-chip path and its twin only
+    if chips != 1:
+        return
     q = 'max_over_time(node_load1{job="job-05",rack="r0"}[5m])'
     gsel = [i for i in range(world.Sg)
             if i % JOBS == 5 and world.g_rack[i] == 0]
     run_query(port, "max_over_time_gauges", q,
-              ref_subset(world, q, gsel, n_prefix, T0_MS // 1000 + start, 60,
-                         T0_MS // 1000 + end, gauge=True),
+              ref_subset(world, q, gsel, n_prefix, start, 60, end,
+                         gauge=True),
               start, end, 60, RTOL_F64, EXEC)
-    q = 'rate(http_requests_total{job="job-07",cadence="tick",rack="r0"}[5m])'
-    sel = [i for i in range(world.S) if world.job[i] == 7
-           and world.rack[i] == 0 and not world.jittered[i]]
+    q, sel = counter_subset(world, 7, "tick")
     run_query(port, "rate_instant", q,
-              ref_subset(world, q, sel, n_prefix, T0_MS // 1000 + end, 60,
-                         T0_MS // 1000 + end),
+              ref_subset(world, q, sel, n_prefix, end, 60, end),
               end, end, 60, RTOL_F32, EXEC, instant=True)
 
 
@@ -438,13 +443,9 @@ def now_phases(port, world):
               ref_sum_by_job(world, world.N, start, 60, end, 300_000),
               start, end, 60, RTOL_F32,
               EXEC + ("filodb_fused_aggs_total",))
-    q = ('rate(http_requests_total{job="job-03",cadence="jitter",'
-         'rack="r0"}[5m])')
-    sel = [i for i in range(world.S) if world.job[i] == 3
-           and world.rack[i] == 0 and world.jittered[i]]
+    q, sel = counter_subset(world, 3, "jitter")
     run_query(port, "rate_jitter_series_at_now", q,
-              ref_subset(world, q, sel, world.N, T0_MS // 1000 + start, 60,
-                         T0_MS // 1000 + end),
+              ref_subset(world, q, sel, world.N, start, 60, end),
               start, end, 60, RTOL_F32, EXEC)
 
 

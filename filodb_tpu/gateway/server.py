@@ -136,7 +136,7 @@ class GatewayServer:
                 self.lines_rejected += 1
             return False
         for schema_name, labels, ts, values in samples:
-            _, _, shard = self._route(schema_name, labels)
+            _, shard = self._route(schema_name, labels)
             b = builders.setdefault(shard, RecordBuilder(self.schemas))
             b.add_sample(schema_name, labels, ts, *values)
         with self._stats_lock:
@@ -144,7 +144,7 @@ class GatewayServer:
         return True
 
     def _route(self, schema_name: str, labels: Dict[str, str]):
-        """(schema name, part key, ingestion shard) of one series."""
+        """(part key, ingestion shard) of one series."""
         pk = PartKey.make(self.schemas.by_name(schema_name), labels)
         if self.spread_provider is not None:
             spread = self.spread_provider.spread_for_labels(
@@ -153,14 +153,14 @@ class GatewayServer:
             spread = self.spread
         shard = ingestion_shard(pk.shard_key_hash(self.part_schema),
                                 pk.part_hash(), spread, self.num_shards)
-        return schema_name, pk, shard
+        return pk, shard
 
     def _resolve(self, line: str, ident: str, fname: str):
         """First sight of a series on the fast path: resolve its route
         through the general parser and remember it."""
         samples = input_records(parse_line(line), self.ws, self.ns)
         (schema_name, labels, _, _), = samples
-        route = self._route(schema_name, labels)
+        route = (schema_name,) + self._route(schema_name, labels)
         with self._stats_lock:
             if len(self._routes) >= self._ROUTE_CACHE_MAX:
                 self._routes.clear()

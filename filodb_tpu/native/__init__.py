@@ -53,9 +53,10 @@ def _build(lib_path: str) -> bool:
             ["g++", "-O3", "-shared", "-fPIC", "-o", tmp, _SRC],
             check=True, capture_output=True, timeout=120)
         os.replace(tmp, lib_path)
-        with open(tmp + ".sha256", "w") as f:
+        # a reader that sees the new library beside an old or half-written
+        # hash only rebuilds once more
+        with open(lib_path + ".sha256", "w") as f:
             f.write(_src_hash())
-        os.replace(tmp + ".sha256", lib_path + ".sha256")
         return True
     except (OSError, subprocess.SubprocessError):
         try:

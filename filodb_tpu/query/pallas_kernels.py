@@ -44,7 +44,6 @@ TR_PAD = np.int32(2**31 - 1)
 _BS = 8
 _TT = 128
 _TC = 32
-_LANES = 128
 
 
 def split3(v: jnp.ndarray) -> jnp.ndarray:
@@ -699,12 +698,10 @@ def window_extract(tr: jnp.ndarray, pay: jnp.ndarray,
     S, C, N = pay.shape
     T_pad = -(-nsteps // _TT) * _TT
     S_pad = -(-S // _BS) * _BS
-    N_pad = -(-N // _LANES) * _LANES     # whole lane tiles (TR_PAD = no sample)
-    if S_pad != S or N_pad != N:
-        tr = jnp.pad(tr, ((0, S_pad - S), (0, N_pad - N)),
+    if S_pad != S:
+        tr = jnp.pad(tr, ((0, S_pad - S), (0, 0)),
                      constant_values=TR_PAD)
-        pay = jnp.pad(pay, ((0, S_pad - S), (0, 0), (0, N_pad - N)))
-        N = N_pad
+        pay = jnp.pad(pay, ((0, S_pad - S), (0, 0), (0, 0)))
     params = jnp.array([[step, window]], dtype=jnp.int32)
     grid = (S_pad // _BS, T_pad // _TT)
     out_shapes = (

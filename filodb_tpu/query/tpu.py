@@ -524,16 +524,30 @@ class _TileEntry:
     plus the coverage bound that makes stale serves correct."""
 
     __slots__ = ("tiles", "idx", "prefix_has_nan", "refs", "cov_min_ms",
-                 "ident_key")
+                 "end_min_ms", "ident_key")
 
     def __init__(self, tiles, idx, prefix_has_nan, refs, cov_min_ms,
-                 ident_key=None):
+                 end_min_ms=None, ident_key=None):
         self.tiles = tiles
         self.idx = idx
         self.prefix_has_nan = prefix_has_nan
         self.refs = refs
         self.cov_min_ms = cov_min_ms    # first ms NOT in tiles; None=all
+        # first ms after the SHORTEST prefix the tiles were built from
+        self.end_min_ms = end_min_ms
         self.ident_key = ident_key
+
+    def stale_view(self) -> "_TileEntry":
+        """This entry serving a NEWER snapshot of the same selection:
+        the series have grown since the build, so the tiles cover
+        nothing past the shortest prefix they were built from — even
+        when that build had covered everything there was
+        (``cov_min_ms`` None)."""
+        bounds = [b for b in (self.cov_min_ms, self.end_min_ms)
+                  if b is not None]
+        return _TileEntry(self.tiles, self.idx, self.prefix_has_nan,
+                          self.refs, min(bounds) if bounds else None,
+                          self.end_min_ms, self.ident_key)
 
 
 class _PackedMember:
@@ -859,8 +873,10 @@ class TpuBackend:
         tiles, idx = tst.build_aligned_tiles(prefix)
         self.tile_builds += 1
         prefix_has_nan = any(np.isnan(p.values).any() for p in prefix)
+        ends = [int(p.ts[-1]) + 1 for p in prefix if p.ts.size]
         return _TileEntry(tiles, idx, prefix_has_nan,
-                          None if use_snap else list(series), cov_min)
+                          None if use_snap else list(series), cov_min,
+                          end_min_ms=min(ends) if ends else None)
 
     @capacity(
         "device-tile-cache", bytes_per_sample=17.0,
@@ -927,7 +943,7 @@ class TpuBackend:
             self.tile_hits += 1
             with self._tile_lock:
                 if key in self._tile_refreshing:
-                    return stale
+                    return stale.stale_view()
                 self._tile_refreshing.add(key)
             held = list(series)     # pin arrays until the rebuild lands
 
@@ -951,7 +967,7 @@ class TpuBackend:
             from filodb_tpu.query import qos as _qos
             self.batcher.executor.submit(
                 refresh, priority=_qos.PRIORITY_BACKGROUND)
-            return stale
+            return stale.stale_view()
         entry = self._build_tile_entry(series, use_snap)
         self._insert_tile_entry(key, ident, entry)
         return entry
