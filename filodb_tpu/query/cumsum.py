@@ -11,10 +11,15 @@ oracle compute.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 
+# jitted: called eagerly (the tile build is op by op) the scan's fresh
+# closure would otherwise retrace and recompile on EVERY call
+@functools.partial(jax.jit, static_argnames=("axis",))
 def cumsum_f64(x: jnp.ndarray, axis: int) -> jnp.ndarray:
     """Inclusive cumulative sum of ``x`` along ``axis`` as a sequential
     scan (one carried row, ``x.shape[axis]`` steps)."""

@@ -7,6 +7,8 @@ live path), and are invalidated by flushes.
 memstore/TimeSeriesPartition.scala:248 encodeOneChunkset; queries read
 buffers + chunks through one API.)"""
 
+import time
+
 import numpy as np
 import pytest
 
@@ -90,6 +92,11 @@ def test_flush_publishes_new_tiles():
     _ingest(shard, 30, T0 + 3000)
     shard.flush_all()                              # publishes new chunks
     r = _run(engine, "rate(reqs_total[5m])", T0 + 600, T0 + 3290)
+    # the previous snapshot's tiles serve while the rebuild runs on the
+    # executor thread: wait for it to land before counting
+    deadline = time.monotonic() + 30
+    while backend.tile_builds == builds and time.monotonic() < deadline:
+        time.sleep(0.05)
     assert backend.tile_builds == builds + 1       # rebuilt once
     assert np.isfinite(r.values).any()
 

@@ -139,19 +139,20 @@ def test_shardstore_donate_site_discovered(df):
     assert any("_append_step" in bk for s in sites for bk in s.body_keys)
 
 
-def test_shardstore_shard_map_sites_discovered_with_positional_axes(df):
+def test_shardstore_shard_map_sites_discovered_with_named_axes(df):
     flow, _ = df
     sites = [s for s in flow.sites if s.relpath == SHARDSTORE
              and s.kind == "shard_map"]
     # counter single+batch, grouped, grouped-pair lowerings at least
     assert len(sites) >= 4, [s.line for s in sites]
     for s in sites:
-        # positional PartitionSpec indices resolve against the module's
-        # ('shard', 'time') mesh order
         assert flow.site_axes(s) <= {"shard", "time"}, \
             (s.line, flow.site_axes(s))
-    assert any(sp.pos_entries for s in sites for sp in s.all_specs), \
-        "positional spec entries no longer parsed"
+    # the specs name mesh.axis_names: under the installed jax a positional
+    # 0 means "replicated" (positional parsing itself is pinned by
+    # test_graftlint.test_partition_spec_positional_indices)
+    assert not any(sp.pos_entries for s in sites for sp in s.all_specs), \
+        "positional spec entries are back in shardstore"
 
 
 def test_shardstore_families_clean_and_nonvacuous(df):
