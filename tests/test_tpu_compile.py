@@ -108,7 +108,8 @@ def _compile(fn, *shapes):
 def test_cumsum_f64_compiles_in_seconds(one_chip, shape, axis):
     x = jax.ShapeDtypeStruct(shape, jnp.float64, sharding=one_chip)
     compiled, secs = _compile(lambda v: cumsum_f64(v, axis), x)
-    assert secs < 30, f"f64 cumulative sum took {secs:.0f}s to compile"
+    # ~1 s idle; the reduce-window form took 230 s at [8, 128]
+    assert secs < 120, f"f64 cumulative sum took {secs:.0f}s to compile"
     # still f64, and no reduce-window crept back in
     assert jax.eval_shape(lambda v: cumsum_f64(v, axis), x).dtype == jnp.float64
     assert "reduce-window" not in compiled.as_text()
@@ -127,7 +128,7 @@ def test_tile_build_corrected_channel_compiles(one_chip):
     v = jax.ShapeDtypeStruct((S, N), jnp.float64, sharding=one_chip)
     valid = jax.ShapeDtypeStruct((S, N), jnp.bool_, sharding=one_chip)
     _, secs = _compile(tst._counter_corrected, v, valid, v)
-    assert secs < 30
+    assert secs < 120
 
 
 def test_pallas_rate_impl_compiles_end_to_end(one_chip):
@@ -140,9 +141,9 @@ def test_pallas_rate_impl_compiles_end_to_end(one_chip):
     lowered = tpu._pallas_rate_impl.lower(
         "rate", t, False, sh((s, n), jnp.int64), sh((s, n), jnp.float64),
         sh((s,), jnp.int32), i64, i64, i64)
-    t0 = time.monotonic()
+    # ~20 s on an idle machine (no wall-clock assertion: the suite shares
+    # its cores); with the f64 reduce-window cumsum it never finished
     compiled = lowered.compile()
-    assert time.monotonic() - t0 < 60
     assert "tpu_custom_call" in compiled.as_text()
 
 
