@@ -37,10 +37,6 @@ DEFAULTS = {
     # spread used for shard-key routing (filodb-defaults.conf:319
     # default-spread); must match the ingest-side spread
     "default-spread": 1,
-    # evaluate queries on the JAX device backend (query/tpu.TpuBackend).
-    # False is the ONLY route to a numpy-oracle-only node: a backend
-    # that fails to construct fails the node, it never downgrades
-    "device-backend": True,
     # lower agg(rangefunc(...)) onto the device mesh when >1 jax device
     "mesh-enabled": False,
     # with mesh-enabled: serve eligible aligned-tile cohorts from
@@ -558,7 +554,9 @@ class FiloServer:
                 self.mapper.update(shard, ShardStatus(st), claimer)
             except ValueError:
                 self.mapper.update(shard, ShardStatus.ACTIVE, claimer)
-        if self.backend is None and self.config.get("device-backend", True):
+        if self.backend is None:
+            # a backend that fails to construct fails the node: there is
+            # no route from here to a numpy-oracle-only node
             from filodb_tpu.query.batcher import MicroBatcher
             from filodb_tpu.query.tpu import TpuBackend
             self.backend = TpuBackend(batcher=MicroBatcher(
