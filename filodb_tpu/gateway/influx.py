@@ -35,10 +35,13 @@ class InfluxRecord:
     tags: Dict[str, str]
     fields: Dict[str, float]
     timestamp_ms: int
+    ident: str = ""         # the raw `measurement[,tag=value...]` part
 
 
 def _split_escaped(s: str, sep: str) -> List[str]:
     """Split on sep, honoring backslash escapes."""
+    if "\\" not in s:
+        return s.split(sep)
     out: List[str] = []
     cur: List[str] = []
     i = 0
@@ -61,23 +64,26 @@ def _split_escaped(s: str, sep: str) -> List[str]:
 def _split_top(s: str) -> Tuple[str, str, Optional[str]]:
     """Split a line into (identity, fieldset, timestamp) on unescaped
     spaces (InfluxProtocolParser.parse top-level scan)."""
-    parts: List[str] = []
-    cur: List[str] = []
-    i = 0
-    while i < len(s):
-        c = s[i]
-        if c == "\\" and i + 1 < len(s):
-            cur.append(c)
-            cur.append(s[i + 1])
-            i += 2
-            continue
-        if c == " ":
-            parts.append("".join(cur))
-            cur = []
-        else:
-            cur.append(c)
-        i += 1
-    parts.append("".join(cur))
+    if "\\" not in s:
+        parts = s.split(" ")
+    else:
+        parts = []
+        cur: List[str] = []
+        i = 0
+        while i < len(s):
+            c = s[i]
+            if c == "\\" and i + 1 < len(s):
+                cur.append(c)
+                cur.append(s[i + 1])
+                i += 2
+                continue
+            if c == " ":
+                parts.append("".join(cur))
+                cur = []
+            else:
+                cur.append(c)
+            i += 1
+        parts.append("".join(cur))
     parts = [p for p in parts if p]
     if len(parts) == 2:
         return parts[0], parts[1], None
@@ -118,7 +124,7 @@ def parse_line(line: str, now_ms: Optional[int] = None) -> InfluxRecord:
         import time
         timestamp_ms = now_ms if now_ms is not None else int(
             time.time() * 1000)
-    return InfluxRecord(measurement, tags, fields, timestamp_ms)
+    return InfluxRecord(measurement, tags, fields, timestamp_ms, ident)
 
 
 # -- InputRecord mapping (conversion/InputRecord.scala) ---------------------
@@ -135,10 +141,12 @@ def input_records(rec: InfluxRecord, ws: str = "demo", ns: str = "App-0"
     base = {"_ws_": ws, "_ns_": ns, **tags}
     fields = rec.fields
     out: List[Tuple[str, Dict[str, str], int, Tuple]] = []
-    le_fields = {k: v for k, v in fields.items()
-                 if k not in ("sum", "count", "min", "max")
-                 and _is_le(k)}
-    if "sum" in fields and "count" in fields and le_fields:
+    le_fields = None
+    if "sum" in fields and "count" in fields:
+        le_fields = {k: v for k, v in fields.items()
+                     if k not in ("sum", "count", "min", "max")
+                     and _is_le(k)}
+    if le_fields:
         les = sorted(le_fields, key=lambda k: float(
             "inf") if k in ("+Inf", "inf") else float(k))
         scheme = CustomBuckets(tuple(

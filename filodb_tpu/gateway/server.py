@@ -54,7 +54,8 @@ class GatewayServer:
         self.batch_lines = batch_lines
         self.ws, self.ns = ws, ns
         self.part_schema = PartitionSchema()
-        # (line identity, field name) -> (schema name, PartKey, shard)
+        # (line identity, field names) -> [(schema name, PartKey, shard)]
+        # per sample of the line
         self._routes: Dict = {}
         self._stats_lock = threading.Lock()
         self.lines_ingested = 0
@@ -91,8 +92,6 @@ class GatewayServer:
         self._thread: Optional[threading.Thread] = None
 
     # -- routing -----------------------------------------------------------
-    # single-field lines without escapes or quotes — what a scraper sends
-    _FAST_FIELDS = frozenset({"counter", "gauge", "value"})
     _ROUTE_CACHE_MAX = 2_000_000
 
     def _route_line(self, line: str, builders: Dict[int, RecordBuilder]
@@ -100,34 +99,11 @@ class GatewayServer:
         """Parse one line, append each resulting sample to its shard's
         builder (GatewayServer.scala:120 shardKeyHash->ingestionShard).
 
-        A series' identity (measurement + tags) decides its schema,
-        part key and shard, and never changes: the common single-field
-        line resolves them once per series (``_routes``) and afterwards
-        parses only the value and the timestamp."""
-        parts = line.split(" ")
-        if len(parts) == 3 and "\\" not in line and '"' not in line:
-            ident, field, ts_raw = parts
-            fname, _, fval = field.partition("=")
-            if fname in self._FAST_FIELDS and "," not in fval:
-                try:
-                    with self._stats_lock:
-                        route = self._routes.get((ident, fname))
-                    if route is None:
-                        route = self._resolve(line, ident, fname)
-                    value = float(fval[:-1] if fval.endswith("i") else fval)
-                    ts = int(ts_raw) // 1_000_000
-                except ValueError:
-                    with self._stats_lock:
-                        self.lines_rejected += 1
-                    return False
-                schema_name, pk, shard = route
-                b = builders.get(shard)
-                if b is None:
-                    b = builders[shard] = RecordBuilder(self.schemas)
-                b.add_keyed(schema_name, pk, ts, value)
-                with self._stats_lock:
-                    self.lines_ingested += 1
-                return True
+        A line's identity (measurement + tags) and its field names
+        decide the schema, part key and shard of each of its samples,
+        and never change: they are resolved once per series
+        (``_routes``); the line itself is always parsed by
+        ``parse_line``/``input_records``."""
         try:
             rec = parse_line(line)
             samples = input_records(rec, self.ws, self.ns)
@@ -135,10 +111,21 @@ class GatewayServer:
             with self._stats_lock:
                 self.lines_rejected += 1
             return False
-        for schema_name, labels, ts, values in samples:
-            _, shard = self._route(schema_name, labels)
-            b = builders.setdefault(shard, RecordBuilder(self.schemas))
-            b.add_sample(schema_name, labels, ts, *values)
+        key = (rec.ident, tuple(rec.fields))
+        with self._stats_lock:
+            routes = self._routes.get(key)
+        if routes is None:
+            routes = [(name,) + self._route(name, labels)
+                      for name, labels, _, _ in samples]
+            with self._stats_lock:
+                if len(self._routes) >= self._ROUTE_CACHE_MAX:
+                    self._routes.clear()
+                self._routes[key] = routes
+        for (schema_name, pk, shard), sample in zip(routes, samples):
+            b = builders.get(shard)
+            if b is None:
+                b = builders[shard] = RecordBuilder(self.schemas)
+            b.add_keyed(schema_name, pk, sample[2], *sample[3])
         with self._stats_lock:
             self.lines_ingested += 1
         return True
@@ -154,18 +141,6 @@ class GatewayServer:
         shard = ingestion_shard(pk.shard_key_hash(self.part_schema),
                                 pk.part_hash(), spread, self.num_shards)
         return pk, shard
-
-    def _resolve(self, line: str, ident: str, fname: str):
-        """First sight of a series on the fast path: resolve its route
-        through the general parser and remember it."""
-        samples = input_records(parse_line(line), self.ws, self.ns)
-        (schema_name, labels, _, _), = samples
-        route = (schema_name,) + self._route(schema_name, labels)
-        with self._stats_lock:
-            if len(self._routes) >= self._ROUTE_CACHE_MAX:
-                self._routes.clear()
-            self._routes[(ident, fname)] = route
-        return route
 
     def _publish(self, builders: Dict[int, RecordBuilder],
                  raise_on_error: bool = False) -> None:

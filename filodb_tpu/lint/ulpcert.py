@@ -513,6 +513,7 @@ def _h_extrapolated_rate():
     same boundary tuples."""
     import numpy as np
 
+    import jax
     import jax.numpy as jnp
 
     from filodb_tpu.promql.refeval import _extrapolated
@@ -526,11 +527,11 @@ def _h_extrapolated_rate():
     t2 = wend - rng.integers(50, 2_000, (T, S))
     v1 = 1e9 + rng.uniform(0, 1e3, (T, S))
     v2 = v1 + rng.uniform(0.0, 800.0, (T, S))
-    prod = np.asarray(_extrapolated_rate(
+    prod = np.asarray(jax.jit(
+        lambda *a: _extrapolated_rate(*a, True, True))(
         jnp.asarray(wstart, jnp.float64), jnp.asarray(wend, jnp.float64),
         jnp.asarray(counts), jnp.asarray(t1, jnp.float64),
-        jnp.asarray(v1), jnp.asarray(t2, jnp.float64), jnp.asarray(v2),
-        True, True))
+        jnp.asarray(v1), jnp.asarray(t2, jnp.float64), jnp.asarray(v2)))
     ref = np.full((T, S), np.nan)
     for t in range(T):
         for si in range(S):
@@ -597,18 +598,17 @@ def _h_grouped_reduce(ndev: int):
     gids = rng.integers(0, G, S).astype(np.int32)
     gids[-2:] = -1                                    # padding rows
     mesh = _shard_mesh(ndev)
-    outs = []
-    for agg in ("sum", "avg"):
-        def body(loc, g):
-            return _grouped_reduce(loc, g, G, agg)
-        # jitted: an eager shard_map compiles op by op (hundreds of tiny
-        # programs) and alone overran the lint's latency budget
-        f = jax.jit(jax.shard_map(
-            body, mesh=mesh, in_specs=(P("shard", None), P("shard")),
-            out_specs=P(), check_vma=False))
-        outs.append(np.asarray(f(jnp.asarray(local),
-                                 jnp.asarray(gids))))
-    return tuple(outs)
+
+    def body(loc, g):
+        return tuple(_grouped_reduce(loc, g, G, agg)
+                     for agg in ("sum", "avg"))
+    # jitted: an eager shard_map compiles op by op (hundreds of tiny
+    # programs) and alone overran the lint's latency budget
+    f = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(P("shard", None), P("shard")),
+        out_specs=P(), check_vma=False))
+    return tuple(np.asarray(o)
+                 for o in f(jnp.asarray(local), jnp.asarray(gids)))
 
 
 @order_harness("grouped-pair-psum")

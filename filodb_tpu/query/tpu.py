@@ -524,30 +524,35 @@ class _TileEntry:
     plus the coverage bound that makes stale serves correct."""
 
     __slots__ = ("tiles", "idx", "prefix_has_nan", "refs", "cov_min_ms",
-                 "end_min_ms", "ident_key")
+                 "built_ends", "ident_key")
 
     def __init__(self, tiles, idx, prefix_has_nan, refs, cov_min_ms,
-                 end_min_ms=None, ident_key=None):
+                 built_ends=(), ident_key=None):
         self.tiles = tiles
         self.idx = idx
         self.prefix_has_nan = prefix_has_nan
         self.refs = refs
         self.cov_min_ms = cov_min_ms    # first ms NOT in tiles; None=all
-        # first ms after the SHORTEST prefix the tiles were built from
-        self.end_min_ms = end_min_ms
+        # per series, the last ms the tiles hold (None: no sample of it)
+        self.built_ends = built_ends
         self.ident_key = ident_key
 
-    def stale_view(self) -> "_TileEntry":
-        """This entry serving a NEWER snapshot of the same selection:
-        the series have grown since the build, so the tiles cover
-        nothing past the shortest prefix they were built from — even
-        when that build had covered everything there was
-        (``cov_min_ms`` None)."""
-        bounds = [b for b in (self.cov_min_ms, self.end_min_ms)
-                  if b is not None]
+    def stale_view(self, series) -> "_TileEntry":
+        """This entry serving ``series``, a NEWER snapshot of the same
+        selection: the tiles cover nothing from the first sample that
+        any series has gained since the build — even when that build had
+        covered everything there was (``cov_min_ms`` None). A series
+        that has not grown (dead, churned away) holds nothing back."""
+        bound = self.cov_min_ms
+        for s, end in zip(series, self.built_ends):
+            if s.ts.size and (end is None or s.ts[-1] > end):
+                j = 0 if end is None else int(
+                    np.searchsorted(s.ts, end, side="right"))
+                t = int(s.ts[j])
+                bound = t if bound is None else min(bound, t)
         return _TileEntry(self.tiles, self.idx, self.prefix_has_nan,
-                          self.refs, min(bounds) if bounds else None,
-                          self.end_min_ms, self.ident_key)
+                          self.refs, bound, self.built_ends,
+                          self.ident_key)
 
 
 class _PackedMember:
@@ -873,10 +878,10 @@ class TpuBackend:
         tiles, idx = tst.build_aligned_tiles(prefix)
         self.tile_builds += 1
         prefix_has_nan = any(np.isnan(p.values).any() for p in prefix)
-        ends = [int(p.ts[-1]) + 1 for p in prefix if p.ts.size]
         return _TileEntry(tiles, idx, prefix_has_nan,
                           None if use_snap else list(series), cov_min,
-                          end_min_ms=min(ends) if ends else None)
+                          built_ends=[int(p.ts[-1]) if p.ts.size else None
+                                      for p in prefix])
 
     @capacity(
         "device-tile-cache", bytes_per_sample=17.0,
@@ -943,7 +948,7 @@ class TpuBackend:
             self.tile_hits += 1
             with self._tile_lock:
                 if key in self._tile_refreshing:
-                    return stale.stale_view()
+                    return stale.stale_view(series)
                 self._tile_refreshing.add(key)
             held = list(series)     # pin arrays until the rebuild lands
 
@@ -967,7 +972,7 @@ class TpuBackend:
             from filodb_tpu.query import qos as _qos
             self.batcher.executor.submit(
                 refresh, priority=_qos.PRIORITY_BACKGROUND)
-            return stale.stale_view()
+            return stale.stale_view(series)
         entry = self._build_tile_entry(series, use_snap)
         self._insert_tile_entry(key, ident, entry)
         return entry

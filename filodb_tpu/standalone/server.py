@@ -1357,8 +1357,9 @@ def _main_idle() -> None:
 
 def _device_or_exit() -> Dict:
     """The device this node runs on, as jax reports it. The CPU backend
-    is served only where the environment asks for it
-    (``JAX_PLATFORMS=cpu``); a node that was meant for an accelerator
+    is served only where the environment asks for it and for nothing
+    else (``JAX_PLATFORMS=cpu`` exactly — ``tpu,cpu`` is a fallback list,
+    not a request for the CPU); a node that was meant for an accelerator
     and came up on anything else exits with the reason instead of
     serving from the host."""
     import os
@@ -1371,10 +1372,11 @@ def _device_or_exit() -> Dict:
         sys.exit(f"filodb-tpu server: no JAX device "
                  f"(JAX_PLATFORMS={want!r}): {e}")
     platform = devs[0].platform
-    if platform == "cpu" and "cpu" not in want.split(","):
+    if platform == "cpu" and want.strip().lower() != "cpu":
         sys.exit("filodb-tpu server: JAX found no accelerator and came up "
                  f"on the CPU (JAX_PLATFORMS={want!r}); set "
-                 "JAX_PLATFORMS=cpu to run a CPU node on purpose")
+                 "JAX_PLATFORMS=cpu, and nothing else in it, to run a CPU "
+                 "node on purpose")
     return {"platform": platform, "kind": devs[0].device_kind,
             "count": len(devs)}
 

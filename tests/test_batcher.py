@@ -206,29 +206,42 @@ def test_executor_owns_submissions_in_order():
     ex.stop()
 
 
-def test_stale_tiles_never_serve_past_the_prefix_they_were_built_from():
+@pytest.mark.parametrize("dead", [(), (2,)], ids=["all-grow", "one-dead"])
+def test_stale_tiles_never_serve_past_the_prefix_they_were_built_from(dead):
     """A flush publishes new chunks and changes the snapshot key; the
     previous snapshot's tiles keep serving while the rebuild runs. Built
     when they covered everything (cov_min_ms None), they must still stop
-    at their own prefix: the newly flushed samples are not in them.
-    (chip_smoke.py's at-now queries caught the whole grid being served
-    from the stale tiles — rates up to 89% low for one query.)"""
+    at the first sample a series has gained since: the newly flushed
+    samples are not in them. (chip_smoke.py's at-now queries caught the
+    whole grid being served from the stale tiles — rates up to 89% low
+    for one query.) A series that stopped early and has not grown holds
+    nothing back: the tiles still serve up to the others' growth."""
     from filodb_tpu.query.engine import periodic_samples
     n0, n1, W = 120, 150, 300_000
     full = _series(n=n1, S=4, snap=False)
 
     def snap(n, num_chunks):
-        return [RawSeries(s.labels, s.ts[:n], s.values[:n], True,
+        lens = [60 if i in dead else n for i in range(len(full))]
+        return [RawSeries(s.labels, s.ts[:k], s.values[:k], True,
                           snapshot_key=("ds", 0, i, num_chunks, 0),
-                          chunk_len=n) for i, s in enumerate(full)]
+                          chunk_len=k)
+                for i, (s, k) in enumerate(zip(full, lens))]
     backend = TpuBackend(batcher=MicroBatcher())
     first = RangeParams(BASE + 600_000, 60_000, BASE + (n0 - 2) * 10_000)
     backend.periodic_samples(snap(n0, 7), first, "rate", W)
     assert backend.tile_builds == 1
     # the rebuild never lands: the stale entry is what serves
     backend.batcher.executor.submit = lambda *a, **k: None
+    served = []
+    dispatch = backend._aligned_dispatch
+    backend._aligned_dispatch = lambda tiles, func, steps, *a: (
+        served.append(steps.size) or dispatch(tiles, func, steps, *a))
     now = RangeParams(BASE + 600_000, 60_000, BASE + (n1 - 1) * 10_000)
-    got = backend.periodic_samples(snap(n1, 8), now, "rate", W).values
+    new = snap(n1, 8)
+    got = backend.periodic_samples(new, now, "rate", W).values
     assert backend.tile_builds == 1
-    want = periodic_samples(full, now, "rate", W).values
+    want = periodic_samples(new, now, "rate", W).values
     np.testing.assert_allclose(got, want, rtol=1e-5, equal_nan=True)
+    # the tiles served every step whose window ends before sample n0
+    steps = np.arange(now.start_ms, now.end_ms + 1, now.step_ms)
+    assert served == [int((steps < BASE + n0 * 10_000).sum())]

@@ -20,6 +20,7 @@ import sys
 import time
 
 import numpy as np
+import pytest
 import urllib.error
 import urllib.parse
 import urllib.request
@@ -131,6 +132,25 @@ def test_backend_construction_failure_exits_nonzero(tmp_path):
     assert res.returncode != 0
     assert b"no device for you" in res.stderr
     assert res.stdout.strip() == b""          # no startup line was printed
+
+
+@pytest.mark.parametrize("want", [None, "", "tpu", "tpu,cpu", "cpu,tpu"])
+def test_cpu_is_served_only_when_asked_for_and_for_nothing_else(
+        monkeypatch, want):
+    """This process runs JAX on the CPU. A node that finds itself there
+    exits with the reason unless JAX_PLATFORMS is exactly `cpu`: a list
+    that names the CPU beside an accelerator is a fallback order, not a
+    request for a CPU node."""
+    from filodb_tpu.standalone import server
+    if want is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", want)
+    with pytest.raises(SystemExit) as e:
+        server._device_or_exit()
+    assert "came up on the CPU" in str(e.value)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert server._device_or_exit()["platform"] == "cpu"
 
 
 def test_kill_minus_9_restart_replays_to_identical_results(tmp_path):
