@@ -41,6 +41,7 @@ from filodb_tpu.lint.locks import guarded_by, single_writer
 from filodb_tpu.core.schemas import (ColumnType, DataSchema, DatasetRef,
                                      Schemas)
 from filodb_tpu.memory import histogram as bh
+from filodb_tpu.obs import trace as obs_trace
 from filodb_tpu.memory import vectors as bv
 
 DEFAULT_MAX_CHUNK_ROWS = 400  # store config max-chunks-size (IngestionConfig)
@@ -665,6 +666,10 @@ class TimeSeriesShard:
         Rows are processed in consecutive same-partition runs (builders
         emit per-series bursts), so the per-partition hot path is one
         batched buffer extension instead of a per-row Python loop."""
+        with obs_trace.span("shard-ingest"):
+            return self._ingest(container, offset)
+
+    def _ingest(self, container: RecordContainer, offset: int) -> int:
         n = 0
         tss, cols = container.arrays()
         wm_recompute = False
@@ -736,37 +741,45 @@ class TimeSeriesShard:
         """Encode write buffers of one flush group, persist new chunks +
         partkeys + the group checkpoint (TimeSeriesShard.scala:1341
         doFlushSteps: encode → ColumnStore.write → index/partkey write →
-        writeCheckpoint).  Returns chunks written."""
+        writeCheckpoint).  Returns chunks written. The ``flush`` stage
+        span observes ``filodb_flush_seconds``."""
+        with obs_trace.span("flush", group=group):
+            return self._flush_group(group, offset)
+
+    def _flush_group(self, group: int, offset: int) -> int:
         n = 0
         touched: List[TimeSeriesPartition] = []
-        for pid, part in self.partitions.items():
-            if pid % self.num_groups != group:
-                continue
-            info = part.switch_buffers()
-            if info is not None:
-                n += 1
-                self.stats.chunks_encoded += 1
-                self.stats.encoded_bytes += sum(len(v) for v in info.vectors)
-            if self.column_store is not None \
-                    and part.num_chunks > part.persisted_chunks:
-                touched.append(part)
+        with obs_trace.span("flush-encode"):
+            for pid, part in self.partitions.items():
+                if pid % self.num_groups != group:
+                    continue
+                info = part.switch_buffers()
+                if info is not None:
+                    n += 1
+                    self.stats.chunks_encoded += 1
+                    self.stats.encoded_bytes += sum(
+                        len(v) for v in info.vectors)
+                if self.column_store is not None \
+                        and part.num_chunks > part.persisted_chunks:
+                    touched.append(part)
         if touched:
             from filodb_tpu.store import PartKeyEntry
             entries = []
-            for part in touched:
-                new = part.chunks[part.persisted_chunks:]
-                self.column_store.write_chunks(
-                    self.ref.dataset, self.shard_num,
-                    part.part_key.to_bytes(), new)
-                part.persisted_chunks = part.num_chunks
-                self.stats.chunks_persisted += len(new)
-                entries.append(PartKeyEntry(
-                    part.part_key.to_bytes(),
-                    self.index.start_time(part.part_id)
-                    or part.earliest_timestamp or 0,
-                    part.last_timestamp or 0))
-            self.column_store.write_part_keys(self.ref.dataset,
-                                              self.shard_num, entries)
+            with obs_trace.span("flush-write", parts=len(touched)):
+                for part in touched:
+                    new = part.chunks[part.persisted_chunks:]
+                    self.column_store.write_chunks(
+                        self.ref.dataset, self.shard_num,
+                        part.part_key.to_bytes(), new)
+                    part.persisted_chunks = part.num_chunks
+                    self.stats.chunks_persisted += len(new)
+                    entries.append(PartKeyEntry(
+                        part.part_key.to_bytes(),
+                        self.index.start_time(part.part_id)
+                        or part.earliest_timestamp or 0,
+                        part.last_timestamp or 0))
+                self.column_store.write_part_keys(
+                    self.ref.dataset, self.shard_num, entries)
         self.stats.flushes_done += 1
         if self.flush_downsampler is not None:
             # persist pending ds records (also covers chunks encoded by

@@ -28,6 +28,7 @@ from filodb_tpu.core.record import PartKey
 from filodb_tpu.core.schemas import PartitionSchema, Schemas
 from filodb_tpu.gateway.influx import input_records, parse_line
 from filodb_tpu.ingest.stream import IngestionStream
+from filodb_tpu.obs import trace as obs_trace
 
 
 @guarded_by("_stats_lock", "lines_ingested", "lines_rejected",
@@ -72,17 +73,13 @@ class GatewayServer:
             @thread_root("gateway-producer")
             def handle(self):
                 builders: Dict[int, RecordBuilder] = {}
-                pending = 0
-                for raw in self.rfile:
-                    line = raw.decode("utf-8", errors="replace").strip()
-                    if not line or line.startswith("#"):
-                        continue
-                    if gateway._route_line(line, builders):
-                        pending += 1
-                    if pending >= gateway.batch_lines:
-                        gateway._publish(builders)
-                        pending = 0
-                gateway._publish(builders)
+                lines = iter(self.rfile)
+                more = True
+                while more:
+                    # one stage span per published batch, not per line
+                    with obs_trace.span("gateway-parse"):
+                        more = gateway._route_batch(lines, builders)
+                    gateway._publish(builders)
 
         class Server(socketserver.ThreadingTCPServer):
             daemon_threads = True
@@ -93,6 +90,21 @@ class GatewayServer:
 
     # -- routing -----------------------------------------------------------
     _ROUTE_CACHE_MAX = 2_000_000
+
+    def _route_batch(self, lines, builders: Dict[int, RecordBuilder]
+                     ) -> bool:
+        """Read, parse and route lines until ``batch_lines`` were
+        accepted; False once ``lines`` is exhausted."""
+        pending = 0
+        for raw in lines:
+            line = raw.decode("utf-8", errors="replace").strip()
+            if not line or line.startswith("#"):
+                continue
+            if self._route_line(line, builders):
+                pending += 1
+                if pending >= self.batch_lines:
+                    return True
+        return False
 
     def _route_line(self, line: str, builders: Dict[int, RecordBuilder]
                     ) -> bool:

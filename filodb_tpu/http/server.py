@@ -1381,14 +1381,15 @@ class FiloHttpServer:
         from filodb_tpu.core.record import RecordBuilder
         builders: Dict[int, RecordBuilder] = {}
         accepted = rejected = 0
-        for raw in body_raw.splitlines():
-            line = raw.decode("utf-8", errors="replace").strip()
-            if not line or line.startswith("#"):
-                continue
-            if gw._route_line(line, builders):
-                accepted += 1
-            else:
-                rejected += 1
+        with obs_trace.span("gateway-parse", edge="http"):
+            for raw in body_raw.splitlines():
+                line = raw.decode("utf-8", errors="replace").strip()
+                if not line or line.startswith("#"):
+                    continue
+                if gw._route_line(line, builders):
+                    accepted += 1
+                else:
+                    rejected += 1
         gw._publish(builders, raise_on_error=True)
         return 200, {"status": "success",
                      "data": {"accepted": accepted,
@@ -1457,7 +1458,6 @@ class FiloHttpServer:
 
     def _query_range(self, engine, qs, ds: str = "timeseries",
                      tctx=None):
-        import time as _time
         query = self._param(qs, "query")
         if not query:
             raise QueryError("missing query parameter")
@@ -1479,12 +1479,11 @@ class FiloHttpServer:
             query, ds, kind="range",
             trace_id=tr.trace_id if tr is not None else None)
         stages: Dict[str, object] = {}
-        t0 = _time.perf_counter()
         code = 0
         try:
             with obs_trace.activate(tr):
                 with obs_trace.span("query", query=query, dataset=ds,
-                                    node=self.node_id or ""):
+                                    node=self.node_id or "") as qsp:
                     code, payload = self._query_range_stages(
                         engine, qs, ds, query, start, end, step, entry,
                         stages,
@@ -1504,8 +1503,9 @@ class FiloHttpServer:
         finally:
             # tail retention runs HERE so every exit path (success,
             # QueryError, shed, crash) decides the trace's fate exactly
-            # once, with the outcome in hand
-            total_s = _time.perf_counter() - t0
+            # once, with the outcome in hand; the latency histogram
+            # reads the root stage span's own clock pair
+            total_s = qsp.dur_ns / 1e9
             self.inflight.unregister(entry)
             tr = self._finish_request_trace(
                 tr, tctx, code, total_s, stages,
@@ -1526,8 +1526,6 @@ class FiloHttpServer:
         only peer hops (``trace_spans`` rides the envelope) and explain
         requests need it; a plain request with a pending tail-sampling
         trace keeps the byte fast path."""
-        import time as _time
-        t0 = _time.perf_counter()
         self.inflight.stage(entry, "parse")
         with obs_trace.span("parse") as sp:
             plan = self.plan_cache.lookup(ds, query, start * 1000,
@@ -1563,11 +1561,10 @@ class FiloHttpServer:
                                    start, end, step, stages)
         if out is not None:
             return out
-        t1 = _time.perf_counter()
         self.inflight.stage(entry, "plan")
         bypass = (self._param(qs, "cache", "")
                   or "").lower() in ("false", "0", "no")
-        with obs_trace.span("plan"):
+        with obs_trace.span("plan") as psp:
             # results cache: split the request into the cached extent
             # and the uncovered spans — only the latter materialize
             # (tail-only recomputation; a full hit materializes nothing)
@@ -1576,16 +1573,15 @@ class FiloHttpServer:
                 end * 1000, bypass=bypass)
             exs = [engine.materialize(p) for p in ses.plans]
         ex_label = type(exs[-1]).__name__ if exs else "ResultCacheHit"
-        t2 = _time.perf_counter()
         self.inflight.stage(entry, "execute")
         with obs_trace.span("execute", plan=ex_label) as _esp:
             res = ses.finish(engine, [ex.execute() for ex in exs])
             _esp.tag(result_cache=ses.state,
                      cached_steps=ses.cached_steps)
-        t3 = _time.perf_counter()
-        stages["parseMs"] = round((t1 - t0) * 1000, 3)
-        stages["planMs"] = round((t2 - t1) * 1000, 3)
-        stages["execMs"] = round((t3 - t2) * 1000, 3)
+        # the slow log's breakdown is the stage spans' own durations
+        stages["parseMs"] = sp.ms
+        stages["planMs"] = psp.ms
+        stages["execMs"] = _esp.ms
         stages["planCache"] = pc_state
         stages["resultCache"] = ses.state
         if isinstance(res, ScalarResult):
@@ -1612,17 +1608,17 @@ class FiloHttpServer:
             warnings = list(getattr(st, "warnings", ()) or ())
             warnings.extend(res.warnings)
             partial = bool(getattr(st, "partial", False) or res.partial)
-            out = prom_json.matrix_bytes(
-                res, stats_json, warnings=warnings, partial=partial,
-                rows_memo=ses.encode_memo())
-            stages["encodeMs"] = round(
-                (_time.perf_counter() - t3) * 1000, 3)
+            with obs_trace.span("encode") as nsp:
+                out = prom_json.matrix_bytes(
+                    res, stats_json, warnings=warnings, partial=partial,
+                    rows_memo=ses.encode_memo())
+            stages["encodeMs"] = nsp.ms
             return 200, out
-        with obs_trace.span("encode"):
+        with obs_trace.span("encode") as nsp:
             out = prom_json.matrix(res, hist_wire=hist_wire)
             out["stats"] = stats_json
             prom_json.attach_degraded(out, res, engine.stats)
-        stages["encodeMs"] = round((_time.perf_counter() - t3) * 1000, 3)
+        stages["encodeMs"] = nsp.ms
         return 200, out
 
     def _finish_request_trace(self, tr, tctx, code: int, total_s: float,
@@ -1677,7 +1673,6 @@ class FiloHttpServer:
 
     def _query_instant(self, engine, qs, ds: str = "timeseries",
                        tctx=None):
-        import time as _time
         query = self._param(qs, "query")
         if not query:
             raise QueryError("missing query parameter")
@@ -1689,12 +1684,11 @@ class FiloHttpServer:
             query, ds, kind="instant",
             trace_id=tr.trace_id if tr is not None else None)
         stages: Dict[str, object] = {}
-        t0 = _time.perf_counter()
         code = 0
         try:
             with obs_trace.activate(tr):
                 with obs_trace.span("query", query=query, dataset=ds,
-                                    node=self.node_id or ""):
+                                    node=self.node_id or "") as qsp:
                     code, payload = self._query_instant_stages(
                         engine, qs, ds, query, time_s, entry, stages)
             if tr is not None and isinstance(payload, dict):
@@ -1708,7 +1702,7 @@ class FiloHttpServer:
                             tr, stages)
             return code, payload
         finally:
-            total_s = _time.perf_counter() - t0
+            total_s = qsp.dur_ns / 1e9
             self.inflight.unregister(entry)
             tr = self._finish_request_trace(
                 tr, tctx, code, total_s, stages,
@@ -1721,11 +1715,9 @@ class FiloHttpServer:
 
     def _query_instant_stages(self, engine, qs, ds, query, time_s,
                               entry, stages):
-        import time as _time
-        t0 = _time.perf_counter()
         self.inflight.stage(entry, "parse")
         # instant queries cache under step=0 (start == end == time)
-        with obs_trace.span("parse"):
+        with obs_trace.span("parse") as sp:
             plan = self.plan_cache.lookup(ds, query, time_s * 1000, 0,
                                           time_s * 1000)
             if plan is None:
@@ -1747,21 +1739,19 @@ class FiloHttpServer:
                                    time_s, time_s, 0, stages)
         if out is not None:
             return out
-        t1 = _time.perf_counter()
         self.inflight.stage(entry, "execute")
-        with obs_trace.span("execute"):
+        with obs_trace.span("execute") as _esp:
             res = engine.execute(plan)
-        t2 = _time.perf_counter()
-        stages["parseMs"] = round((t1 - t0) * 1000, 3)
-        stages["execMs"] = round((t2 - t1) * 1000, 3)
+        stages["parseMs"] = sp.ms
+        stages["execMs"] = _esp.ms
         if isinstance(res, ScalarResult):
             return 200, prom_json.scalar(res, instant=True)
         self.inflight.stage(entry, "encode")
-        with obs_trace.span("encode"):
+        with obs_trace.span("encode") as nsp:
             out = prom_json.vector(res)
             out["stats"] = self._query_stats(engine, res)
             prom_json.attach_degraded(out, res, engine.stats)
-        stages["encodeMs"] = round((_time.perf_counter() - t2) * 1000, 3)
+        stages["encodeMs"] = nsp.ms
         return 200, out
 
     def _build_analyze(self, tr, stages: Dict) -> Dict:
@@ -1949,12 +1939,6 @@ class FiloHttpServer:
         "filodb_batcher_enabled": "Micro-batcher admission on/off",
         "filodb_batcher_batches_total": "Device dispatches issued",
         "filodb_batcher_queries_total": "Queries admitted",
-        "filodb_batcher_batched_queries_total":
-            "Queries that shared a batch (size >= 2)",
-        "filodb_batcher_occupancy_avg": "Mean batch size",
-        "filodb_batcher_occupancy_max": "Max batch size seen",
-        "filodb_batcher_gather_wait_ms_total":
-            "Total residual gather-window wait",
         "filodb_plan_cache_entries": "Parsed-plan LRU entries",
         "filodb_plan_cache_hits_total": "Plan-cache hits",
         "filodb_plan_cache_misses_total": "Plan-cache misses",
@@ -2198,12 +2182,6 @@ class FiloHttpServer:
                 emit("batcher_enabled", {}, 1 if batcher.enabled else 0)
                 emit("batcher_batches_total", {}, bs["batches"])
                 emit("batcher_queries_total", {}, bs["queries"])
-                emit("batcher_batched_queries_total", {},
-                     bs["batched_queries"])
-                emit("batcher_occupancy_avg", {}, bs["occupancy_avg"])
-                emit("batcher_occupancy_max", {}, bs["occupancy_max"])
-                emit("batcher_gather_wait_ms_total", {},
-                     bs["gather_wait_ms"])
                 for cls, n in sorted(bs.get("by_priority",
                                             {}).items()):
                     emit("batcher_priority_queries_total",

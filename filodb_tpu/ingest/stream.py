@@ -38,11 +38,11 @@ from filodb_tpu.core.schemas import ColumnType, Schemas
 from filodb_tpu.lint.locks import guarded_by
 from filodb_tpu.memory.histogram import _decode_scheme, _encode_scheme
 from filodb_tpu.obs import metrics as obs_metrics
+from filodb_tpu.obs import trace as obs_trace
 from filodb_tpu.store import integrity
 from filodb_tpu.testing import chaos
 
-_APPEND_HELP = ("Wall seconds per durable-stream append (encode + "
-                "write + flush + any fsync this append performed)")
+# (filodb_ingest_append_seconds is observed by the wal-append stage span)
 _FSYNC_HELP = ("Wall seconds per durable-stream os.fsync (group commit "
                "coalesces appends: fsync count / append count is the "
                "coalescing ratio)")
@@ -269,8 +269,10 @@ class LogIngestionStream(IngestionStream):
         takeover, a torn tail left by a crashed writer is truncated so the
         new append lands on a record boundary (a CORRUPT tail — bad bytes,
         not just incomplete — is quarantined before the truncate)."""
-        import time as _time
-        t0 = _time.perf_counter()
+        with obs_trace.span("wal-append"):
+            return self._append(container, fsync)
+
+    def _append(self, container: RecordContainer, fsync: bool) -> int:
         payload = encode_container(container)
         data = integrity.encode_frame(payload) if self.integrity_frames \
             else payload
@@ -309,9 +311,6 @@ class LogIngestionStream(IngestionStream):
                 len(payload), self.integrity_frames))
             self._scan_end += len(data)
             self.appends += 1
-        obs_metrics.observe("filodb_ingest_append_seconds", _APPEND_HELP,
-                            _time.perf_counter() - t0,
-                            obs_metrics.FSYNC_BUCKETS_S)
         return off
 
     def _maybe_fsync_locked(self, force: bool = False) -> None:

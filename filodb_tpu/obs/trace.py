@@ -11,19 +11,52 @@ attributing tail latency in a fan-out system.
 
 Design constraints:
 
-  * ~zero cost when no trace is active: ``span()`` reads one
-    thread-local attribute and returns a shared no-op context manager.
-    No allocation, no clock read, no string formatting happens on the
-    untraced path — disabled-tracing responses stay byte-identical and
-    the bench overhead stays within noise.
+  * two kinds of span, told apart by NAME. A name in :data:`STAGES`
+    (the fixed table of layer boundaries of the served path) opens a
+    *stage span*: it is always timed, tracer on or off — one
+    ``perf_counter_ns`` pair and one ``thread_time_ns`` pair feed the
+    per-stage counters on ``/metrics`` (calls, self seconds, CPU
+    seconds), the stage's latency histogram where it has one, the
+    request trace when one is active, and a
+    ``jax.profiler.TraceAnnotation("filodb:<name>")`` so that a running
+    profiler session shows the stage on the host plane beside the
+    device ops. Every OTHER span (peer hops, membership, rule-eval,
+    ``event()``) is ~zero cost when no trace is active: ``span()``
+    reads one thread-local attribute and returns a shared no-op context
+    manager — no allocation, no clock read, no string formatting.
+    Responses are byte-identical either way.
+  * this module never imports JAX: the annotation class is taken from a
+    ``jax`` that is already in ``sys.modules`` and skipped otherwise, so
+    a gateway, supervisor or load-generator process that never imported
+    JAX (a process that loads it may take the chip) stays without it.
   * spans may be recorded from multiple threads (HTTP workers, the
-    batcher's device-executor thread): the active trace is carried in a
-    thread-local and can be captured/reinstalled across thread hops
-    (:func:`capture` / :func:`use` — the micro-batcher does this for
-    closures it runs on the executor thread).
+    batcher's device-executor thread): the active trace AND the open
+    stage frame are carried in a thread-local and can be
+    captured/reinstalled across thread hops (:func:`capture` /
+    :func:`use` — the micro-batcher does this for closures it runs on
+    the executor thread).
   * bounded memory: a trace stops recording past ``MAX_SPANS`` (a
     runaway fan-out can't balloon the ring buffer), and the
     :class:`Tracer`'s recorder keeps the last N finished traces.
+
+Self time: a stage span's duration minus what its child stage spans
+cover. Children on the same thread subtract wall and CPU; a child that
+ran on another thread under ``use(capture())`` (the executor running a
+batch while its leader parks in ``batcher-queue-wait``) subtracts wall
+only — the CPU was another thread's. So over the request threads the
+self seconds of the stages under ``query`` add up to
+``filodb_query_latency_seconds_sum``, and wall minus CPU of a stage is
+time it waited (the GIL, a lock, the device, a socket).
+
+The CPU side is SAMPLED. The thread CPU clock is a system call (0.3 us
+on a workstation, 5.6 us on the TPU host's sandboxed VM, where reading
+it twice in every span cost 8% of the dashboards cell's throughput), so
+only a thread's ROOT stage span decides whether its whole tree reads
+it: at most once per ``_CPU_SAMPLE_NS`` per root stage, each read tree
+standing for the trees skipped since the last one. Requests that arrive
+more than that apart (a 2 s ``sum by``) are all read, weight 1; at 150
+queries a second one in fifteen is, and ``cpu_seconds_total`` stays an
+estimate of the whole while a span costs two cheap clock reads.
 """
 
 from __future__ import annotations
@@ -31,6 +64,7 @@ from __future__ import annotations
 import itertools
 import json
 import random
+import sys
 import threading
 import time
 import urllib.error
@@ -40,6 +74,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 from filodb_tpu.lint.locks import guarded_by
 from filodb_tpu.lint.threads import thread_root
+from filodb_tpu.obs import metrics as obs_metrics
 
 # spans per trace cap: a 256-shard fan-out with retries stays well under
 # this; anything bigger is a runaway and gets truncated (tagged).
@@ -207,16 +242,272 @@ class _LiveSpan:
         return False
 
 
+# -- stage spans: the layer boundaries of the served path --------------------
+
+# name -> help text. A ``span(name)`` whose name is here is a STAGE span
+# (always timed; see the module docstring). Hyphens read as underscores
+# in the family names: ``filodb_stage_<name>_{calls,self_seconds,
+# cpu_seconds}_total``.
+STAGES: Dict[str, str] = {
+    # query path, request thread
+    "admission-wait": "waiting for an admission slot (outside query)",
+    "query": "one query request inside the node (the root: its self "
+             "time is what no stage below it covers)",
+    "parse": "PromQL parse or plan-cache lookup",
+    "plan": "results-cache split and plan materialization",
+    "execute": "exec-plan evaluation (self time: engine work outside "
+               "the stages below it)",
+    "encode": "result to Prometheus JSON (byte fast path or dict path)",
+    "resultcache-stitch": "stitching cached extents with computed spans",
+    "select-series": "index lookup and whole-series reads",
+    "select-span": "index lookup and span-bounded reads (leaf dispatch)",
+    "group-keys": "label dicts of the selection and its group ids",
+    "aggregate": "cross-series aggregation and result shaping on the "
+                 "host",
+    "device-eval": "backend evaluation of one windowed selector (self "
+                   "time: routing outside the stages below it)",
+    "pack": "packing series into padded host blocks",
+    "tile-entry": "tile-cache lookup (a build is its child)",
+    "tile-build": "building one aligned-tile cache entry",
+    "fused-eligibility": "coverage checks of the fused group-sum path",
+    "onehot": "group one-hot and kernel operands of the fused path",
+    "kernel-build": "evaluator build on a dispatch-table miss (trace + "
+                    "compile)",
+    "device-dispatch": "kernel submission to the device (enqueue only)",
+    "device-sync": "waiting for device results to reach the host",
+    "batcher-queue-wait": "parked on the micro-batcher (executor queue "
+                          "+ gather window), less the batch's own "
+                          "stages on the executor thread",
+    # write and set-up path
+    "gateway-parse": "reading, parsing and routing one batch of lines "
+                     "(wall includes waiting on the socket)",
+    "wal-append": "one durable-stream append (encode + write + fsync)",
+    "shard-ingest": "one record container into the memstore",
+    "flush": "one flush group (encode + ColumnStore write + checkpoint)",
+    "flush-encode": "switching and encoding a flush group's buffers",
+    "flush-write": "ColumnStore write of a flush group's chunks and keys",
+}
+
+# stage -> (histogram family, help, buckets): the span's DURATION is
+# observed on exit, so the block has one pair of clock reads
+STAGE_HISTOGRAMS: Dict[str, Tuple[str, str, Tuple[float, ...]]] = {
+    "device-dispatch": (
+        "filodb_device_execute_seconds",
+        "Wall seconds per device dispatch (kernel submission; the "
+        "host sync is the device-sync stage)",
+        obs_metrics.LATENCY_BUCKETS_S),
+    "batcher-queue-wait": (
+        "filodb_batcher_queue_wait_seconds",
+        "Wall seconds a query spent parked on the micro-batcher "
+        "(executor queueing + residual gather window); 0 for "
+        "inline single-query dispatches",
+        obs_metrics.LATENCY_BUCKETS_S),
+    "kernel-build": (
+        "filodb_kernel_build_seconds",
+        "Wall seconds per evaluator build on a dispatch-table "
+        "miss (trace + XLA compile)",
+        obs_metrics.LATENCY_BUCKETS_S),
+    "flush": (
+        "filodb_flush_seconds",
+        "Wall seconds per flush-group persist (encode + "
+        "ColumnStore write + checkpoint)",
+        obs_metrics.LATENCY_BUCKETS_S),
+    "wal-append": (
+        "filodb_ingest_append_seconds",
+        "Wall seconds per durable-stream append (encode + "
+        "write + flush + any fsync this append performed)",
+        obs_metrics.FSYNC_BUCKETS_S),
+}
+
+
+# a root stage span reads the thread CPU clock (for its whole tree) at
+# most this often per root stage; see the module docstring
+_CPU_SAMPLE_NS = 100_000_000
+
+
+class _Stage:
+    """Running totals of one stage. One small lock per stage: an
+    uncontended acquire is ~60 ns, two stages never share one, and
+    nothing has to fold dead threads' cells (the HTTP tier is
+    thread-per-connection) at scrape."""
+
+    __slots__ = ("name", "anno", "family", "help", "hist", "lock",
+                 "calls", "self_ns", "cpu_ns", "cpu_next_ns",
+                 "cpu_skipped")
+
+    def __init__(self, name: str, help: str):
+        self.name = name
+        self.anno = "filodb:" + name
+        self.family = "filodb_stage_" + name.replace("-", "_")
+        self.help = help
+        self.hist = STAGE_HISTOGRAMS.get(name)
+        self.lock = threading.Lock()
+        self.calls = 0
+        self.self_ns = 0
+        self.cpu_ns = 0
+        self.cpu_next_ns = 0        # as a ROOT: when to read CPU next
+        self.cpu_skipped = 0        # roots since the last one read
+
+    def cpu_weight(self, now_ns: int) -> int:
+        """For a root span of this stage opening now: 0 = leave the
+        thread CPU clock alone, n = read it, standing for n roots."""
+        with self.lock:
+            self.cpu_skipped += 1
+            if now_ns < self.cpu_next_ns:
+                return 0
+            weight, self.cpu_skipped = self.cpu_skipped, 0
+            self.cpu_next_ns = now_ns + _CPU_SAMPLE_NS
+            return weight
+
+
+_STAGE_TABLE: Dict[str, _Stage] = {n: _Stage(n, h)
+                                   for n, h in STAGES.items()}
+
+
+def stage_totals() -> Dict[str, Tuple[int, float, float]]:
+    """stage -> (calls, self seconds, CPU seconds) since process start."""
+    out = {}
+    for st in _STAGE_TABLE.values():
+        with st.lock:
+            out[st.name] = (st.calls, st.self_ns / 1e9, st.cpu_ns / 1e9)
+    return out
+
+
+def _collect_stages(builder) -> None:
+    for name, (calls, self_s, cpu_s) in stage_totals().items():
+        st = _STAGE_TABLE[name]
+        for suffix, value, what in (
+                ("_calls_total", calls, "Stage spans closed: "),
+                ("_self_seconds_total", self_s,
+                 "Wall seconds less child stages: "),
+                ("_cpu_seconds_total", cpu_s,
+                 "Thread CPU seconds over the self time (sampled): ")):
+            builder.sample(st.family + suffix, {}, value,
+                           mtype="counter", help=what + st.help)
+
+
+obs_metrics.GLOBAL_REGISTRY.register_collector(_collect_stages)
+
+_annotation_cls = None
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation`` from a ``jax`` some other module
+    of this process imported, else None. Never imports it."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        jax = sys.modules.get("jax")
+        prof = getattr(jax, "profiler", None)
+        _annotation_cls = getattr(prof, "TraceAnnotation", None)
+    return _annotation_cls
+
+
+class _StageSpan:
+    """Context manager of one stage span: counters always, the request
+    trace's :class:`Span` when one is active, the profiler annotation
+    when JAX is loaded. ``with span(..) as sp`` yields this object:
+    ``tag()`` reaches the trace span, ``dur_ns``/``ms`` hold the
+    duration once the block has closed."""
+
+    __slots__ = ("_st", "_tls", "_tags", "_trace", "_span",
+                 "_prev_parent", "_pframe", "_frame", "_anno", "_t0",
+                 "_c0", "dur_ns")
+
+    def __init__(self, st: _Stage, tls: Dict, tags: Dict):
+        self._st = st
+        self._tls = tls             # the opening thread's local dict
+        self._tags = tags
+        self._span = None
+        self.dur_ns = -1
+
+    def tag(self, **tags) -> "_StageSpan":
+        if self._span is not None:
+            self._span.tags.update(tags)
+        return self
+
+    @property
+    def span_id(self) -> Optional[str]:
+        return self._span.span_id if self._span is not None else None
+
+    @property
+    def ms(self) -> float:
+        """Duration in milliseconds (the slow log's ``*Ms`` keys)."""
+        return round(self.dur_ns / 1e6, 3)
+
+    def __enter__(self) -> "_StageSpan":
+        tls = self._tls
+        pframe = self._pframe = tls.get("frame")
+        # [children's wall, children's cpu, CPU-sampling weight]: a
+        # thread's root span (none open, or a hop's) draws the weight
+        weight = pframe[2] if pframe is not None and pframe[2] >= 0 \
+            else self._st.cpu_weight(time.perf_counter_ns())
+        self._frame = tls["frame"] = [0, 0, weight]
+        trace = self._trace = tls.get("trace")
+        if trace is not None:
+            self._prev_parent = tls.get("parent")
+            sp = self._span = Span(self._st.name, _new_id(),
+                                   self._prev_parent, time.time_ns())
+            if self._tags:
+                sp.tags.update(self._tags)
+            tls["parent"] = sp.span_id
+        cls = _annotation_cls or _annotation()
+        if cls is not None:
+            self._anno = cls(self._st.anno)
+            self._anno.__enter__()
+        else:
+            self._anno = None
+        self._c0 = time.thread_time_ns() if weight else 0
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        dur = time.perf_counter_ns() - self._t0
+        frame = self._frame
+        weight = frame[2]
+        cpu = time.thread_time_ns() - self._c0 if weight else 0
+        if self._anno is not None:
+            self._anno.__exit__(exc_type, exc, tb)
+        self.dur_ns = dur
+        tls = self._tls
+        pframe = tls["frame"] = self._pframe
+        if pframe is not None:
+            pframe[0] += dur
+            pframe[1] += cpu
+        st = self._st
+        self_ns = dur - frame[0]
+        with st.lock:
+            st.calls += 1
+            if self_ns > 0:
+                st.self_ns += self_ns
+                st.cpu_ns += weight * max(0, cpu - frame[1])
+        if st.hist is not None:
+            obs_metrics.observe(st.hist[0], st.hist[1], dur / 1e9,
+                                st.hist[2])
+        sp = self._span
+        if sp is not None:
+            sp.dur_ns = dur
+            if exc is not None and sp.error is None:
+                sp.error = f"{type(exc).__name__}: {exc}"
+            tls["parent"] = self._prev_parent
+            self._trace.add(sp)
+        return False
+
+
 # -- the thread-local active-trace API ---------------------------------------
 
 def span(name: str, **tags):
-    """Open a span under the thread's active trace; no-op (shared
-    object, no allocation) when no trace is active. Usable from any
-    layer without threading a tracer object through."""
-    tr = getattr(_state, "trace", None)
+    """Open a span under the thread's active trace. A name in
+    :data:`STAGES` is always timed (:class:`_StageSpan`); any other is
+    the shared no-op object (no allocation) when no trace is active.
+    Usable from any layer without threading a tracer object through."""
+    tls = _state.__dict__
+    st = _STAGE_TABLE.get(name)
+    if st is not None:
+        return _StageSpan(st, tls, tags)
+    tr = tls.get("trace")
     if tr is None:
         return _NOOP
-    return _LiveSpan(tr, name, getattr(_state, "parent", None), tags)
+    return _LiveSpan(tr, name, tls.get("parent"), tags)
 
 
 def event(name: str, **tags) -> None:
@@ -241,37 +532,50 @@ def current_trace() -> Optional[Trace]:
     return getattr(_state, "trace", None)
 
 
-def capture() -> Optional[Tuple[Trace, Optional[str]]]:
-    """Snapshot (trace, parent span id) for reinstalling on another
-    thread (the batcher's executor hop); None when untraced."""
+def capture() -> Optional[Tuple[Optional[Trace], Optional[str],
+                                Optional[List[int]]]]:
+    """Snapshot (trace, parent span id, open stage frame) for
+    reinstalling on another thread (the batcher's executor hop); None
+    when there is neither a trace nor an open stage span."""
     tr = getattr(_state, "trace", None)
-    if tr is None:
+    frame = getattr(_state, "frame", None)
+    if tr is None and frame is None:
         return None
-    return tr, getattr(_state, "parent", None)
+    return tr, getattr(_state, "parent", None), frame
 
 
 class use:
-    """Reinstall a captured trace context on the current thread:
+    """Reinstall a captured context on the current thread:
     ``with trace.use(ctx): ...``. ``ctx=None`` is a no-op (so callers
-    can pass ``capture()``'s result through unconditionally)."""
+    can pass ``capture()``'s result through unconditionally). Stage
+    spans closed inside count as children of the captured stage span:
+    on exit their wall time (not their CPU: it was this thread's) is
+    added to its frame, so the capturing thread must still be inside
+    that span — parked on the hop's result — when this block ends."""
 
-    __slots__ = ("_ctx", "_prev")
+    __slots__ = ("_ctx", "_prev", "_hop")
 
-    def __init__(self, ctx: Optional[Tuple[Trace, Optional[str]]]):
+    def __init__(self, ctx):
         self._ctx = ctx
 
     def __enter__(self):
         if self._ctx is None:
             return self
         self._prev = (getattr(_state, "trace", None),
-                      getattr(_state, "parent", None))
+                      getattr(_state, "parent", None),
+                      getattr(_state, "frame", None))
         _state.trace = self._ctx[0]
         _state.parent = self._ctx[1]
+        # weight -1: root spans under the hop draw their own
+        self._hop = _state.frame = [0, 0, -1] \
+            if self._ctx[2] is not None else None
         return self
 
     def __exit__(self, *exc):
         if self._ctx is not None:
-            _state.trace, _state.parent = self._prev
+            if self._hop is not None:
+                self._ctx[2][0] += self._hop[0]
+            _state.trace, _state.parent, _state.frame = self._prev
         return False
 
 

@@ -532,13 +532,13 @@ class ShardedTiles:
             self.mesh, func, t_local, num_groups, agg), cost_args=args)
         return np.asarray(fn(*args))[:, :steps.size]
 
-    def eval_grouped_pair(self, func: str, steps: np.ndarray,
-                          window_ms: int, gids: np.ndarray,
-                          num_groups: int, offset_ms: int = 0
-                          ) -> Tuple[np.ndarray, np.ndarray]:
-        """Fused `sum by (g)` contract off the resident store ->
-        (sums [T, G], counts [T, G]) numpy, matching the Pallas
-        group-sum kernel's return shape (TpuBackend.fused_groupsum)."""
+    def dispatch_grouped_pair(self, func: str, steps: np.ndarray,
+                              window_ms: int, gids: np.ndarray,
+                              num_groups: int, offset_ms: int = 0):
+        """Enqueue the fused `sum by (g)` program off the resident store
+        -> device (sums [T_pad, G], counts [T_pad, G]); the caller syncs
+        and cuts to ``steps.size`` rows (``eval_grouped_pair`` does
+        both)."""
         t_local, w0s, w0e, step = self._grid(steps, window_ms, offset_ms)
         g = np.full(self.S_pad, -1, dtype=np.int32)
         g[:self.S] = np.asarray(gids, dtype=np.int32)
@@ -551,7 +551,17 @@ class ShardedTiles:
                self._mesh_key())
         fn = _jit_lookup(key, lambda: _build_grouped_pair_eval(
             self.mesh, func, t_local, num_groups), cost_args=args)
-        sums, cnts = fn(*args)
+        return fn(*args)
+
+    def eval_grouped_pair(self, func: str, steps: np.ndarray,
+                          window_ms: int, gids: np.ndarray,
+                          num_groups: int, offset_ms: int = 0
+                          ) -> Tuple[np.ndarray, np.ndarray]:
+        """Fused `sum by (g)` contract off the resident store ->
+        (sums [T, G], counts [T, G]) numpy, matching the Pallas
+        group-sum kernel's return shape (TpuBackend.fused_groupsum)."""
+        sums, cnts = self.dispatch_grouped_pair(
+            func, steps, window_ms, gids, num_groups, offset_ms)
         T = steps.size
         return np.asarray(sums)[:T], np.asarray(cnts)[:T]
 

@@ -76,9 +76,10 @@ from filodb_tpu.obs import metrics as obs_metrics
 from filodb_tpu.obs import trace as obs_trace
 from filodb_tpu.query import qos
 
-_QWAIT_HELP = ("Wall seconds a query spent parked on the micro-batcher "
-               "(executor queueing + residual gather window); 0 for "
-               "inline single-query dispatches")
+# the batcher-queue-wait stage span observes the parked waits into this
+# family; inline dispatches add their 0 here so _count stays the number
+# of queries admitted
+_QWAIT = obs_trace.STAGE_HISTOGRAMS["batcher-queue-wait"][:2]
 _OCC_HELP = "Members per micro-batch dispatch (batch occupancy)"
 
 
@@ -300,8 +301,7 @@ class MicroBatcher:
         if not self.enabled:
             res = run_batch([member])
             self.stats.record(1, 0, prio)
-            obs_metrics.observe("filodb_batcher_queue_wait_seconds",
-                                _QWAIT_HELP, 0.0)
+            obs_metrics.observe(*_QWAIT, 0.0)
             return res.get(0)
         idx = None
         with self._lock:
@@ -326,8 +326,7 @@ class MicroBatcher:
         if not concurrent:
             # lone request: single-query kernel path, inline — no
             # executor hop, no gather window
-            obs_metrics.observe("filodb_batcher_queue_wait_seconds",
-                                _QWAIT_HELP, 0.0)
+            obs_metrics.observe(*_QWAIT, 0.0)
             return self._execute(key, p, run_batch, queued=False)
         if exec_here:
             # leader under concurrency: queue the OPEN batch — arrivals
@@ -336,12 +335,18 @@ class MicroBatcher:
             # The trace context hops threads with the closure so device
             # spans recorded on the executor land in the same trace;
             # the executor queue orders by the batch's priority class.
-            tctx = obs_trace.capture()
-            self.executor.submit(
-                lambda: self._execute(key, p, run_batch, queued=True,
-                                      tctx=tctx),
-                priority=p.priority)
-            return self._wait(p, 0)
+            # The leader parks INSIDE its batcher-queue-wait span before
+            # the capture, so the batch's stages on the executor thread
+            # are that span's children (self time: the pure wait).
+            with obs_trace.span("batcher-queue-wait", leader=True):
+                tctx = obs_trace.capture()
+                self.executor.submit(
+                    lambda: self._execute(key, p, run_batch, queued=True,
+                                          tctx=tctx),
+                    priority=p.priority)
+                res = p.future.result()
+            with obs_trace.span("device-sync"):
+                return res.get(0)
         # CPU: gather by yielding the GIL a few times (concurrent
         # same-shape submitters join during the yields; no fixed sleep
         # enters the latency path), then execute on THIS thread so the
@@ -354,17 +359,13 @@ class MicroBatcher:
             if len(p.members) >= self.max_batch:
                 break
             time.sleep(0)
-        obs_metrics.observe("filodb_batcher_queue_wait_seconds",
-                            _QWAIT_HELP, 0.0)
+        obs_metrics.observe(*_QWAIT, 0.0)
         return self._execute(key, p, run_batch, queued=False)
 
     @hot_path
     def _wait(self, p: _Pending, idx: int) -> np.ndarray:
-        t0 = time.perf_counter()
         with obs_trace.span("batcher-queue-wait"):
             res = p.future.result()
-        obs_metrics.observe("filodb_batcher_queue_wait_seconds",
-                            _QWAIT_HELP, time.perf_counter() - t0)
         with obs_trace.span("device-sync"):
             return res.get(idx)
 

@@ -380,13 +380,19 @@ def aggregate(grid: GridResult, op: str, params: Tuple = (),
               ) -> GridResult:
     """Cross-series aggregation on the grid
     (exec/aggregator/*.scala map-reduce-present protocol)."""
+    with obs_trace.span("aggregate", op=op):
+        return _aggregate(grid, op, params, by, without)
+
+
+def _aggregate(grid, op, params, by, without) -> GridResult:
     if grid.is_hist() and op == "sum":
         return _aggregate_hist_sum(grid, by, without)
     v = grid.values  # [S, T]
     steps = grid.steps
     if grid.num_series == 0:
         return GridResult(steps, [], np.zeros((0, steps.size)))
-    gids, gkeys = _group_keys(grid.keys, tuple(by), tuple(without))
+    with obs_trace.span("group-keys"):
+        gids, gkeys = _group_keys(grid.keys, tuple(by), tuple(without))
     ng = len(gkeys)
     T = steps.size
     present = ~np.isnan(v)
@@ -1125,24 +1131,26 @@ class QueryEngine:
         params = RangeParams(inner.start_ms, inner.step_ms, inner.end_ms)
         res = None
         if series and not any(s.values.ndim == 2 for s in series):
-            keys = [dict(s.labels) for s in series]
-            gids, gkeys = _group_keys(keys, tuple(plan.by),
-                                      tuple(plan.without))
+            with obs_trace.span("group-keys"):
+                keys = [dict(s.labels) for s in series]
+                gids, gkeys = _group_keys(keys, tuple(plan.by),
+                                          tuple(plan.without))
             res = self.backend.fused_groupsum(
                 series, inner.function, params.steps, inner.window_ms,
                 inner.offset_ms, gids, len(gkeys))
         if res is not None:
-            sums, cnts = res                       # [T, G]
-            cnt = cnts.T.astype(np.float64)        # [G, T]
-            with np.errstate(invalid="ignore", divide="ignore"):
-                if plan.op == "sum":
-                    out = sums.T.astype(np.float64)
-                elif plan.op == "count":
-                    out = cnt.copy()
-                else:
-                    out = sums.T.astype(np.float64) / cnt
-            out = np.where(cnt == 0, np.nan, out)
-            return GridResult(params.steps, gkeys, out)
+            with obs_trace.span("aggregate", op=plan.op, path="fused"):
+                sums, cnts = res                       # [T, G]
+                cnt = cnts.T.astype(np.float64)        # [G, T]
+                with np.errstate(invalid="ignore", divide="ignore"):
+                    if plan.op == "sum":
+                        out = sums.T.astype(np.float64)
+                    elif plan.op == "count":
+                        out = cnt.copy()
+                    else:
+                        out = sums.T.astype(np.float64) / cnt
+                out = np.where(cnt == 0, np.nan, out)
+                return GridResult(params.steps, gkeys, out)
         # general path over the already-selected series
         grid = None
         if self.backend is not None:

@@ -572,6 +572,7 @@ def counter_groupsum(func: str, st: int, dspan: int, hi_mode: int,
                 jax.ShapeDtypeStruct((T_pad, G), jnp.float32),
             ),
             interpret=interpret,
+            name="counter_groupsum",
         )(params, v_p, base, onehot)
 
     with jax.enable_x64(False):
@@ -732,6 +733,7 @@ def window_extract(tr: jnp.ndarray, pay: jnp.ndarray,
             out_specs=(st_spec, st_spec, st_spec, st3_spec, st3_spec),
             out_shape=out_shapes,
             interpret=interpret,
+            name="window_extract",
         )(params, tr, pay)
     cnt, tlo, thi, plo, phi = outs
     return (cnt[:S, :nsteps], tlo[:S, :nsteps], thi[:S, :nsteps],

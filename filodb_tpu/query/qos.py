@@ -53,6 +53,7 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from filodb_tpu.lint.locks import guarded_by
+from filodb_tpu.obs import trace as obs_trace
 
 DEFAULT_TENANT = "default"
 TENANT_HEADER = "X-Filo-Tenant"
@@ -571,8 +572,12 @@ class AdmissionController:
     def slot(self, tenant: str = DEFAULT_TENANT):
         """Bounded-wait admission slot; raises AdmissionRejected on
         saturation (the caller may still serve the stale-cache rung —
-        that path reads memory, not a slot)."""
-        if not self.try_acquire():
+        that path reads memory, not a slot). The wait is the
+        ``admission-wait`` stage: the slot is taken before the request's
+        ``query`` span opens, so no other clock sees it."""
+        with obs_trace.span("admission-wait"):
+            admitted = self.try_acquire()
+        if not admitted:
             with self._lock:
                 self.slot_rejections += 1
             raise AdmissionRejected(

@@ -28,6 +28,7 @@ irregular scrape) fall back to the general packed path in tpu.py.
 
 from __future__ import annotations
 
+import functools as _functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -598,6 +599,7 @@ def _take(arr: jnp.ndarray, cols: jnp.ndarray) -> jnp.ndarray:
     return jnp.take(arr, cols, axis=1)
 
 
+@jax.named_scope("select_last")
 def _select_last(arrs, names, num_slots, k_hi, wend):
     """Channel values at the LAST sample with ts <= wend_t, per series:
     2-candidate select between slot K_hi's forward fill and K_hi-1's."""
@@ -615,6 +617,7 @@ def _select_last(arrs, names, num_slots, k_hi, wend):
     return out
 
 
+@jax.named_scope("select_first")
 def _select_first(arrs, names, num_slots, k_lo, wstart):
     """Channel values at the FIRST sample with ts >= wstart_t."""
     N = num_slots
@@ -631,6 +634,7 @@ def _select_first(arrs, names, num_slots, k_lo, wstart):
     return out
 
 
+@jax.named_scope("window_sum")
 def _window_sum(arrs, name, num_slots, k_lo, k_hi, wstart, wend):
     """Exact sum of a channel over samples with ts in [wstart_t, wend_t]:
     prefix difference over slots [K_lo, K_hi] minus edge-slot samples that
@@ -820,7 +824,8 @@ def _eval_counter_t(func: str, nsteps: int, arrs: Dict[str, jnp.ndarray],
     wstart = w0s + t * step
     k_hi = jnp.floor((wend - base + dt / 2.0) / dt).astype(jnp.int64)
     k_lo = jnp.ceil((wstart - base - dt / 2.0) / dt).astype(jnp.int64)
-    TK = lambda a, k: jnp.take(a, k, axis=0)            # [T, S] rows
+    TK = jax.named_scope("window_take")(
+        lambda a, k: jnp.take(a, k, axis=0))            # [T, S] rows
     wend_d = wend.astype(jnp.float64)[:, None]
     wstart_d = wstart.astype(jnp.float64)[:, None]
     # counts: prefix diff + edge-slot jitter corrections
@@ -929,7 +934,8 @@ def _eval_counter_fast(func: str, nsteps: int, arrs: Dict[str, jnp.ndarray],
     k_lo = jnp.ceil((wstart - base - dt / 2.0) / dt).astype(jnp.int64)
     wend_r = (wend - base).astype(jnp.int32)[:, None]       # guarded i32
     wstart_r = (wstart - base).astype(jnp.int32)[:, None]
-    TK = lambda a, k: jnp.take(a, k, axis=0)                # [T, S] rows
+    TK = jax.named_scope("window_take")(
+        lambda a, k: jnp.take(a, k, axis=0))                # [T, S] rows
 
     kc = jnp.clip(k_hi, 0, N - 1).astype(jnp.int32)         # == khx
     kp = jnp.clip(k_hi - 1, 0, N - 1).astype(jnp.int32)
@@ -1029,6 +1035,7 @@ def _eval_counter_slide(func: str, nsteps: int, st: int,
     k_c0 = jnp.floor((w0e - base + dt / 2.0) / dt).astype(jnp.int32)
     k_l0 = jnp.ceil((w0s - base - dt / 2.0) / dt).astype(jnp.int32)
 
+    @jax.named_scope("window_rows")
     def rows(perm, k0):
         r = jnp.mod(k0, sti)
         g = jnp.floor_divide(k0, sti)
@@ -1070,6 +1077,7 @@ def _eval_counter_slide(func: str, nsteps: int, st: int,
            "formula — XLA lowers the chain per-program, so two "
            "programs (mesh-on vs mesh-off instant queries) may differ "
            "by at most twice that budget (rel_bound(cross_program))")
+@jax.named_scope("rate_epilogue_f32")
 def _f32_epilogue(func, counts, t1, v1, t2, v2, wstart_r, wend_r, wdur_s):
     """Shared f32 extrapolation epilogue: exact f64 delta, f32 factor."""
     f32 = jnp.float32
@@ -1127,6 +1135,17 @@ __guarded_by__ = {"_JIT_STATS": "_JIT_STATS_LOCK"}
            "compiled program), priced at one f64 (8 B) per packed "
            "slot of the largest captured constant; the executables "
            "themselves are host code, not HBM")
+def _bind(fn, *static):
+    """``fn`` with its leading static arguments bound, under ``fn``'s own
+    name: ``jax.jit`` of a ``functools.partial`` compiles as
+    ``jit__unknown``, of this as ``jit_<fn>`` — the name the profiler's
+    ``XLA Modules`` line and every op's metadata carry."""
+    @_functools.wraps(fn)
+    def bound(*args):
+        return fn(*static, *args)
+    return bound
+
+
 def _jit_lookup(cache: Dict[Tuple, object], key: Tuple, build,
                 site: str = "tilestore", cost_args=None) -> object:
     """Dispatch-table lookup with hit/miss accounting; ``build()`` makes
@@ -1147,13 +1166,8 @@ def _jit_lookup(cache: Dict[Tuple, object], key: Tuple, build,
         _JIT_STATS["hits" if fn is not None else "misses"] += 1
     if fn is None:
         from filodb_tpu.obs import devprof
-        from filodb_tpu.obs import metrics as obs_metrics
         from filodb_tpu.obs import trace as obs_trace
-        with obs_metrics.timed(
-                "filodb_kernel_build_seconds",
-                "Wall seconds per evaluator build on a dispatch-table "
-                "miss (trace + XLA compile)"), \
-                obs_trace.span("kernel-build", site=site):
+        with obs_trace.span("kernel-build", site=site):
             fn = devprof.build_profiled(site, key, build,
                                         cost_args=cost_args)
         cache[key] = fn
@@ -1228,18 +1242,18 @@ def evaluate_counters_t(tiles: AlignedTiles, func: str, steps: np.ndarray,
                 np.int64(tiles.base_ms), np.int64(tiles.dt_ms),
                 np.int64(w0s), np.int64(w0e), np.int64(step))
         fn = _jit_lookup(_EVAL_T_JIT, key, lambda: jax.jit(
-            _functools.partial(_eval_counter_slide, func, nsteps, st)),
+            _bind(_eval_counter_slide, func, nsteps, st)),
             cost_args=args)
         return fn(*args)
     if fits_i32:
         arrs = _tiles_arrays_fast(tiles, func)
         key = ("fast", func, nsteps)
-        build = lambda: jax.jit(_functools.partial(
+        build = lambda: jax.jit(_bind(
             _eval_counter_fast, func, nsteps))
     else:
         arrs = _tiles_arrays_t(tiles, func)
         key = ("t", func, nsteps)
-        build = lambda: jax.jit(_functools.partial(
+        build = lambda: jax.jit(_bind(
             _eval_counter_t, func, nsteps))
     args = (arrs, np.int64(tiles.num_slots),
             np.int64(tiles.base_ms), np.int64(tiles.dt_ms),
@@ -1328,8 +1342,6 @@ def groupsum_counters(tiles: AlignedTiles, func: str, steps: np.ndarray,
         interpret=interpret)
 
 
-import functools as _functools
-
 _EVAL_JIT: Dict[Tuple, object] = {}
 
 
@@ -1349,7 +1361,7 @@ def evaluate_aligned(tiles: AlignedTiles, func: str, steps: np.ndarray,
             np.int64(tiles.base_ms), np.int64(tiles.dt_ms),
             np.int64(w0s), np.int64(w0e), np.int64(step))
     fn = _jit_lookup(_EVAL_JIT, (func, nsteps), lambda: jax.jit(
-        _functools.partial(_eval_core, func, nsteps)), cost_args=args)
+        _bind(_eval_core, func, nsteps)), cost_args=args)
     return fn(*args)
 
 
@@ -1424,19 +1436,19 @@ def evaluate_counters_t_batch(tiles: AlignedTiles, func: str,
         arrs = _tiles_arrays_slide(tiles, func, st)
         key = ("slide", func, nsteps, st, b_pad)
         build = lambda: jax.jit(jax.vmap(
-            _functools.partial(_eval_counter_slide, func, nsteps, st),
+            _bind(_eval_counter_slide, func, nsteps, st),
             in_axes=_GRID_AXES))
     elif kind == "fast":
         arrs = _tiles_arrays_fast(tiles, func)
         key = ("fast", func, nsteps, b_pad)
         build = lambda: jax.jit(jax.vmap(
-            _functools.partial(_eval_counter_fast, func, nsteps),
+            _bind(_eval_counter_fast, func, nsteps),
             in_axes=_GRID_AXES))
     else:
         arrs = _tiles_arrays_t(tiles, func)
         key = ("t", func, nsteps, b_pad)
         build = lambda: jax.jit(jax.vmap(
-            _functools.partial(_eval_counter_t, func, nsteps),
+            _bind(_eval_counter_t, func, nsteps),
             in_axes=_GRID_AXES))
     args = (arrs, np.int64(tiles.num_slots),
             np.int64(tiles.base_ms), np.int64(tiles.dt_ms),
@@ -1460,7 +1472,7 @@ def evaluate_aligned_batch(tiles: AlignedTiles, func: str, nsteps: int,
             w0s_v, w0e_v, np.int64(step))
     fn = _jit_lookup(_EVAL_VMAP, (func, nsteps, b_pad),
                      lambda: jax.jit(jax.vmap(
-                         _functools.partial(_eval_core, func, nsteps),
+                         _bind(_eval_core, func, nsteps),
                          in_axes=_GRID_AXES)),
                      site="tilestore-batch", cost_args=args)
     return fn(*args)

@@ -48,13 +48,9 @@ from filodb_tpu.lint.numerics import precision
 from filodb_tpu.lint.hotpath import hot_path
 from filodb_tpu.lint.threads import thread_root
 from filodb_tpu.obs import devprof
-from filodb_tpu.obs import metrics as obs_metrics
 from filodb_tpu.obs import trace as obs_trace
 from filodb_tpu.query.cumsum import cumsum_f64
 from filodb_tpu.query.model import GridResult, RangeParams, RawSeries
-
-_DEV_HELP = ("Wall seconds per device dispatch (kernel submission + "
-             "device compute + the batch's one host sync)")
 
 
 def _sds(shape, dtype):
@@ -194,6 +190,7 @@ def _grid(w0s, w0e, step, nsteps):
         _colify(w0e) + t * _colify(step)
 
 
+@jax.named_scope("window_bounds")
 def _bounds(ts, w0s, w0e, step, nsteps):
     """[S, T] window index bounds for a UNIFORM step grid.
 
@@ -229,12 +226,14 @@ def _take(arr, idx):
     return jnp.take_along_axis(arr, idx, axis=1)
 
 
+@jax.named_scope("prefix")
 def _prefix(x):
     """[S, N] -> [S, N+1] exclusive prefix sums."""
     return jnp.concatenate(
         [jnp.zeros((x.shape[0], 1), x.dtype), cumsum_f64(x, axis=1)], axis=1)
 
 
+@jax.named_scope("counter_correction")
 def _correction(vals, lens):
     """Counter-reset correction per sample: cumsum of drop magnitudes."""
     idx = jnp.arange(vals.shape[1])
@@ -252,6 +251,7 @@ def _correction(vals, lens):
            "the pure-Python reference (promql/refeval._extrapolated) "
            "— the two arms of the differential rail agree at the "
            "formula level, not just end to end")
+@jax.named_scope("rate_epilogue")
 def _extrapolated_rate(wstart, wend, counts, t1, v1, t2, v2, is_counter,
                        is_rate):
     """(rangefn/RateFunctions.scala:37 extrapolatedRate, on device.)
@@ -399,12 +399,20 @@ def _window_gather(func: str, w_bound: int, ts, vals, lens, w0s, w0e,
     S, N = ts.shape
     lo, hi = _bounds(ts, w0s, w0e, step, nsteps)   # [S, T]
     has = hi >= lo
-    offs = jnp.arange(w_bound)                  # [W]
-    gidx = lo[:, :, None] + offs[None, None, :]  # [S, T, W]
-    in_win = (gidx <= hi[:, :, None]) & (gidx < lens[:, None, None])
-    gidx_c = jnp.clip(gidx, 0, N - 1)
-    g = jnp.take_along_axis(vals, gidx_c.reshape(S, -1), axis=1).reshape(
-        gidx.shape)
+    with jax.named_scope("window_gather"):
+        offs = jnp.arange(w_bound)                  # [W]
+        gidx = lo[:, :, None] + offs[None, None, :]  # [S, T, W]
+        in_win = (gidx <= hi[:, :, None]) & (gidx < lens[:, None, None])
+        gidx_c = jnp.clip(gidx, 0, N - 1)
+        g = jnp.take_along_axis(vals, gidx_c.reshape(S, -1),
+                                axis=1).reshape(gidx.shape)
+    out = _gather_reduce(func, w_bound, g, in_win, scalar)
+    return jnp.where(has, out, jnp.nan)
+
+
+@jax.named_scope("window_reduce")
+def _gather_reduce(func: str, w_bound: int, g, in_win, scalar):
+    """Reduce the gathered [S, T, W] window tiles over W."""
     if func == "min_over_time":
         out = jnp.min(jnp.where(in_win, g, jnp.inf), axis=2)
         out = jnp.where(jnp.isinf(out), jnp.nan, out)
@@ -430,7 +438,7 @@ def _window_gather(func: str, w_bound: int, ts, vals, lens, w0s, w0e,
         out = jnp.where(scalar < 0, -jnp.inf, out)
     else:
         raise ValueError(f"unhandled gather func {func}")
-    return jnp.where(has, out, jnp.nan)
+    return out
 
 
 _GATHER_FUNCS = frozenset({"min_over_time", "max_over_time",
@@ -723,19 +731,21 @@ class TpuBackend:
                                    int(step), nsteps, w_bound)
             return b.submit(key, member, functools.partial(
                 self._packed_run, func, t_bucket, scalar))
-        with obs_metrics.timed("filodb_device_execute_seconds",
-                               _DEV_HELP), \
-                obs_trace.span("device-dispatch", path="packed"):
-            return self._packed_single(func, ts, vals, lens, w0s, w0e,
-                                       step, nsteps, t_bucket, scalar,
-                                       w_bound)
+        with obs_trace.span("device-dispatch", path="packed"):
+            dev = self._packed_single(func, ts, vals, lens, w0s, w0e,
+                                      step, nsteps, t_bucket, scalar,
+                                      w_bound)
+        with obs_trace.span("device-sync"):
+            return np.asarray(dev)[:ts.shape[0], :nsteps]
 
     @hot_path
     def _packed_single(self, func, ts, vals, lens, w0s, w0e, step, nsteps,
-                       t_bucket, scalar, w_bound) -> np.ndarray:
+                       t_bucket, scalar, w_bound):
         """Single-query packed dispatch with pow2 shape bucketing: S and
         the step count pad to buckets so repeat queries of nearby shapes
-        reuse compiled executables instead of retracing."""
+        reuse compiled executables instead of retracing. Enqueue only:
+        returns the device array [S-bucket, >= nsteps]; the caller's
+        ``device-sync`` stage brings ``[:S, :nsteps]`` to the host."""
         S, N = ts.shape
         s_bucket = _next_pow2(S, 8)
         if s_bucket != S:
@@ -758,8 +768,7 @@ class TpuBackend:
                                               w0e, step, nsteps)
                 if out is not None:
                     self._count_exec(("pallas", func, s_bucket, N, nsteps))
-                    # graftlint: disable=host-transfer-in-hot-loop (single-query path: designed sync point at kernel egress)
-                    return np.asarray(out)[:S]
+                    return out
             self._count_exec(
                 ("endpoint", func, s_bucket, N, t_bucket),
                 probe=_lower_probe(_window_endpoint, func,
@@ -768,8 +777,7 @@ class TpuBackend:
                                    t_bucket, scalar))
             out = _window_endpoint(func, ts, vals, lens,
                                    w0s, w0e, step, t_bucket, scalar)
-        # graftlint: disable=host-transfer-in-hot-loop (single-query path: designed sync point at kernel egress)
-        return np.asarray(out)[:S, :nsteps]
+        return out
 
     def _packed_run(self, func: str, t_bucket: int, scalar: float,
                     members) -> object:
@@ -779,10 +787,8 @@ class TpuBackend:
         path (bit-for-bit identical; the parity test pins it)."""
         from filodb_tpu.query.batcher import SplitResult
 
-        with obs_metrics.timed("filodb_device_execute_seconds",
-                               _DEV_HELP), \
-                obs_trace.span("device-dispatch", path="packed",
-                               batch=len(members)):
+        with obs_trace.span("device-dispatch", path="packed",
+                            batch=len(members)):
             return self._packed_run_inner(func, t_bucket, scalar,
                                           members, SplitResult)
 
@@ -790,11 +796,16 @@ class TpuBackend:
                           members, SplitResult) -> object:
         if len(members) == 1:
             m = members[0]
-            out = self._packed_single(func, m.ts, m.vals, m.lens,
+            dev = self._packed_single(func, m.ts, m.vals, m.lens,
                                       np.int64(m.w0s), np.int64(m.w0e),
                                       np.int64(m.step), m.nsteps, t_bucket,
                                       scalar, m.w_bound)
-            return SplitResult(out, 1, split=lambda h, i: h)
+            # a batch of one syncs HERE, on the thread that dispatched
+            # it (the executor's busy time is the gather window of the
+            # next batch); device-sync is then a child of device-dispatch
+            with obs_trace.span("device-sync"):
+                host = np.asarray(dev)[:m.ts.shape[0], :m.nsteps]
+            return SplitResult(host, 1, split=lambda h, i: h)
         offs = np.cumsum([0] + [m.ts.shape[0] for m in members])
         s_total = int(offs[-1])
         s_bucket = _next_pow2(s_total, 8)
@@ -863,6 +874,10 @@ class TpuBackend:
         runs in the background."""
         from filodb_tpu.query import tilestore as tst
 
+        with obs_trace.span("tile-build", series=len(series)):
+            return self._build_tile_entry_inner(tst, series, use_snap)
+
+    def _build_tile_entry_inner(self, tst, series, use_snap: bool):
         prefix = [
             RawSeries(s.labels, s.ts[:self._prefix_len(s)],
                       s.values[:self._prefix_len(s)], s.is_counter,
@@ -923,6 +938,10 @@ class TpuBackend:
         selections duplicate tiles and >_TILE_CACHE_MAX distinct selectors
         thrash; per-partition tiles would compose but conflict with cohort
         (shared-cadence) packing, which is what makes the kernels fast."""
+        with obs_trace.span("tile-entry", series=len(series)):
+            return self._tile_entry_inner(series)
+
+    def _tile_entry_inner(self, series):
         use_snap = all(s.snapshot_key is not None for s in series)
         if use_snap:
             key = tuple(s.snapshot_key for s in series)
@@ -1079,34 +1098,33 @@ class TpuBackend:
                 # already spans every device, so inline execution on N
                 # query threads would only oversubscribe it
                 use_executor=True if mesh_st is not None else None)
-        with obs_metrics.timed("filodb_device_execute_seconds",
-                               _DEV_HELP), \
-                obs_trace.span("device-dispatch",
-                               path="mesh-aligned" if mesh_st is not None
-                               else "aligned"):
+        with obs_trace.span("device-dispatch",
+                            path="mesh-aligned" if mesh_st is not None
+                            else "aligned"):
+            if mesh_st is not None:
+                self.mesh_dispatches += 1
             if counters:
-                if mesh_st is not None:
-                    self.mesh_dispatches += 1
-                    # graftlint: disable=host-transfer-in-hot-loop (single-query path: designed sync point at kernel egress)
-                    return np.asarray(mesh_st.eval_counters(
-                        func, steps, window_ms, offset_ms)).T
                 # counter family rides the slot-major f32-hybrid fast
                 # path: int32 timestamps + exact f64 boundary deltas,
                 # f32 extrapolation epilogue (~3e-7 relative vs the f64
                 # oracle; grids wider than int32 ms take the exact
                 # path) — test_tilestore pins parity + the exact
                 # fallback
-                # graftlint: disable=host-transfer-in-hot-loop (single-query path: designed sync point at kernel egress)
-                return np.asarray(tst.evaluate_counters_t(
-                    tiles, func, steps, window_ms, offset_ms).T)
-            if mesh_st is not None:
-                self.mesh_dispatches += 1
-                # graftlint: disable=host-transfer-in-hot-loop (single-query path: designed sync point at kernel egress)
-                return np.asarray(mesh_st.eval_aligned(
-                    tiles, func, steps, window_ms, offset_ms))
+                dev = (mesh_st.eval_counters(func, steps, window_ms,
+                                             offset_ms)
+                       if mesh_st is not None else
+                       tst.evaluate_counters_t(tiles, func, steps,
+                                               window_ms, offset_ms))
+            elif mesh_st is not None:
+                dev = mesh_st.eval_aligned(tiles, func, steps, window_ms,
+                                           offset_ms)
+            else:
+                dev = tst.evaluate_aligned(tiles, func, steps, window_ms,
+                                           offset_ms, func_args)
+        with obs_trace.span("device-sync"):
             # graftlint: disable=host-transfer-in-hot-loop (single-query path: designed sync point at kernel egress)
-            return np.asarray(tst.evaluate_aligned(
-                tiles, func, steps, window_ms, offset_ms, func_args))
+            host = np.asarray(dev)
+        return host.T if counters else host
 
     def _mesh_sharded(self, tiles, func: str, steps, window_ms: int,
                       offset_ms: int, family):
@@ -1138,12 +1156,10 @@ class TpuBackend:
         from filodb_tpu.query import tilestore as tst
         from filodb_tpu.query.batcher import SplitResult
 
-        with obs_metrics.timed("filodb_device_execute_seconds",
-                               _DEV_HELP), \
-                obs_trace.span("device-dispatch",
-                               path="mesh-aligned" if mesh_st is not None
-                               else "aligned",
-                               batch=len(members)):
+        with obs_trace.span("device-dispatch",
+                            path="mesh-aligned" if mesh_st is not None
+                            else "aligned",
+                            batch=len(members)):
             return self._aligned_run_inner(tst, SplitResult, tiles,
                                            func, family, nsteps, step,
                                            window_ms, offset_ms, mesh_st,
@@ -1226,44 +1242,60 @@ class TpuBackend:
         tiles, idx = entry.tiles, entry.idx
         if tiles is None or len(idx) != len(series):
             return None
-        # every window must resolve on the tiles' covered prefix: fused
-        # results can't splice a host-side tail scan per group (a stale
-        # entry serving across a flush covers less than the current
-        # chunk prefix — cov_min_ms is the binding bound)
-        if entry.cov_min_ms is not None and steps.size and \
-                int(steps[-1] - offset_ms) >= entry.cov_min_ms:
+        with obs_trace.span("fused-eligibility", series=len(series)):
+            if not self._fused_covered(entry, series, steps, offset_ms):
+                return None
+            # mesh-resident grouped collective first: the one-hot
+            # matmul + psum runs off the device-resident sharded tiles
+            # (no per-query pack), honoring the same fast-family
+            # eligibility as the per-series sharded path
+            mesh_st = None
+            if self.mesh_eval is not None and steps.size >= 1:
+                mesh_st = self._mesh_sharded(
+                    tiles, func, steps, window_ms, offset_ms,
+                    tst.counters_batch_family(tiles, func, steps,
+                                              window_ms, offset_ms))
+        if mesh_st is None and on_cpu and not FUSED_GROUPSUM_INTERPRET:
             return None
+        with obs_trace.span("onehot", groups=G):
+            gvec = np.asarray(gids)[np.asarray(idx)]
+            if mesh_st is None:
+                onehot = np.zeros((len(series), G), np.float32)
+                onehot[np.arange(len(series)), gvec] = 1.0
+        if mesh_st is not None:
+            self.fused_aggs += 1
+            self.mesh_dispatches += 1
+            with obs_trace.span("device-dispatch", path="mesh-fused"):
+                res = mesh_st.dispatch_grouped_pair(
+                    func, steps, window_ms, gvec, G, offset_ms)
+        else:
+            with obs_trace.span("device-dispatch", path="fused"):
+                res = tst.groupsum_counters(
+                    tiles, func, steps, window_ms, onehot, offset_ms,
+                    interpret=on_cpu)
+            if res is None:
+                return None
+            self.fused_aggs += 1
+        with obs_trace.span("device-sync"):
+            T = steps.size
+            return np.asarray(res[0])[:T], np.asarray(res[1])[:T]
+
+    def _fused_covered(self, entry, series, steps: np.ndarray,
+                       offset_ms: int) -> bool:
+        """Every window must resolve on the tiles' covered prefix: fused
+        results can't splice a host-side tail scan per group (a stale
+        entry serving across a flush covers less than the current chunk
+        prefix — cov_min_ms is the binding bound)."""
+        if not steps.size:
+            return True
+        last = int(steps[-1] - offset_ms)
+        if entry.cov_min_ms is not None and last >= entry.cov_min_ms:
+            return False
         for s in series:
             cl = self._prefix_len(s)
-            if cl < s.ts.size and steps.size and \
-                    int(steps[-1] - offset_ms) >= int(s.ts[cl]):
-                return None
-        gvec = np.asarray(gids)[np.asarray(idx)]
-        # mesh-resident grouped collective first: the one-hot matmul +
-        # psum runs off the device-resident sharded tiles (no per-query
-        # pack), honoring the same fast-family eligibility as the
-        # per-series sharded path
-        if self.mesh_eval is not None and steps.size >= 1:
-            mesh_st = self._mesh_sharded(
-                tiles, func, steps, window_ms, offset_ms,
-                tst.counters_batch_family(tiles, func, steps, window_ms,
-                                          offset_ms))
-            if mesh_st is not None:
-                self.fused_aggs += 1
-                self.mesh_dispatches += 1
-                return mesh_st.eval_grouped_pair(func, steps, window_ms,
-                                                 gvec, G, offset_ms)
-        if on_cpu and not FUSED_GROUPSUM_INTERPRET:
-            return None
-        onehot = np.zeros((len(series), G), np.float32)
-        onehot[np.arange(len(series)), gvec] = 1.0
-        res = tst.groupsum_counters(
-            tiles, func, steps, window_ms, onehot, offset_ms,
-            interpret=on_cpu)
-        if res is None:
-            return None
-        self.fused_aggs += 1
-        return np.asarray(res[0]), np.asarray(res[1])
+            if cl < s.ts.size and last >= int(s.ts[cl]):
+                return False
+        return True
 
     @staticmethod
     def _window_sample_bound(series, window_ms: int, n_cap: int) -> int:
