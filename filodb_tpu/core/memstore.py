@@ -29,6 +29,7 @@ Key departures from the JVM design, chosen for the TPU execution model:
 from __future__ import annotations
 
 import threading
+from bisect import bisect_left, bisect_right
 from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
 
@@ -67,6 +68,16 @@ class ChunkSetInfo:
             self.vectors[i]) else bh.decode_histograms(self.vectors[i])
 
 
+def _rows_between(ts: np.ndarray, start_ms: int, end_ms: int) -> int:
+    """Rows of sorted ``ts`` with start_ms <= t <= end_ms, by ``bisect``
+    over the buffer and NOT by ``ndarray.searchsorted``: that releases the
+    GIL on every call, and in a loop over a selection's partitions each
+    release hands it to a waiting request thread (measured: 20x the CPU
+    and the wall time with four threads selecting)."""
+    m = memoryview(ts)
+    return bisect_right(m, end_ms) - bisect_left(m, start_ms)
+
+
 def _is_hist(buf: bytes) -> bool:
     return buf[:1] in (bytes([bh.K_HIST_2D]), bytes([bh.K_HIST_SECT]))
 
@@ -97,7 +108,7 @@ class TimeSeriesPartition:
                  "max_chunk_rows", "_chunk_seq",
                  "ingested", "ooo_dropped", "_decode_cache", "_merge_cache",
                  "persisted_chunks", "odp_pending", "_cache_lock",
-                 "card_active", "on_encode")
+                 "card_active", "on_encode", "_chunk_rows", "_chunk_epoch")
 
     def __init__(self, part_id: int, part_key: PartKey, schema: DataSchema,
                  max_chunk_rows: int = DEFAULT_MAX_CHUNK_ROWS):
@@ -105,6 +116,11 @@ class TimeSeriesPartition:
         self.part_key = part_key
         self.schema = schema
         self.chunks: List[ChunkSetInfo] = []
+        # rows in ``chunks``, and how often the list was REBOUND (evicted,
+        # paged in) rather than appended to: what ``select_facts`` hands
+        # out stays true of a later read while the epoch stands
+        self._chunk_rows = 0
+        self._chunk_epoch = 0
         # write buffers are SEGMENT lists: each ingest run appends one
         # numpy array slice (no per-row Python element churn); histogram
         # columns keep per-row [nb] arrays. Row count tracked separately.
@@ -266,6 +282,7 @@ class TimeSeriesPartition:
         # chunk AND the old buffer tail (double count) or neither (drop)
         with self._cache_lock:
             self.chunks.append(info)
+            self._chunk_rows += info.num_rows
             self._ts_buf = []
             self._col_bufs = [[] for _ in self.schema.data_columns]
             self._buf_rows = 0
@@ -372,12 +389,69 @@ class TimeSeriesPartition:
             entry[3] = cat
         return entry[0], entry[3]
 
+    def select_facts(self, col_index: int, start_ms: int, end_ms: int
+                     ) -> Tuple[int, int, int, int, Optional[int],
+                                Optional[int], int]:
+        """What a consumer can use of one column WITHOUT its samples, in
+        one acquisition of the cache lock and O(1) in the rows held:
+        ``(epoch, num_chunks, chunk_len, n_rows, tail_first_ts, last_ts,
+        in_range)``. ``chunk_len`` is the rows of those ``num_chunks``
+        published chunks, ``n_rows`` adds the complete rows of the write
+        buffer, ``tail_first_ts`` is the timestamp of row ``chunk_len``
+        (None: the buffer is empty), ``last_ts`` that of row ``n_rows -
+        1`` and ``in_range`` the rows with start_ms <= t <= end_ms, as
+        ``read_full`` and two searches would count them. Chunks are
+        append-only, so while ``epoch`` stands a later ``read_full_at``
+        gives these rows, then whatever came after."""
+        with self._cache_lock:
+            chunks = self.chunks
+            chunk_len = self._chunk_rows
+            tail = self._buf_rows       # rows every buffer holds whole
+            in_range = 0
+            last = None
+            if chunks:
+                first, last = chunks[0].start_ts, chunks[-1].end_ts
+                if start_ms <= first and last <= end_ms:
+                    in_range = chunk_len
+                elif start_ms <= last and first <= end_ms:
+                    # the decoded timestamps, not the buffer snapshot
+                    in_range = _rows_between(
+                        self._decoded_chunk_arrays_locked(
+                            col_index, self.schema.columns[col_index])[1][0],
+                        start_ms, end_ms)
+            tail_first = None
+            if tail:
+                segs = self._ts_buf
+                tail_first = int(segs[0][0])
+                left = tail
+                for seg in segs:
+                    # a writer in mid-append has rows beyond what
+                    # _buf_rows vouches for: stop at that count
+                    if seg.size > left:
+                        seg = seg[:left]
+                    a, last = int(seg[0]), int(seg[-1])
+                    if start_ms <= a and last <= end_ms:
+                        in_range += seg.size
+                    elif start_ms <= last and a <= end_ms:
+                        in_range += _rows_between(seg, start_ms, end_ms)
+                    left -= seg.size
+                    if not left:
+                        break
+            return (self._chunk_epoch, len(chunks), chunk_len,
+                    chunk_len + tail, tail_first, last, in_range)
+
     def read_full(self, col_index: int
                   ) -> Tuple[np.ndarray, np.ndarray, int]:
         """All samples of one data column: published chunks (cached decode)
         + current write-buffer tail. Returns (ts, vals, chunk_len) where
         chunk_len is the length of the chunk-backed (immutable) prefix —
         downstream device caches key on it (num_chunks pins its content)."""
+        return self.read_full_at(col_index)[:3]
+
+    def read_full_at(self, col_index: int
+                     ) -> Tuple[np.ndarray, np.ndarray, int, int, int]:
+        """``read_full`` plus the ``(num_chunks, epoch)`` its snapshot was
+        taken at, from the same lock acquisition."""
         col = self.schema.columns[col_index]
         # one lock acquisition covers decode AND the tail snapshot: a
         # switch_buffers publishing the tail as a chunk between the two
@@ -386,6 +460,7 @@ class TimeSeriesPartition:
         with self._cache_lock:
             n_chunks, (cts, cvals) = \
                 self._decoded_chunk_arrays_locked(col_index, col)
+            epoch = self._chunk_epoch
             buf_ts, buf_cols = self.buffer_snapshot()
             # merge-cache bookkeeping stays under the same acquisition:
             # a concurrent reader's pop must never race this thread's
@@ -396,10 +471,10 @@ class TimeSeriesPartition:
             else:
                 cached = self._merge_cache.get(col_index)
         if not buf_ts.size:
-            return cts, cvals, cts.size
+            return cts, cvals, cts.size, n_chunks, epoch
         if cached is not None and cached[0] == n_chunks \
                 and cached[1] == buf_ts.size:
-            return cached[2], cached[3], cts.size
+            return cached[2], cached[3], cts.size, n_chunks, epoch
         if col.col_type == ColumnType.HISTOGRAM:
             rows = buf_cols[col_index - 1]
             tail = (np.stack(rows).astype(np.float64) if rows
@@ -419,7 +494,7 @@ class TimeSeriesPartition:
         with self._cache_lock:
             self._merge_cache[col_index] = (n_chunks, buf_ts.size,
                                             mts, mvals)
-        return mts, mvals, cts.size
+        return mts, mvals, cts.size, n_chunks, epoch
 
     def hist_drop_rows(self, col_index: int) -> np.ndarray:
         """Global reset row indices over this histogram column's full
@@ -871,6 +946,8 @@ class TimeSeriesShard:
             # concurrent reader can't repopulate against the old prefix
             with part._cache_lock:
                 part.chunks = infos + part.chunks
+                part._chunk_rows += sum(c.num_rows for c in infos)
+                part._chunk_epoch += 1
                 part.persisted_chunks += len(infos)
                 part._chunk_seq = max(part._chunk_seq, len(part.chunks))
                 part._decode_cache.clear()
@@ -1021,6 +1098,8 @@ class TimeSeriesShard:
                         # never an empty unflagged partition
                         part.odp_pending = True
                         part.chunks = []
+                        part._chunk_rows = 0
+                        part._chunk_epoch += 1
                         part.persisted_chunks = 0
                         part._decode_cache.clear()
                         part._merge_cache.clear()

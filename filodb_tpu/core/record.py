@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import struct
 from dataclasses import dataclass, field
+from types import MappingProxyType
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
@@ -118,6 +119,16 @@ class PartKey:
     @property
     def label_map(self) -> Dict[str, str]:
         return dict(self.labels)
+
+    @property
+    def shared_labels(self) -> Mapping[str, str]:
+        """The labels as ONE read-only mapping, built once per key: what a
+        selection hands every query instead of a dict of its own."""
+        cached = self.__dict__.get("_shared")
+        if cached is None:
+            cached = MappingProxyType(dict(self.labels))
+            object.__setattr__(self, "_shared", cached)
+        return cached
 
     def metric(self, part_schema: PartitionSchema) -> str:
         return self.label_map.get(part_schema.metric_column, "")

@@ -45,7 +45,8 @@ from filodb_tpu.promql.parser import (TimeStepParams, parse_query,
 from filodb_tpu.query import logical as lp
 from filodb_tpu.query import qos
 from filodb_tpu.testing import chaos
-from filodb_tpu.query.engine import QueryEngine  # noqa: F401 (re-export)
+from filodb_tpu.query.engine import (QueryEngine,  # noqa: F401 (re-export)
+                                     select_counts)
 from filodb_tpu.query.planner import QueryPlanner
 from filodb_tpu.query.model import (GridResult, QueryError, QueryLimitError,
                                     QueryLimits, ScalarResult,
@@ -1933,6 +1934,10 @@ class FiloHttpServer:
             "Queries served by the fused group-sum kernel",
         "filodb_mesh_dispatches_total":
             "Dispatches served from the mesh-resident sharded store",
+        "filodb_select_series_total":
+            "Series handles handed out by whole-series selections",
+        "filodb_select_series_read_total":
+            "Series handles whose samples a consumer then read",
         "filodb_exec_cache_hits_total": "Compiled-executable reuse hits",
         "filodb_exec_cache_misses_total": "Compiled-executable retraces",
         "filodb_exec_cache_entries": "Distinct compiled kernel shapes",
@@ -2186,6 +2191,8 @@ class FiloHttpServer:
                                             {}).items()):
                     emit("batcher_priority_queries_total",
                          {"class": cls}, n)
+        emit("select_series_total", {}, select_counts.handles)
+        emit("select_series_read_total", {}, select_counts.reads)
         pc = self.plan_cache.snapshot()
         emit("plan_cache_entries", {}, pc["entries"])
         emit("plan_cache_hits_total", {}, pc["hits"])

@@ -259,9 +259,10 @@ STAGES: Dict[str, str] = {
                "the stages below it)",
     "encode": "result to Prometheus JSON (byte fast path or dict path)",
     "resultcache-stitch": "stitching cached extents with computed spans",
-    "select-series": "index lookup and whole-series reads",
+    "select-series": "index lookup and one handle of facts per series "
+                     "(samples are read by the stage that touches them)",
     "select-span": "index lookup and span-bounded reads (leaf dispatch)",
-    "group-keys": "label dicts of the selection and its group ids",
+    "group-keys": "group ids and group keys from the selection's labels",
     "aggregate": "cross-series aggregation and result shaping on the "
                  "host",
     "device-eval": "backend evaluation of one windowed selector (self "

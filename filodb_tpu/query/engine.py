@@ -54,10 +54,16 @@ def select_raw_series(shards: Sequence[TimeSeriesShard],
     (SelectRawPartitionsExec.scala:159 doExecute; schema resolved per
     partition like MultiSchemaPartitionsExec).
 
-    ``full=True`` reads each matched partition's WHOLE series (cached chunk
-    decode + buffer tail) and attaches store snapshot keys; the windowing
+    ``full=True`` selects each matched partition's WHOLE series (cached
+    chunk decode + buffer tail) under its store snapshot key; the windowing
     path uses this so device tile caches hit across queries — the step grid
-    itself restricts the evaluation to the query range."""
+    itself restricts the evaluation to the query range. For a local shard
+    nothing is read here: each series is a handle (``RawSeries``) of the
+    facts one ``select_facts`` call gives, the in-range rows are counted
+    from them for ``stats`` and ``limits``, and the samples are read when
+    a consumer first touches ``.ts`` / ``.values``, inside that consumer's
+    stage. ``full=False`` (and what a remote shard group returns) reads
+    [start_ms, end_ms] here."""
     with obs_trace.span("select-series", shards=len(shards)) as _sp:
         out = _select_raw_series(shards, filters, start_ms, end_ms,
                                  column, stats, full, limits, deadline)
@@ -68,6 +74,8 @@ def select_raw_series(shards: Sequence[TimeSeriesShard],
 def _select_raw_series(shards, filters, start_ms, end_ms, column, stats,
                        full, limits, deadline) -> List[RawSeries]:
     out: List[RawSeries] = []
+    cols: Dict[int, Tuple] = {}     # id(schema) -> (index, column, hist?)
+    handles = 0
     for shard in shards:
         if deadline is not None:
             deadline.check("raw series selection")
@@ -109,50 +117,110 @@ def _select_raw_series(shards, filters, start_ms, end_ms, column, stats,
             continue
         for part in shard.lookup_partitions(filters, start_ms, end_ms):
             schema = part.schema
-            col_name = column or schema.value_column
-            try:
-                ci = [c.name for c in schema.columns].index(col_name)
-            except ValueError:
-                raise QueryError(
-                    f"schema {schema.name} has no column {col_name}")
-            col = schema.columns[ci]
+            got = cols.get(id(schema))
+            if got is None:
+                got = cols[id(schema)] = _resolve_column(schema, column)
+            ci, col, is_hist = got
+            les = part._hist_scheme.les() \
+                if is_hist and part._hist_scheme is not None else None
             if full:
-                ts, vals, chunk_len = part.read_full(ci)
-                snap = (shard.ref.dataset, shard.shard_num, part.part_id,
-                        part.num_chunks, ci)
+                s, in_range = _partition_handle(shard, part, ci, col, les,
+                                                is_hist, start_ms, end_ms)
+                handles += 1
             else:
                 ts, vals = part.read_range(start_ms, end_ms, ci)
-                chunk_len, snap = -1, None
-            les = None
-            drops = None
-            if col.col_type == ColumnType.HISTOGRAM:
-                les = part._hist_scheme.les() if part._hist_scheme is not None \
-                    else None
-                if full and col.is_counter_like:
-                    # taken after read_full's snapshot: rows appended in
-                    # between may carry drop indices beyond ts.size
-                    drops = part.hist_drop_rows(ci)
-                    drops = drops[drops < ts.size]
-            out.append(RawSeries(
-                labels=dict(part.part_key.labels),
-                ts=ts, values=vals,
-                is_counter=col.is_counter_like,
-                bucket_les=les,
-                snapshot_key=snap,
-                chunk_len=chunk_len if full else -1,
-                hist_drop_rows=drops,
-            ))
+                s = RawSeries(dict(part.part_key.labels), ts, vals,
+                              col.is_counter_like, les)
+                in_range = int(ts.size)
+            out.append(s)
             if stats is not None:
                 stats.series_scanned += 1
-                if full:
-                    lo = int(np.searchsorted(ts, start_ms, side="left"))
-                    hi = int(np.searchsorted(ts, end_ms, side="right"))
-                    stats.samples_scanned += hi - lo
-                else:
-                    stats.samples_scanned += int(ts.size)
+                stats.samples_scanned += in_range
                 if limits is not None:
-                    limits.check(stats)     # abort before materializing more
+                    limits.check(stats)     # abort before selecting more
+    select_counts.handles += handles
     return out
+
+
+def _resolve_column(schema, column: Optional[str]):
+    """-> (index, column, is it a histogram) of ``column`` or of the
+    schema's value column."""
+    name = column or schema.value_column
+    for ci, col in enumerate(schema.columns):
+        if col.name == name:
+            return ci, col, col.col_type == ColumnType.HISTOGRAM
+    raise QueryError(f"schema {schema.name} has no column {name}")
+
+
+class _SelectCounts:
+    """``filodb_select_series_total`` / ``_read_total``: handles a
+    ``full=True`` selection handed out, and handles whose samples some
+    consumer then read. Plain adds, like the backend's counters."""
+
+    __slots__ = ("handles", "reads")
+
+    def __init__(self):
+        self.handles = 0
+        self.reads = 0
+
+
+select_counts = _SelectCounts()
+
+
+def _partition_handle(shard, part, ci: int, col, les, is_hist: bool,
+                      start_ms: int, end_ms: int) -> Tuple[RawSeries, int]:
+    """One partition of a ``full=True`` selection as a handle (see
+    ``RawSeries``), and its rows in [start_ms, end_ms]."""
+    (epoch, n_chunks, chunk_len, n_rows, tail_first, last,
+     in_range) = part.select_facts(ci, start_ms, end_ms)
+    s = RawSeries.handle(
+        part.part_key.shared_labels, col.is_counter_like, is_hist, les,
+        (shard.ref.dataset, shard.shard_num, part.part_id, n_chunks, ci),
+        chunk_len, tail_first, last,
+        _PartitionRead(shard, part, ci, epoch, n_rows,
+                       is_hist and col.is_counter_like))
+    return s, in_range
+
+
+class _PartitionRead:
+    """The deferred half of a handle: called at the first touch of its
+    samples, inside whatever stage the consumer runs under."""
+
+    __slots__ = ("shard", "part", "ci", "epoch", "n_rows", "drops")
+
+    def __init__(self, shard, part, ci, epoch, n_rows, drops):
+        self.shard = shard
+        self.part = part
+        self.ci = ci
+        self.epoch = epoch
+        self.n_rows = n_rows
+        self.drops = drops
+
+    def __call__(self, s: RawSeries) -> None:
+        part, ci = self.part, self.ci
+        ts, vals, chunk_len, n_chunks, epoch = part.read_full_at(ci)
+        if epoch == self.epoch:
+            # only appended to since: the rows the facts describe come
+            # first, and those are the series
+            if ts.size > self.n_rows:
+                ts, vals = ts[:self.n_rows], vals[:self.n_rows]
+        else:
+            # evicted or paged in under the handle: the chunk list is
+            # another, so facts and samples are taken again, as one
+            while part.odp_pending:
+                self.shard._ensure_loaded(part)
+                ts, vals, chunk_len, n_chunks, epoch = part.read_full_at(ci)
+            key = s.snapshot_key
+            s.snapshot_key = key[:3] + (n_chunks,) + key[4:]
+            s.chunk_len = chunk_len
+        drops = None
+        if self.drops:
+            # taken after the snapshot: rows appended in between may
+            # carry drop indices beyond ts.size
+            drops = part.hist_drop_rows(ci)
+            drops = drops[drops < ts.size]
+        s.fill(ts, vals, drops)
+        select_counts.reads += 1
 
 
 def select_span_series(shards: Sequence[TimeSeriesShard],
@@ -188,14 +256,7 @@ def _select_span_series(shards, filters, start_ms, end_ms, column,
         if deadline is not None:
             deadline.check("span series selection")
         for part in shard.lookup_partitions(filters, start_ms, end_ms):
-            schema = part.schema
-            col_name = column or schema.value_column
-            try:
-                ci = [c.name for c in schema.columns].index(col_name)
-            except ValueError:
-                raise QueryError(
-                    f"schema {schema.name} has no column {col_name}")
-            col = schema.columns[ci]
+            ci, col, _ = _resolve_column(part.schema, column)
             ts_all, val_all, full_chunk_len = part.read_full(ci)
             lo = int(np.searchsorted(ts_all, start_ms, side="left"))
             hi = int(np.searchsorted(ts_all, end_ms, side="right"))
@@ -352,7 +413,7 @@ def _hist_window(s: RawSeries, func: str, wstart, wend) -> np.ndarray:
 # Aggregations across series (RowAggregator / AggregateMapReduce)
 # ---------------------------------------------------------------------------
 
-def _group_keys(keys: List[Dict[str, str]], by: Tuple[str, ...],
+def _group_keys(keys: Sequence[Mapping[str, str]], by: Tuple[str, ...],
                 without: Tuple[str, ...]):
     """Group index per series (AggregateMapReduce grouping,
     AggrOverRangeVectors.scala:98)."""
@@ -1130,10 +1191,10 @@ class QueryEngine:
             self.stats, full=True, limits=self.limits)
         params = RangeParams(inner.start_ms, inner.step_ms, inner.end_ms)
         res = None
-        if series and not any(s.values.ndim == 2 for s in series):
+        if series and not any(s.is_hist for s in series):
             with obs_trace.span("group-keys"):
-                keys = [dict(s.labels) for s in series]
-                gids, gkeys = _group_keys(keys, tuple(plan.by),
+                gids, gkeys = _group_keys([s.labels for s in series],
+                                          tuple(plan.by),
                                           tuple(plan.without))
             res = self.backend.fused_groupsum(
                 series, inner.function, params.steps, inner.window_ms,

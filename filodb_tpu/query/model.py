@@ -38,27 +38,113 @@ class RangeParams:
         return (self.end_ms - self.start_ms) // self.step_ms + 1
 
 
-@dataclass
 class RawSeries:
     """One series' raw samples (RawDataRangeVector equivalent).
 
     ``snapshot_key`` identifies the immutable chunk-backed prefix of this
-    series in its store — (dataset, shard, part_id, num_chunks). Device tile
-    caches key on it: the prefix content is pinned by num_chunks (chunks are
-    append-only and immutable), so repeated queries over an unchanged store
-    snapshot reuse device tiles with zero rebuilds. ``chunk_len`` is the
-    length of that prefix; samples beyond it are the mutable write-buffer
-    tail (merged host-side / via the general path at query time)."""
-    labels: Mapping[str, str]
-    ts: np.ndarray          # int64 ms, sorted
-    values: np.ndarray      # f64 [n] or f64 [n, num_buckets] for histograms
-    is_counter: bool = False
-    bucket_les: Optional[np.ndarray] = None  # for histogram series
-    snapshot_key: Optional[Tuple] = None
-    chunk_len: int = -1     # -1: everything is immutable (no tail)
-    # histogram reset rows from the sectioned drop tables (row i = reset
-    # between rows i-1 and i); None = caller rescans buckets
-    hist_drop_rows: Optional[np.ndarray] = None
+    series in its store — (dataset, shard, part_id, num_chunks, col). Device
+    tile caches key on it: the prefix content is pinned by num_chunks (chunks
+    are append-only and immutable), so repeated queries over an unchanged
+    store snapshot reuse device tiles with zero rebuilds. ``chunk_len`` is
+    the length of that prefix; samples beyond it are the mutable write-buffer
+    tail (merged host-side / via the general path at query time).
+
+    What is read when. Built from arrays (the wire decoders, a span or range
+    selection, ``clip_series``, tests) a series simply holds them. A
+    ``full=True`` selection of a local shard hands out a HANDLE
+    (``RawSeries.handle``): ``labels`` (the part key's shared mapping: copy
+    it before changing it), ``is_counter``, ``is_hist``, ``bucket_les``,
+    ``snapshot_key``, ``chunk_len``, ``tail_first_ts`` and ``last_ts`` are
+    facts taken with the selection, and ``ts`` / ``values`` /
+    ``hist_drop_rows`` are read from the partition the first time one of
+    them is touched, as exactly the rows the selection saw. A consumer that
+    answers from the facts (the fused group-sum on a tile hit) never pays
+    for the samples. A handle belongs to the thread that selected it: read
+    it before handing it to another."""
+
+    __slots__ = ("labels", "is_counter", "is_hist", "bucket_les",
+                 "snapshot_key", "chunk_len", "_ts", "_values", "_drops",
+                 "_tail", "_read")
+
+    def __init__(self, labels: Mapping[str, str],
+                 ts: np.ndarray,            # int64 ms, sorted
+                 values: np.ndarray,        # f64 [n] or [n, num_buckets]
+                 is_counter: bool = False,
+                 bucket_les: Optional[np.ndarray] = None,   # histogram series
+                 snapshot_key: Optional[Tuple] = None,
+                 chunk_len: int = -1,   # -1: everything is immutable (no tail)
+                 # histogram reset rows from the sectioned drop tables (row i
+                 # = reset between rows i-1 and i); None = caller rescans
+                 hist_drop_rows: Optional[np.ndarray] = None):
+        self.labels = labels
+        self.is_counter = is_counter
+        self.is_hist = values.ndim == 2
+        self.bucket_les = bucket_les
+        self.snapshot_key = snapshot_key
+        self.chunk_len = chunk_len
+        self._ts = ts
+        self._values = values
+        self._drops = hist_drop_rows
+        self._tail = None
+        self._read = None
+
+    @classmethod
+    def handle(cls, labels, is_counter, is_hist, bucket_les, snapshot_key,
+               chunk_len, tail_first_ts, last_ts, read) -> "RawSeries":
+        """A series of facts whose samples ``read(series)`` fetches on first
+        touch: it calls ``series.fill`` with them."""
+        s = cls.__new__(cls)
+        s.labels = labels
+        s.is_counter = is_counter
+        s.is_hist = is_hist
+        s.bucket_les = bucket_les
+        s.snapshot_key = snapshot_key
+        s.chunk_len = chunk_len
+        s._tail = (tail_first_ts, last_ts)
+        s._read = read
+        return s
+
+    def fill(self, ts, values, hist_drop_rows=None) -> None:
+        """The samples of a handle; from here on it is a series of arrays
+        (the tail facts are read off them, and say the same)."""
+        self._ts = ts
+        self._values = values
+        self._drops = hist_drop_rows
+        self._tail = None
+        self._read = None
+
+    @property
+    def ts(self) -> np.ndarray:
+        if self._read is not None:
+            self._read(self)
+        return self._ts
+
+    @property
+    def values(self) -> np.ndarray:
+        if self._read is not None:
+            self._read(self)
+        return self._values
+
+    @property
+    def hist_drop_rows(self) -> Optional[np.ndarray]:
+        if self._read is not None:
+            self._read(self)
+        return self._drops
+
+    @property
+    def tail_first_ts(self) -> Optional[int]:
+        """Timestamp of the first row beyond the chunk prefix (None: the
+        prefix is everything there is)."""
+        if self._tail is not None:
+            return self._tail[0]
+        ts, cl = self._ts, self.chunk_len
+        return int(ts[cl]) if 0 <= cl < ts.size else None
+
+    @property
+    def last_ts(self) -> Optional[int]:
+        if self._tail is not None:
+            return self._tail[1]
+        return int(self._ts[-1]) if self._ts.size else None
 
 
 @dataclass

@@ -40,7 +40,7 @@ from filodb_tpu.query import logical as lp
 from filodb_tpu.query.engine import (METRIC_LABELS, QueryEngine,
                                      select_raw_series)
 from filodb_tpu.query.model import (GridResult, QueryError, QueryLimits,
-                                    QueryStats, RangeParams,
+                                    QueryStats, RangeParams, RawSeries,
                                     StaleRoutingError)
 
 # aggregations executable as mesh collectives (parallel/mesh.py MESH_AGGS)
@@ -525,8 +525,6 @@ class MeshAggregateExec(ExecPlan):
         HOST-side on the full matrix so the per-bucket device rows carry no
         dips — the device counter correction is then the identity and the
         result matches the oracle exactly."""
-        import dataclasses
-
         from filodb_tpu.memory import histogram as bh
         out: List = []
         nb = len(self.hist_les)
@@ -536,11 +534,10 @@ class MeshAggregateExec(ExecPlan):
                 mat = mat + bh.hist_counter_correction(
                     mat, drop_rows=s.hist_drop_rows)
             for b in range(nb):
-                out.append(dataclasses.replace(
-                    s, values=mat[:, b] if mat.size else
+                out.append(RawSeries(
+                    s.labels, s.ts, mat[:, b] if mat.size else
                     np.zeros(0, dtype=np.float64),
-                    bucket_les=None, snapshot_key=None,
-                    hist_drop_rows=None))
+                    s.is_counter, chunk_len=s.chunk_len))
         return out
 
     def plan_tree(self, indent: int = 0) -> str:

@@ -553,7 +553,8 @@ class _TileEntry:
         that has not grown (dead, churned away) holds nothing back."""
         bound = self.cov_min_ms
         for s, end in zip(series, self.built_ends):
-            if s.ts.size and (end is None or s.ts[-1] > end):
+            last = s.last_ts
+            if last is not None and (end is None or last > end):
                 j = 0 if end is None else int(
                     np.searchsorted(s.ts, end, side="right"))
                 t = int(s.ts[j])
@@ -670,7 +671,7 @@ class TpuBackend:
         func = function or "last_sample"
         if func not in DEVICE_FUNCS or not series:
             return None
-        if any(s.values.ndim != 1 for s in series):
+        if any(s.is_hist for s in series):
             return None
         steps = params.steps
         nsteps = steps.size
@@ -865,6 +866,17 @@ class TpuBackend:
     def _prefix_len(s) -> int:
         return s.chunk_len if s.chunk_len >= 0 else s.ts.size
 
+    @staticmethod
+    def _tail_min(series, bound: Optional[int]) -> Optional[int]:
+        """The earliest timestamp beyond any series' chunk prefix, or
+        ``bound`` if that is earlier (None: no tail anywhere). From the
+        series' facts: no samples are read."""
+        for s in series:
+            tm = s.tail_first_ts
+            if tm is not None and (bound is None or tm < bound):
+                bound = tm
+        return bound
+
     def _build_tile_entry(self, series, use_snap: bool):
         """Build one tile-cache entry over the series' immutable chunk
         prefixes. ``cov_min_ms`` records the first timestamp NOT covered
@@ -884,12 +896,7 @@ class TpuBackend:
                       s.bucket_les)
             for s in series
         ]
-        cov_min = None
-        for s in series:
-            cl = self._prefix_len(s)
-            if cl < s.ts.size:
-                tm = int(s.ts[cl])
-                cov_min = tm if cov_min is None else min(cov_min, tm)
+        cov_min = self._tail_min(series, None)
         tiles, idx = tst.build_aligned_tiles(prefix)
         self.tile_builds += 1
         prefix_has_nan = any(np.isnan(p.values).any() for p in prefix)
@@ -941,17 +948,19 @@ class TpuBackend:
         with obs_trace.span("tile-entry", series=len(series)):
             return self._tile_entry_inner(series)
 
+    @staticmethod
+    def _tile_key(series, use_snap: bool):
+        if not use_snap:
+            return tuple(id(s) for s in series), None
+        # ident: the snapshot key minus the chunk-count field, stable
+        # across flushes for the same partitions + column selection
+        return (tuple(s.snapshot_key for s in series),
+                tuple(s.snapshot_key[:3] + s.snapshot_key[4:]
+                      for s in series))
+
     def _tile_entry_inner(self, series):
         use_snap = all(s.snapshot_key is not None for s in series)
-        if use_snap:
-            key = tuple(s.snapshot_key for s in series)
-            # snapshot key minus the chunk-count field: stable across
-            # flushes for the same partitions + column selection
-            ident = tuple(s.snapshot_key[:3] + s.snapshot_key[4:]
-                          for s in series)
-        else:
-            key = tuple(id(s) for s in series)
-            ident = None
+        key, ident = self._tile_key(series, use_snap)
         with self._tile_lock:
             entry = self._tile_cache.get(key)
             stale = None
@@ -970,6 +979,8 @@ class TpuBackend:
                     return stale.stale_view(series)
                 self._tile_refreshing.add(key)
             held = list(series)     # pin arrays until the rebuild lands
+            for s in held:
+                s.ts    # a handle is its selecting thread's: read it here
 
             @thread_root("tile-refresh")
             def refresh():
@@ -982,7 +993,8 @@ class TpuBackend:
                         # buffers in place (zero-copy) when the new
                         # tiles extend the old cohort
                         me.refresh(stale.tiles, fresh.tiles)
-                    self._insert_tile_entry(key, ident, fresh)
+                    self._insert_tile_entry(
+                        *self._tile_key(held, use_snap), fresh)
                 finally:
                     with self._tile_lock:
                         self._tile_refreshing.discard(key)
@@ -993,7 +1005,10 @@ class TpuBackend:
                 refresh, priority=_qos.PRIORITY_BACKGROUND)
             return stale.stale_view(series)
         entry = self._build_tile_entry(series, use_snap)
-        self._insert_tile_entry(key, ident, entry)
+        # keyed AFTER the build read the samples: a partition evicted or
+        # paged in under its handle took its facts again with them, and
+        # the tiles go under the key of what they were built from
+        self._insert_tile_entry(*self._tile_key(series, use_snap), entry)
         return entry
 
     def _try_aligned(self, series, func: str, steps: np.ndarray,
@@ -1027,12 +1042,7 @@ class TpuBackend:
         # cover see only tiles: the tail of the CURRENT series, clipped
         # further by the entry's build-time coverage when a stale entry
         # is serving across a flush (the rebuild lands in background)
-        tail_min = entry.cov_min_ms
-        for s in series:
-            cl = self._prefix_len(s)
-            if cl < s.ts.size:
-                tm = int(s.ts[cl])
-                tail_min = tm if tail_min is None else min(tail_min, tm)
+        tail_min = self._tail_min(series, entry.cov_min_ms)
         wends = steps - offset_ms
         t_dev = (steps.size if tail_min is None
                  else int(np.searchsorted(wends, tail_min, side="left")))
@@ -1288,14 +1298,8 @@ class TpuBackend:
         prefix — cov_min_ms is the binding bound)."""
         if not steps.size:
             return True
-        last = int(steps[-1] - offset_ms)
-        if entry.cov_min_ms is not None and last >= entry.cov_min_ms:
-            return False
-        for s in series:
-            cl = self._prefix_len(s)
-            if cl < s.ts.size and last >= int(s.ts[cl]):
-                return False
-        return True
+        tail_min = self._tail_min(series, entry.cov_min_ms)
+        return tail_min is None or int(steps[-1] - offset_ms) < tail_min
 
     @staticmethod
     def _window_sample_bound(series, window_ms: int, n_cap: int) -> int:
