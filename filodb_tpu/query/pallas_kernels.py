@@ -423,11 +423,11 @@ def _groupsum_example():
     """Abstract inputs for jax.eval_shape: st=1 / dspan=0 / both modes
     GS_CUR is the single-stream configuration (mlen = 272)."""
     g_perm = 512
-    args = ("rate", 1, 0, GS_CUR, GS_CUR,
+    args = ("rate", 1, 0, GS_CUR, GS_CUR, True, 256,
             jax.ShapeDtypeStruct((1, 1, g_perm, 3 * _GS_SS), jnp.int32),
             jax.ShapeDtypeStruct((1, 8, _GS_SS), jnp.float32),
             jax.ShapeDtypeStruct((_GS_SS, 16), jnp.float32),
-            1, 5_000, 5_000, 1_000, 256)
+            jax.ShapeDtypeStruct((5,), jnp.int32))
     return args, {}
 
 
@@ -437,6 +437,44 @@ def _groupsum_expect(out):
         if tuple(o.shape) != want[0] or o.dtype != want[1]:
             return f"output {o.shape}/{o.dtype} != {want}"
     return None
+
+
+def counter_groupsum(func: str, st: int, dspan: int, hi_mode: int,
+                     lo_mode: int, v_p, base, onehot,
+                     kl0, w0e_rel, window: int, step: int, nsteps: int,
+                     interpret: bool = False,
+                     exact_branch: Optional[bool] = None):
+    """sum by(group) of rate/increase/delta over stride-permuted dense
+    tiles -> (sums f32 [T, G], counts f32 [T, G]; sum is only meaningful
+    where count > 0).
+
+    v_p: the packed kernel channel [n_s, st, G_perm, 3*_GS_SS] i32 —
+    plane 0 = int32 relative timestamps, planes 1-2 = the per-series
+    fixed-point hi/lo split of the (counter-corrected) value channel
+    (AlignedTiles.t_perm_fixed_tiled). base: [n_s, 8, _GS_SS] f32 — row
+    0 = per-series rebase midpoint (f32), row 1 = 2^(31-s), row 2 =
+    2^-s (AlignedTiles.t_fixed_base). onehot: [n_s * _GS_SS, G] f32
+    group membership (pad series with all-zero one-hot rows).
+
+    Static dispatch contract (the tilestore dispatcher checks it):
+    regular grid with step == st*dt entirely interior to the tile,
+    dense tiles, span fits int32 ms, kc0 - kl0 == dspan * st with
+    kc0/kl0 the per-query boundary slots, and hi_mode/lo_mode sound for
+    the tile's jitter bound (GS_CUR/GS_ALT only when the grid phase
+    clears the max |ts - tick|)."""
+    if exact_branch is None:
+        exact_branch = groupsum_exact_branch(window, st, dspan)
+    params = jnp.stack([jnp.asarray(v, jnp.int32) for v in (
+        kl0, w0e_rel, window, step, nsteps)])
+    return groupsum_call(func, st, dspan, hi_mode, lo_mode,
+                         bool(exact_branch), nsteps, v_p, base, onehot,
+                         params, interpret=interpret)
+
+
+def groupsum_exact_branch(window: int, st: int, dspan: int) -> bool:
+    """Whether the integer extrapolation-branch products fit i32 (a
+    host decision from the query's window: static per compiled kernel)."""
+    return 11 * int(window) * (dspan * st + 1) < 2 ** 31
 
 
 # Worst-case on-chip footprint the tilestore dispatcher may admit (its
@@ -487,31 +525,19 @@ def _groupsum_expect(out):
     span_guard="filodb_tpu.query.tilestore:_slide_eligible",
     example=_groupsum_example, expect=_groupsum_expect,
     notes="dispatched only via tilestore.groupsum_counters, which "
-          "re-derives this footprint per query and falls back to the "
-          "general path above 14 MB")
-def counter_groupsum(func: str, st: int, dspan: int, hi_mode: int,
-                     lo_mode: int, v_p, base, onehot,
-                     kl0, w0e_rel, window: int, step: int, nsteps: int,
-                     interpret: bool = False,
-                     exact_branch: Optional[bool] = None):
-    """sum by(group) of rate/increase/delta over stride-permuted dense
-    tiles -> (sums f32 [T, G], counts f32 [T, G]; sum is only meaningful
-    where count > 0).
-
-    v_p: the packed kernel channel [n_s, st, G_perm, 3*_GS_SS] i32 —
-    plane 0 = int32 relative timestamps, planes 1-2 = the per-series
-    fixed-point hi/lo split of the (counter-corrected) value channel
-    (AlignedTiles.t_perm_fixed_tiled). base: [n_s, 8, _GS_SS] f32 — row
-    0 = per-series rebase midpoint (f32), row 1 = 2^(31-s), row 2 =
-    2^-s (AlignedTiles.t_fixed_base). onehot: [n_s * _GS_SS, G] f32
-    group membership (pad series with all-zero one-hot rows).
-
-    Static dispatch contract (the tilestore dispatcher checks it):
-    regular grid with step == st*dt entirely interior to the tile,
-    dense tiles, span fits int32 ms, kc0 - kl0 == dspan * st with
-    kc0/kl0 the per-query boundary slots, and hi_mode/lo_mode sound for
-    the tile's jitter bound (GS_CUR/GS_ALT only when the grid phase
-    clears the max |ts - tick|)."""
+          "re-derives this footprint per query, falls back to the "
+          "general path above 14 MB, and runs the kernel inside one "
+          "cached executable per static tuple (the one-hot block is "
+          "made there, on the device, from the query's group ids)")
+def groupsum_call(func: str, st: int, dspan: int, hi_mode: int,
+                  lo_mode: int, exact_branch: bool, nsteps: int,
+                  v_p, base, onehot, params, interpret: bool = False):
+    """:func:`counter_groupsum` with the per-query scalars as ONE int32[5]
+    vector ``params`` = (kl0, w0e_rel, window, step, nsteps), which is
+    what the kernel reads from SMEM: the form the jitted dispatcher
+    (tilestore.groupsum_counters) traces, so that a query's scalars
+    reach the chip as one small copy and no program assembles them.
+    Everything before ``v_p`` is static."""
     n_s = v_p.shape[0]
     G = onehot.shape[1]
     assert onehot.shape[0] == n_s * _GS_SS, (onehot.shape, n_s)
@@ -525,15 +551,7 @@ def counter_groupsum(func: str, st: int, dspan: int, hi_mode: int,
     T_pad = -(-nsteps // tt) * tt
     n_ttiles = T_pad // tt
     mlen = _gs_mlen(st, dspan, tt)
-    if exact_branch is None:
-        # integer extrapolation-branch products must fit i32
-        exact_branch = 11 * int(window) * (dspan * st + 1) < 2 ** 31
-    need1 = hi_mode != GS_CUR and st != 1
-    need3 = lo_mode != GS_CUR and st != 1
-    nstreams = 1 + (1 if need1 else 0) + (1 if need3 else 0)
-    params = jnp.asarray(
-        jnp.stack([jnp.asarray(v, jnp.int32) for v in (
-            kl0, w0e_rel, window, step, nsteps)]))
+    nstreams = _gs_nstreams(st, hi_mode, lo_mode)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_s,),

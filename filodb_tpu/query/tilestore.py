@@ -1125,7 +1125,9 @@ import threading as _threading
 
 _JIT_STATS = {"hits": 0, "misses": 0}
 _JIT_STATS_LOCK = _threading.Lock()
-__guarded_by__ = {"_JIT_STATS": "_JIT_STATS_LOCK"}
+_BUILD_LOCKS: Dict[Tuple, object] = {}      # (table id, key) -> build lock
+__guarded_by__ = {"_JIT_STATS": "_JIT_STATS_LOCK",
+                  "_BUILD_LOCKS": "_JIT_STATS_LOCK"}
 
 
 @capacity(
@@ -1164,13 +1166,20 @@ def _jit_lookup(cache: Dict[Tuple, object], key: Tuple, build,
     fn = cache.get(key)
     with _JIT_STATS_LOCK:
         _JIT_STATS["hits" if fn is not None else "misses"] += 1
+        lock = None if fn is not None else _BUILD_LOCKS.setdefault(
+            (id(cache), key), _threading.Lock())
     if fn is None:
         from filodb_tpu.obs import devprof
         from filodb_tpu.obs import trace as obs_trace
-        with obs_trace.span("kernel-build", site=site):
-            fn = devprof.build_profiled(site, key, build,
-                                        cost_args=cost_args)
-        cache[key] = fn
+        # request threads that miss one key together (a warm-up's first
+        # round) build it once: the others wait here and take the entry
+        with lock:
+            fn = cache.get(key)
+            if fn is None:
+                with obs_trace.span("kernel-build", site=site):
+                    fn = devprof.build_profiled(site, key, build,
+                                                cost_args=cost_args)
+                cache[key] = fn
     return fn
 
 
@@ -1262,27 +1271,52 @@ def evaluate_counters_t(tiles: AlignedTiles, func: str, steps: np.ndarray,
     return fn(*args)
 
 
+def _groupsum_program(func: str, st: int, dspan: int, hi_mode: int,
+                      lo_mode: int, exact_branch: bool, nsteps: int,
+                      G: int, interpret: bool, v_p, base, params, ids):
+    """The fused group-sum as ONE traceable program (jitted once per
+    static tuple by groupsum_counters): the [S_pad, G] f32 one-hot from
+    the int32 group ids (an id that names no group, the padding's -1,
+    gives an all-zero row), the Pallas kernel and its [:nsteps] slices.
+    Every input is explicitly typed, so the program is the same under
+    x64 on and off."""
+    from filodb_tpu.query import pallas_kernels as pk
+    onehot = (ids[:, None] == jnp.arange(G, dtype=jnp.int32)[None, :]
+              ).astype(jnp.float32)
+    return pk.groupsum_call(
+        func, st, dspan, hi_mode, lo_mode, exact_branch, nsteps,
+        v_p, base, onehot, params, interpret=interpret)
+
+
 @kernel_contract(
     "groupsum_dispatch", kind="dispatch",
     vmem_budget=14 << 20,
     rel_time_bits=31, span_guard="_slide_eligible",
-    notes="host-side gate for the fused Pallas group-sum kernel: "
-          "regular interior grid via _slide_eligible, merged-stream "
-          "window/step divisibility, dspan cap, full VMEM re-budget "
-          "(accumulators + DMA scratch + onehot + base), Mosaic "
-          "compile backstop falls back to the general path")
+    notes="host-side gate and dispatcher of the fused Pallas group-sum "
+          "kernel: regular interior grid via _slide_eligible, merged-"
+          "stream window/step divisibility, dspan cap, full VMEM "
+          "re-budget (accumulators + DMA scratch + onehot + base) all "
+          "decide BEFORE the executable table is asked; then one cached "
+          "executable (site groupsum) per static tuple takes the "
+          "query's five scalars and its group ids")
 def groupsum_counters(tiles: AlignedTiles, func: str, steps: np.ndarray,
-                      window_ms: int, onehot, offset_ms: int = 0,
+                      window_ms: int, gids, G: int, offset_ms: int = 0,
                       interpret: bool = False):
     """`sum by (g) (rate/increase/delta(sel[w]))` fused on device via the
     Pallas group-sum kernel -> (sums f32 [T, G], counts f32 [T, G]), or
     None when the preconditions don't hold (caller falls back to
     evaluate_counters_t + host/XLA grouping).
 
+    ``gids``: the group id in [0, G) of every series of the tiles, in
+    tile order. One cached executable per (func, grid statics, tile
+    shapes, G) serves every query of that shape: a query sends five
+    int32 scalars and its group ids, nothing is traced or compiled
+    again (``_jit_lookup``: exec-cache hits/misses, ``kernel-build``).
+
     Preconditions: dense tiles; regular grid with step % dt == 0 fully
     interior to the tile; span fits int32 ms relative to the tile base.
-    The kernel pads S to its lane-tile internally via all-zero one-hot
-    rows, so any S works."""
+    The ids are padded to the kernel's lane tile with -1, which names
+    no group, so any S works."""
     assert func in ("rate", "increase", "delta")
     nsteps = steps.size
     if nsteps < 2:
@@ -1305,7 +1339,6 @@ def groupsum_counters(tiles: AlignedTiles, func: str, steps: np.ndarray,
     if st == 1 and k_l0 < 1:
         return None              # the merged block reads one lead row
     S = len(tiles.keys)
-    G = int(np.asarray(onehot).shape[1])
     vch = "cv" if func in ("rate", "increase") else "v"
     if tiles._fixed_channels(vch) is None:
         return None              # non-finite values: exact f64 fallback
@@ -1330,16 +1363,24 @@ def groupsum_counters(tiles: AlignedTiles, func: str, steps: np.ndarray,
     S_pad = -(-S // pk._GS_SS) * pk._GS_SS
     v_p = tiles.t_perm_fixed_tiled(vch, st)
     base = tiles.t_fixed_base(vch)
-    onehot = jnp.asarray(onehot, jnp.float32)
-    if S_pad != S:
-        onehot = jnp.pad(onehot, ((0, S_pad - S), (0, 0)))
+    ids = np.full(S_pad, -1, np.int32)
+    ids[:S] = gids
+    params = np.array([k_l0, w0e - tiles.base_ms, window_ms, step, nsteps],
+                      np.int32)
+    exact_branch = pk.groupsum_exact_branch(window_ms, st, dspan)
+    static = (func, st, dspan, hi_mode, lo_mode, exact_branch, nsteps, G,
+              interpret)
+    key = ("groupsum",) + static + (
+        tuple(v_p.shape), tuple(base.shape),
+        tuple(sorted(pk._gs_ablate_active(interpret))))
+    args = (v_p, base, params, ids)
     # a kernel the chip's compiler refuses fails the query with the
     # compiler's message: the decided-in-advance route to the general
     # path is the _gs_pipeline budget check above, never an except
-    return pk.counter_groupsum(
-        func, st, dspan, hi_mode, lo_mode, v_p, base, onehot,
-        k_l0, w0e - tiles.base_ms, window_ms, step, nsteps,
-        interpret=interpret)
+    fn = _jit_lookup(_EVAL_T_JIT, key, lambda: jax.jit(
+        _bind(_groupsum_program, *static)), site="groupsum",
+        cost_args=args)
+    return fn(*args)
 
 
 _EVAL_JIT: Dict[Tuple, object] = {}

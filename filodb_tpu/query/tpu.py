@@ -1230,9 +1230,12 @@ class TpuBackend:
         only [T, G] group sums + counts leave the chip — the [S, T] rate
         intermediate is never materialized (the reference pays this as
         per-shard AggrOverRangeVectors map-reduce over row iterators,
-        exec/aggregator/*.scala). Returns (sums, cnts) as [T, G] numpy
-        or None when ineligible (caller falls back to the general
-        rangefn + aggregate path)."""
+        exec/aggregator/*.scala). A query hands the device path its
+        group ids in tile order (``gids[idx]``) and five grid scalars;
+        the program is one cached executable of the tilestore table
+        (or the mesh store's grouped collective). Returns (sums, cnts)
+        as [T, G] numpy or None when ineligible (caller falls back to
+        the general rangefn + aggregate path)."""
         from filodb_tpu.query import tilestore as tst
 
         if func not in ("rate", "increase", "delta") or not len(series):
@@ -1267,11 +1270,10 @@ class TpuBackend:
                                               window_ms, offset_ms))
         if mesh_st is None and on_cpu and not FUSED_GROUPSUM_INTERPRET:
             return None
+        # the group ids in tile order, which both device paths take (the
+        # stage's name is one the benchmark's dispatch_host_ms row reads)
         with obs_trace.span("onehot", groups=G):
             gvec = np.asarray(gids)[np.asarray(idx)]
-            if mesh_st is None:
-                onehot = np.zeros((len(series), G), np.float32)
-                onehot[np.arange(len(series)), gvec] = 1.0
         if mesh_st is not None:
             self.fused_aggs += 1
             self.mesh_dispatches += 1
@@ -1281,7 +1283,7 @@ class TpuBackend:
         else:
             with obs_trace.span("device-dispatch", path="fused"):
                 res = tst.groupsum_counters(
-                    tiles, func, steps, window_ms, onehot, offset_ms,
+                    tiles, func, steps, window_ms, gvec, G, offset_ms,
                     interpret=on_cpu)
             if res is None:
                 return None

@@ -137,10 +137,8 @@ def _tiles(S=8, N=288, seed=7, span=None):
 def _gs(tiles, G, func="delta", S=8):
     steps = np.arange(BASE + 400_000, BASE + 2_400_000, 60_000,
                       dtype=np.int64)
-    onehot = np.zeros((S, G), np.float32)
-    onehot[np.arange(S), np.arange(S) % G] = 1.0
-    return tst.groupsum_counters(tiles, func, steps, 300_000, onehot,
-                                 interpret=True)
+    return tst.groupsum_counters(tiles, func, steps, 300_000,
+                                 np.arange(S) % G, G, interpret=True)
 
 
 def test_groupsum_vmem_budget_rejects_wide_group_tables():

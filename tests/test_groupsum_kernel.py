@@ -36,9 +36,7 @@ def test_groupsum_matches_per_series_eval(func):
     steps = np.arange(BASE + 400_000, BASE + 2_400_000, 60_000,
                       dtype=np.int64)
     gid = np.arange(S) % G
-    onehot = np.zeros((S, G), np.float32)
-    onehot[np.arange(S), gid] = 1.0
-    res = tst.groupsum_counters(tiles, func, steps, 300_000, onehot,
+    res = tst.groupsum_counters(tiles, func, steps, 300_000, gid, G,
                                 interpret=True)
     assert res is not None
     sums, cnts = np.asarray(res[0]), np.asarray(res[1])
@@ -79,9 +77,7 @@ def test_groupsum_phase_elided_families(phase):
     steps = np.arange(BASE + 400_000 + phase, BASE + 2_400_000, 60_000,
                       dtype=np.int64)
     gid = np.arange(S) % G
-    onehot = np.zeros((S, G), np.float32)
-    onehot[np.arange(S), gid] = 1.0
-    res = tst.groupsum_counters(tiles, "rate", steps, 300_000, onehot,
+    res = tst.groupsum_counters(tiles, "rate", steps, 300_000, gid, G,
                                 interpret=True)
     assert res is not None
     want_s, want_c = _want(tiles, "rate", steps, 300_000, gid, G)
@@ -98,10 +94,8 @@ def test_groupsum_st1_single_stream():
     steps = np.arange(BASE + 400_000, BASE + 2_000_000, 10_000,
                       dtype=np.int64)
     gid = np.arange(S) % G
-    onehot = np.zeros((S, G), np.float32)
-    onehot[np.arange(S), gid] = 1.0
     res = tst.groupsum_counters(tiles, "increase", steps, 300_000,
-                                onehot, interpret=True)
+                                gid, G, interpret=True)
     assert res is not None
     want_s, want_c = _want(tiles, "increase", steps, 300_000, gid, G)
     np.testing.assert_array_equal(np.asarray(res[1]), want_c)
@@ -111,17 +105,17 @@ def test_groupsum_st1_single_stream():
 
 def test_groupsum_dispatcher_fallbacks():
     tiles = _tiles(16, 288)
-    onehot = np.ones((16, 1), np.float32)
+    gid, G = np.zeros(16, np.int64), 1
     # irregular step (not a slot multiple)
     steps = np.arange(BASE + 400_000, BASE + 1_000_000, 61_000,
                       dtype=np.int64)
     assert tst.groupsum_counters(tiles, "rate", steps, 300_000,
-                                 onehot, interpret=True) is None
+                                 gid, G, interpret=True) is None
     # grid past the tile end
     steps = np.arange(BASE + 400_000, BASE + 288 * DT + 600_000, 60_000,
                       dtype=np.int64)
     assert tst.groupsum_counters(tiles, "rate", steps, 300_000,
-                                 onehot, interpret=True) is None
+                                 gid, G, interpret=True) is None
     # gappy tiles
     rng = np.random.default_rng(3)
     valid = rng.random((16, 288)) > 0.2
@@ -132,17 +126,17 @@ def test_groupsum_dispatcher_fallbacks():
     steps = np.arange(BASE + 400_000, BASE + 1_000_000, 60_000,
                       dtype=np.int64)
     assert tst.groupsum_counters(gappy, "rate", steps, 300_000,
-                                 onehot, interpret=True) is None
+                                 gid, G, interpret=True) is None
     # window not a whole number of steps: merged kc/kl stream contract
     steps = np.arange(BASE + 400_000, BASE + 1_000_000, 60_000,
                       dtype=np.int64)
     assert tst.groupsum_counters(tiles, "rate", steps, 290_000,
-                                 onehot, interpret=True) is None
+                                 gid, G, interpret=True) is None
     # window/step beyond the merged-stream row cap
     steps = np.arange(BASE + 900_000, BASE + 2_000_000, 10_000,
                       dtype=np.int64)
     assert tst.groupsum_counters(tiles, "rate", steps, 600_000,
-                                 onehot, interpret=True) is None
+                                 gid, G, interpret=True) is None
     # non-finite values fall back to the exact f64 path
     bad = _tiles(16, 288)
     bad.vals = bad.vals.at[0, 5].set(np.inf) if hasattr(
@@ -155,7 +149,7 @@ def test_groupsum_dispatcher_fallbacks():
     steps = np.arange(BASE + 400_000, BASE + 1_000_000, 60_000,
                       dtype=np.int64)
     assert tst.groupsum_counters(bad, "rate", steps, 300_000,
-                                 onehot, interpret=True) is None
+                                 gid, G, interpret=True) is None
 
 
 # ---------------------------------------------------------------------------
@@ -196,11 +190,9 @@ def test_groupsum_wide_tile_parity(nsteps):
     steps = (BASE + 400_000
              + np.arange(nsteps, dtype=np.int64) * 60_000)
     gid = np.arange(S) % G
-    onehot = np.zeros((S, G), np.float32)
-    onehot[np.arange(S), gid] = 1.0
     assert pk._gs_pipeline(6, 5, pk.GS_BOTH, pk.GS_BOTH, nsteps,
                            G) is not None
-    res = tst.groupsum_counters(tiles, "rate", steps, 300_000, onehot,
+    res = tst.groupsum_counters(tiles, "rate", steps, 300_000, gid, G,
                                 interpret=True)
     assert res is not None
     sums, cnts = np.asarray(res[0]), np.asarray(res[1])
@@ -230,9 +222,7 @@ def test_groupsum_widest_config_parity_interpret():
     assert pk._gs_pipeline(6, 5, pk.GS_CUR, pk.GS_CUR, T, G) \
         == (pk._GS_TT_WIDE, pk._GS_NBUF_MAX)
     gid = np.arange(S) % G
-    onehot = np.zeros((S, G), np.float32)
-    onehot[np.arange(S), gid] = 1.0
-    res = tst.groupsum_counters(tiles, "rate", steps, 300_000, onehot,
+    res = tst.groupsum_counters(tiles, "rate", steps, 300_000, gid, G,
                                 interpret=True)
     assert res is not None
     sums, cnts = np.asarray(res[0]), np.asarray(res[1])

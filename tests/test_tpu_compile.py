@@ -147,38 +147,42 @@ def test_pallas_rate_impl_compiles_end_to_end(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-# -- (a) the fused group-sum kernel, with the dispatcher's own args -----------
+# -- (a) the fused group-sum program, as the dispatcher jits it ---------------
 
 @pytest.mark.parametrize("jitter_ms,nstreams", [(0, 1), (2000, 3)])
 def test_counter_groupsum_compiles(one_chip, monkeypatch, jittered,
                                    jitter_ms, nstreams):
+    """What a fused query runs on the chip: the dispatcher's ONE jitted
+    program (one-hot from the group ids, the Pallas kernel, the slices),
+    built by the dispatcher's own ``build`` with its own statics."""
     tiles = jittered if jitter_ms else _tiles(0)
-    onehot = np.zeros((S_HOST, G), np.float32)
-    onehot[np.arange(S_HOST), np.arange(S_HOST) % G] = 1.0
     seen = {}
 
-    def capture(*args, **kw):
-        seen["args"], seen["kw"] = args, kw
-        return None
-    monkeypatch.setattr(pk, "counter_groupsum", capture)
-    assert tst.groupsum_counters(tiles, "rate", _steps(), W, onehot) is None
+    def capture(cache, key, build, site="tilestore", cost_args=None):
+        seen.update(key=key, build=build, args=cost_args, site=site)
+        return lambda *a: None
+    monkeypatch.setattr(tst, "_jit_lookup", capture)
+    assert tst.groupsum_counters(tiles, "rate", _steps(), W,
+                                 np.arange(S_HOST) % G, G) is None
     monkeypatch.undo()
-    func, st, dspan, hi, lo, v_p, base, oh = seen["args"][:8]
-    scalars = seen["args"][8:]
+    assert seen["site"] == "groupsum"
+    _, func, st, dspan, hi, lo = seen["key"][:6]
     assert pk._gs_nstreams(st, hi, lo) == nstreams
-
-    def run(v_p, base, oh):
-        return pk.counter_groupsum(func, st, dspan, hi, lo, v_p, base, oh,
-                                   *scalars)
+    v_p, base, params, ids = seen["args"]
+    assert (params.dtype, params.shape) == (np.int32, (5,))
+    assert (ids.dtype, ids.shape) == (np.int32, (S_HOST,))
     # the kernel's series dimension is its grid: n_s lane tiles of _GS_SS
     n_s = S // pk._GS_SS
     assert v_p.shape[0] == base.shape[0] == 1
     sh = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
-    compiled, _ = _compile(
-        run, sh((n_s,) + v_p.shape[1:], v_p.dtype),
+    compiled = seen["build"]().lower(
+        sh((n_s,) + v_p.shape[1:], v_p.dtype),
         sh((n_s,) + base.shape[1:], base.dtype),
-        sh((S, G), jnp.float32))
-    assert "tpu_custom_call" in compiled.as_text()
+        sh((5,), jnp.int32), sh((S,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    # the five scalars arrive as one vector: no program assembles them
+    assert "concatenate" not in text
 
 
 # -- (c) the per-series aligned evaluators, as tilestore jits them ------------
