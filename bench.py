@@ -36,9 +36,8 @@ Honesty notes:
   is timed on the host clock around a forced host transfer of the
   result, and nothing is subtracted.
 - This process is the only one that touches the chip: it starts no child
-  process. The multi-chip sweep, the ingest, downsample and end-to-end
-  benches are their own scripts (`__graft_entry__.py`, `bench_ingest.py`,
-  `bench_downsample.py`, `bench_e2e.py`) and run as their own processes.
+  process. The multi-chip dry run (`__graft_entry__.py`) and the served
+  path's benchmark (`benchmarks/run.py`) run as their own processes.
 - `vs_baseline` divides by a BATCHED numpy oracle (the same aligned
   prefix-sum/boundary algorithm vectorized over a 8,192-series
   subsample, no per-series Python loop), not an interpreter-bound loop.

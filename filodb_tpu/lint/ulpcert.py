@@ -508,7 +508,7 @@ def _h_groupsum_recombine():
 
 @precision_harness("extrapolated-rate-f64")
 def _h_extrapolated_rate():
-    """tpu._extrapolated_rate (the shared f64 formula) vs the
+    """tilestore._extrapolated_rate (the shared f64 formula) vs the
     pure-Python reference loop (promql/refeval._extrapolated) on the
     same boundary tuples."""
     import numpy as np
@@ -517,7 +517,7 @@ def _h_extrapolated_rate():
     import jax.numpy as jnp
 
     from filodb_tpu.promql.refeval import _extrapolated
-    from filodb_tpu.query.tpu import _extrapolated_rate
+    from filodb_tpu.query.tilestore import _extrapolated_rate
     rng = np.random.default_rng(_SEED + 7)
     T, S = 32, 8
     wstart = np.arange(T, dtype=np.int64)[:, None] * 60_000

@@ -29,7 +29,8 @@ from filodb_tpu.query import logical as lp
 from filodb_tpu.query import rangefn as rf
 from filodb_tpu.query.model import (GridResult, QueryError, QueryLimits,
                                     QueryStats, RangeParams, RawSeries,
-                                    ScalarResult, StaleRoutingError)
+                                    ScalarResult, StaleRoutingError,
+                                    clip_series)
 
 METRIC_LABELS = ("_metric_", "__name__")
 
@@ -287,27 +288,6 @@ def _select_span_series(shards, filters, start_ms, end_ms, column,
                 stats.samples_scanned += int(ts.size)
                 if limits is not None:
                     limits.check(stats)
-    return out
-
-
-def clip_series(series: Sequence[RawSeries], start_ms: int, end_ms: int
-                ) -> List[RawSeries]:
-    """Restrict each series to samples in [start_ms, end_ms] (views, no
-    copies). Used to hand the oracle / general device path only the span a
-    window grid can touch, while tile caches keep the full snapshot."""
-    out = []
-    for s in series:
-        lo = int(np.searchsorted(s.ts, start_ms, side="left"))
-        hi = int(np.searchsorted(s.ts, end_ms, side="right"))
-        if lo == 0 and hi == s.ts.size:
-            out.append(s)
-        else:
-            dr = s.hist_drop_rows
-            if dr is not None:
-                dr = dr[(dr >= lo) & (dr < hi)] - lo
-            out.append(RawSeries(s.labels, s.ts[lo:hi], s.values[lo:hi],
-                                 s.is_counter, s.bucket_les,
-                                 hist_drop_rows=dr))
     return out
 
 

@@ -11,7 +11,7 @@ boundary)."""
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Mapping, Optional, Tuple
+from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -145,6 +145,27 @@ class RawSeries:
         if self._tail is not None:
             return self._tail[1]
         return int(self._ts[-1]) if self._ts.size else None
+
+
+def clip_series(series: Sequence[RawSeries], start_ms: int, end_ms: int
+                ) -> List[RawSeries]:
+    """Restrict each series to samples in [start_ms, end_ms] (views, no
+    copies). Used to hand the oracle / general device path only the span a
+    window grid can touch, while tile caches keep the full snapshot."""
+    out = []
+    for s in series:
+        lo = int(np.searchsorted(s.ts, start_ms, side="left"))
+        hi = int(np.searchsorted(s.ts, end_ms, side="right"))
+        if lo == 0 and hi == s.ts.size:
+            out.append(s)
+        else:
+            dr = s.hist_drop_rows
+            if dr is not None:
+                dr = dr[(dr >= lo) & (dr < hi)] - lo
+            out.append(RawSeries(s.labels, s.ts[lo:hi], s.values[lo:hi],
+                                 s.is_counter, s.bucket_les,
+                                 hist_drop_rows=dr))
+    return out
 
 
 @dataclass

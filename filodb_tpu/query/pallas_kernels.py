@@ -1,101 +1,59 @@
-"""Pallas TPU kernels for the windowed query hot loop.
+"""The Pallas TPU kernel of the served path: the fused counter group-sum.
 
-The reference's inner loop (rangefn/RangeFunction.scala:122 addChunks:
-per-chunk binary search + accumulate per window) becomes one fused kernel
-over dense series tiles. XLA-level formulations are all bottlenecked on
-TPU: vmapped searchsorted serializes, per-element gathers cost ~40ns, f64
-scatters ~100ns. This kernel instead computes, per (series row, window):
+`sum by (g) (rate(c[w]))`, and its `increase`/`delta` forms, as ONE pass
+over the stride-permuted aligned tiles (tilestore.AlignedTiles). The
+reference pays this as per-shard AggrOverRangeVectors map-reduce over
+row iterators (exec/aggregator/*.scala over rangefn/RangeFunction.scala
+windows). XLA's best arrangement of the same computation (slices ->
+epilogue -> one-hot matmul) pays ~2.5x the HBM traffic materializing the
+[T, S] rate intermediate and re-reading it on the MXU; here the boundary
+row-blocks per step-tile are DMA'd HBM->VMEM (double-buffered,
+prefetched across the sequential program grid), the f32 extrapolation
+epilogue (rangefn/RateFunctions.scala:23-79 semantics) runs in VMEM,
+and only the [T, G] group sums + counts ever leave the chip.
 
-  * ``started[t,i] = ts_i <= wend_t`` and ``after[t,i] = ts_i >= wstart_t``
-    — with sorted rows these are prefix/suffix masks, so the FIRST sample
-    >= wstart and LAST sample <= wend are mask XOR-shifts (no search);
-  * window sample counts as mask reductions;
-  * boundary timestamps/values as one-hot masked reductions (each has
-    exactly ONE nonzero term, so f32/int32 accumulation is exact).
+Values ride a per-series 2xint32 FIXED-POINT channel: at pack time each
+series is rebased to its in-tile midpoint and scaled by a per-series
+power of two so the full in-tile value range spans 61 bits split as
+hi*2^31 + lo. Boundary deltas are computed as exact int32 subtractions
+(dh, dl) and only the final f32 recombine dh*2^(31-s) + dl*2^-s rounds
+— relative to the DELTA, not the absolute counter value — so the error
+is 2^-23|delta| + span*2^-53: the same noise floor as the reference's
+f64 path (RateFunctions.scala computes v2-v1 in f64), at 8 bytes per
+value instead of 16 and with native i32 VPU ops instead of f64
+emulation. Timestamps enter as int32 ms relative to the tile base: the
+dispatcher (tilestore._slide_eligible) guards that the whole query span
+fits in int31 (~24.8 days).
 
-f64 payloads (Prometheus semantics) are carried as THREE f32 channels
-(24+24+5 mantissa bits >= 53): split3() is exact, each channel extraction
-is exact, and the f64 recombine outside the kernel is exact.
+Traffic shape: the dispatcher only takes grids where the window is a
+whole number of steps ((kc0-kl0) % st == 0), which puts the
+window-end family (kc0) and window-start family (kl0) in the SAME
+stride-residue plane, dspan = (kc0-kl0)/st rows apart — one merged DMA
+of TT+dspan rows serves both, and all views are STATIC slices of one
+rolled block. The jitter fallback families (kc0-1 / kl0+1) are elided
+entirely (hi_mode/lo_mode) when the query grid's phase relative to the
+scrape ticks clears the tile's max jitter: then "is the boundary
+sample inside the window" has the same answer for every series and
+every step, statically.
 
-Timestamps enter as int32 offsets relative to the first window start —
-callers must guard that the whole query span fits in int31 (~24.8 days).
+This module imports nothing of the query package: the dispatcher
+(tilestore.groupsum_counters) runs the kernel inside one cached
+executable per static tuple, and tpu.TpuBackend.fused_groupsum calls
+that.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from filodb_tpu.lint.contracts import ANY, SEM, SMEM, Block, kernel_contract
 from filodb_tpu.lint.numerics import precision
-
-# int32 sentinel for padded samples: beyond any valid relative timestamp
-TR_PAD = np.int32(2**31 - 1)
-
-# tile sizes: BS series rows x TT windows per program (TPU block tiling
-# requires multiples of (8, 128) on the trailing dims); the kernel loops
-# over _TC-window chunks internally so mask temporaries stay [BS, TC, N]
-_BS = 8
-_TT = 128
-_TC = 32
-
-
-def split3(v: jnp.ndarray) -> jnp.ndarray:
-    """Exactly split f64 [S, N] into three stacked f32 channels [S, 3, N]:
-    v == h + m + l with no rounding (53 <= 24+24+24 mantissa bits)."""
-    h = v.astype(jnp.float32)
-    r = v - h.astype(jnp.float64)
-    m = r.astype(jnp.float32)
-    l = (r - m.astype(jnp.float64)).astype(jnp.float32)
-    return jnp.stack([h, m, l], axis=1)
-
-
-def combine3(c: jnp.ndarray) -> jnp.ndarray:
-    """[..., 3, T] f32 channels -> f64 (exact)."""
-    return (c[..., 0, :].astype(jnp.float64)
-            + c[..., 1, :].astype(jnp.float64)
-            + c[..., 2, :].astype(jnp.float64))
-
-
-# ---------------------------------------------------------------------------
-# Fused counter group-sum kernel: the north-star `sum by (g) (rate(c[w]))`
-# as ONE pass over the stride-permuted tiles. XLA's best arrangement of
-# the same computation (slices -> epilogue -> one-hot matmul) pays ~2.5x
-# the HBM traffic materializing the [T, S] rate intermediate and
-# re-reading it on the MXU; here the boundary row-blocks per step-tile
-# are DMA'd HBM->VMEM (double-buffered, prefetched across the sequential
-# program grid), the f32 extrapolation epilogue
-# (rangefn/RateFunctions.scala:23-79 semantics) runs in VMEM, and only
-# the [T, G] group sums + counts ever leave the chip.
-#
-# Values ride a per-series 2xint32 FIXED-POINT channel: at pack time each
-# series is rebased to its in-tile midpoint and scaled by a per-series
-# power of two so the full in-tile value range spans 61 bits split as
-# hi*2^31 + lo. Boundary deltas are computed as exact int32 subtractions
-# (dh, dl) and only the final f32 recombine dh*2^(31-s) + dl*2^-s rounds
-# — relative to the DELTA, not the absolute counter value — so the error
-# is 2^-23|delta| + span*2^-53: the same noise floor as the reference's
-# f64 path (RateFunctions.scala computes v2-v1 in f64), at 8 bytes per
-# value instead of 16 and with native i32 VPU ops instead of f64
-# emulation.
-#
-# Traffic shape: the dispatcher only takes grids where the window is a
-# whole number of steps ((kc0-kl0) % st == 0), which puts the
-# window-end family (kc0) and window-start family (kl0) in the SAME
-# stride-residue plane, dspan = (kc0-kl0)/st rows apart — one merged DMA
-# of TT+dspan rows serves both, and all views are STATIC slices of one
-# rolled block. The jitter fallback families (kc0-1 / kl0+1) are elided
-# entirely (hi_mode/lo_mode) when the query grid's phase relative to the
-# scrape ticks clears the tile's max jitter: then "is the boundary
-# sample inside the window" has the same answer for every series and
-# every step, statically.
-# ---------------------------------------------------------------------------
 
 _GS_TT = 256           # query steps per tile (sublane dim of compute):
 #                        256 halves the sequential-grid iteration count
@@ -119,37 +77,6 @@ GS_CUR = 1             # the nominal slot is always inside the window
 GS_ALT = 2             # the nominal slot is always outside: use kc0-1/kl0+1
 
 _GS_DSPAN_MAX = 48     # dispatcher cap on window/step (merged-stream rows)
-
-import os as _os  # noqa: E402
-# dev-only ablation knob (noroll/noepi/nodot/lowdot). DELIBERATELY only
-# honored in interpret/debug mode: every ablation produces WRONG numbers
-# by design (they exist to isolate kernel-stage costs in benchmarks),
-# so a stray env var must never corrupt compiled production results.
-_GS_ABLATE = frozenset(
-    x for x in (_os.environ.get("GS_ABLATE") or "").split(",") if x)
-_GS_ABLATE_WARNED = False
-
-
-def _gs_ablate_active(interpret: bool) -> frozenset:
-    """Effective ablation set for one kernel build; logs LOUDLY when any
-    ablation is active and when a compiled-mode run ignores the knob."""
-    global _GS_ABLATE_WARNED
-    if not _GS_ABLATE:
-        return _GS_ABLATE
-    import logging
-    log = logging.getLogger(__name__)
-    if not interpret:
-        if not _GS_ABLATE_WARNED:
-            _GS_ABLATE_WARNED = True
-            log.warning(
-                "GS_ABLATE=%s ignored: ablations only apply in "
-                "interpret/debug mode (results would be wrong)",
-                ",".join(sorted(_GS_ABLATE)))
-        return frozenset()
-    log.warning("GS_ABLATE active (%s): group-sum kernel results are "
-                "INTENTIONALLY wrong (benchmark ablation mode)",
-                ",".join(sorted(_GS_ABLATE)))
-    return _GS_ABLATE
 
 
 def _gs_mlen(st: int, dspan: int, tt: int = _GS_TT) -> int:
@@ -190,7 +117,7 @@ def _gs_pipeline(st: int, dspan: int, hi_mode: int, lo_mode: int,
 
 def _groupsum_kernel(func: str, st: int, dspan: int, hi_mode: int,
                      lo_mode: int, exact_branch: bool, n_ttiles: int,
-                     mlen: int, tt: int, nbuf: int, ablate: frozenset,
+                     mlen: int, tt: int, nbuf: int,
                      params_ref, v_ref, base_ref, oh_ref,
                      sum_ref, cnt_ref, v_scr, sems):
     """Grid: (n_s,) sequential. params (SMEM, i32):
@@ -288,18 +215,13 @@ def _groupsum_kernel(func: str, st: int, dspan: int, hi_mode: int,
         # (plain dynamic_slice on vectors has no Mosaic lowering, and
         # NEGATIVE dynamic roll shifts mis-lower — rotate left by
         # `len - off` instead). Row i of R is permuted-G row g_m + i.
-        if "noroll" in ablate:
-            R = v_scr[slot, 0]
-        else:
-            R = pltpu.roll(v_scr[slot, 0], shift=mlen - offm, axis=0)
+        R = pltpu.roll(v_scr[slot, 0], shift=mlen - offm, axis=0)
 
         def view(row0):
             return R[row0:row0 + tt]
 
         def fam_view(idx, kf):
             full = v_scr[slot, idx, :tt + _GS_AL]
-            if "noroll" in ablate:
-                return full[:tt]
             g = jax.lax.div(kf, jnp.int32(st)) + ti * tt
             off = g - pl.multiple_of((g // _GS_AL) * _GS_AL, _GS_AL)
             return pltpu.roll(full, shift=(tt + _GS_AL) - off,
@@ -392,23 +314,15 @@ def _groupsum_kernel(func: str, st: int, dspan: int, hi_mode: int,
         factor = extrap / sampled
         if func == "rate":
             factor = factor / (window.astype(jnp.float32) * 1e-3)
-        if "noepi" in ablate:
-            out = delta
-        else:
-            out = delta * factor
+        out = delta * factor
         ok = live & (counts >= 2) & ~jnp.isnan(out)
         local = jnp.where(ok, out, jnp.float32(0.0))
         okf = jnp.where(ok, jnp.float32(1.0), jnp.float32(0.0))
         oh = oh_ref[:]
         sl = pl.ds(ti * tt, tt)
-        if "nodot" in ablate:
-            sum_ref[sl, :] += local[:, :16]
-            cnt_ref[sl, :] += okf[:, :16]
-            return
         # HIGHEST: the MXU's default bf16 input truncation would round
         # every rate to 8 mantissa bits (bf16(0.1) = 0.10009765625)
-        prec = (jax.lax.Precision.DEFAULT if "lowdot" in ablate
-                else jax.lax.Precision.HIGHEST)
+        prec = jax.lax.Precision.HIGHEST
         sum_ref[sl, :] += jnp.dot(local, oh,
                                   preferred_element_type=jnp.float32,
                                   precision=prec)
@@ -576,8 +490,7 @@ def groupsum_call(func: str, st: int, dspan: int, hi_mode: int,
 
     def body(params, v_p, base, onehot, *, _k=functools.partial(
             _groupsum_kernel, func, st, dspan, hi_mode, lo_mode,
-            bool(exact_branch), n_ttiles, mlen, tt, nbuf,
-            _gs_ablate_active(interpret))):
+            bool(exact_branch), n_ttiles, mlen, tt, nbuf)):
         def kern(params_ref, v_ref, base_ref, oh_ref,
                  sum_ref, cnt_ref, v_scr, sems):
             _k(params_ref, v_ref, base_ref[0], oh_ref,
@@ -596,163 +509,3 @@ def groupsum_call(func: str, st: int, dspan: int, hi_mode: int,
     with jax.enable_x64(False):
         sums, cnts = body(params, v_p, base, onehot)
     return sums[:nsteps], cnts[:nsteps]
-
-
-def _extract_kernel(nchan: int, params_ref, tr_ref, pay_ref,
-                    cnt_ref, tlo_ref, thi_ref, plo_ref, phi_ref):
-    """One (series-tile, window-tile) program."""
-    j = pl.program_id(1)
-    step = params_ref[0, 0]
-    window = params_ref[0, 1]
-    tr = tr_ref[:]                                        # [BS, N] i32
-    trb = tr[:, None, :]                                  # [BS, 1, N]
-    # neighbor timestamps (computed once, 2D int32 — Mosaic cannot
-    # concatenate i1 vectors, so shift masks are derived by comparison)
-    tr_next = jnp.concatenate(
-        [tr[:, 1:], jnp.full_like(tr[:, :1], TR_PAD)], axis=1)
-    tr_prev = jnp.concatenate(
-        [jnp.full_like(tr[:, :1], jnp.int32(-2**31)), tr[:, :-1]], axis=1)
-    trn = tr_next[:, None, :]
-    trp = tr_prev[:, None, :]
-    for sub in range(_TT // _TC):
-        t_idx = jax.lax.broadcasted_iota(jnp.int32, (1, _TC, 1), 1)
-        wstart = (j * _TT + sub * _TC + t_idx) * step     # [1, TC, 1]
-        wend = wstart + window
-        started = trb <= wend                             # [BS, TC, N]
-        after = trb >= wstart
-        inwin = started & after
-        sl_t = slice(sub * _TC, (sub + 1) * _TC)
-        cnt_ref[:, sl_t] = jnp.where(inwin, jnp.int32(1),
-                                     jnp.int32(0)).sum(
-            axis=2, dtype=jnp.int32)
-        # last in-window sample: started is prefix-true (rows sorted),
-        # so the transition is where the NEXT sample is past wend
-        oh_hi = started & (trn > wend) & after
-        # first in-window sample: after is suffix-true; transition where
-        # the PREVIOUS sample is before wstart
-        oh_lo = after & (trp < wstart) & started
-        tlo_ref[:, sl_t] = jnp.where(oh_lo, trb, jnp.int32(0)).sum(
-            axis=2, dtype=jnp.int32)
-        thi_ref[:, sl_t] = jnp.where(oh_hi, trb, jnp.int32(0)).sum(
-            axis=2, dtype=jnp.int32)
-        for c in range(nchan):
-            v = pay_ref[:, c, :][:, None, :]              # [BS, 1, N]
-            plo_ref[:, c, sl_t] = jnp.where(oh_lo, v, jnp.float32(0)).sum(
-                axis=2, dtype=jnp.float32)
-            phi_ref[:, c, sl_t] = jnp.where(oh_hi, v, jnp.float32(0)).sum(
-                axis=2, dtype=jnp.float32)
-
-
-def _extract_example():
-    args = (jax.ShapeDtypeStruct((8, 2048), jnp.int32),
-            jax.ShapeDtypeStruct((8, 3, 2048), jnp.float32))
-    return args, {"step": 1_000, "window": 5_000, "nsteps": 128}
-
-
-def _extract_expect(out):
-    want = [((8, 128), jnp.int32)] * 3 + [((8, 3, 128), jnp.float32)] * 2
-    got = [(tuple(o.shape), o.dtype) for o in out]
-    if got != want:
-        return f"outputs {got} != {want}"
-    return None
-
-
-# Representative worst case: N = 2048 samples per row block. The [BS,
-# TC, N] mask temporaries dominate the footprint — they are compute
-# intermediates, declared here as scratch so the budget covers them.
-@kernel_contract(
-    "window_extract", kind="pallas",
-    grid=(4, 2),
-    blocks=(
-        Block("params", (1, 2), "int32", space=SMEM, tiled=False),
-        Block("tr", (_BS, 2048), "int32",
-              array_shape=(32, 2048), index_map=lambda i, j: (i, 0)),
-        # C=3 payload channels sit mid-block: Mosaic pads the sublane
-        # dim, so the (8,128) check is waived for this block
-        Block("pay", (_BS, 3, 2048), "float32", tiled=False,
-              array_shape=(32, 3, 2048),
-              index_map=lambda i, j: (i, 0, 0)),
-    ),
-    scratch=(
-        Block("mask_started", (_BS, _TC, 2048), "int32"),
-        Block("mask_after", (_BS, _TC, 2048), "int32"),
-        Block("onehot_edges", (_BS, _TC, 2048), "int32"),
-    ),
-    outputs=(
-        Block("cnt", (_BS, _TT), "int32",
-              array_shape=(32, 256), index_map=lambda i, j: (i, j)),
-        Block("t_lo", (_BS, _TT), "int32",
-              array_shape=(32, 256), index_map=lambda i, j: (i, j)),
-        Block("t_hi", (_BS, _TT), "int32",
-              array_shape=(32, 256), index_map=lambda i, j: (i, j)),
-        Block("pay_lo", (_BS, 3, _TT), "float32", tiled=False,
-              array_shape=(32, 3, 256),
-              index_map=lambda i, j: (i, 0, j)),
-        Block("pay_hi", (_BS, 3, _TT), "float32", tiled=False,
-              array_shape=(32, 3, 256),
-              index_map=lambda i, j: (i, 0, j)),
-    ),
-    vmem_budget=8 << 20,
-    rel_time_bits=31,
-    span_guard="filodb_tpu.query.tpu:_window_endpoint_pallas",
-    example=_extract_example, expect=_extract_expect,
-    notes="rate-family boundary extraction for irregular series; "
-          "timestamps are int32 offsets from the first window start "
-          "(TR_PAD sentinel for padding)")
-def window_extract(tr: jnp.ndarray, pay: jnp.ndarray,
-                   step, window, nsteps: int,
-                   interpret: bool = False
-                   ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray,
-                              jnp.ndarray, jnp.ndarray]:
-    """Run the boundary-extract kernel.
-
-    tr:  [S, N] int32 sample times relative to the FIRST window start
-         (pad = TR_PAD). S must be a multiple of the row tile.
-    pay: [S, C, N] f32 payload channels to extract at window boundaries.
-    Windows: wstart_t = t*step (relative), wend_t = wstart_t + window.
-
-    Returns (counts i32 [S,T], t_lo i32, t_hi i32,
-             pay_at_lo f32 [S,C,T], pay_at_hi f32 [S,C,T]) — entries only
-    meaningful where counts >= 1."""
-    S, C, N = pay.shape
-    T_pad = -(-nsteps // _TT) * _TT
-    S_pad = -(-S // _BS) * _BS
-    if S_pad != S:
-        tr = jnp.pad(tr, ((0, S_pad - S), (0, 0)),
-                     constant_values=TR_PAD)
-        pay = jnp.pad(pay, ((0, S_pad - S), (0, 0), (0, 0)))
-    params = jnp.array([[step, window]], dtype=jnp.int32)
-    grid = (S_pad // _BS, T_pad // _TT)
-    out_shapes = (
-        jax.ShapeDtypeStruct((S_pad, T_pad), jnp.int32),
-        jax.ShapeDtypeStruct((S_pad, T_pad), jnp.int32),
-        jax.ShapeDtypeStruct((S_pad, T_pad), jnp.int32),
-        jax.ShapeDtypeStruct((S_pad, C, T_pad), jnp.float32),
-        jax.ShapeDtypeStruct((S_pad, C, T_pad), jnp.float32),
-    )
-    st_spec = pl.BlockSpec((_BS, _TT), lambda i, j: (i, j),
-                           memory_space=pltpu.VMEM)
-    st3_spec = pl.BlockSpec((_BS, C, _TT), lambda i, j: (i, 0, j),
-                            memory_space=pltpu.VMEM)
-    # trace the kernel in 32-bit mode: under jax_enable_x64, index-map and
-    # literal constants become i64, which Mosaic cannot legalize
-    with jax.enable_x64(False):
-        outs = pl.pallas_call(
-            functools.partial(_extract_kernel, C),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 2), lambda i, j: (0, 0),
-                             memory_space=pltpu.SMEM),
-                pl.BlockSpec((_BS, N), lambda i, j: (i, 0),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((_BS, C, N), lambda i, j: (i, 0, 0),
-                             memory_space=pltpu.VMEM),
-            ],
-            out_specs=(st_spec, st_spec, st_spec, st3_spec, st3_spec),
-            out_shape=out_shapes,
-            interpret=interpret,
-            name="window_extract",
-        )(params, tr, pay)
-    cnt, tlo, thi, plo, phi = outs
-    return (cnt[:S, :nsteps], tlo[:S, :nsteps], thi[:S, :nsteps],
-            plo[:S, :, :nsteps], phi[:S, :, :nsteps])

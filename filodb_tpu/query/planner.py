@@ -41,7 +41,7 @@ from filodb_tpu.query.engine import (METRIC_LABELS, QueryEngine,
                                      select_raw_series)
 from filodb_tpu.query.model import (GridResult, QueryError, QueryLimits,
                                     QueryStats, RangeParams, RawSeries,
-                                    StaleRoutingError)
+                                    StaleRoutingError, clip_series)
 
 # aggregations executable as mesh collectives (parallel/mesh.py MESH_AGGS)
 _MESH_AGGS = frozenset({"sum", "count", "avg", "min", "max", "group"})
@@ -427,8 +427,6 @@ class MeshAggregateExec(ExecPlan):
     deadline: Optional[object] = None
 
     def execute(self) -> GridResult:
-        from filodb_tpu.query.engine import clip_series
-
         n_mesh = self.mesh_executor.mesh.shape["shard"]
         series_by_shard: List[List] = []
         # limits budget is per-query: check against fresh stats, then fold

@@ -42,6 +42,7 @@ import jax.numpy as jnp  # noqa: E402
 from filodb_tpu.lint.capacity import capacity
 from filodb_tpu.lint.contracts import kernel_contract
 from filodb_tpu.lint.numerics import order_insensitive, precision  # noqa: F401
+from filodb_tpu.query import pallas_kernels as pk
 from filodb_tpu.query.cumsum import cumsum_f64
 from filodb_tpu.query.model import RawSeries
 
@@ -351,28 +352,25 @@ class AlignedTiles:
         key = (name + "#tiled", st)
         c = self._tperm.get(key)
         if c is None:
-            from filodb_tpu.query.pallas_kernels import (_GS_AL,
-                                                         _GS_DSPAN_MAX,
-                                                         _GS_SS,
-                                                         _GS_TT_WIDE)
             N = src.shape[0]
             S = src.shape[1]
             # pad the permuted G axis past every tail tile: the kernel's
             # merged kc/kl stream reads up to dspan (<= _GS_DSPAN_MAX)
             # + alignment rows past the last window-end row — sized for
             # the WIDEST step tile the pipeline chooser can pick
-            G = -(-N // st) + _GS_TT_WIDE + 2 * _GS_AL + _GS_DSPAN_MAX
+            G = (-(-N // st) + pk._GS_TT_WIDE + 2 * pk._GS_AL
+                 + pk._GS_DSPAN_MAX)
             padn = G * st - N
             if padn:
                 src = jnp.concatenate(
                     [src, jnp.zeros((padn, S), src.dtype)], axis=0)
-            S_pad = -(-S // _GS_SS) * _GS_SS
+            S_pad = -(-S // pk._GS_SS) * pk._GS_SS
             if S_pad != S:
                 src = jnp.concatenate(
                     [src, jnp.zeros((G * st, S_pad - S), src.dtype)],
                     axis=1)
             c = jnp.asarray(
-                src.reshape(G, st, S_pad // _GS_SS, _GS_SS)
+                src.reshape(G, st, S_pad // pk._GS_SS, pk._GS_SS)
                 .transpose(2, 1, 0, 3))
             self._tperm[key] = c
         return c
@@ -461,20 +459,19 @@ class AlignedTiles:
         key = (vch + "#fixed_base", 0)
         c = self._tperm.get(key)
         if c is None:
-            from filodb_tpu.query.pallas_kernels import _GS_SS
             fx = self._fixed_channels(vch)
             assert fx is not None
             mid, s = fx[2], fx[3]
             c1 = jnp.ldexp(jnp.float32(1.0), 31 - s)
             c2 = jnp.ldexp(jnp.float32(1.0), -s)
             S = mid.shape[0]
-            S_pad = -(-S // _GS_SS) * _GS_SS
+            S_pad = -(-S // pk._GS_SS) * pk._GS_SS
             rows = jnp.zeros((3, S_pad), jnp.float32)
             rows = rows.at[0, :S].set(mid).at[1, :S].set(c1)
             rows = rows.at[2, :S].set(c2)
             rows = jnp.pad(rows, ((0, 5), (0, 0)))
             c = jnp.asarray(
-                rows.reshape(8, S_pad // _GS_SS, _GS_SS)
+                rows.reshape(8, S_pad // pk._GS_SS, pk._GS_SS)
                 .transpose(1, 0, 2))
             self._tperm[key] = c
         return c
@@ -708,8 +705,6 @@ def _eval_core(func: str, nsteps: int, arrs: Dict[str, jnp.ndarray],
     """Traceable evaluation body (jitted via _EVAL_JIT). Everything except
     (func, nsteps) is traced, so one compiled program serves every store
     snapshot of the same shape."""
-    from filodb_tpu.query.tpu import _extrapolated_rate
-
     t = jnp.arange(nsteps, dtype=jnp.int64)
     wend = w0e + t * step
     wstart = w0s + t * step
@@ -872,7 +867,6 @@ def _eval_counter_t(func: str, nsteps: int, arrs: Dict[str, jnp.ndarray],
     v1 = jnp.where(none_lo, jnp.nan,
                    jnp.where(useb, TK(bf_v, kcl),
                              TK(bf_v, kn)))
-    from filodb_tpu.query.tpu import _extrapolated_rate
     is_counter = func != "delta"
     out = _extrapolated_rate(wstart_d, wend_d, counts,
                              t1, v1, t2, v2, is_counter, func == "rate")
@@ -1066,6 +1060,41 @@ def _eval_counter_slide(func: str, nsteps: int, st: int,
     v1 = jnp.where(useb, v_kcl, v_kn)
     return _f32_epilogue(func, counts, t1, v1, t2, v2, wstart_r, wend_r,
                          (w0e - w0s).astype(jnp.float32) / 1000.0)
+
+
+@precision(
+    "extrapolated-rate-f64", bits=53, rel_ulps=4,
+    reason="the shared f64 extrapolation formula every exact counter "
+           "path funnels through; certified within a few f64 ulps of "
+           "the pure-Python reference (promql/refeval._extrapolated) "
+           "— the two arms of the differential rail agree at the "
+           "formula level, not just end to end")
+@jax.named_scope("rate_epilogue")
+def _extrapolated_rate(wstart, wend, counts, t1, v1, t2, v2, is_counter,
+                       is_rate):
+    """(rangefn/RateFunctions.scala:37 extrapolatedRate, on device.)
+    Shape-agnostic: callers broadcast wstart/wend against their tile
+    orientation ([S, T] row-major or [T, S] slot-major)."""
+    counts = counts.astype(jnp.float64)
+    dstart = (t1 - wstart).astype(jnp.float64) / 1000.0
+    dend = (wend - t2).astype(jnp.float64) / 1000.0
+    sampled = (t2 - t1).astype(jnp.float64) / 1000.0
+    avg_dur = sampled / (counts - 1.0)
+    delta = v2 - v1
+    if is_counter:
+        dzero = jnp.where((delta > 0) & (v1 >= 0),
+                          sampled * (v1 / jnp.where(delta == 0, jnp.nan,
+                                                    delta)),
+                          jnp.inf)
+        dstart = jnp.minimum(dstart, dzero)
+    thresh = avg_dur * 1.1
+    extrap = sampled \
+        + jnp.where(dstart < thresh, dstart, avg_dur / 2.0) \
+        + jnp.where(dend < thresh, dend, avg_dur / 2.0)
+    scaled = delta * (extrap / sampled)
+    if is_rate:
+        scaled = scaled / (wend - wstart) * 1000.0
+    return jnp.where(counts >= 2, scaled, jnp.nan)
 
 
 @precision(
@@ -1280,7 +1309,6 @@ def _groupsum_program(func: str, st: int, dspan: int, hi_mode: int,
     gives an all-zero row), the Pallas kernel and its [:nsteps] slices.
     Every input is explicitly typed, so the program is the same under
     x64 on and off."""
-    from filodb_tpu.query import pallas_kernels as pk
     onehot = (ids[:, None] == jnp.arange(G, dtype=jnp.int32)[None, :]
               ).astype(jnp.float32)
     return pk.groupsum_call(
@@ -1329,7 +1357,6 @@ def groupsum_counters(tiles: AlignedTiles, func: str, steps: np.ndarray,
     if el is None:
         return None
     st, k_c0, k_l0 = el
-    from filodb_tpu.query import pallas_kernels as pk
     # merged-stream contract: the window must span a whole number of
     # steps so the kc/kl families share a stride-residue plane
     d = k_c0 - k_l0
@@ -1370,9 +1397,7 @@ def groupsum_counters(tiles: AlignedTiles, func: str, steps: np.ndarray,
     exact_branch = pk.groupsum_exact_branch(window_ms, st, dspan)
     static = (func, st, dspan, hi_mode, lo_mode, exact_branch, nsteps, G,
               interpret)
-    key = ("groupsum",) + static + (
-        tuple(v_p.shape), tuple(base.shape),
-        tuple(sorted(pk._gs_ablate_active(interpret))))
+    key = ("groupsum",) + static + (tuple(v_p.shape), tuple(base.shape))
     args = (v_p, base, params, ids)
     # a kernel the chip's compiler refuses fails the query with the
     # compiler's message: the decided-in-advance route to the general

@@ -44,13 +44,17 @@ import jax.numpy as jnp  # noqa: E402
 from filodb_tpu.lint.caches import cache_registry
 from filodb_tpu.lint.capacity import capacity
 from filodb_tpu.lint.contracts import kernel_contract
-from filodb_tpu.lint.numerics import precision
 from filodb_tpu.lint.hotpath import hot_path
 from filodb_tpu.lint.threads import thread_root
 from filodb_tpu.obs import devprof
 from filodb_tpu.obs import trace as obs_trace
+from filodb_tpu.query import qos
+from filodb_tpu.query import tilestore as tst
+from filodb_tpu.query.batcher import MicroBatcher, SplitResult
 from filodb_tpu.query.cumsum import cumsum_f64
-from filodb_tpu.query.model import GridResult, RangeParams, RawSeries
+from filodb_tpu.query.model import (GridResult, RangeParams, RawSeries,
+                                    clip_series)
+from filodb_tpu.query.tilestore import _extrapolated_rate
 
 
 def _sds(shape, dtype):
@@ -244,41 +248,6 @@ def _correction(vals, lens):
     return cumsum_f64(drops, axis=1)
 
 
-@precision(
-    "extrapolated-rate-f64", bits=53, rel_ulps=4,
-    reason="the shared f64 extrapolation formula every exact counter "
-           "path funnels through; certified within a few f64 ulps of "
-           "the pure-Python reference (promql/refeval._extrapolated) "
-           "— the two arms of the differential rail agree at the "
-           "formula level, not just end to end")
-@jax.named_scope("rate_epilogue")
-def _extrapolated_rate(wstart, wend, counts, t1, v1, t2, v2, is_counter,
-                       is_rate):
-    """(rangefn/RateFunctions.scala:37 extrapolatedRate, on device.)
-    Shape-agnostic: callers broadcast wstart/wend against their tile
-    orientation ([S, T] row-major or [T, S] slot-major)."""
-    counts = counts.astype(jnp.float64)
-    dstart = (t1 - wstart).astype(jnp.float64) / 1000.0
-    dend = (wend - t2).astype(jnp.float64) / 1000.0
-    sampled = (t2 - t1).astype(jnp.float64) / 1000.0
-    avg_dur = sampled / (counts - 1.0)
-    delta = v2 - v1
-    if is_counter:
-        dzero = jnp.where((delta > 0) & (v1 >= 0),
-                          sampled * (v1 / jnp.where(delta == 0, jnp.nan,
-                                                    delta)),
-                          jnp.inf)
-        dstart = jnp.minimum(dstart, dzero)
-    thresh = avg_dur * 1.1
-    extrap = sampled \
-        + jnp.where(dstart < thresh, dstart, avg_dur / 2.0) \
-        + jnp.where(dend < thresh, dend, avg_dur / 2.0)
-    scaled = delta * (extrap / sampled)
-    if is_rate:
-        scaled = scaled / (wend - wstart) * 1000.0
-    return jnp.where(counts >= 2, scaled, jnp.nan)
-
-
 @kernel_contract(
     "window_endpoint", kind="jit",
     example=lambda: _tile_example(extra=("rate",)),
@@ -444,87 +413,9 @@ def _gather_reduce(func: str, w_bound: int, g, in_win, scalar):
 _GATHER_FUNCS = frozenset({"min_over_time", "max_over_time",
                            "quantile_over_time"})
 
-# rate family served by the Pallas boundary-extract kernel when series
-# are irregular (the aligned tilestore path handles regular cadence)
-_PALLAS_FUNCS = frozenset({"rate", "increase", "delta"})
-
-
-@kernel_contract(
-    "pallas_rate", kind="jit",
-    example=lambda: (
-        ("rate", 128, False,
-         _sds((8, 128), jnp.int64), _sds((8, 128), jnp.float64),
-         _sds((8,), jnp.int32), _sds((), jnp.int64),
-         _sds((), jnp.int64), _sds((), jnp.int64)), {}),
-    expect=_grid_expect(8, 128),
-    rel_time_bits=31, span_guard="_window_endpoint_pallas",
-    notes="irregular-cadence rate family: counter correction + exact "
-          "f64->3xf32 split feeding the Pallas boundary-extract kernel; "
-          "timestamps rebased to w0s must fit int31 ms")
-@functools.partial(jax.jit, static_argnames=("func", "nsteps", "interpret"))
-def _pallas_rate_impl(func, nsteps, interpret, ts, vals, lens, w0s, w0e,
-                      step):
-    from filodb_tpu.query import pallas_kernels as pk
-
-    S, N = ts.shape
-    idx = jnp.arange(N)[None, :]
-    in_len = idx < lens[:, None]
-    is_counter = func != "delta"
-    v = vals + _correction(vals, lens) if is_counter else vals
-    tr = jnp.where(in_len, ts - w0s, pk.TR_PAD).astype(jnp.int32)
-    pay = pk.split3(jnp.where(in_len, v, 0.0)).astype(jnp.float32)
-    window = (w0e - w0s).astype(jnp.int32)
-    cnt, tlo, thi, plo, phi = pk.window_extract(
-        tr, pay, step.astype(jnp.int32), window, nsteps,
-        interpret=interpret)
-    t = jnp.arange(nsteps, dtype=jnp.int64)
-    wstart = w0s + t * step
-    wend = w0e + t * step
-    t1 = tlo.astype(jnp.int64) + w0s
-    t2 = thi.astype(jnp.int64) + w0s
-    v1 = pk.combine3(plo)
-    v2 = pk.combine3(phi)
-    out = _extrapolated_rate(wstart[None, :], wend[None, :], cnt, t1, v1, t2, v2,
-                             is_counter, func == "rate")
-    return jnp.where(cnt >= 1, out, jnp.nan)
-
-
-def _window_endpoint_pallas(func, ts, vals, lens, w0s, w0e, step, nsteps):
-    """Pallas boundary-extract path for rate/increase/delta. Returns None
-    when preconditions fail (range exceeds int32, or no compiled-TPU
-    backend and the problem is too big for interpret mode)."""
-    mask = np.arange(ts.shape[1])[None, :] < lens[:, None]
-    if not mask.any():
-        return None
-    t_min, t_max = int(ts[mask].min()), int(ts[mask].max())
-    span_ok = (abs(t_min - int(w0s)) < 2**31 - 2
-               and abs(t_max - int(w0s)) < 2**31 - 2
-               and int(w0e - w0s) + (nsteps - 1) * int(step) < 2**31 - 2)
-    if not span_ok:
-        return None
-    on_tpu = jax.default_backend() not in ("cpu",)
-    if not on_tpu and not PALLAS_RATE_INTERPRET:
-        return None     # CPU serving: endpoint kernel (see flag above)
-    if not on_tpu and ts.size > 262_144:
-        return None     # interpret mode is for small (test) shapes only
-    return _pallas_rate_impl(func, nsteps, not on_tpu,
-                             jnp.asarray(ts), jnp.asarray(vals),
-                             jnp.asarray(lens), jnp.asarray(w0s),
-                             jnp.asarray(w0e), jnp.asarray(step))
-
-
 # tests set this to exercise the fused group-sum kernel in interpret
 # mode on the CPU test mesh; production CPU nodes leave it off
 FUSED_GROUPSUM_INTERPRET = False
-
-# tests set this to exercise the Pallas boundary-extract rate path in
-# interpret mode on CPU; production CPU nodes leave it off — interpret
-# mode re-jits per (shape, nsteps) at ~0.5-1s a piece, and with live
-# ingest moving the write-buffer tail every flush changes the tail
-# step count, so a serving node would hit a fresh compile every few
-# seconds. The endpoint kernel is bit-for-bit identical for the rate
-# family (pinned by test_batcher), so CPU serving loses nothing.
-PALLAS_RATE_INTERPRET = False
 
 
 class _TileEntry:
@@ -624,7 +515,6 @@ class TpuBackend:
         self.tile_hits = 0      # observability: cache hits
         self.fused_aggs = 0     # observability: fused group-sum queries
         if batcher == "default":
-            from filodb_tpu.query.batcher import MicroBatcher
             batcher = MicroBatcher()
         self.batcher = batcher
         # executable-reuse observability for the packed kernel family:
@@ -655,7 +545,6 @@ class TpuBackend:
     def executable_cache_stats(self) -> Dict[str, int]:
         """Packed-kernel + tilestore executable-reuse counters (the
         compile-cache hit/miss surface in /metrics)."""
-        from filodb_tpu.query import tilestore as tst
         ts_stats = tst.executable_cache_stats()
         with self._exec_lock:
             return {"hits": self.exec_cache_hits + ts_stats["hits"],
@@ -705,8 +594,6 @@ class TpuBackend:
         uniform grid. Host-side packing happens here, on the calling
         worker thread — under the micro-batcher it overlaps device
         compute of the previous batch."""
-        from filodb_tpu.query.engine import clip_series
-
         nsteps = steps.size
         w0e = np.int64(steps[0] - offset_ms)
         w0s = np.int64(w0e - window_ms)
@@ -722,30 +609,35 @@ class TpuBackend:
         w_bound = self._window_sample_bound(series, window_ms, ts.shape[1]) \
             if func in _GATHER_FUNCS else 0
         t_bucket = _next_pow2(nsteps, 8)
+        # concurrent queries sharing (func, N, T-bucket) stack along
+        # the series axis and run as ONE kernel dispatch
+        key = ("packed", func, ts.shape[1], t_bucket,
+               func != "last_sample", scalar)
+        member = _PackedMember(ts, vals, lens, int(w0s), int(w0e),
+                               int(step), nsteps, w_bound)
+        return self._run_or_batch(key, member, functools.partial(
+            self._packed_run, func, t_bucket, scalar))
+
+    def _run_or_batch(self, key, member, run, may_batch: bool = True,
+                      use_executor: Optional[bool] = None) -> np.ndarray:
+        """The one choice between a shared and a lone dispatch:
+        ``run(members) -> SplitResult`` goes through the micro-batcher
+        when there is one and the query may join a batch, else it runs
+        here with this query as its only member."""
         b = self.batcher
-        if b is not None and b.enabled:
-            # concurrent queries sharing (func, N, T-bucket) stack along
-            # the series axis and run as ONE kernel dispatch
-            key = ("packed", func, ts.shape[1], t_bucket,
-                   func != "last_sample", scalar)
-            member = _PackedMember(ts, vals, lens, int(w0s), int(w0e),
-                                   int(step), nsteps, w_bound)
-            return b.submit(key, member, functools.partial(
-                self._packed_run, func, t_bucket, scalar))
-        with obs_trace.span("device-dispatch", path="packed"):
-            dev = self._packed_single(func, ts, vals, lens, w0s, w0e,
-                                      step, nsteps, t_bucket, scalar,
-                                      w_bound)
+        if may_batch and b is not None and b.enabled:
+            return b.submit(key, member, run, use_executor=use_executor)
+        res = run([member])
         with obs_trace.span("device-sync"):
-            return np.asarray(dev)[:ts.shape[0], :nsteps]
+            return res.get(0)
 
     @hot_path
-    def _packed_single(self, func, ts, vals, lens, w0s, w0e, step, nsteps,
+    def _packed_single(self, func, ts, vals, lens, w0s, w0e, step,
                        t_bucket, scalar, w_bound):
         """Single-query packed dispatch with pow2 shape bucketing: S and
         the step count pad to buckets so repeat queries of nearby shapes
         reuse compiled executables instead of retracing. Enqueue only:
-        returns the device array [S-bucket, >= nsteps]; the caller's
+        returns the device array [S-bucket, t_bucket]; the caller's
         ``device-sync`` stage brings ``[:S, :nsteps]`` to the host."""
         S, N = ts.shape
         s_bucket = _next_pow2(S, 8)
@@ -761,15 +653,6 @@ class TpuBackend:
             out = _window_gather(func, w_bound, ts, vals, lens,
                                  w0s, w0e, step, t_bucket, scalar)
         else:
-            if func in _PALLAS_FUNCS:
-                # the Pallas boundary-extract path keeps the exact step
-                # count (its grid layout is nsteps-derived); bit-for-bit
-                # with _window_endpoint — pinned by test_batcher
-                out = _window_endpoint_pallas(func, ts, vals, lens, w0s,
-                                              w0e, step, nsteps)
-                if out is not None:
-                    self._count_exec(("pallas", func, s_bucket, N, nsteps))
-                    return out
             self._count_exec(
                 ("endpoint", func, s_bucket, N, t_bucket),
                 probe=_lower_probe(_window_endpoint, func,
@@ -786,21 +669,18 @@ class TpuBackend:
         axis, dispatch ONE kernel with per-row window vectors, split by
         per-query segment offsets. A batch of one takes the single-query
         path (bit-for-bit identical; the parity test pins it)."""
-        from filodb_tpu.query.batcher import SplitResult
-
         with obs_trace.span("device-dispatch", path="packed",
                             batch=len(members)):
-            return self._packed_run_inner(func, t_bucket, scalar,
-                                          members, SplitResult)
+            return self._packed_run_inner(func, t_bucket, scalar, members)
 
     def _packed_run_inner(self, func: str, t_bucket: int, scalar: float,
-                          members, SplitResult) -> object:
+                          members) -> object:
         if len(members) == 1:
             m = members[0]
             dev = self._packed_single(func, m.ts, m.vals, m.lens,
                                       np.int64(m.w0s), np.int64(m.w0e),
-                                      np.int64(m.step), m.nsteps, t_bucket,
-                                      scalar, m.w_bound)
+                                      np.int64(m.step), t_bucket, scalar,
+                                      m.w_bound)
             # a batch of one syncs HERE, on the thread that dispatched
             # it (the executor's busy time is the gather window of the
             # next batch); device-sync is then a child of device-dispatch
@@ -838,9 +718,6 @@ class TpuBackend:
                                  jnp.asarray(w0s_v), jnp.asarray(w0e_v),
                                  jnp.asarray(step_v), t_bucket, scalar)
         else:
-            # rate-family members ride _window_endpoint here (the Pallas
-            # boundary-extract kernel takes scalar grids); exact f64 on
-            # both paths — bit-for-bit, pinned by the parity test
             self._count_exec(
                 ("endpoint-b", func, s_bucket, N, t_bucket),
                 probe=_lower_probe(_window_endpoint, func,
@@ -884,12 +761,10 @@ class TpuBackend:
         whose windows reach past it through the packed path — this is
         what makes serving a STALE entry correct while a flush's rebuild
         runs in the background."""
-        from filodb_tpu.query import tilestore as tst
-
         with obs_trace.span("tile-build", series=len(series)):
-            return self._build_tile_entry_inner(tst, series, use_snap)
+            return self._build_tile_entry_inner(series, use_snap)
 
-    def _build_tile_entry_inner(self, tst, series, use_snap: bool):
+    def _build_tile_entry_inner(self, series, use_snap: bool):
         prefix = [
             RawSeries(s.labels, s.ts[:self._prefix_len(s)],
                       s.values[:self._prefix_len(s)], s.is_counter,
@@ -1000,9 +875,8 @@ class TpuBackend:
                         self._tile_refreshing.discard(key)
             # background class: a tile rebuild improves FUTURE queries
             # and must never delay a queued interactive dispatch
-            from filodb_tpu.query import qos as _qos
             self.batcher.executor.submit(
-                refresh, priority=_qos.PRIORITY_BACKGROUND)
+                refresh, priority=qos.PRIORITY_BACKGROUND)
             return stale.stale_view(series)
         entry = self._build_tile_entry(series, use_snap)
         # keyed AFTER the build read the samples: a partition evicted or
@@ -1023,8 +897,6 @@ class TpuBackend:
         columns — so ingest never invalidates the device store, flushes do
         (SURVEY §7: 'recent samples answered from a host-side tail scan
         merged at present stage')."""
-        from filodb_tpu.query import tilestore as tst
-
         if func not in tst.ALIGNED_FUNCS:
             return None
         entry = self._tile_entry(series)
@@ -1067,74 +939,43 @@ class TpuBackend:
     def _aligned_dispatch(self, tiles, func: str, steps: np.ndarray,
                           window_ms: int, offset_ms: int,
                           func_args) -> np.ndarray:
-        """Aligned-tile kernel dispatch -> [S, T] numpy.
+        """Aligned-tile kernel dispatch -> [S, T] numpy, for a non-empty
+        ``steps``.
 
         With the micro-batcher on, concurrent queries over the SAME
         cached tiles that share (func, step count, step, window) — the
         dashboard-refresh shape, differing only in grid position — run
         as ONE vmapped device dispatch along the grid axis. A lone
-        query (or batcher off) takes the scalar evaluator exactly as
-        before; the vmapped families are bit-for-bit the scalar ones
-        (test_batcher pins it)."""
-        from filodb_tpu.query import tilestore as tst
-
-        counters = func in ("rate", "increase", "delta")
-        b = self.batcher
+        query (batcher off, or a call with ``func_args``, which never
+        joins a batch) is a batch of one: the scalar evaluator; the
+        vmapped families are bit-for-bit the scalar ones (test_batcher
+        pins it)."""
         nsteps = steps.size
-        if counters and nsteps >= 1:
+        family = None
+        if func in ("rate", "increase", "delta"):
             family = tst.counters_batch_family(tiles, func, steps,
                                                window_ms, offset_ms)
-        else:
-            family = None
         mesh_st = None
-        if not func_args and nsteps >= 1:
+        if not func_args:
             mesh_st = self._mesh_sharded(tiles, func, steps, window_ms,
                                          offset_ms, family)
-        if b is not None and b.enabled and not func_args and nsteps >= 1:
-            w0e = int(steps[0] - offset_ms)
-            w0s = w0e - window_ms
-            step = int(steps[1] - steps[0]) if nsteps > 1 else 1
-            # id(tiles) is safe as a key component: members hold a
-            # reference to the tiles object, so the id cannot be
-            # recycled while the batch is open
-            key = ("aligned", id(tiles), func, nsteps, step, window_ms,
-                   family, mesh_st is not None)
-            return b.submit(
-                key, (w0s, w0e, steps, tiles),
-                functools.partial(self._aligned_run, tiles, func,
-                                  family, nsteps, step, window_ms,
-                                  offset_ms, mesh_st),
-                # ONE thread owns sharded submissions: a mesh program
-                # already spans every device, so inline execution on N
-                # query threads would only oversubscribe it
-                use_executor=True if mesh_st is not None else None)
-        with obs_trace.span("device-dispatch",
-                            path="mesh-aligned" if mesh_st is not None
-                            else "aligned"):
-            if mesh_st is not None:
-                self.mesh_dispatches += 1
-            if counters:
-                # counter family rides the slot-major f32-hybrid fast
-                # path: int32 timestamps + exact f64 boundary deltas,
-                # f32 extrapolation epilogue (~3e-7 relative vs the f64
-                # oracle; grids wider than int32 ms take the exact
-                # path) — test_tilestore pins parity + the exact
-                # fallback
-                dev = (mesh_st.eval_counters(func, steps, window_ms,
-                                             offset_ms)
-                       if mesh_st is not None else
-                       tst.evaluate_counters_t(tiles, func, steps,
-                                               window_ms, offset_ms))
-            elif mesh_st is not None:
-                dev = mesh_st.eval_aligned(tiles, func, steps, window_ms,
-                                           offset_ms)
-            else:
-                dev = tst.evaluate_aligned(tiles, func, steps, window_ms,
-                                           offset_ms, func_args)
-        with obs_trace.span("device-sync"):
-            # graftlint: disable=host-transfer-in-hot-loop (single-query path: designed sync point at kernel egress)
-            host = np.asarray(dev)
-        return host.T if counters else host
+        w0e = int(steps[0] - offset_ms)
+        w0s = w0e - window_ms
+        step = int(steps[1] - steps[0]) if nsteps > 1 else 1
+        # id(tiles) is safe as a key component: members hold a
+        # reference to the tiles object, so the id cannot be
+        # recycled while the batch is open
+        key = ("aligned", id(tiles), func, nsteps, step, window_ms,
+               family, mesh_st is not None)
+        return self._run_or_batch(
+            key, (w0s, w0e, steps, tiles, func_args),
+            functools.partial(self._aligned_run, tiles, func, family,
+                              nsteps, step, window_ms, offset_ms, mesh_st),
+            may_batch=not func_args,
+            # ONE thread owns sharded submissions: a mesh program
+            # already spans every device, so inline execution on N
+            # query threads would only oversubscribe it
+            use_executor=True if mesh_st is not None else None)
 
     def _mesh_sharded(self, tiles, func: str, steps, window_ms: int,
                       offset_ms: int, family):
@@ -1162,29 +1003,31 @@ class TpuBackend:
                      mesh_st, members) -> object:
         """Execute one aligned batch: B=1 takes the scalar evaluator,
         B>=2 one vmapped dispatch computing every member's grid (the
-        mesh-sharded twins of both when ``mesh_st`` serves)."""
-        from filodb_tpu.query import tilestore as tst
-        from filodb_tpu.query.batcher import SplitResult
-
+        mesh-sharded twins of both when ``mesh_st`` serves). A member
+        is ``(w0s, w0e, steps, tiles, func_args)``."""
         with obs_trace.span("device-dispatch",
                             path="mesh-aligned" if mesh_st is not None
                             else "aligned",
                             batch=len(members)):
-            return self._aligned_run_inner(tst, SplitResult, tiles,
-                                           func, family, nsteps, step,
-                                           window_ms, offset_ms, mesh_st,
-                                           members)
+            return self._aligned_run_inner(tiles, func, family, nsteps,
+                                           step, window_ms, offset_ms,
+                                           mesh_st, members)
 
-    def _aligned_run_inner(self, tst, SplitResult, tiles, func: str,
-                           family, nsteps: int, step: int,
-                           window_ms: int, offset_ms: int, mesh_st,
-                           members) -> object:
+    def _aligned_run_inner(self, tiles, func: str, family, nsteps: int,
+                           step: int, window_ms: int, offset_ms: int,
+                           mesh_st, members) -> object:
         counters = func in ("rate", "increase", "delta")
         if mesh_st is not None:
             self.mesh_dispatches += len(members)
         if len(members) == 1:
-            steps0 = members[0][2]
+            steps0, func_args = members[0][2], members[0][4]
             if counters:
+                # counter family rides the slot-major f32-hybrid fast
+                # path: int32 timestamps + exact f64 boundary deltas,
+                # f32 extrapolation epilogue (~3e-7 relative vs the f64
+                # oracle; grids wider than int32 ms take the exact
+                # path) — test_tilestore pins parity + the exact
+                # fallback
                 if mesh_st is not None:
                     dev = mesh_st.eval_counters(func, steps0, window_ms,
                                                 offset_ms)
@@ -1197,7 +1040,7 @@ class TpuBackend:
                                            window_ms, offset_ms)
             else:
                 dev = tst.evaluate_aligned(tiles, func, steps0, window_ms,
-                                           offset_ms, ())
+                                           offset_ms, func_args)
             return SplitResult(dev, 1, split=lambda h, i: h)
         w0s_list = [m[0] for m in members]
         w0e_list = [m[1] for m in members]
@@ -1236,11 +1079,8 @@ class TpuBackend:
         (or the mesh store's grouped collective). Returns (sums, cnts)
         as [T, G] numpy or None when ineligible (caller falls back to
         the general rangefn + aggregate path)."""
-        from filodb_tpu.query import tilestore as tst
-
         if func not in ("rate", "increase", "delta") or not len(series):
             return None
-        import jax
         on_cpu = jax.default_backend() == "cpu"
         if on_cpu and not FUSED_GROUPSUM_INTERPRET \
                 and self.mesh_eval is None:

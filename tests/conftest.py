@@ -20,11 +20,10 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_enable_x64", True)
 
-# exercise the fused Pallas group-sum + boundary-extract rate paths
-# (interpret mode) on the CPU test mesh; production CPU nodes keep both
-# off (tpu.py gates) — interpret-mode re-jits per shape, which a
-# serving node must never pay per query
+# exercise the fused Pallas group-sum path (interpret mode) on the CPU
+# test mesh; production CPU nodes keep it off (tpu.py gate) —
+# interpret-mode re-jits per shape, which a serving node must never pay
+# per query
 from filodb_tpu.query import tpu as _tpu  # noqa: E402
 
 _tpu.FUSED_GROUPSUM_INTERPRET = True
-_tpu.PALLAS_RATE_INTERPRET = True

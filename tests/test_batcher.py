@@ -1,8 +1,7 @@
 """Micro-batcher parity + mechanics (serving fast path, PR 3).
 
 The acceptance contract: batched and unbatched execution must be
-bit-for-bit on the same inputs, on CPU (with the Pallas paths in
-interpret mode — conftest flips the gates). Covers the aligned
+bit-for-bit on the same inputs, on CPU. Covers the aligned
 tilestore families (slide/fast counters + the general evaluator), the
 packed general path (series-axis stacking with per-row window
 vectors), the executor-queued TPU-style path and the CPU inline path,
@@ -70,7 +69,7 @@ def _run_concurrent(backend, series, func, window_ms, n=8, nsteps=16):
 @pytest.mark.parametrize("func,regular,window_ms", [
     ("rate", True, 300_000),           # aligned slide/fast family
     ("avg_over_time", True, 600_000),  # aligned general evaluator
-    ("rate", False, 300_000),          # packed path (vs pallas single)
+    ("rate", False, 300_000),          # packed endpoint family
     ("max_over_time", False, 300_000),  # packed gather family
     ("sum_over_time", False, 300_000),  # packed prefix-sum family
 ])
@@ -91,6 +90,43 @@ def test_batched_equals_unbatched_bit_for_bit(func, regular, window_ms,
     snap = backend.batcher.stats.snapshot()
     assert snap["queries"] >= 24
     assert snap["occupancy_max"] >= 1
+
+
+@pytest.mark.parametrize("func,regular,func_args", [
+    ("rate", True, ()),                 # aligned counter family
+    ("avg_over_time", True, ()),        # aligned general evaluator
+    ("avg_over_time", True, (0.5,)),    # aligned, never joins a batch
+    ("rate", False, ()),                # packed endpoint family
+    ("max_over_time", False, ()),       # packed gather family
+], ids=["aligned-counter", "aligned-gauge", "aligned-func-args",
+        "packed-rate", "packed-gather"])
+def test_lone_paths_are_one_path(func, regular, func_args):
+    """No batcher, a disabled batcher and an enabled batcher with one
+    client run the same body: a batch of one. Equal bytes, one
+    ``device-dispatch``, and the same ``device-sync`` spans (a packed
+    batch of one syncs on the dispatching thread, inside
+    ``device-dispatch``; handing the host array out is a second, empty
+    span, as it is under the batcher)."""
+    from filodb_tpu.obs import trace as obs_trace
+
+    series = _series(regular=regular)
+    seen = []
+    for batcher in (None, MicroBatcher(enabled=False), MicroBatcher()):
+        backend = TpuBackend(batcher=batcher)
+        trace = obs_trace.Trace()
+        with obs_trace.activate(trace):
+            got = backend.periodic_samples(series, _params(0), func,
+                                           300_000, func_args)
+        names = [sp.name for sp in trace.spans]
+        dispatch = [sp for sp in trace.spans
+                    if sp.name == "device-dispatch"]
+        assert len(dispatch) == 1
+        assert dispatch[0].tags["batch"] == 1
+        assert dispatch[0].tags["path"] == \
+            ("aligned" if regular else "packed")
+        seen.append((got.values.tobytes(), names.count("device-sync")))
+    assert seen[0] == seen[1] == seen[2]
+    assert seen[0][1] == (1 if regular else 2)
 
 
 def test_batched_queries_actually_batch():

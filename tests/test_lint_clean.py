@@ -118,9 +118,9 @@ def test_every_pallas_call_site_has_contract():
               "filodb_tpu.parallel.mesh"):
         importlib.import_module(m)
     names = {k[1] for k in CONTRACTS}
-    # the two real pallas_call wrappers + their dispatchers
-    assert {"counter_groupsum", "window_extract", "groupsum_dispatch",
-            "counters_t_dispatch", "pallas_rate"} <= names
+    # the one pallas_call wrapper, its dispatcher and the counters'
+    assert {"counter_groupsum", "groupsum_dispatch",
+            "counters_t_dispatch"} <= names
     # kernel entry points across the named modules
     assert {"window_endpoint", "window_gather", "downsample_gauge",
             "downsample_regular", "counter_emit_mask", "cascade_aligned",

@@ -353,7 +353,7 @@ def test_backend_mesh_batch_run_parity():
     members = []
     for k in range(3):
         s = steps + k * STEP
-        members.append((int(s[0]) - W, int(s[0]), s, tiles))
+        members.append((int(s[0]) - W, int(s[0]), s, tiles, ()))
     res = be._aligned_run(tiles, "rate", fam, steps.size, STEP, W, 0,
                           st, members)
     for k in range(3):

@@ -21,10 +21,10 @@ from filodb_tpu.standalone.supervisor import split_quota, worker_config
 # -- launchers stay off JAX: a parent that touched it would hold the chip --
 
 @pytest.mark.parametrize("module", [
-    "filodb_tpu.standalone.supervisor", "chip_smoke", "bench_e2e"])
+    "filodb_tpu.standalone.supervisor", "chip_smoke"])
 def test_launcher_import_does_not_import_jax(module):
-    """The supervisor, the chip smoke and the e2e bench start node
-    processes that need the chip; importing them must leave JAX alone."""
+    """The supervisor and the chip smoke start node processes that need
+    the chip; importing them must leave JAX alone."""
     import pathlib
     import subprocess
     import sys

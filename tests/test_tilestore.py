@@ -128,9 +128,9 @@ def test_irregular_series_fall_back():
 
 
 @pytest.mark.parametrize("func", ["rate", "increase", "delta"])
-def test_irregular_rate_family_via_pallas(func):
-    """Irregular series route to the Pallas boundary-extract kernel
-    (interpret mode on CPU) and must match the oracle."""
+def test_irregular_rate_family_takes_packed_path(func):
+    """Irregular series build no aligned tiles: the packed endpoint
+    evaluator serves them and must match the oracle."""
     rng = np.random.default_rng(11)
     series = []
     for i in range(3):

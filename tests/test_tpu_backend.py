@@ -58,6 +58,32 @@ def test_device_matches_oracle(func):
                                err_msg=f"mismatch for {func}")
 
 
+@pytest.mark.parametrize("func", ["rate", "increase", "delta"])
+def test_packed_rate_one_program_per_step_bucket(func):
+    """A lone irregular-cadence query pads its step count to a power of
+    two like a batched one: 17, 23 and 31 steps are ONE program of the
+    packed executable table (one miss, two hits), each right against
+    the oracle."""
+    from filodb_tpu.query.engine import periodic_samples
+    series = make_series(counter=func != "delta", with_nans=True,
+                         irregular=True)
+    backend = TpuBackend()
+    before = backend.executable_cache_stats()
+    for nsteps in (17, 23, 31):
+        # 30 s steps: each grid clips to 64 < n <= 128 samples a series,
+        # one sample bucket, so only the step bucket is in question
+        params = RangeParams(PARAMS.start_ms, 30_000,
+                             PARAMS.start_ms + (nsteps - 1) * 30_000)
+        got = backend.periodic_samples(series, params, func, WINDOW)
+        oracle = periodic_samples(series, params, func, WINDOW)
+        assert got.values.shape == (len(series), nsteps)
+        np.testing.assert_allclose(got.values, oracle.values, rtol=1e-9,
+                                   atol=1e-9, equal_nan=True)
+    after = backend.executable_cache_stats()
+    assert after["misses"] - before["misses"] == 1
+    assert after["hits"] - before["hits"] == 2
+
+
 def test_pack_series_drops_nans():
     series = make_series(n_series=2, with_nans=True)
     ts, vals, lens = pack_series(series)
