@@ -13,7 +13,8 @@ from __future__ import annotations
 
 import re
 from dataclasses import dataclass
-from typing import Dict, Iterable, List, Mapping, Optional, Sequence, Set
+from typing import (Dict, Iterable, List, Mapping, Optional, Sequence, Set,
+                    Tuple)
 
 import numpy as np
 
@@ -21,6 +22,8 @@ from filodb_tpu.lint.locks import single_writer
 
 # sentinel for "still ingesting" (PartKeyLuceneIndex endTime semantics)
 END_TIME_INGESTING = (1 << 62)
+# the cover of a match no lifetime bounds (``TagIndex.part_ids_and_cover``)
+EVERY_RANGE = (-END_TIME_INGESTING, END_TIME_INGESTING)
 
 
 @dataclass(frozen=True)
@@ -168,21 +171,38 @@ class TagIndex:
                               start_time: int, end_time: int) -> List[int]:
         """Series matching all filters whose [start,end] lifetime overlaps the
         query range (partIdsFromFilters, PartKeyLuceneIndex.scala:993ff)."""
+        return self.part_ids_and_cover(filters, start_time, end_time)[0]
+
+    def part_ids_and_cover(self, filters: Sequence[ColumnFilter],
+                           start_time: int, end_time: int
+                           ) -> Tuple[List[int], Optional[Tuple[int, int]]]:
+        """``part_ids_from_filters`` and the ranges it holds for: ``(latest
+        start, earliest end)`` over the lifetimes of the series the filters
+        match. While the index stands, a range that begins at or before the
+        second and ends at or after the first gets the same ids. ``None``
+        where this range left a series out by its lifetime: then the ids
+        are this range's alone."""
         if filters:
             ids: Optional[Set[int]] = None
             for f in filters:
                 got = self._ids_for_filter(f)
                 ids = got if ids is None else (ids & got)
                 if not ids:
-                    return []
+                    return [], EVERY_RANGE
         else:
             ids = set(self._all)
+        start, end = self._start, self._end
         out = [
             pid for pid in ids
-            if self._start[pid] <= end_time and self._end[pid] >= start_time
+            if start[pid] <= end_time and end[pid] >= start_time
         ]
         out.sort()
-        return out
+        if len(out) < len(ids):
+            return out, None
+        if not out:
+            return out, EVERY_RANGE
+        return out, (max(map(start.__getitem__, out)),
+                     min(map(end.__getitem__, out)))
 
     def label_values(self, label: str,
                      filters: Sequence[ColumnFilter] = (),

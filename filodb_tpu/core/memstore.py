@@ -28,6 +28,7 @@ Key departures from the JVM design, chosen for the TPU execution model:
 
 from __future__ import annotations
 
+import itertools
 import threading
 from bisect import bisect_left, bisect_right
 from dataclasses import dataclass, field
@@ -46,6 +47,14 @@ from filodb_tpu.obs import trace as obs_trace
 from filodb_tpu.memory import vectors as bv
 
 DEFAULT_MAX_CHUNK_ROWS = 400  # store config max-chunks-size (IngestionConfig)
+
+
+# ``TimeSeriesShard.version`` draws from here: a number is handed out once
+# in the process (``next`` of a C iterator is one step under the GIL), so
+# a version read twice and found equal means no change finished in
+# between, whichever threads were bumping and whichever shard object sits
+# under the same dataset and number.
+_STORE_VERSIONS = itertools.count(1)
 
 
 def chunk_id(start_ts: int, seq: int) -> int:
@@ -440,6 +449,19 @@ class TimeSeriesPartition:
             return (self._chunk_epoch, len(chunks), chunk_len,
                     chunk_len + tail, tail_first, last, in_range)
 
+    def timestamp_parts(self, col_index: int
+                        ) -> Tuple[int, List[np.ndarray]]:
+        """``(epoch, arrays)``: the timestamps ``read_full_at`` would
+        give, as the pieces they are held in (the decoded chunks, then the
+        write buffer's segments), from one acquisition of the cache lock
+        and without joining them: a caller that walks a whole selection
+        joins once. While ``epoch`` stands, the first ``n_rows`` of them
+        are the rows ``select_facts`` counted."""
+        with self._cache_lock:
+            cts = self._decoded_chunk_arrays_locked(
+                col_index, self.schema.columns[col_index])[1][0]
+            return self._chunk_epoch, [cts] + self._ts_buf
+
     def read_full(self, col_index: int
                   ) -> Tuple[np.ndarray, np.ndarray, int]:
         """All samples of one data column: published chunks (cached decode)
@@ -661,6 +683,19 @@ class TimeSeriesShard:
         # threads; page-in rebinds part.chunks — everything else on the
         # read path sees immutable snapshots and needs no lock)
         self._odp_lock = threading.Lock()
+        # what a selection memo (query/engine.py) compares: see _changed
+        self.version = next(_STORE_VERSIONS)
+
+    @publishes("store-version")
+    def _changed(self) -> None:
+        """Every change a selection could see ends here: rows ingested,
+        a partition created, a buffer switched or a group flushed, a
+        partition evicted, paged in or bootstrapped, part keys removed.
+        Called AFTER the change is visible and BEFORE its caller returns
+        (so before a write is acknowledged): facts taken between two equal
+        readings of ``version`` hold every acknowledged write. Dropping a
+        decode cache changes no fact and does not come here."""
+        self.version = next(_STORE_VERSIONS)
 
     def update_integrity(self, stream_quarantined: int,
                          max_allowed: int) -> bool:
@@ -727,6 +762,7 @@ class TimeSeriesShard:
         self._by_part_key[kb] = pid
         self.index.add_part_key(pid, part_key.label_map, first_ts)
         self.stats.num_series = len(self.partitions)
+        self._changed()
         return part
 
     # the watermark/backfill-epoch mutation publishers: pull events —
@@ -742,7 +778,10 @@ class TimeSeriesShard:
         emit per-series bursts), so the per-partition hot path is one
         batched buffer extension instead of a per-row Python loop."""
         with obs_trace.span("shard-ingest"):
-            return self._ingest(container, offset)
+            try:
+                return self._ingest(container, offset)
+            finally:
+                self._changed()
 
     def _ingest(self, container: RecordContainer, offset: int) -> int:
         n = 0
@@ -819,7 +858,10 @@ class TimeSeriesShard:
         writeCheckpoint).  Returns chunks written. The ``flush`` stage
         span observes ``filodb_flush_seconds``."""
         with obs_trace.span("flush", group=group):
-            return self._flush_group(group, offset)
+            try:
+                return self._flush_group(group, offset)
+            finally:
+                self._changed()
 
     def _flush_group(self, group: int, offset: int) -> int:
         n = 0
@@ -922,6 +964,7 @@ class TimeSeriesShard:
         self.stats.partitions_bootstrapped += n
         # shells joined the min-set via their persisted end times
         self.ingest_watermark_ms = self._compute_watermark()
+        self._changed()
         return n
 
     def _ensure_loaded(self, part: TimeSeriesPartition) -> None:
@@ -963,14 +1006,20 @@ class TimeSeriesShard:
                         break
             part.odp_pending = False
             self.stats.partitions_paged_in += 1
+            self._changed()
 
     # -- read path --------------------------------------------------------
     def lookup_partitions(self, filters: Sequence[ColumnFilter],
-                          start_ts: int, end_ts: int
+                          start_ts: int, end_ts: int, cover_to=None
                           ) -> List[TimeSeriesPartition]:
         """(memstore lookupPartitions via the tag index; pages in evicted
-        partitions read-through like OnDemandPagingShard)."""
-        pids = self.index.part_ids_from_filters(filters, start_ts, end_ts)
+        partitions read-through like OnDemandPagingShard). ``cover_to`` is
+        called with the ranges the match holds for
+        (``TagIndex.part_ids_and_cover``)."""
+        pids, cover = self.index.part_ids_and_cover(filters, start_ts,
+                                                    end_ts)
+        if cover_to is not None:
+            cover_to(cover)
         out = []
         for p in pids:
             part = self.partitions[p]
@@ -1061,6 +1110,12 @@ class TimeSeriesShard:
         chunks are written out first, memory is released, the index entry
         stays so queries can page the data back. Without one, the series is
         dropped entirely (memory-only deployments)."""
+        try:
+            return self._evict_partitions(cutoff_ts)
+        finally:
+            self._changed()
+
+    def _evict_partitions(self, cutoff_ts: int) -> int:
         evict = [
             pid for pid, p in self.partitions.items()
             if (p.last_timestamp is not None and p.last_timestamp < cutoff_ts
@@ -1182,7 +1237,9 @@ class TimeSeriesMemStore:
         its copy when the original owner returns — ShardManager.scala
         stopShards semantics)."""
         with self._shards_lock:
-            self._shards.get(ref, {}).pop(shard_num, None)
+            shard = self._shards.get(ref, {}).pop(shard_num, None)
+        if shard is not None:
+            shard._changed()    # no memo of it is served again
 
     def shards(self, ref: DatasetRef) -> List[TimeSeriesShard]:
         return [s for _, s in sorted(self._shards.get(ref, {}).items())]

@@ -1938,6 +1938,10 @@ class FiloHttpServer:
             "Series handles handed out by whole-series selections",
         "filodb_select_series_read_total":
             "Series handles whose samples a consumer then read",
+        "filodb_select_memo_hits_total":
+            "Whole-series selections answered by the selection memo",
+        "filodb_select_memo_misses_total":
+            "Whole-series selections over local shards that ran the loop",
         "filodb_exec_cache_hits_total": "Compiled-executable reuse hits",
         "filodb_exec_cache_misses_total": "Compiled-executable retraces",
         "filodb_exec_cache_entries": "Distinct compiled kernel shapes",
@@ -2193,6 +2197,8 @@ class FiloHttpServer:
                          {"class": cls}, n)
         emit("select_series_total", {}, select_counts.handles)
         emit("select_series_read_total", {}, select_counts.reads)
+        emit("select_memo_hits_total", {}, select_counts.memo_hits)
+        emit("select_memo_misses_total", {}, select_counts.memo_misses)
         pc = self.plan_cache.snapshot()
         emit("plan_cache_entries", {}, pc["entries"])
         emit("plan_cache_hits_total", {}, pc["hits"])

@@ -15,13 +15,16 @@ from __future__ import annotations
 
 import math
 import re
+import threading
+from collections import OrderedDict
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
-from filodb_tpu.core.index import ColumnFilter
+from filodb_tpu.core.index import EVERY_RANGE, ColumnFilter
 from filodb_tpu.core.memstore import TimeSeriesShard
 from filodb_tpu.core.schemas import ColumnType
+from filodb_tpu.lint.caches import cache_registry, event_source
 from filodb_tpu.memory import histogram as bh
 from filodb_tpu.obs import trace as obs_trace
 from filodb_tpu.memory.vectors import counter_correction
@@ -74,6 +77,16 @@ def select_raw_series(shards: Sequence[TimeSeriesShard],
 
 def _select_raw_series(shards, filters, start_ms, end_ms, column, stats,
                        full, limits, deadline) -> List[RawSeries]:
+    entry = select_memo.begin(shards, filters, column) if full else None
+    if entry is not None:
+        hit = select_memo.lookup(entry, start_ms, end_ms, stats, limits)
+        if hit is not None:
+            if deadline is not None:
+                deadline.check("raw series selection")
+            select_counts.memo_hits += 1
+            select_counts.handles += len(hit)
+            return hit
+        select_counts.memo_misses += 1
     out: List[RawSeries] = []
     cols: Dict[int, Tuple] = {}     # id(schema) -> (index, column, hist?)
     handles = 0
@@ -116,7 +129,9 @@ def _select_raw_series(shards, filters, start_ms, end_ms, column, stats,
                         limits.check(stats)
             out.extend(got)
             continue
-        for part in shard.lookup_partitions(filters, start_ms, end_ms):
+        for part in shard.lookup_partitions(
+                filters, start_ms, end_ms,
+                entry.covers if entry is not None else None):
             schema = part.schema
             got = cols.get(id(schema))
             if got is None:
@@ -126,7 +141,8 @@ def _select_raw_series(shards, filters, start_ms, end_ms, column, stats,
                 if is_hist and part._hist_scheme is not None else None
             if full:
                 s, in_range = _partition_handle(shard, part, ci, col, les,
-                                                is_hist, start_ms, end_ms)
+                                                is_hist, start_ms, end_ms,
+                                                entry)
                 handles += 1
             else:
                 ts, vals = part.read_range(start_ms, end_ms, ci)
@@ -140,6 +156,8 @@ def _select_raw_series(shards, filters, start_ms, end_ms, column, stats,
                 if limits is not None:
                     limits.check(stats)     # abort before selecting more
     select_counts.handles += handles
+    if entry is not None:
+        return select_memo.store(entry, out)
     return out
 
 
@@ -156,50 +174,69 @@ def _resolve_column(schema, column: Optional[str]):
 class _SelectCounts:
     """``filodb_select_series_total`` / ``_read_total``: handles a
     ``full=True`` selection handed out, and handles whose samples some
-    consumer then read. Plain adds, like the backend's counters."""
+    consumer then read; ``filodb_select_memo_{hits,misses}_total``: such
+    selections over local shards that the memo answered, and that ran the
+    loop. Plain adds, like the backend's counters."""
 
-    __slots__ = ("handles", "reads")
+    __slots__ = ("handles", "reads", "memo_hits", "memo_misses")
 
     def __init__(self):
         self.handles = 0
         self.reads = 0
+        self.memo_hits = 0
+        self.memo_misses = 0
 
 
 select_counts = _SelectCounts()
 
 
 def _partition_handle(shard, part, ci: int, col, les, is_hist: bool,
-                      start_ms: int, end_ms: int) -> Tuple[RawSeries, int]:
+                      start_ms: int, end_ms: int,
+                      entry: "Optional[_MemoEntry]") -> Tuple[RawSeries, int]:
     """One partition of a ``full=True`` selection as a handle (see
     ``RawSeries``), and its rows in [start_ms, end_ms]."""
     (epoch, n_chunks, chunk_len, n_rows, tail_first, last,
      in_range) = part.select_facts(ci, start_ms, end_ms)
+    read = _PartitionRead(shard, part, ci, epoch, n_rows,
+                          is_hist and col.is_counter_like, entry)
+    if entry is not None:
+        entry.reads.append(read)
+        entry.rows += n_rows
     s = RawSeries.handle(
         part.part_key.shared_labels, col.is_counter_like, is_hist, les,
         (shard.ref.dataset, shard.shard_num, part.part_id, n_chunks, ci),
-        chunk_len, tail_first, last,
-        _PartitionRead(shard, part, ci, epoch, n_rows,
-                       is_hist and col.is_counter_like))
+        chunk_len, tail_first, last, read)
     return s, in_range
+
+
+# one handle is filled once, whichever holders of a memoised selection
+# touch it first and however many at a time
+_FILL_LOCK = threading.Lock()
 
 
 class _PartitionRead:
     """The deferred half of a handle: called at the first touch of its
-    samples, inside whatever stage the consumer runs under."""
+    samples, inside whatever stage the consumer runs under. ``entry`` is
+    the memo entry the handle may be shared through: a handle that holds
+    samples is its reader's, so the first read ends the sharing."""
 
-    __slots__ = ("shard", "part", "ci", "epoch", "n_rows", "drops")
+    __slots__ = ("shard", "part", "ci", "epoch", "n_rows", "drops", "entry")
 
-    def __init__(self, shard, part, ci, epoch, n_rows, drops):
+    def __init__(self, shard, part, ci, epoch, n_rows, drops, entry):
         self.shard = shard
         self.part = part
         self.ci = ci
         self.epoch = epoch
         self.n_rows = n_rows
         self.drops = drops
+        self.entry = entry
 
     def __call__(self, s: RawSeries) -> None:
+        if self.entry is not None and self.entry.held is not None:
+            select_memo.drop(self.entry)
         part, ci = self.part, self.ci
         ts, vals, chunk_len, n_chunks, epoch = part.read_full_at(ci)
+        key = None
         if epoch == self.epoch:
             # only appended to since: the rows the facts describe come
             # first, and those are the series
@@ -212,16 +249,254 @@ class _PartitionRead:
                 self.shard._ensure_loaded(part)
                 ts, vals, chunk_len, n_chunks, epoch = part.read_full_at(ci)
             key = s.snapshot_key
-            s.snapshot_key = key[:3] + (n_chunks,) + key[4:]
-            s.chunk_len = chunk_len
+            key = key[:3] + (n_chunks,) + key[4:]
         drops = None
         if self.drops:
             # taken after the snapshot: rows appended in between may
             # carry drop indices beyond ts.size
             drops = part.hist_drop_rows(ci)
             drops = drops[drops < ts.size]
-        s.fill(ts, vals, drops)
+        with _FILL_LOCK:
+            if s.filled:
+                return          # another holder's read came first
+            if key is not None:
+                s.snapshot_key = key
+                s.chunk_len = chunk_len
+            s.fill(ts, vals, drops)
         select_counts.reads += 1
+
+
+# ---------------------------------------------------------------------------
+# The selection memo: a selection the store has not changed under is
+# selected once
+# ---------------------------------------------------------------------------
+
+_MEMO_ENTRIES = 16              # the tile cache's count (tpu._TILE_CACHE_MAX)
+_MEMO_MAX_ROWS = 1 << 22        # rows one entry may count: 16 MiB of offsets
+_MEMO_MAX_GROUPINGS = 8         # (by, without) sets kept with one entry
+
+
+class Selection(list):
+    """The handles of a ``full=True`` selection over local shards, with the
+    memo entry they are shared through (None: shared with nobody). A list
+    of its holder's own; the handles are every holder's until one is
+    read."""
+
+    __slots__ = ("entry",)
+
+    def __init__(self, series, entry):
+        super().__init__(series)
+        self.entry = entry
+
+
+@event_source("store-version")
+def _store_versions(shards) -> Tuple[int, ...]:
+    """``TimeSeriesShard.version`` of each shard, as it reads now."""
+    return tuple([s.version for s in shards])
+
+
+class _MemoEntry:
+    """What one selection learned that the next one for the same (shards,
+    filters, column) would learn again, while no shard's version moves:
+    the handles in selection order, the ranges the index match holds for,
+    the group ids per (by, without), and, built at the first reuse, every
+    timestamp of the selection in one sorted array, so the rows of any
+    [start_ms, end_ms] are two searches and no loop over partitions.
+    Facts only: it is dropped at the first read of one of its handles."""
+
+    __slots__ = ("key", "shards", "versions", "held", "reads", "rows",
+                 "lo", "hi", "base", "span", "offsets", "groups")
+
+    def __init__(self, key, shards, versions):
+        self.key = key
+        self.shards = shards
+        self.versions = versions        # read BEFORE the selection ran
+        # (handles, their deferred reads) while the entry may be served:
+        # one attribute, so a holder sees both or neither
+        self.held: Optional[Tuple[List[RawSeries],
+                                  List[_PartitionRead]]] = None
+        self.reads: List[_PartitionRead] = []   # filled as the loop runs
+        self.rows = 0
+        # the index match is every range's that starts at or before ``hi``
+        # and ends at or after ``lo`` (TagIndex.part_ids_and_cover); lo
+        # None: some shard's match was this range's alone
+        self.lo: Optional[int]
+        self.lo, self.hi = EVERY_RANGE
+        self.base = self.span = 0
+        self.offsets: Optional[np.ndarray] = None
+        self.groups: Dict[Tuple, Tuple] = {}
+
+    def covers(self, cover: Optional[Tuple[int, int]]) -> None:
+        """Fold one shard's cover in."""
+        if cover is None or self.lo is None:
+            self.lo = None
+        else:
+            self.lo = max(self.lo, cover[0])
+            self.hi = min(self.hi, cover[1])
+
+    def holds_for(self, start_ms: int, end_ms: int) -> bool:
+        return self.lo is not None and self.lo <= end_ms \
+            and self.hi >= start_ms
+
+    def clear(self) -> None:
+        """Out of the memo: nothing of the store stays reachable from it
+        (its handles live on in the lists already handed out)."""
+        self.held = None
+        self.reads = []
+        self.offsets = None
+        self.groups = {}
+
+    def rows_between(self, reads: List[_PartitionRead], start_ms: int,
+                     end_ms: int) -> Optional[int]:
+        """Rows of the selection with start_ms <= t <= end_ms, as the loop
+        over ``select_facts`` adds them up; None where the timestamps can
+        no longer be taken as the handles' facts describe them."""
+        offsets = self.offsets
+        if offsets is None:
+            offsets = self._sort_timestamps(reads)
+            if offsets is None:
+                return None
+        lo, hi = start_ms - self.base, end_ms - self.base
+        if hi < 0 or lo > self.span or not offsets.size:
+            return 0
+        kind = offsets.dtype.type       # a needle of the array's own type
+        first = 0 if lo <= 0 else int(offsets.searchsorted(kind(lo), "left"))
+        if hi >= self.span:
+            return offsets.size - first
+        return int(offsets.searchsorted(kind(hi), "right")) - first
+
+    def _sort_timestamps(self, reads) -> Optional[np.ndarray]:
+        pieces: List[np.ndarray] = []
+        for read in reads:
+            epoch, segs = read.part.timestamp_parts(read.ci)
+            if epoch != read.epoch:
+                return None         # evicted or paged in since
+            left = read.n_rows
+            for seg in segs:
+                if not left:
+                    break
+                if seg.size > left:
+                    seg = seg[:left]
+                pieces.append(seg)
+                left -= seg.size
+        if not pieces:
+            self.offsets = np.zeros(0, dtype=np.int64)
+            return self.offsets
+        ts = np.concatenate(pieces)         # once a selection, not a series
+        ts.sort()
+        self.base, self.span = int(ts[0]), int(ts[-1] - ts[0])
+        ts -= self.base
+        # 49 days of milliseconds fit in 32 bits: half the bytes held
+        self.offsets = ts.astype(np.uint32) if self.span < (1 << 32) - 1 \
+            else ts
+        return self.offsets
+
+
+@cache_registry("select-memo", keyed=("shards", "filters", "column"),
+                validated_by={"store-version": ("begin", "store")})
+class _SelectMemo:
+    """The memo of ``select_raw_series(.., full=True)`` over local shards:
+    at most ``_MEMO_ENTRIES`` entries, least recently used out first. An
+    entry is served while every shard's version reads as it did before the
+    entry's selection ran (core/memstore.py ``_changed``: the version moves
+    after a change is visible and before it is acknowledged), the index
+    match holds for the asked range, and nobody has read a sample through
+    its handles."""
+
+    def __init__(self):
+        self._entries: "OrderedDict[Tuple, _MemoEntry]" = OrderedDict()
+        self._lock = threading.Lock()
+
+    def __len__(self) -> int:
+        return len(self._entries)
+
+    def begin(self, shards, filters, column) -> Optional[_MemoEntry]:
+        """A blank entry for the selection about to run, carrying the
+        versions as they read BEFORE it; None where the memo does not
+        apply (a shard is remote; a filter off the JSON wire holds its
+        ``in`` values as a list, which is no key)."""
+        for shard in shards:
+            if hasattr(shard, "fetch_raw"):
+                return None
+        key = (tuple(map(id, shards)), tuple(filters), column)
+        try:
+            hash(key)
+        except TypeError:
+            return None
+        return _MemoEntry(key, tuple(shards), _store_versions(shards))
+
+    def lookup(self, new: _MemoEntry, start_ms: int, end_ms: int, stats,
+               limits) -> Optional[Selection]:
+        """The stored selection under ``new``'s key, with ``stats`` counted
+        as the loop counts them; None: run the loop."""
+        with self._lock:
+            entry = self._entries.get(new.key)
+            if entry is not None:
+                self._entries.move_to_end(new.key)
+        if entry is None:
+            return None
+        if entry.versions != new.versions:
+            self.drop(entry)
+            return None
+        held = entry.held
+        if held is None or not entry.holds_for(start_ms, end_ms):
+            return None
+        series, reads = held
+        rows = entry.rows_between(reads, start_ms, end_ms)
+        if rows is None:
+            self.drop(entry)
+            return None
+        if stats is not None:
+            n_series = stats.series_scanned + len(series)
+            n_rows = stats.samples_scanned + rows
+            if limits is not None and limits.refuses(n_series, n_rows):
+                return None     # the loop refuses it, at the count it reached
+            stats.series_scanned = n_series
+            stats.samples_scanned = n_rows
+        return Selection(series, entry)
+
+    def store(self, entry: _MemoEntry, series: List[RawSeries]
+              ) -> List[RawSeries]:
+        """The selection that just ran, kept if it may be served again;
+        what the caller gets in either case."""
+        if entry.lo is None or entry.rows > _MEMO_MAX_ROWS \
+                or _store_versions(entry.shards) != entry.versions:
+            entry.clear()       # a change overlapped it, or it is too much
+            return series
+        entry.held = (series, entry.reads)
+        with self._lock:
+            # (two selections that both missed: the later takes the place)
+            out = [self._entries.pop(entry.key, None)]
+            self._entries[entry.key] = entry
+            for key, old in list(self._entries.items()):
+                # an entry over a shard that has since moved (or left the
+                # store) would never be served: it goes now, and at the
+                # latest the oldest does
+                if old is not entry and (
+                        len(self._entries) > _MEMO_ENTRIES
+                        or _store_versions(old.shards) != old.versions):
+                    del self._entries[key]
+                    out.append(old)
+        for old in out:
+            if old is not None:
+                old.clear()
+        return Selection(series, entry)
+
+    def drop(self, entry: _MemoEntry) -> None:
+        with self._lock:
+            if self._entries.get(entry.key) is entry:
+                del self._entries[entry.key]
+        entry.clear()
+
+    def clear(self) -> None:
+        with self._lock:
+            out = list(self._entries.values())
+            self._entries.clear()
+        for entry in out:
+            entry.clear()
+
+
+select_memo = _SelectMemo()
 
 
 def select_span_series(shards: Sequence[TimeSeriesShard],
@@ -414,6 +689,24 @@ def _group_keys(keys: Sequence[Mapping[str, str]], by: Tuple[str, ...],
             gkeys.append(gk)
         gids.append(gid)
     return np.array(gids, dtype=np.int64), gkeys
+
+
+def _selection_groups(series: Sequence[RawSeries], by: Tuple[str, ...],
+                      without: Tuple[str, ...]):
+    """``_group_keys`` of a selection's labels, worked out once for every
+    holder of a memoised selection: the ids are shared (and frozen), the
+    keys handed out as copies, since a caller may change them."""
+    entry = getattr(series, "entry", None)
+    if entry is None:
+        return _group_keys([s.labels for s in series], by, without)
+    got = entry.groups.get((by, without))
+    if got is None:
+        got = _group_keys([s.labels for s in series], by, without)
+        got[0].setflags(write=False)
+        if len(entry.groups) >= _MEMO_MAX_GROUPINGS:
+            entry.groups.clear()
+        entry.groups[(by, without)] = got
+    return got[0], [dict(k) for k in got[1]]
 
 
 def aggregate(grid: GridResult, op: str, params: Tuple = (),
@@ -1173,9 +1466,8 @@ class QueryEngine:
         res = None
         if series and not any(s.is_hist for s in series):
             with obs_trace.span("group-keys"):
-                gids, gkeys = _group_keys([s.labels for s in series],
-                                          tuple(plan.by),
-                                          tuple(plan.without))
+                gids, gkeys = _selection_groups(series, tuple(plan.by),
+                                                tuple(plan.without))
             res = self.backend.fused_groupsum(
                 series, inner.function, params.steps, inner.window_ms,
                 inner.offset_ms, gids, len(gkeys))

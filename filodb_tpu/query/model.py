@@ -59,8 +59,10 @@ class RawSeries:
     ``hist_drop_rows`` are read from the partition the first time one of
     them is touched, as exactly the rows the selection saw. A consumer that
     answers from the facts (the fused group-sum on a tile hit) never pays
-    for the samples. A handle belongs to the thread that selected it: read
-    it before handing it to another."""
+    for the samples. An unread handle may be shared: the selection memo
+    (query/engine.py) hands the same handles to every query the store has
+    not changed under, until the first read of one ends that; whoever
+    holds it then still reads those rows, once."""
 
     __slots__ = ("labels", "is_counter", "is_hist", "bucket_les",
                  "snapshot_key", "chunk_len", "_ts", "_values", "_drops",
@@ -113,37 +115,52 @@ class RawSeries:
         self._tail = None
         self._read = None
 
+    # A handle of a memoised selection (query/engine.py) has several
+    # holders, and another's read may land between any two lines here: each
+    # property takes ``_read`` / ``_tail`` once, and ``fill`` sets the
+    # arrays before it clears either.
+    @property
+    def filled(self) -> bool:
+        """Does it hold its samples (always, unless a handle not yet
+        read)."""
+        return self._read is None
+
     @property
     def ts(self) -> np.ndarray:
-        if self._read is not None:
-            self._read(self)
+        read = self._read
+        if read is not None:
+            read(self)
         return self._ts
 
     @property
     def values(self) -> np.ndarray:
-        if self._read is not None:
-            self._read(self)
+        read = self._read
+        if read is not None:
+            read(self)
         return self._values
 
     @property
     def hist_drop_rows(self) -> Optional[np.ndarray]:
-        if self._read is not None:
-            self._read(self)
+        read = self._read
+        if read is not None:
+            read(self)
         return self._drops
 
     @property
     def tail_first_ts(self) -> Optional[int]:
         """Timestamp of the first row beyond the chunk prefix (None: the
         prefix is everything there is)."""
-        if self._tail is not None:
-            return self._tail[0]
+        tail = self._tail
+        if tail is not None:
+            return tail[0]
         ts, cl = self._ts, self.chunk_len
         return int(ts[cl]) if 0 <= cl < ts.size else None
 
     @property
     def last_ts(self) -> Optional[int]:
-        if self._tail is not None:
-            return self._tail[1]
+        tail = self._tail
+        if tail is not None:
+            return tail[1]
         return int(self._ts[-1]) if self._ts.size else None
 
 
@@ -311,6 +328,12 @@ class QueryLimits:
     (core/query/QueryContext PlannerParams enforcedLimits). 0 = off."""
     series_limit: int = 0
     sample_limit: int = 0
+
+    def refuses(self, series_scanned: int, samples_scanned: int) -> bool:
+        """Would ``check`` raise at these counts."""
+        return bool(
+            self.series_limit and series_scanned > self.series_limit
+            or self.sample_limit and samples_scanned > self.sample_limit)
 
     def check(self, stats: "QueryStats") -> None:
         if self.series_limit and stats.series_scanned > self.series_limit:
