@@ -44,6 +44,7 @@ from filodb_tpu.promql.parser import (TimeStepParams, parse_query,
                                       parse_query_range, selector_to_filters)
 from filodb_tpu.query import logical as lp
 from filodb_tpu.query import qos
+from filodb_tpu.query.batcher import transfer_counts
 from filodb_tpu.testing import chaos
 from filodb_tpu.query.engine import (QueryEngine,  # noqa: F401 (re-export)
                                      select_counts)
@@ -1934,6 +1935,18 @@ class FiloHttpServer:
             "Queries served by the fused group-sum kernel",
         "filodb_mesh_dispatches_total":
             "Dispatches served from the mesh-resident sharded store",
+        "filodb_fused_refused_total":
+            "Queries of the fused shape that the fused path refused",
+        "filodb_fused_refused_gaps_total":
+            "Fused refusals over tiles with holes (missed scrapes)",
+        "filodb_aligned_fast_evals_total":
+            "Counter queries served by the aligned f32-hybrid evaluator",
+        "filodb_aligned_slide_evals_total":
+            "Counter queries served by the aligned slide evaluator",
+        "filodb_aligned_exact_evals_total":
+            "Counter queries served by the aligned all-f64 evaluator",
+        "filodb_device_to_host_bytes_total":
+            "Bytes the device-sync stages brought to the host",
         "filodb_select_series_total":
             "Series handles handed out by whole-series selections",
         "filodb_select_series_read_total":
@@ -2176,6 +2189,18 @@ class FiloHttpServer:
                  getattr(self.backend, "fused_aggs", 0))
             emit("mesh_dispatches_total", {},
                  getattr(self.backend, "mesh_dispatches", 0))
+            # why the fused path was left, and which aligned family
+            # served counters instead (label-free: readers sum labels)
+            emit("fused_refused_total", {},
+                 getattr(self.backend, "fused_refused", 0))
+            emit("fused_refused_gaps_total", {},
+                 getattr(self.backend, "fused_refused_gaps", 0))
+            evals = getattr(self.backend, "aligned_evals", {})
+            emit("aligned_fast_evals_total", {}, evals.get("fast", 0))
+            emit("aligned_slide_evals_total", {}, evals.get("slide", 0))
+            emit("aligned_exact_evals_total", {}, evals.get("t", 0))
+            emit("device_to_host_bytes_total", {},
+                 transfer_counts.d2h_bytes)
             # serving fast path: compiled-executable reuse (shape
             # buckets) + micro-batcher occupancy
             exec_stats = getattr(self.backend, "executable_cache_stats",

@@ -136,6 +136,20 @@ class DeviceExecutor:
             self._q.put((1 << 30, next(self._seq), None))
 
 
+class _TransferCounts:
+    """``filodb_device_to_host_bytes_total``: bytes the ``device-sync``
+    stages brought to the host (here and at the backend's two syncs of
+    its own). Plain adds, like the backend's counters."""
+
+    __slots__ = ("d2h_bytes",)
+
+    def __init__(self):
+        self.d2h_bytes = 0
+
+
+transfer_counts = _TransferCounts()
+
+
 class SplitResult:
     """Stacked device output of one batch, split back per member.
 
@@ -159,6 +173,8 @@ class SplitResult:
                 # the one amortized sync point for the whole batch
                 # graftlint: disable=host-transfer-in-hot-loop,oversized-transfer (single per-batch sync for the whole batch; the device buffer is dropped right after, so no resident channel is being re-pulled)
                 self._host = np.asarray(self._stacked)
+                if self._host is not self._stacked:     # came off a device
+                    transfer_counts.d2h_bytes += self._host.nbytes
                 self._stacked = None
         if self._split is not None:
             return self._split(self._host, i)

@@ -1438,11 +1438,19 @@ class QueryEngine:
         exists (exec/AggrOverRangeVectors map-reduce, fused).
 
         None is returned only for plan SHAPES this path doesn't own;
-        once the series are selected, any kernel ineligibility
-        (irregular cadence, tail data, histograms, non-divisible grid)
-        falls back to rangefn + aggregate() over the SAME selection —
-        never a second fetch (remote shard groups pull raw series over
-        the wire) or double-counted stats."""
+        once the series are selected, any kernel ineligibility falls
+        back to rangefn + aggregate() over the SAME selection — never a
+        second fetch (remote shard groups pull raw series over the
+        wire) or double-counted stats. Histograms are not offered to
+        the backend. What the backend refuses counts in
+        ``filodb_fused_refused_total`` (``TpuBackend.fused_groupsum``
+        lists the reasons: no kernel on this backend, irregular
+        cadence, tail data, a non-divisible or not interior grid,
+        non-finite values, VMEM), and a selection refused for holes in
+        its tiles (one missed scrape in one series) in
+        ``filodb_fused_refused_gaps_total`` as well; the aligned family
+        that then serves it counts in
+        ``filodb_aligned_{fast,slide,exact}_evals_total``."""
         if self.backend is None or plan.op not in ("sum", "count", "avg"):
             return None
         if plan.params:

@@ -50,7 +50,8 @@ from filodb_tpu.obs import devprof
 from filodb_tpu.obs import trace as obs_trace
 from filodb_tpu.query import qos
 from filodb_tpu.query import tilestore as tst
-from filodb_tpu.query.batcher import MicroBatcher, SplitResult
+from filodb_tpu.query.batcher import (MicroBatcher, SplitResult,
+                                      transfer_counts)
 from filodb_tpu.query.cumsum import cumsum_f64
 from filodb_tpu.query.model import (GridResult, RangeParams, RawSeries,
                                     clip_series)
@@ -514,6 +515,13 @@ class TpuBackend:
         self.tile_builds = 0    # observability: device tile (re)builds
         self.tile_hits = 0      # observability: cache hits
         self.fused_aggs = 0     # observability: fused group-sum queries
+        # fused_groupsum calls that came back None, and those of them
+        # that the kernel gate refused over tiles with holes
+        self.fused_refused = 0
+        self.fused_refused_gaps = 0
+        # counter queries per aligned evaluator family
+        # (tst.counters_batch_family; a batch of B counts B)
+        self.aligned_evals = {"fast": 0, "slide": 0, "t": 0}
         if batcher == "default":
             batcher = MicroBatcher()
         self.batcher = batcher
@@ -685,7 +693,9 @@ class TpuBackend:
             # it (the executor's busy time is the gather window of the
             # next batch); device-sync is then a child of device-dispatch
             with obs_trace.span("device-sync"):
-                host = np.asarray(dev)[:m.ts.shape[0], :m.nsteps]
+                host = np.asarray(dev)
+                transfer_counts.d2h_bytes += host.nbytes
+                host = host[:m.ts.shape[0], :m.nsteps]
             return SplitResult(host, 1, split=lambda h, i: h)
         offs = np.cumsum([0] + [m.ts.shape[0] for m in members])
         s_total = int(offs[-1])
@@ -1019,6 +1029,8 @@ class TpuBackend:
         counters = func in ("rate", "increase", "delta")
         if mesh_st is not None:
             self.mesh_dispatches += len(members)
+        if counters:
+            self.aligned_evals[family[0]] += len(members)
         if len(members) == 1:
             steps0, func_args = members[0][2], members[0][4]
             if counters:
@@ -1078,7 +1090,27 @@ class TpuBackend:
         the program is one cached executable of the tilestore table
         (or the mesh store's grouped collective). Returns (sums, cnts)
         as [T, G] numpy or None when ineligible (caller falls back to
-        the general rangefn + aggregate path)."""
+        the general rangefn + aggregate path).
+
+        Every None counts in ``filodb_fused_refused_total``. The
+        reasons, in the order they are looked at: not a counter
+        function, or nothing selected; a CPU node without the
+        interpreted kernel or a mesh; series that do not share one
+        cadence grid (no tiles); a window that reaches the write-buffer
+        tail (``_fused_covered``); and the kernel gate
+        (``tst.groupsum_counters``, or the mesh store's placement):
+        tiles with holes, which ``filodb_fused_refused_gaps_total``
+        counts apart, a grid that is irregular, not interior or not a
+        whole number of steps a window, non-finite values, or no
+        pipeline within VMEM."""
+        res = self._fused_groupsum(series, func, steps, window_ms,
+                                   offset_ms, gids, G)
+        if res is None:
+            self.fused_refused += 1
+        return res
+
+    def _fused_groupsum(self, series, func, steps, window_ms, offset_ms,
+                        gids, G):
         if func not in ("rate", "increase", "delta") or not len(series):
             return None
         on_cpu = jax.default_backend() == "cpu"
@@ -1109,6 +1141,8 @@ class TpuBackend:
                     tst.counters_batch_family(tiles, func, steps,
                                               window_ms, offset_ms))
         if mesh_st is None and on_cpu and not FUSED_GROUPSUM_INTERPRET:
+            # the mesh store places dense tiles only
+            self.fused_refused_gaps += not tiles._dense
             return None
         # the group ids in tile order, which both device paths take (the
         # stage's name is one the benchmark's dispatch_host_ms row reads)
@@ -1126,11 +1160,16 @@ class TpuBackend:
                     tiles, func, steps, window_ms, gvec, G, offset_ms,
                     interpret=on_cpu)
             if res is None:
+                # _slide_eligible is the gate's one predicate; that the
+                # tiles have holes is read beside it, not inside it
+                self.fused_refused_gaps += not tiles._dense
                 return None
             self.fused_aggs += 1
         with obs_trace.span("device-sync"):
             T = steps.size
-            return np.asarray(res[0])[:T], np.asarray(res[1])[:T]
+            sums, cnts = np.asarray(res[0]), np.asarray(res[1])
+            transfer_counts.d2h_bytes += sums.nbytes + cnts.nbytes
+            return sums[:T], cnts[:T]
 
     def _fused_covered(self, entry, series, steps: np.ndarray,
                        offset_ms: int) -> bool:

@@ -1,0 +1,207 @@
+"""The served ``sum by (job)`` of ``rate[5m]`` over a store with missed
+scrapes: a scrape that failed stored no sample, so a series has holes on
+the cadence grid, its tiles are not dense and the fused group-sum path
+refuses the whole selection (``tilestore._slide_eligible``). The aligned
+f32-hybrid family serves it instead, the ``[T, S]`` grid comes to the host
+and ``engine.aggregate`` groups it.
+
+Held here, through ``FiloServer``'s HTTP query path with the TPU backend
+against ``promql/refeval.py`` in float64: the answer over each kind of hole,
+and the route, as ``/metrics`` tells it. A small fleet of this file's own
+(2 apps x 4 jobs x 16 instances, 120 scrape ticks 10 s apart, every second
+series +-2 s off the tick, one counter reset), the same for every case but
+for the scrapes a case takes out.
+"""
+
+import json
+import math
+import urllib.parse
+import urllib.request
+
+import numpy as np
+import pytest
+
+from filodb_tpu.core.record import RecordBuilder
+from filodb_tpu.core.schemas import DEFAULT_SCHEMAS
+from filodb_tpu.gateway.producer import (TestTimeseriesProducer,
+                                         ingest_builders)
+from filodb_tpu.promql.refeval import RefSeries, ref_eval
+from filodb_tpu.query import engine as eng
+from filodb_tpu.standalone.server import FiloServer
+
+T0 = 1_600_000_000          # s; tick k is at T0 + 10 k
+TICKS = 120
+APPS, JOBS, INST = 2, 4, 16
+START, END, STEP = T0 + 360, T0 + 1080, 60    # 13 steps, windows inside
+QUERY = ('{op}(rate(http_requests_total{{_ws_="demo",_ns_="App-0"}}[5m]))'
+         ' by (job)')
+# the window of the step at T0 + 600 is [T0 + 300, T0 + 600]: ticks 30..60
+FIRST_SLOT, LAST_SLOT = 30, 60
+# the per-series rates leave the device in float32 (the f32-hybrid
+# evaluator's epilogue, ~3e-7 relative; the fused kernel's sums likewise)
+# and are summed per group in float64 on the host, which keeps the terms'
+# relative error: float32 epilogue over float64 group sums
+RTOL = 1e-6
+
+
+def _fleet(seed=20261003):
+    """[(labels, ts ms [TICKS], vals [TICKS])] of App-0 and App-1."""
+    rng = np.random.default_rng(seed)
+    out = []
+    for a in range(APPS):
+        for j in range(JOBS):
+            for i in range(INST):
+                n = len(out)
+                ts = (T0 + 10 * np.arange(TICKS, dtype=np.int64)) * 1000
+                if n % 2:
+                    ts = ts + rng.integers(-2000, 2001, TICKS)
+                vals = np.cumsum(rng.integers(0, 50, TICKS)).astype(float)
+                if n == 5:
+                    vals[70:] -= vals[69]           # a counter reset
+                out.append(({"_metric_": "http_requests_total",
+                             "_ws_": "demo", "_ns_": f"App-{a}",
+                             "job": f"job-{j}", "instance": f"i-{n:03d}"},
+                            ts, vals))
+    return out
+
+
+def _flaky(n):
+    return n % 4 == 3
+
+
+def _single_misses(n, rng):
+    return rng.choice(np.arange(2, TICKS - 2), 4, replace=False) \
+        if _flaky(n) else []
+
+
+def _run_of_four(n, rng):
+    k = int(rng.integers(2, TICKS - 6))
+    return range(k, k + 4) if _flaky(n) else []
+
+
+def _under_two(n, rng):
+    """Ticks 31..60 out: the window of T0 + 600 keeps one sample. One
+    instance of job-0 (its group's count drops there) and every instance
+    of job-1 (the group has no point there)."""
+    return range(31, 61) if n == 3 or INST <= n < 2 * INST else []
+
+
+# case -> (series number, rng) -> the ticks whose scrape failed
+CASES = {
+    "dense": lambda n, rng: [],
+    "single-misses": _single_misses,
+    "run-of-four": _run_of_four,
+    "hole-on-first-slot": lambda n, rng: [FIRST_SLOT] if _flaky(n) else [],
+    "hole-on-last-slot": lambda n, rng: [LAST_SLOT] if _flaky(n) else [],
+    "window-under-two-samples": _under_two,
+    "one-flaky-among-dense": lambda n, rng: [47] if n == 6 else [],
+}
+
+
+def _store(case):
+    """-> (server, [RefSeries]) with the case's scrapes taken out."""
+    srv = FiloServer({"num-shards": 2, "port": 0}).start()
+    producer = TestTimeseriesProducer(DEFAULT_SCHEMAS, num_shards=2)
+    rng = np.random.default_rng(7)
+    builders, ref = {}, []
+    for n, (labels, ts, vals) in enumerate(_fleet()):
+        keep = np.ones(TICKS, bool)
+        keep[list(CASES[case](n, rng))] = False
+        b = builders.setdefault(producer.shard_for("prom-counter", labels),
+                                RecordBuilder(DEFAULT_SCHEMAS))
+        for t, v in zip(ts[keep].tolist(), vals[keep].tolist()):
+            b.add_sample("prom-counter", labels, t, v)
+        ref.append(RefSeries(labels, ts[keep].tolist(), vals[keep].tolist()))
+    ingest_builders(srv.store, srv.ref, builders)
+    srv.store.flush_all(srv.ref)
+    return srv, ref
+
+
+def _metrics(srv):
+    with urllib.request.urlopen(f"http://127.0.0.1:{srv.port}/metrics",
+                                timeout=30) as r:
+        lines = r.read().decode().splitlines()
+    return {ln.rsplit(" ", 1)[0]: float(ln.rsplit(" ", 1)[1])
+            for ln in lines if ln.startswith("filodb_") and "{" not in ln}
+
+
+def _served(srv, op):
+    """-> {job: {step s: value}} as the node answers over HTTP."""
+    url = (f"http://127.0.0.1:{srv.port}/promql/timeseries/api/v1/"
+           "query_range?" + urllib.parse.urlencode(dict(
+               query=QUERY.format(op=op), start=START, end=END, step=STEP,
+               cache="false")))
+    body = json.loads(urllib.request.urlopen(url, timeout=300).read())
+    assert body["status"] == "success"
+    return {r["metric"]["job"]: {int(t): float(v) for t, v in r["values"]}
+            for r in body["data"]["result"]}
+
+
+def _reference(ref, op):
+    rows = ref_eval(QUERY.format(op=op), ref, START, STEP, END)
+    steps = range(START, END + 1, STEP)
+    return {dict(key)["job"]: {t: v for t, v in zip(steps, row)
+                               if not math.isnan(v)}
+            for key, row in rows.items()}
+
+
+OPS = ("sum", "count")      # both are shapes the fused path owns
+
+NEW_FAMILIES = (
+    "filodb_fused_refused_total", "filodb_fused_refused_gaps_total",
+    "filodb_aligned_fast_evals_total", "filodb_aligned_slide_evals_total",
+    "filodb_aligned_exact_evals_total", "filodb_device_to_host_bytes_total")
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_served_sum_by_job_over_missed_scrapes(case, monkeypatch):
+    oracle_calls = []
+    real = eng.periodic_samples
+
+    def oracle(*a, **kw):
+        oracle_calls.append(1)
+        return real(*a, **kw)
+    monkeypatch.setattr(eng, "periodic_samples", oracle)
+    srv, ref = _store(case)
+    n_query = len(OPS)
+    try:
+        m0 = _metrics(srv)
+        assert set(NEW_FAMILIES) <= set(m0)
+        for op in OPS:
+            got, want = _served(srv, op), _reference(ref, op)
+            assert set(got) == set(want) == {f"job-{j}" for j in range(JOBS)}
+            for job, row in want.items():
+                assert sorted(got[job]) == sorted(row), (job, "steps")
+                np.testing.assert_allclose(
+                    [got[job][t] for t in sorted(row)],
+                    [row[t] for t in sorted(row)], rtol=RTOL, err_msg=job)
+            if case == "window-under-two-samples":
+                # the step whose window keeps one sample of the series
+                assert T0 + 600 not in got["job-1"]
+                if op == "count":
+                    assert got["job-0"][T0 + 600] == INST - 1
+                    assert got["job-0"][T0 + 540] == INST
+        m1 = _metrics(srv)
+        d = {f: m1[f] - m0[f] for f in m1 if f in m0}
+        assert not oracle_calls
+        assert d["filodb_device_to_host_bytes_total"] > 0
+        if case == "dense":
+            assert d["filodb_fused_aggs_total"] == n_query
+            assert d["filodb_fused_refused_total"] == 0
+            assert d["filodb_fused_refused_gaps_total"] == 0
+            assert d["filodb_aligned_fast_evals_total"] == 0
+            # two [T, G] float32 grids a query and nothing else
+            assert d["filodb_device_to_host_bytes_total"] \
+                <= n_query * 2 * 4 * 16 * 128
+        else:
+            assert d["filodb_fused_aggs_total"] == 0
+            assert d["filodb_fused_refused_total"] == n_query
+            assert d["filodb_fused_refused_gaps_total"] == n_query
+            assert d["filodb_aligned_fast_evals_total"] == n_query
+            assert d["filodb_aligned_slide_evals_total"] == 0
+            assert d["filodb_aligned_exact_evals_total"] == 0
+            # the whole [T, S] float32 grid of rates comes back
+            assert d["filodb_device_to_host_bytes_total"] \
+                >= n_query * 13 * JOBS * INST * 4
+    finally:
+        srv.stop()

@@ -206,6 +206,34 @@ def test_eval_counter_fast_compiles(one_chip, jittered):
     _compile(fn, *_shapes((arrs,) + _grid_args(), one_chip))
 
 
+@pytest.fixture(scope="module")
+def holed():
+    """The jittered tiles with every fourth series missing scrapes: not
+    dense, so the evaluator takes the filled and prefix-count channels."""
+    t = _tiles(2000)
+    valid = np.ones((S_HOST, N), bool)
+    valid[3::4, 100:104] = False
+    valid[3::4, 300] = False
+    return tst.AlignedTiles(t.keys, BASE, DT, valid, np.asarray(t.ts),
+                            np.asarray(t.vals))
+
+
+@pytest.mark.parametrize("width", [None, 2, 8])
+def test_eval_counter_fast_over_holes_compiles(one_chip, holed, width):
+    """What serves a ``sum by`` whose selection has a missed scrape: the
+    f32-hybrid evaluator over non-dense channels, alone and vmapped over
+    the grid scalars at the batcher's two first widths."""
+    arrs = tst._tiles_arrays_fast(holed, "rate")
+    assert "ps_ones" in arrs
+    fn = functools.partial(tst._eval_counter_fast, "rate", T)
+    args = (arrs,) + _grid_args()
+    if width is not None:
+        fn = jax.vmap(fn, in_axes=tst._GRID_AXES)
+        args = tuple(np.full(width, a) if ax == 0 else a
+                     for a, ax in zip(args, tst._GRID_AXES))
+    _compile(fn, *_shapes(args, one_chip))
+
+
 def test_eval_counter_slide_compiles(one_chip, jittered):
     st = STEP // DT
     arrs = tst._tiles_arrays_slide(jittered, "rate", st)
