@@ -1932,7 +1932,10 @@ class FiloHttpServer:
         "filodb_tile_builds_total": "Device tile (re)builds",
         "filodb_tile_cache_hits_total": "Device tile-cache hits",
         "filodb_fused_aggs_total":
-            "Queries served by the fused group-sum kernel",
+            "Queries served by a fused group-sum program",
+        "filodb_fused_holes_aggs_total":
+            "Fused queries served by the grouped non-dense program "
+            "(tiles with holes)",
         "filodb_mesh_dispatches_total":
             "Dispatches served from the mesh-resident sharded store",
         "filodb_fused_refused_total":
@@ -2187,6 +2190,8 @@ class FiloHttpServer:
             # sharded (mesh-resident) dispatches
             emit("fused_aggs_total", {},
                  getattr(self.backend, "fused_aggs", 0))
+            emit("fused_holes_aggs_total", {},
+                 getattr(self.backend, "fused_holes_aggs", 0))
             emit("mesh_dispatches_total", {},
                  getattr(self.backend, "mesh_dispatches", 0))
             # why the fused path was left, and which aligned family

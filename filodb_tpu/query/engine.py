@@ -1433,9 +1433,11 @@ class QueryEngine:
 
     def _try_fused_agg(self, plan) -> Optional[GridResult]:
         """`sum/avg/count by (g) (rate/increase/delta(sel[w]))` fused
-        end-to-end on device: grouping happens inside the Pallas
-        group-sum kernel and the [S, T] per-series intermediate never
-        exists (exec/AggrOverRangeVectors map-reduce, fused).
+        end-to-end on device: grouping happens inside one device
+        program (the Pallas group-sum kernel over dense tiles, the
+        grouped non-dense evaluator over tiles with holes) and the
+        [S, T] per-series intermediate never leaves the chip
+        (exec/AggrOverRangeVectors map-reduce, fused).
 
         None is returned only for plan SHAPES this path doesn't own;
         once the series are selected, any kernel ineligibility falls
@@ -1445,12 +1447,14 @@ class QueryEngine:
         the backend. What the backend refuses counts in
         ``filodb_fused_refused_total`` (``TpuBackend.fused_groupsum``
         lists the reasons: no kernel on this backend, irregular
-        cadence, tail data, a non-divisible or not interior grid,
-        non-finite values, VMEM), and a selection refused for holes in
-        its tiles (one missed scrape in one series) in
-        ``filodb_fused_refused_gaps_total`` as well; the aligned family
-        that then serves it counts in
-        ``filodb_aligned_{fast,slide,exact}_evals_total``."""
+        cadence, tail data; over dense tiles a non-divisible or not
+        interior grid, non-finite values, VMEM), and a selection with
+        holes in its tiles that is refused (a grid wider than int32 ms)
+        in ``filodb_fused_refused_gaps_total`` as well; the aligned
+        family that then serves it counts in
+        ``filodb_aligned_{fast,slide,exact}_evals_total``. A selection
+        with holes that is served counts in
+        ``filodb_fused_holes_aggs_total``."""
         if self.backend is None or plan.op not in ("sum", "count", "avg"):
             return None
         if plan.params:

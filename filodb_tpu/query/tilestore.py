@@ -1300,6 +1300,13 @@ def evaluate_counters_t(tiles: AlignedTiles, func: str, steps: np.ndarray,
     return fn(*args)
 
 
+def _group_onehot(ids, G: int):
+    """int32 group ids [S] -> f32 one-hot [S, G], built on the device;
+    an id outside [0, G) (the padding's -1) gives an all-zero row."""
+    return (ids[:, None] == jnp.arange(G, dtype=jnp.int32)[None, :]
+            ).astype(jnp.float32)
+
+
 def _groupsum_program(func: str, st: int, dspan: int, hi_mode: int,
                       lo_mode: int, exact_branch: bool, nsteps: int,
                       G: int, interpret: bool, v_p, base, params, ids):
@@ -1309,43 +1316,75 @@ def _groupsum_program(func: str, st: int, dspan: int, hi_mode: int,
     gives an all-zero row), the Pallas kernel and its [:nsteps] slices.
     Every input is explicitly typed, so the program is the same under
     x64 on and off."""
-    onehot = (ids[:, None] == jnp.arange(G, dtype=jnp.int32)[None, :]
-              ).astype(jnp.float32)
     return pk.groupsum_call(
         func, st, dspan, hi_mode, lo_mode, exact_branch, nsteps,
-        v_p, base, onehot, params, interpret=interpret)
+        v_p, base, _group_onehot(ids, G), params, interpret=interpret)
+
+
+def _groupsum_holes_program(func: str, nsteps: int, G: int, arrs, grid,
+                            ids):
+    """The fused group-sum over tiles with holes as ONE traceable program
+    (jitted once per static tuple by groupsum_counters): the non-dense
+    f32-hybrid evaluator's [T, S] rates, NaN where a window holds fewer
+    than two samples, then the Pallas kernel's own epilogue — the masked
+    one-hot matmul to [T, G] sums and counts, f32 at HIGHEST precision
+    (the MXU's default bf16 input truncation is another answer), so a
+    selection sums the same way with holes and without. ``grid`` is
+    int64[6]: w0s, w0e, step, then the tile's num_slots, base_ms, dt_ms;
+    ``ids`` int32 [S] in tile order, an id outside [0, G) names no
+    group."""
+    w0s, w0e, step, num_slots, base, dt = grid
+    out = _eval_counter_fast(func, nsteps, arrs, num_slots, base, dt,
+                             w0s, w0e, step)
+    ok = ~jnp.isnan(out)
+    onehot = _group_onehot(ids, G)
+    dot = _functools.partial(jnp.dot, preferred_element_type=jnp.float32,
+                             precision=jax.lax.Precision.HIGHEST)
+    return (dot(jnp.where(ok, out, jnp.float32(0.0)), onehot),
+            dot(ok.astype(jnp.float32), onehot))
 
 
 @kernel_contract(
     "groupsum_dispatch", kind="dispatch",
     vmem_budget=14 << 20,
     rel_time_bits=31, span_guard="_slide_eligible",
-    notes="host-side gate and dispatcher of the fused Pallas group-sum "
-          "kernel: regular interior grid via _slide_eligible, merged-"
-          "stream window/step divisibility, dspan cap, full VMEM "
-          "re-budget (accumulators + DMA scratch + onehot + base) all "
-          "decide BEFORE the executable table is asked; then one cached "
-          "executable (site groupsum) per static tuple takes the "
-          "query's five scalars and its group ids")
+    notes="host-side gate and dispatcher of the fused group-sum, two "
+          "programs behind it. Dense tiles, the Pallas kernel: regular "
+          "interior grid via _slide_eligible, merged-stream window/step "
+          "divisibility, dspan cap, full VMEM re-budget (accumulators + "
+          "DMA scratch + onehot + base) all decide BEFORE the executable "
+          "table is asked; then one cached executable (site groupsum) "
+          "per static tuple takes the query's five scalars and its "
+          "group ids. Tiles with holes: _groupsum_holes")
 def groupsum_counters(tiles: AlignedTiles, func: str, steps: np.ndarray,
                       window_ms: int, gids, G: int, offset_ms: int = 0,
                       interpret: bool = False):
-    """`sum by (g) (rate/increase/delta(sel[w]))` fused on device via the
-    Pallas group-sum kernel -> (sums f32 [T, G], counts f32 [T, G]), or
-    None when the preconditions don't hold (caller falls back to
-    evaluate_counters_t + host/XLA grouping).
+    """`sum by (g) (rate/increase/delta(sel[w]))` fused on device ->
+    (sums f32 [T, G], counts f32 [T, G]), or None when the preconditions
+    don't hold (caller falls back to evaluate_counters_t + host/XLA
+    grouping). One gate, two programs, chosen from ``tiles._dense``:
+    the Pallas group-sum kernel over dense tiles, the grouped non-dense
+    f32-hybrid evaluator (``_groupsum_holes``) over tiles with holes.
+    Both are boundary samples -> f32 extrapolation -> masked group
+    matmul, over two layouts of the same samples.
 
     ``gids``: the group id in [0, G) of every series of the tiles, in
     tile order. One cached executable per (func, grid statics, tile
-    shapes, G) serves every query of that shape: a query sends five
-    int32 scalars and its group ids, nothing is traced or compiled
-    again (``_jit_lookup``: exec-cache hits/misses, ``kernel-build``).
+    shapes, G) serves every query of that shape: a query sends one
+    small integer vector and its group ids, nothing is traced or
+    compiled again (``_jit_lookup``: exec-cache hits/misses,
+    ``kernel-build``).
 
-    Preconditions: dense tiles; regular grid with step % dt == 0 fully
-    interior to the tile; span fits int32 ms relative to the tile base.
-    The ids are padded to the kernel's lane tile with -1, which names
-    no group, so any S works."""
+    Preconditions of the kernel: dense tiles; regular grid with
+    step % dt == 0 fully interior to the tile; a whole number of steps
+    a window; finite values; a pipeline within VMEM; span fits int32 ms
+    relative to the tile base. The ids are padded to the kernel's lane
+    tile with -1, which names no group, so any S works. Over holes only
+    the last of them is asked (``_groupsum_holes``)."""
     assert func in ("rate", "increase", "delta")
+    if not tiles._dense:
+        return _groupsum_holes(tiles, func, steps, window_ms, gids, G,
+                               offset_ms)
     nsteps = steps.size
     if nsteps < 2:
         return None
@@ -1405,6 +1444,41 @@ def groupsum_counters(tiles: AlignedTiles, func: str, steps: np.ndarray,
     fn = _jit_lookup(_EVAL_T_JIT, key, lambda: jax.jit(
         _bind(_groupsum_program, *static)), site="groupsum",
         cost_args=args)
+    return fn(*args)
+
+
+@kernel_contract(
+    "groupsum_holes_dispatch", kind="dispatch",
+    rel_time_bits=31, span_guard="counters_batch_family",
+    notes="the fused gate's branch over tiles with holes: the grouped "
+          "non-dense f32-hybrid evaluator where counters_batch_family "
+          "says ('fast',), i.e. the whole grid fits int32 ms relative "
+          "to the tile base; _eval_counter_fast clips its own indices, "
+          "so none of the kernel's shape conditions is asked. One "
+          "cached executable (site groupsum) per (func, nsteps, G, "
+          "channel shape) takes int64[6] and the group ids")
+def _groupsum_holes(tiles: AlignedTiles, func: str, steps: np.ndarray,
+                    window_ms: int, gids, G: int, offset_ms: int):
+    """``groupsum_counters`` over tiles that are not dense -> (sums f32
+    [T, G], counts f32 [T, G]) from ``_groupsum_holes_program`` over the
+    seven cached channels of ``_tiles_arrays_fast`` (what the aligned
+    path holds resident already), or None for the exact all-f64
+    ``("t",)`` family: a grid wider than int32 ms."""
+    nsteps = steps.size
+    if nsteps < 1 or counters_batch_family(
+            tiles, func, steps, window_ms, offset_ms) != ("fast",):
+        return None
+    w0e = int(steps[0] - offset_ms)
+    step = int(steps[1] - steps[0]) if nsteps > 1 else 1
+    arrs = _tiles_arrays_fast(tiles, func)
+    grid = np.array([w0e - window_ms, w0e, step, tiles.num_slots,
+                     tiles.base_ms, tiles.dt_ms], np.int64)
+    args = (arrs, grid, np.asarray(gids, np.int32))
+    key = ("groupsum", "holes", func, nsteps, G,
+           tuple(arrs["tsr"].shape))
+    fn = _jit_lookup(_EVAL_T_JIT, key, lambda: jax.jit(
+        _bind(_groupsum_holes_program, func, nsteps, G)),
+        site="groupsum", cost_args=args)
     return fn(*args)
 
 

@@ -515,8 +515,11 @@ class TpuBackend:
         self.tile_builds = 0    # observability: device tile (re)builds
         self.tile_hits = 0      # observability: cache hits
         self.fused_aggs = 0     # observability: fused group-sum queries
+        # those of them that the grouped non-dense program served
+        # (tiles with holes); the rest ran the Pallas kernel or the mesh
+        self.fused_holes_aggs = 0
         # fused_groupsum calls that came back None, and those of them
-        # that the kernel gate refused over tiles with holes
+        # that the gate refused over tiles with holes
         self.fused_refused = 0
         self.fused_refused_gaps = 0
         # counter queries per aligned evaluator family
@@ -1081,28 +1084,33 @@ class TpuBackend:
                        window_ms: int, offset_ms: int,
                        gids: np.ndarray, G: int):
         """`sum/avg/count by (g)` of rate/increase/delta fused on device:
-        the Pallas group-sum kernel consumes the cached aligned tiles and
-        only [T, G] group sums + counts leave the chip — the [S, T] rate
-        intermediate is never materialized (the reference pays this as
+        one program consumes the cached aligned tiles and only [T, G]
+        group sums + counts leave the chip — the [S, T] rate
+        intermediate is never read back (the reference pays this as
         per-shard AggrOverRangeVectors map-reduce over row iterators,
         exec/aggregator/*.scala). A query hands the device path its
-        group ids in tile order (``gids[idx]``) and five grid scalars;
-        the program is one cached executable of the tilestore table
-        (or the mesh store's grouped collective). Returns (sums, cnts)
-        as [T, G] numpy or None when ineligible (caller falls back to
-        the general rangefn + aggregate path).
+        group ids in tile order (``gids[idx]``) and one small integer
+        vector; the program is one cached executable of the tilestore
+        table, the Pallas group-sum kernel over dense tiles or the
+        grouped non-dense evaluator over tiles with holes
+        (``filodb_fused_holes_aggs_total`` counts those apart), or the
+        mesh store's grouped collective. Returns (sums, cnts) as [T, G]
+        numpy or None when ineligible (caller falls back to the general
+        rangefn + aggregate path).
 
         Every None counts in ``filodb_fused_refused_total``. The
         reasons, in the order they are looked at: not a counter
         function, or nothing selected; a CPU node without the
         interpreted kernel or a mesh; series that do not share one
         cadence grid (no tiles); a window that reaches the write-buffer
-        tail (``_fused_covered``); and the kernel gate
-        (``tst.groupsum_counters``, or the mesh store's placement):
-        tiles with holes, which ``filodb_fused_refused_gaps_total``
-        counts apart, a grid that is irregular, not interior or not a
-        whole number of steps a window, non-finite values, or no
-        pipeline within VMEM."""
+        tail (``_fused_covered``); and the gate
+        (``tst.groupsum_counters``, or the mesh store's placement).
+        Over dense tiles: a grid that is irregular, not interior or not
+        a whole number of steps a window, non-finite values, or no
+        pipeline within VMEM. Over tiles with holes, counted apart in
+        ``filodb_fused_refused_gaps_total``: a grid wider than int32 ms
+        from the tile base (the exact all-f64 family), or a CPU node
+        whose mesh store places dense tiles only."""
         res = self._fused_groupsum(series, func, steps, window_ms,
                                    offset_ms, gids, G)
         if res is None:
@@ -1160,11 +1168,10 @@ class TpuBackend:
                     tiles, func, steps, window_ms, gvec, G, offset_ms,
                     interpret=on_cpu)
             if res is None:
-                # _slide_eligible is the gate's one predicate; that the
-                # tiles have holes is read beside it, not inside it
                 self.fused_refused_gaps += not tiles._dense
                 return None
             self.fused_aggs += 1
+            self.fused_holes_aggs += not tiles._dense
         with obs_trace.span("device-sync"):
             T = steps.size
             sums, cnts = np.asarray(res[0]), np.asarray(res[1])

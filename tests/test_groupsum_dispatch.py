@@ -8,7 +8,9 @@ bits, never a time.
 traced once, a new static its own entry; (b) bit-for-bit the kernel called
 directly with a host one-hot; (c) four threads' first call at once; (d)
 through the served engine: ``&explain=analyze`` dispositions and the
-``filodb_exec_cache_*`` counters.
+``filodb_exec_cache_*`` counters; (e) the gate's other program, over tiles
+with holes (``_groupsum_holes_program``, plain XLA): the per-series
+evaluator's rates grouped in float64 here, and one executable a shape.
 """
 
 import json
@@ -328,3 +330,187 @@ def test_served_fused_query_builds_once_then_runs_the_compiled_object(
         assert [t for t, _ in r["values"]] == sorted(w)
         np.testing.assert_allclose([float(v) for _, v in r["values"]],
                                    [w[t] for t in sorted(w)], rtol=1e-5)
+
+
+# -- (e) tiles with holes: the grouped non-dense evaluator ---------------------
+
+S_H, N_H, G_H = 64, 200, 5
+
+
+def _holed(seed=7, S=S_H):
+    """Tiles whose series miss scrapes: every fourth four single ticks,
+    series 1 everything from slot 40 to 110 (empty in the windows that end
+    there), series 2 all but one sample of slots 60..95."""
+    rng = np.random.default_rng(seed)
+    ts = (BASE + np.arange(N_H)[None, :] * DT
+          + rng.integers(-2000, 2001, (S, N_H)))
+    vals = 1e9 + np.cumsum(rng.uniform(0, 5, (S, N_H)), axis=1)
+    vals[5, N_H // 2:] *= 0.99              # counter reset
+    valid = np.ones((S, N_H), bool)
+    for r in range(3, S, 4):
+        valid[r, rng.choice(np.arange(2, N_H - 2), 4, replace=False)] = False
+    valid[1, 40:111] = False
+    valid[2, 60:96] = False
+    valid[2, 77] = True
+    return tst.AlignedTiles([{} for _ in range(S)], BASE, DT, valid, ts,
+                            vals)
+
+
+@pytest.fixture(scope="module")
+def holed():
+    return _holed()
+
+
+# (first window end relative to BASE, step, steps): windows are W = 300 s
+GRIDS = {
+    "interior": (400_000, 60_000, 20),
+    "not-interior": (-120_000, 60_000, 44),     # from before slot 0 to past N
+    "window-not-whole-steps": (400_000, 70_000, 18),
+    "step-not-whole-slots": (403_000, 45_500, 25),
+    "one-step": (900_000, 0, 1),
+}
+
+
+def _grid(name):
+    first, step, n = GRIDS[name]
+    return BASE + first + np.arange(n, dtype=np.int64) * step
+
+
+@pytest.mark.parametrize("grid", sorted(GRIDS))
+@pytest.mark.parametrize("op", ["sum", "count", "avg"])
+@pytest.mark.parametrize("func", ["rate", "increase", "delta"])
+def test_grouped_program_over_holes_equals_per_series_grouped_in_float64(
+        holed, func, op, grid):
+    steps = _grid(grid)
+    gid = np.arange(S_H) % (G_H - 1)        # group G_H - 1 has no series
+    res = tst.groupsum_counters(holed, func, steps, W, gid, G_H)
+    assert res is not None
+    sums, cnts = np.asarray(res[0]), np.asarray(res[1])
+    assert sums.shape == cnts.shape == (steps.size, G_H)
+    assert sums.dtype == cnts.dtype == np.float32
+    per = np.asarray(tst.evaluate_counters_t(holed, func, steps, W),
+                     np.float64)            # [T, S], NaN: under two samples
+    assert per.shape == (steps.size, S_H)
+    ok = ~np.isnan(per)
+    want_c = np.stack([ok[:, gid == g].sum(axis=1) for g in range(G_H)], 1)
+    want_s = np.stack([np.where(ok, per, 0.0)[:, gid == g].sum(axis=1)
+                       for g in range(G_H)], 1)
+    np.testing.assert_array_equal(cnts, want_c)
+    if grid == "interior":
+        # the cases the fleet was built for are inside this grid
+        assert not ok[:, 1].all() and not ok[:, 2].all()
+        assert ok[:, 1].any() and ok[:, 0].all()
+    if grid == "not-interior":
+        assert not ok[0].any() and not ok[-1].any() and ok.any()
+    if op == "count":
+        return
+    scale = np.abs(np.where(ok, per, 0.0)).max() or 1.0
+    if op == "sum":
+        np.testing.assert_allclose(sums, want_s, rtol=2e-6,
+                                   atol=2e-6 * scale)
+        assert not sums[:, G_H - 1].any() and not cnts[:, G_H - 1].any()
+    else:
+        # as engine._try_fused_agg takes avg out of (sums, cnts)
+        with np.errstate(invalid="ignore", divide="ignore"):
+            got = np.where(cnts == 0, np.nan, sums.astype(np.float64) / cnts)
+            want = np.where(want_c == 0, np.nan, want_s / want_c)
+        np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6 * scale)
+
+
+def _holes_keys():
+    return [k for k in _groupsum_keys() if k[1] == "holes"]
+
+
+def test_holes_one_miss_then_hits_over_positions_groups_and_tiles(
+        fresh_table, monkeypatch):
+    """Another grid position, another group vector and ANOTHER selection
+    of the same shape (another app's tiles) all run the one executable:
+    its key holds shapes, not the tiles."""
+    built = []
+    real = tst._groupsum_holes_program
+
+    def counted(*a):
+        built.append(1)
+        return real(*a)
+    counted.__name__ = real.__name__
+    monkeypatch.setattr(tst, "_groupsum_holes_program", counted)
+    T = 20
+    a, b = _holed(7), _holed(8)
+    for t in (a, b):
+        tst._tiles_arrays_fast(t, "rate")
+    rng = np.random.default_rng(3)
+    before = tst.executable_cache_stats()
+    outs = []
+    for i in range(6):
+        gid = rng.integers(0, G_H, S_H)
+        res = tst.groupsum_counters(a if i % 2 else b, "rate",
+                                    _steps(T, 60_000 * i), W, gid, G_H)
+        outs.append(np.asarray(res[0]))
+    assert _delta(before) == {"hits": 5, "misses": 1, "entries": 1}
+    assert len(built) == 1                  # traced once
+    assert len(_holes_keys()) == 1 and len(_groupsum_keys()) == 1
+    assert all(not np.array_equal(outs[0], o) for o in outs[1:])
+    # a new static is its own entry, and only that
+    for kw in (dict(T=T + 1), dict(G=G_H + 1), dict(func="delta"),
+               dict(S=S_H + 8)):
+        t = _holed(7, kw.get("S", S_H))
+        before = tst.executable_cache_stats()
+        for _ in range(2):
+            assert tst.groupsum_counters(
+                t, kw.get("func", "rate"), _steps(kw.get("T", T)), W,
+                np.arange(len(t.keys)) % G_H, kw.get("G", G_H)) is not None
+        assert _delta(before) == {"hits": 1, "misses": 1, "entries": 1}, kw
+
+
+def test_holes_send_one_int64_vector_and_the_ids(holed, monkeypatch):
+    """PR 29's rule: numpy in, no scalar becomes a device array on the
+    way, the seven channels are the aligned path's cached ones."""
+    seen = {}
+    real = tst._jit_lookup
+
+    def spy(cache, key, build, site="tilestore", cost_args=None):
+        seen.update(key=key, args=cost_args, site=site)
+        return real(cache, key, build, site=site, cost_args=cost_args)
+    monkeypatch.setattr(tst, "_jit_lookup", spy)
+    steps = _steps(12, 3000)
+    gid = np.arange(S_H, dtype=np.int64) % G_H
+    assert tst.groupsum_counters(holed, "rate", steps, W, gid, G_H,
+                                 offset_ms=60_000) is not None
+    assert seen["site"] == "groupsum"
+    assert seen["key"] == ("groupsum", "holes", "rate", 12, G_H,
+                           (N_H, S_H))
+    arrs, grid, ids = seen["args"]
+    assert type(grid) is np.ndarray and grid.dtype == np.int64
+    w0e = int(steps[0]) - 60_000
+    assert grid.tolist() == [w0e - W, w0e, 60_000, N_H, BASE, DT]
+    assert type(ids) is np.ndarray and ids.dtype == np.int32
+    assert ids.tolist() == gid.tolist()
+    cached = tst._tiles_arrays_fast(holed, "rate")
+    assert sorted(arrs) == sorted(cached) and len(arrs) == 7
+    assert all(arrs[k] is cached[k] for k in arrs)
+
+
+def test_holes_an_id_outside_the_groups_is_in_no_group(holed):
+    gid = np.arange(S_H) % G_H
+    full = tst.groupsum_counters(holed, "rate", _steps(12), W, gid, G_H)
+    out = gid.copy()
+    out[gid == 2] = -1
+    part = tst.groupsum_counters(holed, "rate", _steps(12), W, out, G_H)
+    keep = [0, 1, 3, 4]
+    np.testing.assert_array_equal(np.asarray(part[0])[:, keep],
+                                  np.asarray(full[0])[:, keep])
+    assert not np.asarray(part[1])[:, 2].any()
+    assert np.asarray(full[1])[:, 2].any()
+
+
+def test_grid_wider_than_int32_ms_over_holes_is_refused(holed, fresh_table):
+    """The exact all-f64 family keeps the aligned path; dense tiles are
+    not asked this question by the holes branch at all."""
+    steps = BASE + 400_000 + np.arange(3, dtype=np.int64) * (2 ** 30)
+    assert tst.counters_batch_family(holed, "rate", steps, W) == ("t",)
+    before = tst.executable_cache_stats()
+    assert tst.groupsum_counters(holed, "rate", steps, W,
+                                 np.arange(S_H) % G_H, G_H) is None
+    assert _delta(before) == {"hits": 0, "misses": 0, "entries": 0}
+    assert tst.groupsum_counters(holed, "rate", steps[:0], W,
+                                 np.arange(S_H) % G_H, G_H) is None

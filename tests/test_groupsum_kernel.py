@@ -125,7 +125,19 @@ def test_groupsum_dispatcher_fallbacks():
                              valid, ts, vals)
     steps = np.arange(BASE + 400_000, BASE + 1_000_000, 60_000,
                       dtype=np.int64)
-    assert tst.groupsum_counters(gappy, "rate", steps, 300_000,
+    # ... are no fallback since PR 33: the gate's second program serves
+    # them (tests/test_groupsum_dispatch.py part (e)), unless the grid is
+    # wider than int32 ms from the tile base
+    sums, cnts = tst.groupsum_counters(gappy, "rate", steps, 300_000,
+                                       gid, G, interpret=True)
+    per = np.asarray(tst.evaluate_counters_t(gappy, "rate", steps, 300_000))
+    np.testing.assert_array_equal(np.asarray(cnts)[:, 0],
+                                  (~np.isnan(per)).sum(axis=1))
+    np.testing.assert_allclose(np.asarray(sums)[:, 0],
+                               np.nansum(per.astype(np.float64), axis=1),
+                               rtol=2e-6)
+    wide = steps[0] + np.arange(3, dtype=np.int64) * 2 ** 30
+    assert tst.groupsum_counters(gappy, "rate", wide, 300_000,
                                  gid, G, interpret=True) is None
     # window not a whole number of steps: merged kc/kl stream contract
     steps = np.arange(BASE + 400_000, BASE + 1_000_000, 60_000,

@@ -1,9 +1,10 @@
 """The served ``sum by (job)`` of ``rate[5m]`` over a store with missed
 scrapes: a scrape that failed stored no sample, so a series has holes on
-the cadence grid, its tiles are not dense and the fused group-sum path
-refuses the whole selection (``tilestore._slide_eligible``). The aligned
-f32-hybrid family serves it instead, the ``[T, S]`` grid comes to the host
-and ``engine.aggregate`` groups it.
+the cadence grid and its tiles are not dense. The fused gate
+(``tilestore.groupsum_counters``) then serves the selection with the
+grouped form of the non-dense f32-hybrid evaluator instead of the Pallas
+kernel: one cached executable, and only the ``[T, G]`` sums and counts
+come to the host, as over dense tiles.
 
 Held here, through ``FiloServer``'s HTTP query path with the TPU backend
 against ``promql/refeval.py`` in float64: the answer over each kind of hole,
@@ -37,10 +38,9 @@ QUERY = ('{op}(rate(http_requests_total{{_ws_="demo",_ns_="App-0"}}[5m]))'
          ' by (job)')
 # the window of the step at T0 + 600 is [T0 + 300, T0 + 600]: ticks 30..60
 FIRST_SLOT, LAST_SLOT = 30, 60
-# the per-series rates leave the device in float32 (the f32-hybrid
-# evaluator's epilogue, ~3e-7 relative; the fused kernel's sums likewise)
-# and are summed per group in float64 on the host, which keeps the terms'
-# relative error: float32 epilogue over float64 group sums
+# the per-series rates are float32 on the device (the f32-hybrid
+# evaluator's epilogue, ~3e-7 relative; the fused kernel's likewise) and
+# both fused programs sum a group's 16 of them in float32
 RTOL = 1e-6
 
 
@@ -95,6 +95,8 @@ CASES = {
     "hole-on-last-slot": lambda n, rng: [LAST_SLOT] if _flaky(n) else [],
     "window-under-two-samples": _under_two,
     "one-flaky-among-dense": lambda n, rng: [47] if n == 6 else [],
+    "every-series-has-a-hole":
+        lambda n, rng: rng.choice(np.arange(2, TICKS - 2), 2, replace=False),
 }
 
 
@@ -125,30 +127,43 @@ def _metrics(srv):
             for ln in lines if ln.startswith("filodb_") and "{" not in ln}
 
 
-def _served(srv, op):
+def _served(srv, op, app=0, start=START, end=END, step=STEP):
     """-> {job: {step s: value}} as the node answers over HTTP."""
     url = (f"http://127.0.0.1:{srv.port}/promql/timeseries/api/v1/"
            "query_range?" + urllib.parse.urlencode(dict(
-               query=QUERY.format(op=op), start=START, end=END, step=STEP,
-               cache="false")))
+               query=QUERY.format(op=op).replace("App-0", f"App-{app}"),
+               start=start, end=end, step=step, cache="false")))
     body = json.loads(urllib.request.urlopen(url, timeout=300).read())
     assert body["status"] == "success"
     return {r["metric"]["job"]: {int(t): float(v) for t, v in r["values"]}
             for r in body["data"]["result"]}
 
 
-def _reference(ref, op):
-    rows = ref_eval(QUERY.format(op=op), ref, START, STEP, END)
-    steps = range(START, END + 1, STEP)
+def _reference(ref, op, app=0, start=START, end=END, step=STEP):
+    rows = ref_eval(QUERY.format(op=op).replace("App-0", f"App-{app}"), ref,
+                    start, step, end)
+    steps = range(start, end + 1, step)
     return {dict(key)["job"]: {t: v for t, v in zip(steps, row)
                                if not math.isnan(v)}
             for key, row in rows.items()}
 
 
-OPS = ("sum", "count")      # both are shapes the fused path owns
+def _assert_answer(got, want):
+    assert set(got) == set(want)
+    for job, row in want.items():
+        assert sorted(got[job]) == sorted(row), (job, "steps")
+        np.testing.assert_allclose([got[job][t] for t in sorted(row)],
+                                   [row[t] for t in sorted(row)], rtol=RTOL,
+                                   err_msg=job)
+
+
+OPS = ("sum", "count", "avg")   # the shapes the fused path owns
+
+T_G_GRIDS = 2 * 13 * JOBS * 4   # bytes of two [T, G] float32 grids
 
 NEW_FAMILIES = (
-    "filodb_fused_refused_total", "filodb_fused_refused_gaps_total",
+    "filodb_fused_holes_aggs_total", "filodb_fused_refused_total",
+    "filodb_fused_refused_gaps_total",
     "filodb_aligned_fast_evals_total", "filodb_aligned_slide_evals_total",
     "filodb_aligned_exact_evals_total", "filodb_device_to_host_bytes_total")
 
@@ -169,12 +184,8 @@ def test_served_sum_by_job_over_missed_scrapes(case, monkeypatch):
         assert set(NEW_FAMILIES) <= set(m0)
         for op in OPS:
             got, want = _served(srv, op), _reference(ref, op)
-            assert set(got) == set(want) == {f"job-{j}" for j in range(JOBS)}
-            for job, row in want.items():
-                assert sorted(got[job]) == sorted(row), (job, "steps")
-                np.testing.assert_allclose(
-                    [got[job][t] for t in sorted(row)],
-                    [row[t] for t in sorted(row)], rtol=RTOL, err_msg=job)
+            assert set(want) == {f"job-{j}" for j in range(JOBS)}
+            _assert_answer(got, want)
             if case == "window-under-two-samples":
                 # the step whose window keeps one sample of the series
                 assert T0 + 600 not in got["job-1"]
@@ -184,24 +195,67 @@ def test_served_sum_by_job_over_missed_scrapes(case, monkeypatch):
         m1 = _metrics(srv)
         d = {f: m1[f] - m0[f] for f in m1 if f in m0}
         assert not oracle_calls
-        assert d["filodb_device_to_host_bytes_total"] > 0
-        if case == "dense":
-            assert d["filodb_fused_aggs_total"] == n_query
-            assert d["filodb_fused_refused_total"] == 0
-            assert d["filodb_fused_refused_gaps_total"] == 0
-            assert d["filodb_aligned_fast_evals_total"] == 0
-            # two [T, G] float32 grids a query and nothing else
-            assert d["filodb_device_to_host_bytes_total"] \
-                <= n_query * 2 * 4 * 16 * 128
-        else:
-            assert d["filodb_fused_aggs_total"] == 0
-            assert d["filodb_fused_refused_total"] == n_query
-            assert d["filodb_fused_refused_gaps_total"] == n_query
-            assert d["filodb_aligned_fast_evals_total"] == n_query
-            assert d["filodb_aligned_slide_evals_total"] == 0
-            assert d["filodb_aligned_exact_evals_total"] == 0
-            # the whole [T, S] float32 grid of rates comes back
-            assert d["filodb_device_to_host_bytes_total"] \
-                >= n_query * 13 * JOBS * INST * 4
+        # both sides of the gate's one choice are fused: one program a
+        # query, two [T, G] float32 grids read back and nothing else
+        assert d["filodb_fused_aggs_total"] == n_query
+        assert d["filodb_fused_holes_aggs_total"] \
+            == (0 if case == "dense" else n_query)
+        assert d["filodb_fused_refused_total"] == 0
+        assert d["filodb_fused_refused_gaps_total"] == 0
+        for family in ("fast", "slide", "exact"):
+            assert d[f"filodb_aligned_{family}_evals_total"] == 0
+        assert d["filodb_device_to_host_bytes_total"] == n_query * T_G_GRIDS
+    finally:
+        srv.stop()
+
+
+def test_a_second_request_over_holes_builds_nothing():
+    """Another app of the same shape at another grid position runs the
+    executable the first request built: no exec-cache miss and no
+    ``kernel-build`` stage, one int64 vector and one id vector go up."""
+    srv, ref = _store("single-misses")
+    try:
+        _assert_answer(_served(srv, "sum"), _reference(ref, "sum"))
+        m0 = _metrics(srv)
+        moved = dict(app=1, start=START + 60, end=END + 60)
+        _assert_answer(_served(srv, "sum", **moved),
+                       _reference(ref, "sum", **moved))
+        _assert_answer(_served(srv, "avg", **moved),
+                       _reference(ref, "avg", **moved))
+        m1 = _metrics(srv)
+        d = {f: m1[f] - m0[f] for f in m1 if f in m0}
+        assert d["filodb_fused_holes_aggs_total"] == 2
+        assert d["filodb_exec_cache_misses_total"] == 0
+        assert d["filodb_exec_cache_hits_total"] == 2
+        assert d["filodb_stage_kernel_build_calls_total"] == 0
+        assert d["filodb_stage_device_dispatch_calls_total"] == 2
+        assert d["filodb_device_execute_seconds_count"] == 2
+        assert d["filodb_batcher_queries_total"] == 0
+    finally:
+        srv.stop()
+
+
+def test_a_grid_wider_than_int32_ms_over_holes_is_refused_and_counted():
+    """The ``("t",)`` family: the exact all-f64 aligned evaluator and
+    the host's ``aggregate`` still serve it, and the refusal still
+    counts as one for holes."""
+    srv, ref = _store("single-misses")
+    wide = dict(start=START, end=START + 2 * 2 ** 21, step=2 ** 21)
+    try:
+        m0 = _metrics(srv)
+        got, want = _served(srv, "sum", **wide), _reference(ref, "sum", **wide)
+        assert all(list(row) == [START] for row in want.values())
+        np.testing.assert_allclose(
+            [got[job][START] for job in sorted(want)],
+            [want[job][START] for job in sorted(want)], rtol=1e-9)
+        assert set(got) == set(want)
+        m1 = _metrics(srv)
+        d = {f: m1[f] - m0[f] for f in m1 if f in m0}
+        assert d["filodb_fused_aggs_total"] == 0
+        assert d["filodb_fused_holes_aggs_total"] == 0
+        assert d["filodb_fused_refused_total"] == 1
+        assert d["filodb_fused_refused_gaps_total"] == 1
+        assert d["filodb_aligned_exact_evals_total"] == 1
+        assert d["filodb_aligned_fast_evals_total"] == 0
     finally:
         srv.stop()

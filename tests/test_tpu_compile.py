@@ -220,9 +220,10 @@ def holed():
 
 @pytest.mark.parametrize("width", [None, 2, 8])
 def test_eval_counter_fast_over_holes_compiles(one_chip, holed, width):
-    """What serves a ``sum by`` whose selection has a missed scrape: the
-    f32-hybrid evaluator over non-dense channels, alone and vmapped over
-    the grid scalars at the batcher's two first widths."""
+    """What serves a per-series ``rate`` whose selection has a missed
+    scrape (and, until PR 33, its ``sum by``): the f32-hybrid evaluator
+    over non-dense channels, alone and vmapped over the grid scalars at
+    the batcher's two first widths."""
     arrs = tst._tiles_arrays_fast(holed, "rate")
     assert "ps_ones" in arrs
     fn = functools.partial(tst._eval_counter_fast, "rate", T)
@@ -232,6 +233,52 @@ def test_eval_counter_fast_over_holes_compiles(one_chip, holed, width):
         args = tuple(np.full(width, a) if ax == 0 else a
                      for a, ax in zip(args, tst._GRID_AXES))
     _compile(fn, *_shapes(args, one_chip))
+
+
+@pytest.mark.parametrize("func", ["rate", "delta"])
+def test_groupsum_over_holes_compiles(one_chip, monkeypatch, func):
+    """What serves a ``sum by`` whose selection has a missed scrape: the
+    dispatcher's ONE jitted program (the non-dense evaluator, the one-hot
+    from the group ids, two f32 matmuls at HIGHEST), built by the
+    dispatcher's own ``build``, at the benchmark's third cell: 2,048
+    series x 728 slots, 16 groups, 31 steps."""
+    s_cell, n_cell, t_cell = 2048, 728, 31
+    rng = np.random.default_rng(12)
+    ts = (BASE + np.arange(n_cell, dtype=np.float64)[None, :] * DT
+          + rng.integers(-2000, 2001, (S_HOST, n_cell)))
+    vals = np.cumsum(rng.integers(0, 50, (S_HOST, n_cell)).astype(
+        np.float64), axis=1)
+    valid = np.ones((S_HOST, n_cell), bool)
+    valid[3::4, 100:104] = False
+    valid[3::4, 300] = False
+    tiles = tst.AlignedTiles([{} for _ in range(S_HOST)], BASE, DT, valid,
+                             ts, vals)
+    seen = {}
+
+    def capture(cache, key, build, site="tilestore", cost_args=None):
+        seen.update(key=key, build=build, args=cost_args, site=site)
+        return lambda *a: None
+    monkeypatch.setattr(tst, "_jit_lookup", capture)
+    steps = BASE + 600_000 + np.arange(t_cell, dtype=np.int64) * STEP
+    assert tst.groupsum_counters(tiles, func, steps, W,
+                                 np.arange(S_HOST) % G, G) is None
+    monkeypatch.undo()
+    assert seen["site"] == "groupsum"
+    assert seen["key"] == ("groupsum", "holes", func, t_cell, G,
+                           (n_cell, S_HOST))
+    arrs, grid, ids = seen["args"]
+    assert (grid.dtype, grid.shape) == (np.int64, (6,))
+    assert (ids.dtype, ids.shape) == (np.int32, (S_HOST,))
+
+    def sds(a):
+        shape = tuple(s_cell if d == S_HOST else d for d in a.shape)
+        return jax.ShapeDtypeStruct(shape, a.dtype, sharding=one_chip)
+    compiled = seen["build"]().lower(
+        *jax.tree_util.tree_map(sds, (arrs, grid, ids))).compile()
+    text = compiled.as_text()
+    # no Pallas kernel in this program, and what leaves it is [T, G] f32
+    assert "tpu_custom_call" not in text
+    assert re.search(r"ENTRY[^\n]*->\s*\(f32\[31,16\][^\n]*f32\[31,16\]", text)
 
 
 def test_eval_counter_slide_compiles(one_chip, jittered):
