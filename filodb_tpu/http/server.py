@@ -1938,6 +1938,15 @@ class FiloHttpServer:
             "(tiles with holes)",
         "filodb_mesh_dispatches_total":
             "Dispatches served from the mesh-resident sharded store",
+        "filodb_mesh_refused_total":
+            "Queries of the fused shape that a node's mesh-resident store "
+            "turned down (one chip served them), by reason",
+        "filodb_mesh_placements_total":
+            "Selections whose tiles the mesh-resident store put across "
+            "the mesh (builds, not hits)",
+        "filodb_mesh_placement_evictions_total":
+            "Placements of the mesh-resident store dropped to make room "
+            "(built again if asked for once more)",
         "filodb_fused_refused_total":
             "Queries of the fused shape that the fused path refused",
         "filodb_fused_refused_gaps_total":
@@ -2194,6 +2203,14 @@ class FiloHttpServer:
                  getattr(self.backend, "fused_holes_aggs", 0))
             emit("mesh_dispatches_total", {},
                  getattr(self.backend, "mesh_dispatches", 0))
+            for reason, n in sorted(getattr(self.backend, "mesh_refused",
+                                            {}).items()):
+                emit("mesh_refused_total", {"reason": reason}, n)
+            mesh_eval = getattr(self.backend, "mesh_eval", None)
+            emit("mesh_placements_total", {},
+                 getattr(mesh_eval, "placements", 0))
+            emit("mesh_placement_evictions_total", {},
+                 getattr(mesh_eval, "evictions", 0))
             # why the fused path was left, and which aligned family
             # served counters instead (label-free: readers sum labels)
             emit("fused_refused_total", {},

@@ -45,6 +45,7 @@ class IngestionDriver:
                  flush_every_records: Optional[int] = None,
                  flush_interval_s: float = 1.0,
                  poll_interval_s: float = 0.02,
+                 idle_wait_s: Optional[float] = None,
                  on_event: Optional[Callable] = None,
                  max_resident_samples: int = 0,
                  ingest_batch_records: int = 64,
@@ -56,6 +57,16 @@ class IngestionDriver:
         self.flush_every_records = flush_every_records
         self.flush_interval_s = flush_interval_s
         self.poll_interval_s = poll_interval_s
+        # None: an idle driver polls its stream every poll_interval_s (a
+        # stream that another process may append to has no other way to
+        # tell). A number: the stream's only writer is in THIS process
+        # and wakes the driver at every append (``stream.on_append``), so
+        # an idle driver sleeps this long, and never past the time its
+        # next flush is due. A node that owns 128 shards has 128 idle
+        # drivers: at 50 polls a second each they held the interpreter
+        # against the node's own set-up and queries (PERF.md, PR 34)
+        self.idle_wait_s = idle_wait_s
+        self._wake = threading.Event()
         self.on_event = on_event or (lambda *a: None)
         # memory-pressure watermark (0 = no cap): checked after flushes
         self.max_resident_samples = max_resident_samples
@@ -80,6 +91,8 @@ class IngestionDriver:
 
     # -- lifecycle ---------------------------------------------------------
     def start(self) -> "IngestionDriver":
+        if self.idle_wait_s is not None:
+            self.stream.on_append = self._wake.set
         self._thread = threading.Thread(
             target=self._run, name=f"ingest-shard-{self.shard.shard_num}",
             daemon=True)
@@ -88,6 +101,7 @@ class IngestionDriver:
 
     def stop(self, flush: bool = True, timeout: float = 10.0) -> None:
         self._stop.set()
+        self._wake.set()
         if self._thread is not None:
             self._thread.join(timeout)
         if flush and self.next_offset > 0:
@@ -112,10 +126,24 @@ class IngestionDriver:
             while not self._stop.is_set():
                 if not self._ingest_available():
                     self._maybe_flush(force_time_check=True)
-                    self._stop.wait(self.poll_interval_s)
+                    self._idle()
         except Exception:               # pragma: no cover - defensive
             self._set_status(ShardStatus.ERROR)
             raise
+
+    def _idle(self) -> None:
+        """Nothing to ingest: sleep until there may be."""
+        if self.idle_wait_s is None:
+            self._stop.wait(self.poll_interval_s)
+            return
+        wait = self.idle_wait_s
+        if self.next_offset:        # rows to flush: up when that is due
+            due = self._last_flush_t + self.flush_interval_s
+            wait = min(wait, max(0.0, due - time.monotonic()))
+        self._wake.wait(wait)
+        # cleared BEFORE the next read: an append that lands after this
+        # line is either read by that read or wakes the wait after it
+        self._wake.clear()
 
     def _recover(self) -> None:
         """Replay from the checkpoint watermark to the stream end observed

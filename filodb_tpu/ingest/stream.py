@@ -29,7 +29,8 @@ import os
 import struct
 import threading
 from dataclasses import dataclass
-from typing import Dict, Iterator, List, Optional, Sequence, Tuple
+from typing import (Callable, Dict, Iterator, List, Optional, Sequence,
+                    Tuple)
 
 import numpy as np
 
@@ -63,6 +64,10 @@ class IngestionStream:
     """Source abstraction (IngestionStream.scala:14): a sequence of
     RecordContainers with monotonically increasing offsets."""
 
+    # called after an append of THIS process's writer has landed: the
+    # consumer that need not poll for it (ingest/driver.py idle_wait_s)
+    on_append: Optional[Callable[[], None]] = None
+
     def read(self, from_offset: int, max_records: int = 64
              ) -> List[SomeData]:
         """Poll: return up to ``max_records`` batches at/after
@@ -88,7 +93,10 @@ class MemoryIngestionStream(IngestionStream):
     def append(self, container: RecordContainer) -> int:
         with self._lock:
             self._records.append(container)
-            return len(self._records) - 1
+            off = len(self._records) - 1
+        if self.on_append is not None:
+            self.on_append()
+        return off
 
     def read(self, from_offset: int, max_records: int = 64
              ) -> List[SomeData]:
@@ -270,7 +278,10 @@ class LogIngestionStream(IngestionStream):
         new append lands on a record boundary (a CORRUPT tail — bad bytes,
         not just incomplete — is quarantined before the truncate)."""
         with obs_trace.span("wal-append"):
-            return self._append(container, fsync)
+            off = self._append(container, fsync)
+        if self.on_append is not None:
+            self.on_append()
+        return off
 
     def _append(self, container: RecordContainer, fsync: bool) -> int:
         payload = encode_container(container)

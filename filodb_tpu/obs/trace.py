@@ -271,6 +271,8 @@ STAGES: Dict[str, str] = {
     "tile-entry": "tile-cache lookup (a build is its child)",
     "tile-build": "building one aligned-tile cache entry",
     "fused-eligibility": "coverage checks of the fused group-sum path",
+    "mesh-place": "putting one selection's tiles across the mesh (host "
+                  "transposes and device_put; a build, not a hit)",
     "onehot": "group one-hot and kernel operands of the fused path",
     "kernel-build": "evaluator build on a dispatch-table miss (trace + "
                     "compile)",

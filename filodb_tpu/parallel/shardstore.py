@@ -60,6 +60,7 @@ from filodb_tpu.lint.capacity import (capacity, drop_resident,
 from filodb_tpu.lint.contracts import kernel_contract
 from filodb_tpu.lint.locks import guarded_by
 from filodb_tpu.lint.numerics import order_insensitive, precision
+from filodb_tpu.obs import trace as obs_trace
 from filodb_tpu.parallel.mesh import _grouped_reduce, make_mesh
 from filodb_tpu.query.cumsum import cumsum_f64
 
@@ -635,6 +636,9 @@ class ShardedTileEvaluator:
         # id(tiles) -> (weakref to tiles, ShardedTiles)
         self._placed: Dict[int, Tuple[object, ShardedTiles]] = {}
         self.placements = 0          # observability: builds
+        # those that pushed the oldest placement out: it is built again
+        # if its tiles are asked for once more
+        self.evictions = 0
         self.donated_refreshes = 0   # observability: zero-copy appends
 
     @property
@@ -651,7 +655,8 @@ class ShardedTileEvaluator:
             got = self._placed.get(key)
             if got is not None:
                 return got[1]
-        placed = ShardedTiles(self.mesh, tiles)
+        with obs_trace.span("mesh-place", series=len(tiles.keys)):
+            placed = ShardedTiles(self.mesh, tiles)
 
         def _drop(_ref, *, _self=self, _key=key):
             with _self._lock:
@@ -661,6 +666,7 @@ class ShardedTileEvaluator:
         with self._lock:
             while len(self._placed) >= self.MAX_PLACEMENTS:
                 self._placed.pop(next(iter(self._placed)))
+                self.evictions += 1
             self._placed[key] = (ref, placed)
             self.placements += 1
         return placed
@@ -693,6 +699,7 @@ class ShardedTileEvaluator:
     def snapshot(self) -> Dict[str, object]:
         with self._lock:
             return {"placements": self.placements,
+                    "evictions": self.evictions,
                     "resident": len(self._placed),
                     "donated_refreshes": self.donated_refreshes,
                     "devices": self.ndev}

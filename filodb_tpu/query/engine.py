@@ -272,7 +272,10 @@ class _PartitionRead:
 # ---------------------------------------------------------------------------
 
 _MEMO_ENTRIES = 16              # the tile cache's count (tpu._TILE_CACHE_MAX)
-_MEMO_MAX_ROWS = 1 << 22        # rows one entry may count: 16 MiB of offsets
+# rows the entries may count TOGETHER: 256 MiB of offsets, what sixteen
+# entries of 1 << 22 rows come to; one all-store selection (49,152 series x
+# 720 rows = 35 M) may take most of it and push the least recently used out
+_MEMO_MAX_ROWS = 1 << 26
 _MEMO_MAX_GROUPINGS = 8         # (by, without) sets kept with one entry
 
 
@@ -396,7 +399,8 @@ class _MemoEntry:
                 validated_by={"store-version": ("begin", "store")})
 class _SelectMemo:
     """The memo of ``select_raw_series(.., full=True)`` over local shards:
-    at most ``_MEMO_ENTRIES`` entries, least recently used out first. An
+    at most ``_MEMO_ENTRIES`` entries that count at most ``_MEMO_MAX_ROWS``
+    rows together, least recently used out first. An
     entry is served while every shard's version reads as it did before the
     entry's selection ran (core/memstore.py ``_changed``: the version moves
     after a change is visible and before it is acknowledged), the index
@@ -468,14 +472,17 @@ class _SelectMemo:
             # (two selections that both missed: the later takes the place)
             out = [self._entries.pop(entry.key, None)]
             self._entries[entry.key] = entry
+            rows = sum(e.rows for e in self._entries.values())
             for key, old in list(self._entries.items()):
                 # an entry over a shard that has since moved (or left the
                 # store) would never be served: it goes now, and at the
                 # latest the oldest does
                 if old is not entry and (
                         len(self._entries) > _MEMO_ENTRIES
+                        or rows > _MEMO_MAX_ROWS
                         or _store_versions(old.shards) != old.versions):
                     del self._entries[key]
+                    rows -= old.rows
                     out.append(old)
         for old in out:
             if old is not None:

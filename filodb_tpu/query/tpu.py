@@ -503,6 +503,9 @@ class TpuBackend:
         # device-resident tiles — bit-for-bit the single-device values
         self.mesh_eval = mesh_eval
         self.mesh_dispatches = 0    # observability: sharded dispatches
+        # queries of the fused shape that the mesh store turned down and
+        # ONE chip served, by what turned them down (_mesh_sharded)
+        self.mesh_refused = {"tiles": 0, "grid": 0, "family": 0}
         self._tile_cache: Dict = {}
         # guards cache get/insert/evict against concurrent HTTP query
         # threads (non-atomic FIFO evict could KeyError, inserts overshoot)
@@ -970,8 +973,8 @@ class TpuBackend:
                                                window_ms, offset_ms)
         mesh_st = None
         if not func_args:
-            mesh_st = self._mesh_sharded(tiles, func, steps, window_ms,
-                                         offset_ms, family)
+            mesh_st, _ = self._mesh_sharded(tiles, func, steps, window_ms,
+                                            offset_ms, family)
         w0e = int(steps[0] - offset_ms)
         w0s = w0e - window_ms
         step = int(steps[1] - steps[0]) if nsteps > 1 else 1
@@ -992,24 +995,27 @@ class TpuBackend:
 
     def _mesh_sharded(self, tiles, func: str, steps, window_ms: int,
                       offset_ms: int, family):
-        """The device-resident sharded placement serving this dispatch,
-        or None for the single-device path. Counter families route only
-        when the single-device dispatcher would pick the f32-hybrid
-        slide/fast evaluator (identical values), so mesh-on vs mesh-off
-        responses stay byte-identical; the exact-f64 wide-grid family
-        keeps the single-device path."""
+        """-> (the device-resident sharded placement serving this
+        dispatch, None) or (None, what turned a mesh node's store down;
+        None on a node without one) for the single-device path. Counter
+        families route only when the single-device dispatcher would pick
+        the f32-hybrid slide/fast evaluator (identical values), so
+        mesh-on vs mesh-off responses stay byte-identical; the exact-f64
+        wide-grid family keeps the single-device path ("family"), as do
+        tiles with holes or a span past int32 ms ("tiles") and a grid
+        that leaves int32 ms from the tile base ("grid")."""
         me = self.mesh_eval
         if me is None or tiles is None:
-            return None
+            return None, None
         if family is not None and family[0] not in ("slide", "fast"):
-            return None
+            return None, "family"
         st = me.place(tiles)
         if st is None:
-            return None
+            return None, "tiles"
         if family is not None and not st.query_fits(
                 np.asarray(steps), window_ms, offset_ms):
-            return None
-        return st
+            return None, "grid"
+        return st, None
 
     def _aligned_run(self, tiles, func: str, family, nsteps: int,
                      step: int, window_ms: int, offset_ms: int,
@@ -1144,10 +1150,14 @@ class TpuBackend:
             # eligibility as the per-series sharded path
             mesh_st = None
             if self.mesh_eval is not None and steps.size >= 1:
-                mesh_st = self._mesh_sharded(
+                # (a placement the store has to build is the mesh-place
+                # stage, a child of this one)
+                mesh_st, why_not = self._mesh_sharded(
                     tiles, func, steps, window_ms, offset_ms,
                     tst.counters_batch_family(tiles, func, steps,
                                               window_ms, offset_ms))
+                if why_not is not None:
+                    self.mesh_refused[why_not] += 1
         if mesh_st is None and on_cpu and not FUSED_GROUPSUM_INTERPRET:
             # the mesh store places dense tiles only
             self.fused_refused_gaps += not tiles._dense

@@ -1067,9 +1067,15 @@ class FiloServer:
         adoption, planned adoption, and handoff rollback so a shard's
         writer is always built the same way."""
         from filodb_tpu.ingest import IngestionDriver
+        # a node on its own is its streams' only writer (its gateway,
+        # _start_ingestion) and wakes a driver at every append; with
+        # peers another node's gateway appends to this node's logs and
+        # a driver can only poll for that
+        alone = int(self.config.get("num-nodes", 1)) == 1
         return IngestionDriver(
             self.store.get_shard(self.ref, shard), stream,
             mapper=self.mapper,
+            idle_wait_s=0.5 if alone else None,
             flush_every_records=self.config.get("flush-every-records"),
             flush_interval_s=float(self.config.get("flush-interval-s",
                                                    2.0)),

@@ -4,9 +4,11 @@ flushes, checkpoint watermark recovery, shard status FSM transitions.
 (Parity model: coordinator/src/test IngestionStreamSpec +
 IngestionActor.scala:174-345 recovery protocol.)"""
 
+import tempfile
 import time
 
 import numpy as np
+import pytest
 
 from filodb_tpu.core.memstore import TimeSeriesShard
 from filodb_tpu.core.record import RecordBuilder
@@ -213,3 +215,98 @@ def test_ingest_batch_records_recovery_replay(tmp_path):
         results.append((shard.stats.rows_ingested,
                         shard.ingest_watermark_ms))
     assert results[0] == results[1]
+
+
+# -- an idle driver that its stream wakes (idle_wait_s) ------------------------
+
+class _CountedReads(MemoryIngestionStream):
+    """A stream that counts its polls."""
+    reads = 0
+
+    def read(self, from_offset, max_records=64):
+        self.reads += 1
+        return super().read(from_offset, max_records)
+
+
+def _idle_driver(stream, **kw):
+    shard = TimeSeriesShard(REF, DEFAULT_SCHEMAS, 0, num_groups=2,
+                            max_chunk_rows=64)
+    mapper = ShardMapper(1)
+    drv = IngestionDriver(shard, stream, mapper=mapper, **kw).start()
+    assert _wait(lambda: mapper.status(0) is ShardStatus.ACTIVE)
+    return shard, drv
+
+
+@pytest.mark.parametrize("make", [
+    MemoryIngestionStream,
+    lambda: LogIngestionStream(tempfile.mkdtemp() + "/s/stream.log",
+                               DEFAULT_SCHEMAS)], ids=["memory", "log"])
+def test_an_append_wakes_the_idle_driver_at_once(make):
+    """A sleep of 30 s that an append ends: nothing else explains rows
+    ingested inside two."""
+    stream = make()
+    shard, drv = _idle_driver(stream, idle_wait_s=30.0, flush_interval_s=60)
+    try:
+        time.sleep(0.1)                 # the driver is asleep by now
+        _publish(stream, n_batches=3, rows_per_batch=20)
+        assert _wait(lambda: drv.next_offset == 3, timeout=2.0)
+        assert shard.stats.rows_ingested == 60
+        time.sleep(0.1)
+        _publish(stream, n_batches=2, rows_per_batch=20)    # and again
+        assert _wait(lambda: drv.next_offset == 5, timeout=2.0)
+    finally:
+        t = time.monotonic()
+        drv.stop()
+        assert time.monotonic() - t < 2.0       # stop wakes it too
+
+
+@pytest.mark.parametrize("idle_wait_s,least,most", [(None, 8, 10**9),
+                                                    (5.0, 0, 1)])
+def test_an_idle_driver_that_is_woken_does_not_poll(idle_wait_s, least,
+                                                    most):
+    """0.4 s of an empty stream: a polling driver reads it every 20 ms,
+    a woken one not at all (once, if it was still on its way to sleep)."""
+    stream = _CountedReads()
+    _, drv = _idle_driver(stream, idle_wait_s=idle_wait_s)
+    try:
+        before = stream.reads
+        time.sleep(0.4)
+        assert least <= stream.reads - before <= most
+    finally:
+        drv.stop()
+
+
+def test_an_idle_driver_is_up_when_its_flush_is_due():
+    """Rows ingested and a flush interval of 0.3 s: the time-based flush
+    is not held back by an idle wait of 30 s."""
+    stream = MemoryIngestionStream()
+    shard, drv = _idle_driver(stream, idle_wait_s=30.0, flush_interval_s=0.3)
+    try:
+        _publish(stream, n_batches=2, rows_per_batch=20)
+        assert _wait(lambda: drv.next_offset == 2, timeout=2.0)
+        done = shard.stats.flushes_done
+        # one group an interval, round-robin: two more within 1.5 s
+        assert _wait(lambda: shard.stats.flushes_done >= done + 2,
+                     timeout=1.5)
+    finally:
+        drv.stop()
+
+
+@pytest.mark.parametrize("nodes,want", [(1, 0.5), (2, None)])
+def test_a_node_on_its_own_wakes_its_drivers(tmp_path, nodes, want):
+    from filodb_tpu.standalone.server import FiloServer
+    cfg = {"num-shards": 4, "port": 0, "grpc-port": None,
+           "stream-dir": str(tmp_path / "streams"),
+           "data-dir": str(tmp_path / "data")}
+    if nodes > 1:
+        cfg.update({"num-nodes": nodes, "node-ordinal": 0,
+                    "peers": {"node1": "http://127.0.0.1:1"}})
+    srv = FiloServer(cfg).start()
+    try:
+        assert srv.drivers
+        for shard, drv in srv.drivers.items():
+            assert drv.idle_wait_s == want
+            assert (srv.streams[shard].on_append is not None) \
+                == (want is not None)
+    finally:
+        srv.stop()

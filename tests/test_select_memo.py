@@ -642,6 +642,36 @@ def test_the_memo_stays_inside_its_caps(monkeypatch):
         assert len(entry.groups) <= eng._MEMO_MAX_GROUPINGS
 
 
+def test_the_row_cap_is_over_all_entries_together(monkeypatch):
+    """One budget of rows for the whole memo: a selection as large as the
+    budget is kept (an all-store board's 35 M rows under the 1 << 26 there
+    are), and what no longer fits beside a new entry goes, least recently
+    used first."""
+    assert eng._MEMO_MAX_ROWS == 1 << 26
+    shard = TimeSeriesShard(REF, DEFAULT_SCHEMAS, 0, max_chunk_rows=100)
+    _ingest(shard, "flushed", 0, 120, n_series=24)
+    shard.flush_all()
+    metric = ColumnFilter.eq("_metric_", "reqs_total")
+    one = [[metric, ColumnFilter.eq("instance", f"i{s}")] for s in range(4)]
+    half = [[metric, ColumnFilter.regex("instance", pat)]
+            for pat in ("i(\\d|1[01])", "i(1[2-9]|2\\d)")]
+    monkeypatch.setattr(eng, "_MEMO_MAX_ROWS", 24 * 120)
+    for f in one + half:            # 4 x 120 rows, then 2 x 12 x 120
+        got, _, hit = _select([shard], f, 0, 2**62)
+        assert not hit and len(got) in (1, 12)
+        assert sum(e.rows for e in select_memo._entries.values()) \
+            <= 24 * 120
+    # the two halves fill the budget: the four single series went
+    assert [e.rows for e in select_memo._entries.values()] == [12 * 120] * 2
+    assert all(_select([shard], f, 0, 2**62)[2] for f in half)
+    assert not any(_select([shard], f, 0, 2**62)[2] for f in one[:1])
+    # the whole fleet takes the budget alone
+    whole = _filters("flushed")
+    assert [_select([shard], whole, 0, 2**62)[2] for _ in range(2)] == \
+        [False, True]
+    assert [e.rows for e in select_memo._entries.values()] == [24 * 120]
+
+
 def test_timestamps_wider_than_32_bits_are_counted_exactly():
     shard = TimeSeriesShard(REF, DEFAULT_SCHEMAS, 0, max_chunk_rows=100)
     b = RecordBuilder(DEFAULT_SCHEMAS)
