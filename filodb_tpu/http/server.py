@@ -1967,6 +1967,11 @@ class FiloHttpServer:
             "Whole-series selections answered by the selection memo",
         "filodb_select_memo_misses_total":
             "Whole-series selections over local shards that ran the loop",
+        "filodb_selection_facts_hits_total":
+            "Requests that took tile key, tail bound and histogram flag "
+            "from the selection memo's entry (no pass over the series)",
+        "filodb_selection_facts_misses_total":
+            "Requests that made the pass over their selection's series",
         "filodb_exec_cache_hits_total": "Compiled-executable reuse hits",
         "filodb_exec_cache_misses_total": "Compiled-executable retraces",
         "filodb_exec_cache_entries": "Distinct compiled kernel shapes",
@@ -2246,6 +2251,8 @@ class FiloHttpServer:
         emit("select_series_read_total", {}, select_counts.reads)
         emit("select_memo_hits_total", {}, select_counts.memo_hits)
         emit("select_memo_misses_total", {}, select_counts.memo_misses)
+        emit("selection_facts_hits_total", {}, select_counts.facts_hits)
+        emit("selection_facts_misses_total", {}, select_counts.facts_misses)
         pc = self.plan_cache.snapshot()
         emit("plan_cache_entries", {}, pc["entries"])
         emit("plan_cache_hits_total", {}, pc["hits"])

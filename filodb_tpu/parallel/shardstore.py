@@ -63,6 +63,7 @@ from filodb_tpu.lint.numerics import order_insensitive, precision
 from filodb_tpu.obs import trace as obs_trace
 from filodb_tpu.parallel.mesh import _grouped_reduce, make_mesh
 from filodb_tpu.query.cumsum import cumsum_f64
+from filodb_tpu.query.model import PerGrouping
 
 # cache inventory (graftlint): the sharded-evaluator dispatch table
 # memoizes compiled shard_map programs keyed purely on (kernel family,
@@ -367,6 +368,9 @@ class ShardedTiles:
         self._cv = place(cv.T, np.float64)
         # non-counter aligned channel placements, per function family
         self._aligned: Dict[Tuple, Dict[str, jnp.ndarray]] = {}
+        # the padded group ids on the devices, per tile-order vector the
+        # backend's tile entry handed out (``_row_gids``)
+        self._gids = PerGrouping()
         # runtime residency accounting: live device bytes under the
         # filodb_device_memory_bytes{family,shard} gauge, dropped when
         # the store is collected
@@ -512,6 +516,21 @@ class ShardedTiles:
             self.mesh, func, t_local, b_pad, sig), cost_args=args)
         return fn(*args)[:, :self.S, :nsteps]
 
+    def _put_gids(self, gids: np.ndarray) -> jnp.ndarray:
+        g = np.full(self.S_pad, -1, dtype=np.int32)     # -1 = padding rows
+        g[:self.S] = gids
+        row = NamedSharding(self.mesh, P(self.mesh.axis_names[0]))
+        return jax.device_put(g, row)
+
+    def _row_gids(self, gids) -> jnp.ndarray:
+        """``gids`` (tile order) padded to ``int32[S_pad]`` and put on the
+        devices with the row sharding: once per frozen vector (the
+        backend's tile entry hands every request of one grouping the SAME
+        read-only one), so that a request sends its grid scalars and
+        nothing else. The copies go with the placement, and on
+        ``append_slots``. Any other array is put as it comes."""
+        return self._gids.get(gids, self._put_gids)
+
     def eval_grouped(self, func: str, steps: np.ndarray, window_ms: int,
                      gids: np.ndarray, num_groups: int, agg: str = "sum",
                      offset_ms: int = 0) -> np.ndarray:
@@ -519,11 +538,8 @@ class ShardedTiles:
         off the resident store: one-hot matmul + psum over the shard
         axis -> [G, T] numpy."""
         t_local, w0s, w0e, step = self._grid(steps, window_ms, offset_ms)
-        g = np.full(self.S_pad, -1, dtype=np.int32)   # -1 = padding rows
-        g[:self.S] = np.asarray(gids, dtype=np.int32)
-        row = NamedSharding(self.mesh, P(self.mesh.axis_names[0]))
         vv = self._cv if func in ("rate", "increase") else self._v
-        args = (self._tsr, vv, jax.device_put(g, row),
+        args = (self._tsr, vv, self._row_gids(gids),
                 np.int64(self.n_filled), np.int64(self.base_ms),
                 np.int64(self.dt_ms), w0s, w0e, step)
         args = (agg,) + args
@@ -539,13 +555,12 @@ class ShardedTiles:
         """Enqueue the fused `sum by (g)` program off the resident store
         -> device (sums [T_pad, G], counts [T_pad, G]); the caller syncs
         and cuts to ``steps.size`` rows (``eval_grouped_pair`` does
-        both)."""
+        both). The program, its key and its arguments' shapes and dtypes
+        are the same whether ``gids`` was on the devices already
+        (``_row_gids``) or is put now."""
         t_local, w0s, w0e, step = self._grid(steps, window_ms, offset_ms)
-        g = np.full(self.S_pad, -1, dtype=np.int32)
-        g[:self.S] = np.asarray(gids, dtype=np.int32)
-        row = NamedSharding(self.mesh, P(self.mesh.axis_names[0]))
         vv = self._cv if func in ("rate", "increase") else self._v
-        args = (self._tsr, vv, jax.device_put(g, row),
+        args = (self._tsr, vv, self._row_gids(gids),
                 np.int64(self.n_filled), np.int64(self.base_ms),
                 np.int64(self.dt_ms), w0s, w0e, step)
         key = ("mesh-grouped-pair", func, t_local, num_groups,
@@ -608,6 +623,9 @@ class ShardedTiles:
             np.int64(self.n_filled))
         self.n_filled = n_new
         self._aligned.clear()   # row-major placements are per-snapshot
+        # the new tiles come with a tile entry, and so tile-order vectors,
+        # of their own: the old ones' copies would never be asked for
+        self._gids.kept.clear()
         self._record_residency()
         return True
 

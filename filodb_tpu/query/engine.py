@@ -30,10 +30,12 @@ from filodb_tpu.obs import trace as obs_trace
 from filodb_tpu.memory.vectors import counter_correction
 from filodb_tpu.query import logical as lp
 from filodb_tpu.query import rangefn as rf
-from filodb_tpu.query.model import (GridResult, QueryError, QueryLimits,
-                                    QueryStats, RangeParams, RawSeries,
-                                    ScalarResult, StaleRoutingError,
-                                    clip_series)
+from filodb_tpu.query.model import (MAX_GROUPINGS, GridResult, QueryError,
+                                    QueryLimits, QueryStats, RangeParams,
+                                    RawSeries, ScalarResult,
+                                    SelectionFacts, StaleRoutingError,
+                                    clip_series, select_counts,
+                                    selection_facts)
 
 METRIC_LABELS = ("_metric_", "__name__")
 
@@ -171,25 +173,6 @@ def _resolve_column(schema, column: Optional[str]):
     raise QueryError(f"schema {schema.name} has no column {name}")
 
 
-class _SelectCounts:
-    """``filodb_select_series_total`` / ``_read_total``: handles a
-    ``full=True`` selection handed out, and handles whose samples some
-    consumer then read; ``filodb_select_memo_{hits,misses}_total``: such
-    selections over local shards that the memo answered, and that ran the
-    loop. Plain adds, like the backend's counters."""
-
-    __slots__ = ("handles", "reads", "memo_hits", "memo_misses")
-
-    def __init__(self):
-        self.handles = 0
-        self.reads = 0
-        self.memo_hits = 0
-        self.memo_misses = 0
-
-
-select_counts = _SelectCounts()
-
-
 def _partition_handle(shard, part, ci: int, col, les, is_hist: bool,
                       start_ms: int, end_ms: int,
                       entry: "Optional[_MemoEntry]") -> Tuple[RawSeries, int]:
@@ -276,7 +259,7 @@ _MEMO_ENTRIES = 16              # the tile cache's count (tpu._TILE_CACHE_MAX)
 # entries of 1 << 22 rows come to; one all-store selection (49,152 series x
 # 720 rows = 35 M) may take most of it and push the least recently used out
 _MEMO_MAX_ROWS = 1 << 26
-_MEMO_MAX_GROUPINGS = 8         # (by, without) sets kept with one entry
+_MEMO_MAX_GROUPINGS = MAX_GROUPINGS     # (by, without) sets of one entry
 
 
 class Selection(list):
@@ -302,13 +285,15 @@ class _MemoEntry:
     """What one selection learned that the next one for the same (shards,
     filters, column) would learn again, while no shard's version moves:
     the handles in selection order, the ranges the index match holds for,
-    the group ids per (by, without), and, built at the first reuse, every
-    timestamp of the selection in one sorted array, so the rows of any
-    [start_ms, end_ms] are two searches and no loop over partitions.
-    Facts only: it is dropped at the first read of one of its handles."""
+    the group ids per (by, without), what a request derives from the
+    handles alone (``facts``: query/model.py ``selection_facts`` fills and
+    reads the slot), and, built at the first reuse, every timestamp of the
+    selection in one sorted array, so the rows of any [start_ms, end_ms]
+    are two searches and no loop over partitions. Facts only: it is
+    dropped at the first read of one of its handles."""
 
     __slots__ = ("key", "shards", "versions", "held", "reads", "rows",
-                 "lo", "hi", "base", "span", "offsets", "groups")
+                 "lo", "hi", "base", "span", "offsets", "groups", "facts")
 
     def __init__(self, key, shards, versions):
         self.key = key
@@ -328,6 +313,9 @@ class _MemoEntry:
         self.base = self.span = 0
         self.offsets: Optional[np.ndarray] = None
         self.groups: Dict[Tuple, Tuple] = {}
+        # served only while ``held`` is: nothing of it (the tile key, not
+        # the tiles) keeps a tile-cache entry alive
+        self.facts: Optional[SelectionFacts] = None
 
     def covers(self, cover: Optional[Tuple[int, int]]) -> None:
         """Fold one shard's cover in."""
@@ -348,6 +336,7 @@ class _MemoEntry:
         self.reads = []
         self.offsets = None
         self.groups = {}
+        self.facts = None
 
     def rows_between(self, reads: List[_PartitionRead], start_ms: int,
                      end_ms: int) -> Optional[int]:
@@ -1482,14 +1471,18 @@ class QueryEngine:
             self.shards, raw.filters, fetch_start, fetch_end, raw.column,
             self.stats, full=True, limits=self.limits)
         params = RangeParams(inner.start_ms, inner.step_ms, inner.end_ms)
-        res = None
-        if series and not any(s.is_hist for s in series):
+        res = facts = None
+        if series:
+            # taken once a request and handed down: on a memoised
+            # selection nothing below walks the series again
+            facts = selection_facts(series)
+        if facts is not None and not facts.any_hist:
             with obs_trace.span("group-keys"):
                 gids, gkeys = _selection_groups(series, tuple(plan.by),
                                                 tuple(plan.without))
             res = self.backend.fused_groupsum(
                 series, inner.function, params.steps, inner.window_ms,
-                inner.offset_ms, gids, len(gkeys))
+                inner.offset_ms, gids, len(gkeys), facts)
         if res is not None:
             with obs_trace.span("aggregate", op=plan.op, path="fused"):
                 sums, cnts = res                       # [T, G]
@@ -1508,7 +1501,7 @@ class QueryEngine:
         if self.backend is not None:
             grid = self.backend.periodic_samples(
                 series, params, inner.function, inner.window_ms, (),
-                inner.offset_ms)
+                inner.offset_ms, facts)
         if grid is None:
             grid = periodic_samples(
                 clip_series(series, fetch_start, fetch_end), params,

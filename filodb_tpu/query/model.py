@@ -164,6 +164,170 @@ class RawSeries:
         return int(self._ts[-1]) if self._ts.size else None
 
 
+class _SelectCounts:
+    """``filodb_select_series_total`` / ``_read_total``: handles a
+    ``full=True`` selection handed out, and handles whose samples some
+    consumer then read; ``filodb_select_memo_{hits,misses}_total``: such
+    selections over local shards that the memo answered, and that ran the
+    loop; ``filodb_selection_facts_{hits,misses}_total``: requests that
+    took their ``SelectionFacts`` from the memo entry, and that made the
+    pass over the series. Plain adds, like the backend's counters."""
+
+    __slots__ = ("handles", "reads", "memo_hits", "memo_misses",
+                 "facts_hits", "facts_misses")
+
+    def __init__(self):
+        self.handles = 0
+        self.reads = 0
+        self.memo_hits = 0
+        self.memo_misses = 0
+        self.facts_hits = 0
+        self.facts_misses = 0
+
+
+select_counts = _SelectCounts()
+
+# groupings kept with one selection, wherever it lives: the (by, without)
+# sets of a memo entry, the tile-order group ids of a tile entry, the
+# device-resident ids of a mesh placement
+MAX_GROUPINGS = 8
+
+
+class PerGrouping:
+    """What is derived from a group-id array, kept per FROZEN array. The
+    selection memo hands every holder of a selection the same read-only ids
+    per (by, without), and a keeper of this kind hands out read-only arrays
+    in its turn, so an array's identity names its grouping; the reference
+    kept beside the id keeps the id from being recycled. At most
+    ``MAX_GROUPINGS`` (then all go: each is made again when asked for). An
+    array that is not frozen is nobody's: made as it comes, not kept."""
+
+    __slots__ = ("kept",)
+
+    def __init__(self):
+        self.kept: Dict[int, Tuple[np.ndarray, object]] = {}
+
+    def get(self, gids, make):
+        """``make(gids)``, once per frozen ``gids``."""
+        gids = np.asarray(gids)
+        if gids.flags.writeable:
+            return make(gids)
+        got = self.kept.get(id(gids))
+        if got is not None and got[0] is gids:
+            return got[1]
+        made = make(gids)
+        if len(self.kept) >= MAX_GROUPINGS:
+            self.kept.clear()
+        # (two threads' first requests: both take the one that landed)
+        return self.kept.setdefault(id(gids), (gids, made))[1]
+
+
+class TileKey:
+    """A selection's key in the device tile cache: its snapshot keys (or
+    object ids) as one tuple, hashed once. Two keys made from two selections
+    of the same store are equal tuple by tuple (24,576 compares); the
+    backend swaps a ``SelectionFacts``' key for the cache's own at the
+    first hit, so every later lookup ends at ``is``."""
+
+    __slots__ = ("parts", "_hash")
+
+    def __init__(self, parts: Tuple):
+        self.parts = parts
+        self._hash = hash(parts)
+
+    def __hash__(self) -> int:
+        return self._hash
+
+    def __eq__(self, other) -> bool:
+        if self is other:
+            return True
+        if not isinstance(other, TileKey):
+            return NotImplemented
+        return self._hash == other._hash and self.parts == other.parts
+
+
+class SelectionFacts:
+    """What a request derives from its selection alone, and not from its
+    grid, in one place and from one function, so that a selection that is
+    shared (the selection memo, query/engine.py) is walked once and not
+    once a request:
+
+    ``key``       the tile-cache key: the snapshot keys where every series
+                  carries one (``use_snap``: pinned content), else the
+                  series' object ids;
+    ``ident``     the snapshot keys minus the chunk-count field, stable
+                  across flushes for the same partitions and column (None
+                  without ``use_snap``);
+    ``tail_min``  the earliest timestamp beyond any series' chunk prefix
+                  (None: no tail anywhere), from the facts: nothing is read;
+    ``any_hist``  is any series a histogram.
+
+    They are as fresh as the handles they were read from: a read may
+    rewrite a handle's facts (a partition evicted or paged in under it), so
+    whoever reads the samples makes them again afterwards."""
+
+    __slots__ = ("key", "ident", "tail_min", "any_hist")
+
+    def __init__(self, series: Sequence[RawSeries]):
+        keys = [s.snapshot_key for s in series]
+        if None not in keys:
+            self.key = TileKey(tuple(keys))
+            self.ident = tuple([k[:3] + k[4:] for k in keys])
+        else:
+            self.key = TileKey(tuple([id(s) for s in series]))
+            self.ident = None
+        tails = [t for t in [s.tail_first_ts for s in series]
+                 if t is not None]
+        self.tail_min = min(tails) if tails else None
+        self.any_hist = True in [s.is_hist for s in series]
+
+    @property
+    def use_snap(self) -> bool:
+        return self.ident is not None
+
+    def tail_bound(self, cov_min_ms: Optional[int]) -> Optional[int]:
+        """``tail_min``, or a tile entry's coverage bound if that is
+        earlier (None: neither)."""
+        tm = self.tail_min
+        if tm is None or cov_min_ms is not None and cov_min_ms < tm:
+            return cov_min_ms
+        return tm
+
+
+def selection_facts(series: Sequence[RawSeries]) -> SelectionFacts:
+    """The facts of ``series``, once a request: from the memo entry the
+    selection is shared through, if it has one that is still served and
+    some holder has made them (a hit: no pass over the series), else made
+    here (a miss) and left there for the next holder. A plain list, a
+    selection off remote shards or one whose entry was dropped makes them
+    the same way.
+
+    Exactly as fresh as the handles: the entry is dropped at the first read
+    of one of them (before that read may rewrite a snapshot key), when a
+    shard's version moves, and on ``clear()``; a dropped entry's slot is
+    never read again. A holder that took the facts before a concurrent drop
+    is where a holder that built its key before one always was: the tiles
+    under the old key hold the old key's pinned content. No lock: the slot
+    is one attribute, read once and written once.
+
+    A selection that is used once (its consumer reads the handles, so its
+    entry dies with the request) pays the one pass here and no more than
+    before: the tile build makes its own after it read, as the key was
+    built before and after a build."""
+    entry = getattr(series, "entry", None)
+    served = entry is not None and entry.held is not None
+    if served:
+        facts = entry.facts         # once: a drop empties the slot
+        if facts is not None:
+            select_counts.facts_hits += 1
+            return facts
+    facts = SelectionFacts(series)
+    select_counts.facts_misses += 1
+    if served:
+        entry.facts = facts
+    return facts
+
+
 def clip_series(series: Sequence[RawSeries], start_ms: int, end_ms: int
                 ) -> List[RawSeries]:
     """Restrict each series to samples in [start_ms, end_ms] (views, no

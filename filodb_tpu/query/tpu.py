@@ -53,8 +53,9 @@ from filodb_tpu.query import tilestore as tst
 from filodb_tpu.query.batcher import (MicroBatcher, SplitResult,
                                       transfer_counts)
 from filodb_tpu.query.cumsum import cumsum_f64
-from filodb_tpu.query.model import (GridResult, RangeParams, RawSeries,
-                                    clip_series)
+from filodb_tpu.query.model import (GridResult, PerGrouping, RangeParams,
+                                    RawSeries, SelectionFacts, clip_series,
+                                    selection_facts)
 from filodb_tpu.query.tilestore import _extrapolated_rate
 
 
@@ -424,18 +425,34 @@ class _TileEntry:
     plus the coverage bound that makes stale serves correct."""
 
     __slots__ = ("tiles", "idx", "prefix_has_nan", "refs", "cov_min_ms",
-                 "built_ends", "ident_key")
+                 "built_ends", "key", "ident_key", "gvecs")
 
     def __init__(self, tiles, idx, prefix_has_nan, refs, cov_min_ms,
-                 built_ends=(), ident_key=None):
+                 built_ends=(), ident_key=None, gvecs=None):
         self.tiles = tiles
-        self.idx = idx
+        # selection index of each tile row, int64 from the build on
+        self.idx = np.asarray(idx, dtype=np.int64)
         self.prefix_has_nan = prefix_has_nan
         self.refs = refs
         self.cov_min_ms = cov_min_ms    # first ms NOT in tiles; None=all
         # per series, the last ms the tiles hold (None: no sample of it)
         self.built_ends = built_ends
+        self.key = None                 # the cache's own key object
         self.ident_key = ident_key
+        # the tile-order group ids per grouping the memo handed out
+        self.gvecs = PerGrouping() if gvecs is None else gvecs
+
+    def _gather(self, gids: np.ndarray) -> np.ndarray:
+        gvec = gids[self.idx]
+        # frozen like the ids it came from, so that the mesh placement may
+        # keep its device copy by the same rule
+        gvec.setflags(write=gids.flags.writeable)
+        return gvec
+
+    def tile_order(self, gids) -> np.ndarray:
+        """``gids[idx]``: the group ids in tile order, which both fused
+        device paths take; gathered once per frozen ``gids``."""
+        return self.gvecs.get(gids, self._gather)
 
     def stale_view(self, series) -> "_TileEntry":
         """This entry serving ``series``, a NEWER snapshot of the same
@@ -453,7 +470,7 @@ class _TileEntry:
                 bound = t if bound is None else min(bound, t)
         return _TileEntry(self.tiles, self.idx, self.prefix_has_nan,
                           self.refs, bound, self.built_ends,
-                          self.ident_key)
+                          self.ident_key, self.gvecs)
 
 
 class _PackedMember:
@@ -568,13 +585,18 @@ class TpuBackend:
     def periodic_samples(self, series: Sequence[RawSeries],
                          params: RangeParams, function: str, window_ms: int,
                          func_args: Sequence[float] = (),
-                         offset_ms: int = 0) -> Optional[GridResult]:
+                         offset_ms: int = 0,
+                         facts: Optional[SelectionFacts] = None
+                         ) -> Optional[GridResult]:
         """Returns None to signal fallback to the numpy oracle (histograms,
-        unsupported functions)."""
+        unsupported functions). ``facts``: the selection's, where the
+        caller has taken them (``selection_facts``: once a request)."""
         func = function or "last_sample"
         if func not in DEVICE_FUNCS or not series:
             return None
-        if any(s.is_hist for s in series):
+        if facts is None:
+            facts = selection_facts(series)
+        if facts.any_hist:
             return None
         steps = params.steps
         nsteps = steps.size
@@ -587,7 +609,7 @@ class TpuBackend:
         try:
             with obs_trace.span("device-eval", func=func,
                                 series=len(series)) as _sp:
-                aligned = self._try_aligned(series, func, steps,
+                aligned = self._try_aligned(series, facts, func, steps,
                                             params.step_ms, window_ms,
                                             offset_ms, func_args)
                 if aligned is not None:
@@ -759,42 +781,37 @@ class TpuBackend:
     def _prefix_len(s) -> int:
         return s.chunk_len if s.chunk_len >= 0 else s.ts.size
 
-    @staticmethod
-    def _tail_min(series, bound: Optional[int]) -> Optional[int]:
-        """The earliest timestamp beyond any series' chunk prefix, or
-        ``bound`` if that is earlier (None: no tail anywhere). From the
-        series' facts: no samples are read."""
-        for s in series:
-            tm = s.tail_first_ts
-            if tm is not None and (bound is None or tm < bound):
-                bound = tm
-        return bound
-
-    def _build_tile_entry(self, series, use_snap: bool):
+    def _build_tile_entry(self, series):
         """Build one tile-cache entry over the series' immutable chunk
-        prefixes. ``cov_min_ms`` records the first timestamp NOT covered
+        prefixes -> (entry, the selection's facts as they read AFTER the
+        build read the samples: a partition evicted or paged in under its
+        handle took its facts again with them, so the tiles go under the
+        key, and serve under the tail bound, of what they were built
+        from). ``cov_min_ms`` records the first timestamp NOT covered
         by the tiles (None = full coverage): consumers must route steps
         whose windows reach past it through the packed path — this is
         what makes serving a STALE entry correct while a flush's rebuild
         runs in the background."""
         with obs_trace.span("tile-build", series=len(series)):
-            return self._build_tile_entry_inner(series, use_snap)
+            return self._build_tile_entry_inner(series)
 
-    def _build_tile_entry_inner(self, series, use_snap: bool):
+    def _build_tile_entry_inner(self, series):
         prefix = [
             RawSeries(s.labels, s.ts[:self._prefix_len(s)],
                       s.values[:self._prefix_len(s)], s.is_counter,
                       s.bucket_les)
             for s in series
         ]
-        cov_min = self._tail_min(series, None)
+        facts = SelectionFacts(series)
         tiles, idx = tst.build_aligned_tiles(prefix)
         self.tile_builds += 1
         prefix_has_nan = any(np.isnan(p.values).any() for p in prefix)
-        return _TileEntry(tiles, idx, prefix_has_nan,
-                          None if use_snap else list(series), cov_min,
-                          built_ends=[int(p.ts[-1]) if p.ts.size else None
-                                      for p in prefix])
+        entry = _TileEntry(tiles, idx, prefix_has_nan,
+                           None if facts.use_snap else list(series),
+                           facts.tail_min,
+                           built_ends=[int(p.ts[-1]) if p.ts.size else None
+                                       for p in prefix])
+        return entry, facts
 
     @capacity(
         "device-tile-cache", bytes_per_sample=17.0,
@@ -811,18 +828,24 @@ class TpuBackend:
                 if old is not None and \
                         self._tile_ident.get(old.ident_key) == old_key:
                     self._tile_ident.pop(old.ident_key, None)
+            entry.key = key
             entry.ident_key = ident
             self._tile_cache[key] = entry
             if ident is not None:
                 self._tile_ident[ident] = key
 
-    def _tile_entry(self, series):
-        """Cache of (tiles, idx) built over each series' IMMUTABLE chunk
-        prefix. Keyed by store snapshot keys when the selection carries them
-        (dataset, shard, part_id, num_chunks — pinned content, so the cache
-        hits across queries until a flush publishes new chunks); falls back
-        to object identity (holding refs so ids can't be recycled) for
-        ad-hoc series. Bounded FIFO.
+    def _tile_entry(self, series, facts: SelectionFacts):
+        """-> (entry, facts). Cache of (tiles, idx) built over each series'
+        IMMUTABLE chunk prefix. Keyed by store snapshot keys when the
+        selection carries them (dataset, shard, part_id, num_chunks —
+        pinned content, so the cache hits across queries until a flush
+        publishes new chunks); falls back to object identity (holding refs
+        so ids can't be recycled) for ad-hoc series. Bounded FIFO.
+
+        The key, like the tail bound the callers fold in, comes from
+        ``facts`` (``selection_facts``): on a memoised selection no request
+        walks the series for it. A hit returns ``facts`` as given; a build
+        returns the facts made AFTER it read the samples.
 
         A flush changes num_chunks and would historically stall the next
         query ~tens of ms rebuilding tiles. Now the PREVIOUS snapshot's
@@ -837,21 +860,10 @@ class TpuBackend:
         thrash; per-partition tiles would compose but conflict with cohort
         (shared-cadence) packing, which is what makes the kernels fast."""
         with obs_trace.span("tile-entry", series=len(series)):
-            return self._tile_entry_inner(series)
+            return self._tile_entry_inner(series, facts)
 
-    @staticmethod
-    def _tile_key(series, use_snap: bool):
-        if not use_snap:
-            return tuple(id(s) for s in series), None
-        # ident: the snapshot key minus the chunk-count field, stable
-        # across flushes for the same partitions + column selection
-        return (tuple(s.snapshot_key for s in series),
-                tuple(s.snapshot_key[:3] + s.snapshot_key[4:]
-                      for s in series))
-
-    def _tile_entry_inner(self, series):
-        use_snap = all(s.snapshot_key is not None for s in series)
-        key, ident = self._tile_key(series, use_snap)
+    def _tile_entry_inner(self, series, facts):
+        key, ident = facts.key, facts.ident
         with self._tile_lock:
             entry = self._tile_cache.get(key)
             stale = None
@@ -861,13 +873,21 @@ class TpuBackend:
                     stale = self._tile_cache.get(old_key)
         if entry is not None:
             self.tile_hits += 1
-            return entry
+            # an equal key of another selection's making: take the
+            # cache's own, so the next lookup through these facts ends at
+            # ``is`` (the memo holds the key, never the entry: one pushed
+            # out of the cache is freed, and built again when asked for)
+            if entry.key is not key and entry.key is not None:
+                facts.key = entry.key
+            return entry, facts
         if stale is not None and self.batcher is not None:
-            # stale-but-correct serve + background refresh (once per key)
+            # stale-but-correct serve + background refresh (once per key);
+            # ``stale_view`` keeps its loop over the series: their growth
+            # since the build is no fact of the selection's
             self.tile_hits += 1
             with self._tile_lock:
                 if key in self._tile_refreshing:
-                    return stale.stale_view(series)
+                    return stale.stale_view(series), facts
                 self._tile_refreshing.add(key)
             held = list(series)     # pin arrays until the rebuild lands
             for s in held:
@@ -876,7 +896,7 @@ class TpuBackend:
             @thread_root("tile-refresh")
             def refresh():
                 try:
-                    fresh = self._build_tile_entry(held, use_snap)
+                    fresh, built = self._build_tile_entry(held)
                     me = self.mesh_eval
                     if me is not None and stale.tiles is not None:
                         # cross-flush hand-over of the mesh placement:
@@ -884,8 +904,7 @@ class TpuBackend:
                         # buffers in place (zero-copy) when the new
                         # tiles extend the old cohort
                         me.refresh(stale.tiles, fresh.tiles)
-                    self._insert_tile_entry(
-                        *self._tile_key(held, use_snap), fresh)
+                    self._insert_tile_entry(built.key, built.ident, fresh)
                 finally:
                     with self._tile_lock:
                         self._tile_refreshing.discard(key)
@@ -893,15 +912,12 @@ class TpuBackend:
             # and must never delay a queued interactive dispatch
             self.batcher.executor.submit(
                 refresh, priority=qos.PRIORITY_BACKGROUND)
-            return stale.stale_view(series)
-        entry = self._build_tile_entry(series, use_snap)
-        # keyed AFTER the build read the samples: a partition evicted or
-        # paged in under its handle took its facts again with them, and
-        # the tiles go under the key of what they were built from
-        self._insert_tile_entry(*self._tile_key(series, use_snap), entry)
-        return entry
+            return stale.stale_view(series), facts
+        entry, facts = self._build_tile_entry(series)
+        self._insert_tile_entry(facts.key, facts.ident, entry)
+        return entry, facts
 
-    def _try_aligned(self, series, func: str, steps: np.ndarray,
+    def _try_aligned(self, series, facts, func: str, steps: np.ndarray,
                      step_ms: int, window_ms: int, offset_ms: int,
                      func_args) -> Optional[np.ndarray]:
         """Aligned-tile fast path (tilestore): regular-cadence series are
@@ -915,7 +931,7 @@ class TpuBackend:
         merged at present stage')."""
         if func not in tst.ALIGNED_FUNCS:
             return None
-        entry = self._tile_entry(series)
+        entry, facts = self._tile_entry(series, facts)
         tiles, idx = entry.tiles, entry.idx
         if func == "last_sample":
             # stale markers must stay visible to the step; the immutable
@@ -930,7 +946,7 @@ class TpuBackend:
         # cover see only tiles: the tail of the CURRENT series, clipped
         # further by the entry's build-time coverage when a stale entry
         # is serving across a flush (the rebuild lands in background)
-        tail_min = self._tail_min(series, entry.cov_min_ms)
+        tail_min = facts.tail_bound(entry.cov_min_ms)
         wends = steps - offset_ms
         t_dev = (steps.size if tail_min is None
                  else int(np.searchsorted(wends, tail_min, side="left")))
@@ -943,7 +959,7 @@ class TpuBackend:
         # restore original series order (build may drop/reorder rows)
         full = np.empty((len(series), steps.size), dtype=np.float64)
         dev = np.empty((len(series), t_dev), dtype=np.float64)
-        dev[np.asarray(idx)] = res
+        dev[idx] = res
         full[:, :t_dev] = dev
         if t_dev < steps.size:
             full[:, t_dev:] = self._general(series, func, steps[t_dev:],
@@ -1088,15 +1104,22 @@ class TpuBackend:
 
     def fused_groupsum(self, series, func: str, steps: np.ndarray,
                        window_ms: int, offset_ms: int,
-                       gids: np.ndarray, G: int):
+                       gids: np.ndarray, G: int,
+                       facts: Optional[SelectionFacts] = None):
         """`sum/avg/count by (g)` of rate/increase/delta fused on device:
         one program consumes the cached aligned tiles and only [T, G]
         group sums + counts leave the chip — the [S, T] rate
         intermediate is never read back (the reference pays this as
         per-shard AggrOverRangeVectors map-reduce over row iterators,
         exec/aggregator/*.scala). A query hands the device path its
-        group ids in tile order (``gids[idx]``) and one small integer
-        vector; the program is one cached executable of the tilestore
+        group ids in tile order (``gids[idx]``, kept with the tile entry
+        per frozen ``gids``) and one small integer vector, and takes the
+        tile key and the tail bound from ``facts`` (the selection's:
+        ``selection_facts``, taken here where the caller has not), which
+        says where key and bound come from and nothing about the answer:
+        the order of the refusals below, the path that serves and the
+        program are what they were without it; the
+        program is one cached executable of the tilestore
         table, the Pallas group-sum kernel over dense tiles or the
         grouped non-dense evaluator over tiles with holes
         (``filodb_fused_holes_aggs_total`` counts those apart), or the
@@ -1118,13 +1141,13 @@ class TpuBackend:
         from the tile base (the exact all-f64 family), or a CPU node
         whose mesh store places dense tiles only."""
         res = self._fused_groupsum(series, func, steps, window_ms,
-                                   offset_ms, gids, G)
+                                   offset_ms, gids, G, facts)
         if res is None:
             self.fused_refused += 1
         return res
 
     def _fused_groupsum(self, series, func, steps, window_ms, offset_ms,
-                        gids, G):
+                        gids, G, facts):
         if func not in ("rate", "increase", "delta") or not len(series):
             return None
         on_cpu = jax.default_backend() == "cpu"
@@ -1137,12 +1160,14 @@ class TpuBackend:
             # mesh-sharded grouped collective below is XLA, not Pallas,
             # so it serves on any backend)
             return None
-        entry = self._tile_entry(series)
+        if facts is None:
+            facts = selection_facts(series)
+        entry, facts = self._tile_entry(series, facts)
         tiles, idx = entry.tiles, entry.idx
         if tiles is None or len(idx) != len(series):
             return None
         with obs_trace.span("fused-eligibility", series=len(series)):
-            if not self._fused_covered(entry, series, steps, offset_ms):
+            if not self._fused_covered(entry, facts, steps, offset_ms):
                 return None
             # mesh-resident grouped collective first: the one-hot
             # matmul + psum runs off the device-resident sharded tiles
@@ -1162,10 +1187,12 @@ class TpuBackend:
             # the mesh store places dense tiles only
             self.fused_refused_gaps += not tiles._dense
             return None
-        # the group ids in tile order, which both device paths take (the
-        # stage's name is one the benchmark's dispatch_host_ms row reads)
+        # the group ids in tile order, which both device paths take: a
+        # lookup where the memo handed the grouping out, a gather else
+        # (the stage's name is one the benchmark's dispatch_host_ms row
+        # reads)
         with obs_trace.span("onehot", groups=G):
-            gvec = np.asarray(gids)[np.asarray(idx)]
+            gvec = entry.tile_order(gids)
         if mesh_st is not None:
             self.fused_aggs += 1
             self.mesh_dispatches += 1
@@ -1188,7 +1215,8 @@ class TpuBackend:
             transfer_counts.d2h_bytes += sums.nbytes + cnts.nbytes
             return sums[:T], cnts[:T]
 
-    def _fused_covered(self, entry, series, steps: np.ndarray,
+    @staticmethod
+    def _fused_covered(entry, facts, steps: np.ndarray,
                        offset_ms: int) -> bool:
         """Every window must resolve on the tiles' covered prefix: fused
         results can't splice a host-side tail scan per group (a stale
@@ -1196,7 +1224,7 @@ class TpuBackend:
         prefix — cov_min_ms is the binding bound)."""
         if not steps.size:
             return True
-        tail_min = self._tail_min(series, entry.cov_min_ms)
+        tail_min = facts.tail_bound(entry.cov_min_ms)
         return tail_min is None or int(steps[-1] - offset_ms) < tail_min
 
     @staticmethod

@@ -93,7 +93,15 @@ def _node(mesh, holes=()):
 
 
 @pytest.fixture(scope="module")
-def nodes():
+def place_calls_at_start():
+    """The ``mesh-place`` stage's calls before this file's nodes exist: the
+    stage counters are the process's, and another test file on the same
+    worker may have placed."""
+    return obs_trace.stage_totals()["mesh-place"][0]
+
+
+@pytest.fixture(scope="module")
+def nodes(place_calls_at_start):
     """The same fleet on a node with the mesh and on one without."""
     select_memo.clear()
     meshed, ref = _node(True)
@@ -200,11 +208,14 @@ def test_mesh_answer_is_the_single_chip_answer_byte_for_byte(nodes, op,
     assert m[MESH] == 0 and m[REFUSED] == 0     # no mesh node: not a refusal
 
 
-def test_the_selection_is_placed_once_and_all_of_it(nodes):
+def test_the_selection_is_placed_once_and_all_of_it(nodes,
+                                                    place_calls_at_start):
     meshed, _, _ = nodes
     _raw(meshed)                                # (placed by now at the latest)
     m0, _ = _metrics(meshed)
-    assert m0[PLACED] == m0[PLACE_CALLS] == 1 and m0[EVICTED] == 0
+    # the node's own counters, and the process's stage since the fixture
+    assert m0[PLACED] == m0[PLACE_CALLS] - place_calls_at_start == 1
+    assert m0[EVICTED] == 0
     for k in (2, 3):
         _raw(meshed, start=START + 60 * k, end=END + 60 * k)
     assert _delta(meshed, m0, MESH, PLACED, PLACE_CALLS, EVICTED) \
@@ -220,8 +231,69 @@ def test_the_selection_is_placed_once_and_all_of_it(nodes):
     assert shard_shapes == {(placed.cap, placed.S_pad // n_shard)}
 
 
+def _gid_puts(monkeypatch, placed):
+    """-> a list that grows by one for every ``jax.device_put`` of an
+    ``[S_pad]`` vector (the padded group ids) from here on."""
+    puts = []
+    real = shardstore.jax.device_put
+
+    def counting(x, *args, **kw):
+        if getattr(x, "shape", None) == (placed.S_pad,):
+            puts.append(x.dtype)
+        return real(x, *args, **kw)
+    monkeypatch.setattr(shardstore.jax, "device_put", counting)
+    return puts
+
+
+@pytest.mark.parametrize("then", ["many-requests", "a-new-placement"])
+def test_the_group_ids_are_put_on_the_devices_once(nodes, monkeypatch, then):
+    """The padded tile-order group ids live on the devices with the
+    placement: a request sends its grid scalars and nothing else, whatever
+    its op and grid position, and the answer stays the single-chip answer
+    byte for byte. A placement built anew puts them anew, once."""
+    meshed, plain, _ = nodes
+    for _ in range(3):      # (the entry the tile build's read ended, then
+        _raw(meshed)        # one that stays: its ids are kept from here)
+    ev = meshed.backend.mesh_eval
+    placed, = (st for _, st in ev._placed.values())
+    kept = dict(placed._gids.kept)
+    assert 1 <= len(kept) <= 2
+    puts = _gid_puts(monkeypatch, placed)
+    m0, _ = _metrics(meshed)
+    if then == "a-new-placement":
+        with ev._lock:
+            ev._placed.clear()
+    monkeypatch.setattr(tpu, "FUSED_GROUPSUM_INTERPRET", False)
+    asked = 0
+    for k, op in enumerate(OPS * 2):
+        moved = dict(start=START + 60 * (k % 3), end=END + 60 * (k % 3))
+        a, b = _raw(meshed, op, **moved), _raw(plain, op, **moved)
+        assert json.dumps(json.loads(a)["data"], sort_keys=True) \
+            == json.dumps(json.loads(b)["data"], sort_keys=True)
+        asked += 1
+    new = then == "a-new-placement"
+    assert _delta(meshed, m0, MESH, PLACED, "filodb_fused_refused_total") \
+        == [asked, int(new), 0]
+    assert puts == [np.int32] * int(new)
+    now, = (st for _, st in ev._placed.values())
+    assert (now is placed) == (not new)
+    if not new:
+        assert {k: v[1] for k, v in now._gids.kept.items()} \
+            == {k: v[1] for k, v in kept.items()}     # the same arrays
+    # every kept copy is the padded vector of its (frozen) tile-order ids
+    for gvec, on_dev in now._gids.kept.values():
+        assert not gvec.flags.writeable
+        host = np.asarray(on_dev)
+        assert host.dtype == np.int32 and host.shape == (now.S_pad,)
+        assert np.array_equal(host[:now.S], gvec)
+        assert (host[now.S:] == -1).all()
+        assert len(on_dev.sharding.device_set) == ev.ndev
+
+
 @pytest.mark.parametrize("family,mtype", [
     (REFUSED, "counter"), (PLACED, "counter"), (EVICTED, "counter"),
+    ("filodb_selection_facts_hits_total", "counter"),
+    ("filodb_selection_facts_misses_total", "counter"),
     ("filodb_stage_mesh_place_calls_total", "counter"),
     ("filodb_stage_mesh_place_self_seconds_total", "counter"),
     ("filodb_stage_mesh_place_cpu_seconds_total", "counter")])

@@ -362,6 +362,54 @@ def test_backend_mesh_batch_run_parity():
         assert np.array_equal(res.get(k), want, equal_nan=True)
 
 
+@pytest.mark.parametrize("gids_are", ["frozen", "writable"])
+def test_frozen_group_ids_are_put_on_the_devices_once(gids_are,
+                                                      monkeypatch):
+    """A read-only tile-order vector names its device copy by identity (the
+    backend's tile entry hands one out per grouping); any other array is
+    padded and put every time, as before. Same program, same answer."""
+    from filodb_tpu.parallel import shardstore
+    from filodb_tpu.query.model import MAX_GROUPINGS
+    tiles = _tiles()
+    ev = ShardedTileEvaluator(_mesh(4, 1))
+    st = ev.place(tiles)
+    gids = np.arange(13) % 3
+    want = st.eval_grouped_pair("rate", _steps(), W, gids.copy(), 3)
+    if gids_are == "frozen":
+        gids.setflags(write=False)
+    puts = []
+    real = jax.device_put
+
+    def counting(x, *args, **kw):
+        puts.append(getattr(x, "shape", None))
+        return real(x, *args, **kw)
+    monkeypatch.setattr(shardstore.jax, "device_put", counting)
+    frozen = gids_are == "frozen"
+    for k in range(3):
+        got = st.eval_grouped_pair("rate", _steps(), W, gids, 3)
+        assert np.array_equal(got[0], want[0]) \
+            and np.array_equal(got[1], want[1])
+        assert puts == [(st.S_pad,)] * (1 if frozen else k + 1)
+    assert len(st._gids.kept) == int(frozen)
+    # the other grouped program takes the same copy
+    st.eval_grouped("rate", _steps(), W, gids, 3, "sum")
+    assert len(puts) == (1 if frozen else 4)
+    # bounded: a ninth grouping does not grow it
+    for i in range(3 * MAX_GROUPINGS):
+        other = (np.arange(13) + i) % 3
+        other.setflags(write=False)
+        st.dispatch_grouped_pair("rate", _steps(), W, other, 3)
+        assert len(st._gids.kept) <= MAX_GROUPINGS
+    # the donated refresh brings tiles, and so tile-order vectors, of its
+    # own: the copies go, and the same vector is put again
+    del puts[:]
+    assert ev.refresh(tiles, _extend(tiles, 32))
+    assert st._gids.kept == {}
+    st.dispatch_grouped_pair("rate", _steps(), W, gids, 3)
+    assert puts.count((st.S_pad,)) == 1 \
+        and len(st._gids.kept) == int(frozen)
+
+
 def test_fused_groupsum_rides_resident_collective():
     from filodb_tpu.query.model import RawSeries
     from filodb_tpu.query.tpu import TpuBackend
