@@ -16,7 +16,8 @@ Design constraints:
     *stage span*: it is always timed, tracer on or off — one
     ``perf_counter_ns`` pair and one ``thread_time_ns`` pair feed the
     per-stage counters on ``/metrics`` (calls, self seconds, CPU
-    seconds), the stage's latency histogram where it has one, the
+    seconds, collector seconds), the stage's latency histogram where it
+    has one, the
     request trace when one is active, and a
     ``jax.profiler.TraceAnnotation("filodb:<name>")`` so that a running
     profiler session shows the stage on the host plane beside the
@@ -46,7 +47,22 @@ batch while its leader parks in ``batcher-queue-wait``) subtracts wall
 only — the CPU was another thread's. So over the request threads the
 self seconds of the stages under ``query`` add up to
 ``filodb_query_latency_seconds_sum``, and wall minus CPU of a stage is
-time it waited (the GIL, a lock, the device, a socket).
+time it waited (the GIL, a lock, the device, a socket). What ONE such
+wait for the interpreter costs at the present load is measured beside
+it: ``obs/process.py``'s probe (``filodb_interpreter_wait_seconds``)
+times how long a thread that wants the interpreter waits for it, which
+is what every hand-back of a stage pays.
+
+The fourth column of a stage, ``gc_seconds``: the garbage collector
+runs on whichever thread trips its threshold and holds the interpreter
+throughout, so its pause is part of that thread's CPU and of the wall
+of every other. ``obs/process.py``'s ``gc.callbacks`` timer hands each
+pause to :func:`charge_collector`, which adds it to the innermost stage
+frame open on the collecting thread (never to a parent: the child's
+whole duration already leaves the parent's self time); the stage's
+``__exit__`` moves it to ``filodb_stage_<S>_gc_seconds_total``. Exact,
+not sampled. A collection with no stage open on its thread is in the
+process families (``filodb_gc_pause_seconds_total``) only.
 
 The CPU side is SAMPLED. The thread CPU clock is a system call (0.3 us
 on a workstation, 5.6 us on the TPU host's sandboxed VM, where reading
@@ -247,7 +263,7 @@ class _LiveSpan:
 # name -> help text. A ``span(name)`` whose name is here is a STAGE span
 # (always timed; see the module docstring). Hyphens read as underscores
 # in the family names: ``filodb_stage_<name>_{calls,self_seconds,
-# cpu_seconds}_total``.
+# cpu_seconds,gc_seconds}_total``.
 STAGES: Dict[str, str] = {
     # query path, request thread
     "admission-wait": "waiting for an admission slot (outside query)",
@@ -335,7 +351,7 @@ class _Stage:
     thread-per-connection) at scrape."""
 
     __slots__ = ("name", "anno", "family", "help", "hist", "lock",
-                 "calls", "self_ns", "cpu_ns", "cpu_next_ns",
+                 "calls", "self_ns", "cpu_ns", "gc_ns", "cpu_next_ns",
                  "cpu_skipped")
 
     def __init__(self, name: str, help: str):
@@ -348,6 +364,7 @@ class _Stage:
         self.calls = 0
         self.self_ns = 0
         self.cpu_ns = 0
+        self.gc_ns = 0              # collector pauses inside its spans
         self.cpu_next_ns = 0        # as a ROOT: when to read CPU next
         self.cpu_skipped = 0        # roots since the last one read
 
@@ -367,24 +384,29 @@ _STAGE_TABLE: Dict[str, _Stage] = {n: _Stage(n, h)
                                    for n, h in STAGES.items()}
 
 
-def stage_totals() -> Dict[str, Tuple[int, float, float]]:
-    """stage -> (calls, self seconds, CPU seconds) since process start."""
+def stage_totals() -> Dict[str, Tuple[int, float, float, float]]:
+    """stage -> (calls, self seconds, CPU seconds, collector seconds)
+    since process start."""
     out = {}
     for st in _STAGE_TABLE.values():
         with st.lock:
-            out[st.name] = (st.calls, st.self_ns / 1e9, st.cpu_ns / 1e9)
+            out[st.name] = (st.calls, st.self_ns / 1e9, st.cpu_ns / 1e9,
+                            st.gc_ns / 1e9)
     return out
 
 
 def _collect_stages(builder) -> None:
-    for name, (calls, self_s, cpu_s) in stage_totals().items():
+    for name, (calls, self_s, cpu_s, gc_s) in stage_totals().items():
         st = _STAGE_TABLE[name]
         for suffix, value, what in (
                 ("_calls_total", calls, "Stage spans closed: "),
                 ("_self_seconds_total", self_s,
                  "Wall seconds less child stages: "),
                 ("_cpu_seconds_total", cpu_s,
-                 "Thread CPU seconds over the self time (sampled): ")):
+                 "Thread CPU seconds over the self time (sampled): "),
+                ("_gc_seconds_total", gc_s,
+                 "Garbage-collector pauses inside the self time "
+                 "(exact; part of the CPU seconds): ")):
             builder.sample(st.family + suffix, {}, value,
                            mtype="counter", help=what + st.help)
 
@@ -440,11 +462,12 @@ class _StageSpan:
     def __enter__(self) -> "_StageSpan":
         tls = self._tls
         pframe = self._pframe = tls.get("frame")
-        # [children's wall, children's cpu, CPU-sampling weight]: a
-        # thread's root span (none open, or a hop's) draws the weight
+        # [children's wall, children's cpu, CPU-sampling weight,
+        # collector ns (charge_collector)]: a thread's root span (none
+        # open, or a hop's) draws the weight
         weight = pframe[2] if pframe is not None and pframe[2] >= 0 \
             else self._st.cpu_weight(time.perf_counter_ns())
-        self._frame = tls["frame"] = [0, 0, weight]
+        self._frame = tls["frame"] = [0, 0, weight, 0]
         trace = self._trace = tls.get("trace")
         if trace is not None:
             self._prev_parent = tls.get("parent")
@@ -480,6 +503,7 @@ class _StageSpan:
         self_ns = dur - frame[0]
         with st.lock:
             st.calls += 1
+            st.gc_ns += frame[3]
             if self_ns > 0:
                 st.self_ns += self_ns
                 st.cpu_ns += weight * max(0, cpu - frame[1])
@@ -494,6 +518,32 @@ class _StageSpan:
             tls["parent"] = self._prev_parent
             self._trace.add(sp)
         return False
+
+
+# -- the collector, as the stage spans see it ---------------------------------
+
+def charge_collector(ns: int) -> None:
+    """Add one collector pause to the innermost stage frame open on THIS
+    thread (``obs/process.py``'s ``gc.callbacks`` timer calls it on the
+    collecting thread). Nothing where no stage is open, nor under the
+    bare hop frame (weight -1) that :class:`use` installs before a stage
+    opens under it: such a pause is in the process families only."""
+    frame = _state.__dict__.get("frame")
+    if frame is not None and frame[2] >= 0:
+        frame[3] += ns
+
+
+def collector_annotation(generation: int):
+    """An entered ``TraceAnnotation("filodb:gc<generation>")``, for the
+    caller to ``__exit__`` when the collection stops, or None where JAX
+    is not loaded. For generations 1 and 2: 0 is too frequent for a
+    traced run, and the caller does not ask."""
+    cls = _annotation_cls or _annotation()
+    if cls is None:
+        return None
+    anno = cls("filodb:gc%d" % generation)
+    anno.__enter__()
+    return anno
 
 
 # -- the thread-local active-trace API ---------------------------------------

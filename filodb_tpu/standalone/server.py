@@ -22,6 +22,7 @@ from filodb_tpu.core.memstore import TimeSeriesMemStore
 from filodb_tpu.core.schemas import DEFAULT_SCHEMAS, DatasetRef
 from filodb_tpu.http.server import FiloHttpServer
 from filodb_tpu.lint.threads import thread_root
+from filodb_tpu.obs import process as obs_process
 from filodb_tpu.parallel.shardmapper import (ShardMapper,
                                              assign_shards_evenly,
                                              shards_for_ordinal)
@@ -360,6 +361,9 @@ class FiloServer:
         self.bus_client = None
         self._bus_tick_stop = threading.Event()
         self._bus_tick_thread: Optional[threading.Thread] = None
+        # holds a share of the process's interpreter instruments
+        # (obs/process.py) from start() to stop()
+        self._instruments = False
         # self-monitoring (obs/selfmon.py): loop + its internal
         # dataset's dedicated stream/driver (None when off)
         self.selfmon = None
@@ -751,8 +755,11 @@ class FiloServer:
         # host-level series from day one: RSS/fds/threads/GC/uptime +
         # filodb_build_info ride every exposition build (and therefore
         # the self-monitoring ingest below)
-        from filodb_tpu.obs.process import register_process_collector
-        register_process_collector()
+        # ... and what the interpreter itself costs a request: the
+        # collection timer and the interpreter probe, once a process
+        obs_process.register_process_collector()
+        obs_process.acquire_instruments()
+        self._instruments = True
         if streaming:
             self._start_ingestion()
         if self.config.get("self-monitor"):
@@ -762,7 +769,11 @@ class FiloServer:
         # serving-path GC hygiene: move the (large, permanent) startup
         # object graph out of the collector's reach and make full
         # collections 10x rarer — a gen-2 sweep over jax/XLA module
-        # state stalls every in-flight query for ~100ms+ on small hosts
+        # state stalls every in-flight query (how long, and how often,
+        # is filodb_gc_pause_seconds_total{generation="2"} and
+        # filodb_gc_stalls_total). The freeze precedes any ingest: what
+        # the store holds afterwards is NOT frozen, and a full
+        # collection walks it
         if self.config.get("gc-freeze", True):
             import gc
             gc.collect()
@@ -1299,6 +1310,9 @@ class FiloServer:
         return rows
 
     def stop(self) -> None:
+        if self._instruments:
+            self._instruments = False
+            obs_process.release_instruments()
         if self.rules is not None:
             self.rules.stop()
         if self._rules_driver is not None:

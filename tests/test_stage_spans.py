@@ -350,7 +350,7 @@ REMOVED = ("filodb_batcher_occupancy_avg", "filodb_batcher_occupancy_max",
 
 @pytest.mark.parametrize("suffix,mtype", [
     ("calls_total", "counter"), ("self_seconds_total", "counter"),
-    ("cpu_seconds_total", "counter")])
+    ("cpu_seconds_total", "counter"), ("gc_seconds_total", "counter")])
 def test_every_stage_family_has_help_and_type(srv, suffix, mtype):
     text, vals = _metrics(srv)
     for name in obt.STAGES:
@@ -444,6 +444,40 @@ def test_stages_are_events_on_the_profilers_host_plane(srv, tmp_path):
                     "filodb:execute", "filodb:device-dispatch"} <= inside
             found += 1
     assert found == 2
+
+
+def test_collections_are_events_on_the_profilers_host_plane(srv, tmp_path):
+    """Generations 1 and 2, beside the stage they interrupt; generation
+    0 is too frequent for a traced run and stays off the plane."""
+    import gc
+
+    import jax
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with obt.span("execute"):
+            gc.collect(0)
+            gc.collect(1)
+            gc.collect(2)
+    finally:
+        jax.profiler.stop_trace()
+    pb = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                       / "*.xplane.pb"))[0]
+    host = next(p for p in ProfileData.from_file(pb).planes
+                if p.name == "/host:CPU")
+    found = set()
+    for line in host.lines:
+        evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+               for e in line.events if e.name.startswith("filodb:")]
+        for name, x0, x1 in evs:
+            if name != "filodb:execute":
+                continue
+            found |= {n for n, s0, s1 in evs
+                      if n.startswith("filodb:gc") and x0 <= s0 and s1 <= x1}
+    assert found == {"filodb:gc1", "filodb:gc2"}
 
 
 def test_stage_counters_lose_no_update_under_contention():
