@@ -1972,6 +1972,11 @@ class FiloHttpServer:
             "from the selection memo's entry (no pass over the series)",
         "filodb_selection_facts_misses_total":
             "Requests that made the pass over their selection's series",
+        "filodb_plan_selection_facts_hits_total":
+            "Mesh lowerings that took from the selection memo's entry "
+            "that the selection holds no histogram (no index match)",
+        "filodb_plan_selection_facts_walks_total":
+            "Mesh lowerings that walked every matched partition's schema",
         "filodb_exec_cache_hits_total": "Compiled-executable reuse hits",
         "filodb_exec_cache_misses_total": "Compiled-executable retraces",
         "filodb_exec_cache_entries": "Distinct compiled kernel shapes",
@@ -2253,6 +2258,9 @@ class FiloHttpServer:
         emit("select_memo_misses_total", {}, select_counts.memo_misses)
         emit("selection_facts_hits_total", {}, select_counts.facts_hits)
         emit("selection_facts_misses_total", {}, select_counts.facts_misses)
+        emit("plan_selection_facts_hits_total", {}, select_counts.plan_hits)
+        emit("plan_selection_facts_walks_total", {},
+             select_counts.plan_walks)
         pc = self.plan_cache.snapshot()
         emit("plan_cache_entries", {}, pc["entries"])
         emit("plan_cache_hits_total", {}, pc["hits"])

@@ -18,7 +18,8 @@ lower through ``shard_map``:
     the time axis, series slices the shard axis;
   * grouped aggregation keeps the one-hot [S, G] matmul + ``psum``
     collective of the scatter-gather path (mesh._grouped_reduce) but
-    feeds it from the resident tiles;
+    feeds it from the resident tiles; the fused pair sums by group with
+    masked f64 sums (an f64 matmul is emulated on the chip);
   * PartitionSpecs name the mesh's axes through ``mesh.axis_names``
     (first axis = series shards, second = output steps):
     ``P(None, s_axis)`` = replicated slots x sharded series,
@@ -243,7 +244,7 @@ def _build_aligned_eval(mesh: Mesh, func: str, nsteps_local: int,
 
 @order_insensitive(
     "grouped-pair-psum", tolerance=1e-12,
-    reason="sums and counts are f64 per-device one-hot matmul "
+    reason="sums and counts are f64 per-device masked-sum "
            "partials psummed over the shard axis; regrouping across "
            "device counts moves the sums by at most a few f64 ulps "
            "(counts are exact integers in f64) — certified at "
@@ -251,7 +252,7 @@ def _build_aligned_eval(mesh: Mesh, func: str, nsteps_local: int,
 def _build_grouped_pair_eval(mesh: Mesh, func: str, nsteps_local: int,
                              num_groups: int):
     """The fused-groupsum contract from resident tiles: per-device
-    windowed counter evaluation + one-hot matmul, psum over the shard
+    windowed counter evaluation + masked sum by group, psum over the shard
     axis -> (sums [T, G], counts [T, G]) f64 — sums meaningful where
     counts > 0, exactly the Pallas group-sum kernel's return shape."""
     from filodb_tpu.query.tilestore import _eval_counter_fast
@@ -264,12 +265,17 @@ def _build_grouped_pair_eval(mesh: Mesh, func: str, nsteps_local: int,
         arrs = {"tsr": tsr, "ff_v": vv}
         local = _eval_counter_fast(func, nsteps_local, arrs, n, base,
                                    dt, w0s + t_off, w0e + t_off, step)
-        valid = (gids >= 0)
-        ok = ~jnp.isnan(local) & valid[None, :]
-        onehot = ((gids[:, None] == jnp.arange(num_groups)[None, :])
-                  & valid[:, None]).astype(jnp.float64)      # [S_l, G]
-        sums = jnp.where(ok, local, 0.0).astype(jnp.float64) @ onehot
-        cnts = ok.astype(jnp.float64) @ onehot               # [T_l, G]
+        # masked f64 sums, not an f64 dot: the chip has no f64 matmul,
+        # and its compiler spells one as nine loops over bf16 pieces,
+        # 210 of this program's 268 device ops and half of its time at
+        # 16 groups, more at 1,024 (chip, PR 37); f32 rates add in f64
+        # without rounding, so the sums came out the same to the bit.
+        # A padding row's -1 is no group's id.
+        member = gids[None, :] == jnp.arange(num_groups)[:, None]  # [G, S_l]
+        ok = ~jnp.isnan(local)[:, None, :] & member[None]    # [T_l, G, S_l]
+        sums = jnp.sum(jnp.where(ok, local[:, None, :], 0.0),
+                       axis=2, dtype=jnp.float64)
+        cnts = jnp.sum(ok, axis=2, dtype=jnp.int32).astype(jnp.float64)
         return (jax.lax.psum(sums, s_axis), jax.lax.psum(cnts, s_axis))
 
     @jax.jit

@@ -385,7 +385,8 @@ class _MemoEntry:
 
 
 @cache_registry("select-memo", keyed=("shards", "filters", "column"),
-                validated_by={"store-version": ("begin", "store")})
+                validated_by={"store-version": ("begin", "store",
+                                                "facts_for")})
 class _SelectMemo:
     """The memo of ``select_raw_series(.., full=True)`` over local shards:
     at most ``_MEMO_ENTRIES`` entries that count at most ``_MEMO_MAX_ROWS``
@@ -408,6 +409,13 @@ class _SelectMemo:
         versions as they read BEFORE it; None where the memo does not
         apply (a shard is remote; a filter off the JSON wire holds its
         ``in`` values as a list, which is no key)."""
+        key = self._key(shards, filters, column)
+        if key is None:
+            return None
+        return _MemoEntry(key, tuple(shards), _store_versions(shards))
+
+    @staticmethod
+    def _key(shards, filters, column) -> Optional[Tuple]:
         for shard in shards:
             if hasattr(shard, "fetch_raw"):
                 return None
@@ -416,7 +424,27 @@ class _SelectMemo:
             hash(key)
         except TypeError:
             return None
-        return _MemoEntry(key, tuple(shards), _store_versions(shards))
+        return key
+
+    def facts_for(self, shards, filters, column, start_ms: int,
+                  end_ms: int) -> Optional[SelectionFacts]:
+        """The ``facts`` of the entry that ``lookup`` would serve for this
+        selection and range as the versions read NOW, if some holder has
+        made them; None: nothing is known (no entry, a version moved, the
+        match does not hold for the range, a handle was read, no facts
+        yet). For whoever must know of a selection BEFORE it runs (the
+        planner's mesh lowering). It only reads: no stats, no limits, no
+        ``offsets``, and what it finds stale it leaves for ``lookup``."""
+        key = self._key(shards, filters, column)
+        if key is None:
+            return None
+        with self._lock:
+            entry = self._entries.get(key)
+        if entry is None or entry.versions != _store_versions(shards) \
+                or entry.held is None \
+                or not entry.holds_for(start_ms, end_ms):
+            return None
+        return entry.facts          # once: a drop empties the slot
 
     def lookup(self, new: _MemoEntry, start_ms: int, end_ms: int, stats,
                limits) -> Optional[Selection]:

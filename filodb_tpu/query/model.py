@@ -171,10 +171,14 @@ class _SelectCounts:
     selections over local shards that the memo answered, and that ran the
     loop; ``filodb_selection_facts_{hits,misses}_total``: requests that
     took their ``SelectionFacts`` from the memo entry, and that made the
-    pass over the series. Plain adds, like the backend's counters."""
+    pass over the series; ``filodb_plan_selection_facts_{hits,walks}_total``:
+    mesh lowerings (query/planner.py ``_hist_selection``) that learned
+    from the memo entry's facts that the selection holds no histogram, and
+    that walked the matched partitions to learn it. Plain adds, like the
+    backend's counters."""
 
     __slots__ = ("handles", "reads", "memo_hits", "memo_misses",
-                 "facts_hits", "facts_misses")
+                 "facts_hits", "facts_misses", "plan_hits", "plan_walks")
 
     def __init__(self):
         self.handles = 0
@@ -183,6 +187,8 @@ class _SelectCounts:
         self.memo_misses = 0
         self.facts_hits = 0
         self.facts_misses = 0
+        self.plan_hits = 0
+        self.plan_walks = 0
 
 
 select_counts = _SelectCounts()

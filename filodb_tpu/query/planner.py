@@ -38,10 +38,11 @@ from filodb_tpu.core.record import shard_key_hash
 from filodb_tpu.lint.caches import publishes
 from filodb_tpu.query import logical as lp
 from filodb_tpu.query.engine import (METRIC_LABELS, QueryEngine,
-                                     select_raw_series)
+                                     select_memo, select_raw_series)
 from filodb_tpu.query.model import (GridResult, QueryError, QueryLimits,
                                     QueryStats, RangeParams, RawSeries,
-                                    StaleRoutingError, clip_series)
+                                    StaleRoutingError, clip_series,
+                                    select_counts)
 
 # aggregations executable as mesh collectives (parallel/mesh.py MESH_AGGS)
 _MESH_AGGS = frozenset({"sum", "count", "avg", "min", "max", "group"})
@@ -1342,7 +1343,20 @@ class QueryPlanner:
     @staticmethod
     def _hist_selection(shards, raw: lp.RawSeriesPlan):
         """("none"|"hist"|"mixed", les or None): whether the selection hits
-        histogram columns, and the shared bucket scheme if consistent."""
+        histogram columns, and the shared bucket scheme if consistent.
+
+        The engine selects the same thing by the same key a moment later,
+        and memoises it: where the selection memo would serve an entry for
+        this range as the versions read now and the entry's facts say no
+        series is a histogram, that is the answer, with no index match and
+        no pass over the partitions. Anything else (no entry, a version
+        moved, no facts yet, a histogram somewhere) walks."""
+        facts = select_memo.facts_for(shards, raw.filters, raw.column,
+                                      raw.start_ms, raw.end_ms)
+        if facts is not None and not facts.any_hist:
+            select_counts.plan_hits += 1
+            return "none", None
+        select_counts.plan_walks += 1
         from filodb_tpu.core.schemas import ColumnType
         saw_hist = saw_scalar = False
         les = None

@@ -1169,8 +1169,8 @@ class TpuBackend:
         with obs_trace.span("fused-eligibility", series=len(series)):
             if not self._fused_covered(entry, facts, steps, offset_ms):
                 return None
-            # mesh-resident grouped collective first: the one-hot
-            # matmul + psum runs off the device-resident sharded tiles
+            # mesh-resident grouped collective first: the sums by
+            # group + psum run off the device-resident sharded tiles
             # (no per-query pack), honoring the same fast-family
             # eligibility as the per-series sharded path
             mesh_st = None

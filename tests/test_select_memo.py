@@ -711,6 +711,7 @@ def test_the_counters_are_on_metrics_and_the_cache_is_declared():
         "filodb_select_memo_misses_total": select_counts.memo_misses}
     assert select_counts.memo_hits >= 2 and select_counts.memo_misses >= 1
     decl = cache_inventory()["select-memo"]
-    assert decl["validated_by"] == {"store-version": ("begin", "store")}
+    assert decl["validated_by"] == {
+        "store-version": ("begin", "store", "facts_for")}
     assert TimeSeriesShard._changed.__publishes__ == ("store-version",)
     assert eng._store_versions.__event_source__ == ("store-version",)

@@ -24,6 +24,7 @@ import urllib.request
 import numpy as np
 import pytest
 
+from filodb_tpu.core.memstore import TimeSeriesShard
 from filodb_tpu.core.record import RecordBuilder
 from filodb_tpu.core.schemas import DEFAULT_SCHEMAS
 from filodb_tpu.gateway.producer import (TestTimeseriesProducer,
@@ -290,10 +291,49 @@ def test_the_group_ids_are_put_on_the_devices_once(nodes, monkeypatch, then):
         assert len(on_dev.sharding.device_set) == ev.ndev
 
 
+PLAN_HITS = "filodb_plan_selection_facts_hits_total"
+PLAN_WALKS = "filodb_plan_selection_facts_walks_total"
+
+
+def test_a_repeated_request_plans_without_asking_a_shard(monkeypatch):
+    """The cell's template twice over 128 shards nobody writes to: the
+    first request's lowering walks every shard's match; once an entry of
+    the selection memo stands (the request after the tile build), the
+    lowering takes "no histogram" from its facts, and no shard is asked
+    for its partitions by the planner or by the engine."""
+    select_memo.clear()
+    srv, ref = _node(True)
+    asked = []
+    real = TimeSeriesShard.lookup_partitions
+
+    def spy(shard, *args, **kw):
+        asked.append(shard.shard_num)
+        return real(shard, *args, **kw)
+    monkeypatch.setattr(TimeSeriesShard, "lookup_partitions", spy)
+    try:
+        m0, _ = _metrics(srv)
+        first = _raw(srv)
+        # every shard twice: the lowering's walk, then the engine's select
+        assert sorted(asked) == sorted(2 * list(range(SHARDS)))
+        assert _delta(srv, m0, PLAN_HITS, PLAN_WALKS) == [0, 1]
+        _raw(srv)           # (the tile build's read ended the first entry)
+        del asked[:]
+        m0, _ = _metrics(srv)
+        for k in (1, 2):
+            assert json.loads(_raw(srv))["data"] == json.loads(first)["data"]
+            assert asked == []
+            assert _delta(srv, m0, PLAN_HITS, PLAN_WALKS, MESH) == [k, 0, k]
+        _assert_answer(_served(first), _reference(ref, "sum"))
+    finally:
+        srv.stop()
+        select_memo.clear()
+
+
 @pytest.mark.parametrize("family,mtype", [
     (REFUSED, "counter"), (PLACED, "counter"), (EVICTED, "counter"),
     ("filodb_selection_facts_hits_total", "counter"),
     ("filodb_selection_facts_misses_total", "counter"),
+    (PLAN_HITS, "counter"), (PLAN_WALKS, "counter"),
     ("filodb_stage_mesh_place_calls_total", "counter"),
     ("filodb_stage_mesh_place_self_seconds_total", "counter"),
     ("filodb_stage_mesh_place_cpu_seconds_total", "counter")])

@@ -328,6 +328,9 @@ def test_grouped_pair_on_four_chips_is_sharded_with_collective(topo):
     text = compiled.as_text()
     assert any(c in text for c in ("all-reduce", "all-gather")), \
         "no cross-chip collective in the grouped program"
+    # an f64 dot is spelled as loops over bf16 pieces, four fifths of the
+    # device ops a request leaves in a trace (PERF.md section 6, PR 37)
+    assert " while(" not in text, "an emulated f64 dot is back"
     whole = cap * S * (4 + 8) + S * 4
     per_device = compiled.memory_analysis().argument_size_in_bytes
     assert 0.2 * whole < per_device < 0.3 * whole, (per_device, whole)
