@@ -6,6 +6,11 @@ columns and whose ``runs()`` is one ``[i, j, PartKey]`` per series, handed to
 routed by the same hash the gateway uses
 (``ingestion_shard(pk.shard_key_hash(..), pk.part_hash(), spread, shards)``).
 
+A world of histograms goes in the ``prom-histogram`` schema: columns
+``sum``, ``count`` (the ``+Inf`` bucket) and ``h`` in the per-row form the
+container takes from the gateway (``gateway/influx.py`` ``input_records``):
+one ``(scheme, bucket counts)`` a sample, so O(rows) Python for ``h``.
+
 This BYPASSES the gateway and the WAL, so it is set-up only and is never
 inside a measured window: every write a cell measures goes through the
 gateway's TCP port. A shard has one writer at a time: this runs on the node
@@ -20,6 +25,7 @@ import numpy as np
 
 from filodb_tpu.core.record import PartKey, RecordContainer, ingestion_shard
 from filodb_tpu.core.schemas import DEFAULT_SCHEMAS, PartitionSchema
+from filodb_tpu.memory.histogram import CustomBuckets
 
 
 def backfill(server, world):
@@ -45,8 +51,8 @@ def backfill(server, world):
         c.timestamps = np.ascontiguousarray(world.ts[idx, :n]).reshape(-1)
         c._runs = [[k * n, (k + 1) * n, pk]
                    for k, (_, pk) in enumerate(members)]
-        c._arrays_cache = (idx.size * n, c.timestamps, [
-            np.ascontiguousarray(world.vals[idx, :n]).reshape(-1)])
+        c._arrays_cache = (idx.size * n, c.timestamps,
+                           _columns(world, idx, n))
         got = shards[shard].ingest(c)
         if got != idx.size * n:
             raise RuntimeError(f"shard {shard} took {got} of "
@@ -58,3 +64,14 @@ def backfill(server, world):
     return {"rows": rows, "series": world.n_series, "ingest_s": t1 - t0,
             "flush_s": time.monotonic() - t1,
             "shard_series": {str(s): len(m) for s, m in by_shard.items()}}
+
+
+def _columns(world, idx, n):
+    """The data columns of the rows ``[idx, :n]``, row-major."""
+    if world.les is None:
+        return [np.ascontiguousarray(world.vals[idx, :n]).reshape(-1)]
+    scheme = CustomBuckets(tuple(float(le) for le in world.les))
+    counts = world.vals[idx, :n].reshape(-1, len(world.les)).astype(np.int64)
+    return [np.ascontiguousarray(world.sums[idx, :n]).reshape(-1),
+            counts[:, -1].astype(np.float64),
+            [(scheme, row) for row in counts]]

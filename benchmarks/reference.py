@@ -3,25 +3,64 @@
 Independent of the program: imports nothing of ``filodb_tpu`` and takes
 nothing the node has made. Plain numpy in float64 over the ``World`` arrays
 the parent generated from ``--seed`` (``ts`` int64 ms ``[S, N]`` sorted per
-row, ``vals`` float64 ``[S, N]``).
+row, ``vals`` float64 ``[S, N]``, or ``[S, N, B]`` bucket counts).
 
 A query is the structured form a workload file gives (the PromQL string the
 node is sent is rendered from the same structure by ``traffic.render``):
 
     {"metric": ..., "select": {label: value | [values]}, "fn": "rate",
-     "window_s": 300, "agg": "sum" | None, "by": ["job"]}
+     "window_s": 300, "agg": "sum" | None, "by": ["job"], "quantile": q}
 
 Semantics (Prometheus's, as FiloDB serves them): step ``t`` sees the samples
 with ``t - window <= ts <= t``; ``rate`` is the extrapolated rate with
 counter-reset correction; an aggregation skips series with no value at a step
 and a group with none has no point there.
 
+Histograms (``"quantile": q`` in the query, ``histogram_quantile(q, sum(
+rate(..)) by (..))``) come in two forms, and both end in ``bucket_quantile``:
+
+- classic: one counter series a bucket, with an ``le`` label; ``by`` holds
+  ``le``. The sums by ``by`` are grouped by their key without ``le``, the
+  ``le`` values parsed (``+Inf`` included) and sorted.
+- native: a world with ``les`` (FiloDB's ``prom-histogram`` rows: one series
+  carries every bucket, ``vals`` is ``[S, N, B]``). ``_rate`` is applied
+  bucket by bucket over the bucket axis, then summed by ``by`` as any other
+  aggregation. The counter semantics assumed: each bucket is a cumulative
+  counter, and a reset is corrected bucket by bucket (a bucket that falls
+  has its previous value added back). FiloDB instead finds a reset as a row
+  in which ANY bucket falls and adds back the whole previous histogram; the
+  two agree where every bucket that holds a count falls at once, which the
+  datagen guarantees (a reset row is all zeros).
+
+A histogram query carries a counter's resets from its first window's start,
+as FiloDB does (it reads a query's samples from there and corrects the
+resets it sees among them): a reset is seen only between two samples at or
+after that start. This sets the zero point a rate extrapolates to (a window
+whose first sample comes after a reset the query saw extrapolates from the
+corrected value, one after a reset it did not see from the value stored).
+The counter cells' reference carries resets from the slice's first column,
+one before the first window, and is kept as it was bit for bit.
+
+Bounds of a quantile: the quantile of the lower rows and of the upper rows,
+the smaller and the larger of the two. A target's buckets share its
+timestamps, so where its rate sits on the extrapolation threshold (``TIE``)
+both branches scale all its buckets by one factor (where no bucket's zero
+point cuts its extrapolation short), and a quantile does not change under a
+common scale. The sum over a group's targets can still take different
+branches for different targets, and a mix of branches is neither the lower
+nor the upper histogram, so its quantile may lie outside the two. ``TIE``
+still bounds that: a target sits on the threshold only where its
+whole-millisecond timestamps meet it to within ``TIE``, and a mix needs two
+targets of one group on it at one step, taken in opposite ways by the
+program; any other target enters both sums alike. Where that happens anyway
+the check reads a gap: it can fail a sound run, never pass a wrong one.
+
 ``control`` computes the same answers the way a tempted later PR would, for
-the control of "How correct is decided": ``"bf16"`` keeps sample values in
-bfloat16 (the precision below the float32 epilogue the program's counter
-kernels end in, and far below the float64 its gauges are served in),
-``"stale"`` answers without each window's newest sample (a stale answer,
-which the configurations' guarantees forbid).
+the control of "How correct is decided": ``"bf16"`` keeps sample values (a
+histogram's bucket counts) in bfloat16 (the precision below the float32
+epilogue the program's counter kernels end in, and far below the float64 its
+gauges are served in), ``"stale"`` answers without each window's newest
+sample (a stale answer, which the configurations' guarantees forbid).
 """
 
 import json
@@ -71,9 +110,18 @@ def _window_index(ts, steps, window_ms, drop_newest):
     return lo, hi
 
 
-def _rate(ts, vals, steps, window_ms, lo, hi, is_rate):
+def _rate(ts, vals, steps, window_ms, lo, hi, is_rate, from_ms=None):
+    """``from_ms``: a reset counts only between two samples at or after it
+    (FiloDB reads a query's samples from its first window's start and
+    carries the resets it sees from there); None: from the slice's first
+    column, one before the first window. The two differ only in the zero
+    point of a window whose first sample comes after a reset that the one
+    sees and the other does not (PERF.md section 7)."""
     drop = np.diff(vals, axis=1, prepend=vals[:, :1])
-    corr = np.cumsum(np.where(drop < 0, vals - drop, 0.0), axis=1)
+    seen = drop < 0
+    if from_ms is not None:
+        seen &= np.concatenate([ts[:, :1], ts[:, :-1]], axis=1) >= from_ms
+    corr = np.cumsum(np.where(seen, vals - drop, 0.0), axis=1)
     vals = vals + corr
     n = ts.shape[1]
     counts = hi - lo + 1
@@ -139,8 +187,15 @@ def evaluate(world, q, start_s, end_s, step_s, control=None):
         vals = to_bf16(vals)
     lo, hi = _window_index(ts, steps, window_ms, control == "stale")
     fn = q["fn"]
-    if fn in RATE_FNS:
-        bounds = _rate(ts, vals, steps, window_ms, lo, hi, fn == "rate")
+    # a histogram query carries its resets from its first window's start
+    from_ms = steps[0] - window_ms if "quantile" in q else None
+    if fn in RATE_FNS and vals.ndim == 3:   # native histograms: [S, B, T]
+        per = [_rate(ts, vals[:, :, b], steps, window_ms, lo, hi,
+                     fn == "rate", from_ms) for b in range(vals.shape[2])]
+        bounds = tuple(np.stack([p[i] for p in per], axis=1) for i in (0, 1))
+    elif fn in RATE_FNS:
+        bounds = _rate(ts, vals, steps, window_ms, lo, hi, fn == "rate",
+                       from_ms)
     elif fn in OVER_TIME:
         bounds = (_over_time(vals, lo, hi, OVER_TIME[fn]),) * 2
     else:
@@ -165,7 +220,82 @@ def evaluate(world, q, start_s, end_s, step_s, control=None):
         pair = tuple(AGGS[agg](rows[members]) for rows in bounds)
         if not np.isnan(pair[0]).all():
             out[key] = pair
+    if "quantile" in q:
+        out = _quantiles(q["quantile"], q.get("by", []), world.les, out)
     return out, (steps // 1000).tolist()
+
+
+def _quantiles(q, by, les, sums):
+    """The sums by ``by`` -> ``{key without le: (lower, upper)}`` of their
+    ``q``-quantile: native where the world has ``les`` (each key's rows
+    ``[B, T]``), classic otherwise (one row a key, ``le`` among ``by``)."""
+    # key -> (bucket bounds, lower [B, T], upper [B, T])
+    if les is not None:
+        hists = {key: (les, *pair) for key, pair in sums.items()}
+    else:
+        hists = {}
+        at = by.index("le")
+        groups = {}
+        for key, pair in sums.items():
+            groups.setdefault(key[:at] + key[at + 1:], []).append(
+                (float(key[at]), pair))
+        for key, buckets in groups.items():
+            buckets.sort(key=lambda b: b[0])
+            hists[key] = (np.array([b[0] for b in buckets]),
+                          *(np.stack([b[1][i] for b in buckets])
+                            for i in (0, 1)))
+    out = {}
+    for key, (bounds, low, high) in hists.items():
+        qs = [bucket_quantile(q, bounds, rows[None], monotone=les is None)[0]
+              for rows in (low, high)]
+        if not np.isnan(qs[0]).all():
+            out[key] = (np.minimum(*qs), np.maximum(*qs))
+    return out
+
+
+def bucket_quantile(q, les, counts, monotone=False):
+    """Prometheus's ``bucketQuantile`` (promql/quantile.go) at every
+    (group, step): ``les`` float64 ``[B]`` ascending, ``counts`` cumulative
+    ``[groups, B, steps]`` with NaN for a bucket that has no point there.
+    -> float64 ``[groups, steps]``, NaN where there is no point.
+
+    Per step, over the buckets that have a point: none where the ``+Inf``
+    bucket is missing, fewer than two buckets are left, or no observation
+    was made; ``monotone`` (classic series only) first takes the running
+    max over the buckets; a rank inside the ``+Inf`` bucket gives the
+    second-highest bound, inside a first bucket whose bound is <= 0 that
+    bound; otherwise linear inside the bucket, from 0 for the first."""
+    les = np.asarray(les, dtype=np.float64)
+    out = np.full((counts.shape[0], counts.shape[2]), np.nan)
+    for g in range(counts.shape[0]):
+        for t in range(counts.shape[2]):
+            col = counts[g, :, t]
+            have = ~np.isnan(col)
+            out[g, t] = _one_quantile(q, les[have], col[have], monotone)
+    return out
+
+
+def _one_quantile(q, les, counts, monotone):
+    if les.size < 2 or les[-1] != math.inf:
+        return math.nan
+    if monotone:
+        counts = np.maximum.accumulate(counts)
+    observations = counts[-1]
+    if observations == 0:
+        return math.nan
+    rank = q * observations
+    b = next((i for i in range(les.size - 1) if counts[i] >= rank),
+             les.size - 1)
+    if b == les.size - 1:
+        return float(les[-2])
+    if b == 0 and les[0] <= 0:
+        return float(les[0])
+    start, count = 0.0, float(counts[b])
+    if b > 0:
+        start = float(les[b - 1])
+        count -= float(counts[b - 1])
+        rank -= float(counts[b - 1])
+    return start + (float(les[b]) - start) * (rank / count)
 
 
 def _agg_sum(sub):

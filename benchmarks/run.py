@@ -264,6 +264,7 @@ def run_cell(cell_name, seed, seconds, trace, *, look_for_chip=True,
     try:
         datagen = importlib.import_module("datagen." + spec.config["datagen"])
         world = datagen.make(spec.config, seed, scale)
+        traffic_mod.check(spec.workload, world)
         try:
             up = node.read_line(1100)
         except RuntimeError as e:

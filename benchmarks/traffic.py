@@ -16,13 +16,19 @@ and where its range lies.
      "queries": [{"name": .., "weight": 1, "range_s": 1800, "step_s": 60,
                   "end": "history" | "now",
                   "query": {"metric", "select": {label: "$var" | value},
-                            "fn", "window_s", "agg", "by", "key_label"},
+                            "fn", "window_s", "agg", "by", "key_label",
+                            "quantile"},
                   "draw": {"var": {"label": .., "dist": "zipf"|"uniform"|
                                    "fixed", "s": 1.0, "k": 1, "values": []}}}],
      "warmup": {"each": ["var"], "max_rounds": 6, "round_s": 3},
      "scrape": {"in_flight_scrapes": 1} | null,
      "check": {"sample": 64, "limits": {"max_rel_err": 1e-5}},
      "must_rise": ["filodb_fused_aggs_total"]}
+
+A query with ``"quantile": q`` (0 < q < 1, only over ``"agg": "sum"`` of a
+rate) is ``histogram_quantile(q, <the aggregation>)``: over a world of native
+histograms ``by`` names the result's labels, over classic ``le`` series it
+names ``le`` too (``check`` refuses a mix that does not fit its world).
 """
 
 import re
@@ -33,6 +39,7 @@ import time
 import numpy as np
 
 from client import KeepAliveClient, request_bytes, scrape_metrics
+from reference import RATE_FNS
 
 PREGEN = 4096                  # requests drawn per client before the window
 MISSES = "filodb_exec_cache_misses_total"
@@ -49,12 +56,39 @@ def render(q):
     if not q.get("agg"):
         return inner
     by = f' by ({",".join(q["by"])})' if q.get("by") else ""
-    return f'{q["agg"]}({inner}){by}'
+    out = f'{q["agg"]}({inner}){by}'
+    if "quantile" in q:
+        out = f'histogram_quantile({q["quantile"]!r}, {out})'
+    return out
 
 
 def key_labels(q):
-    return list(q.get("by", [])) if q.get("agg") else [
-        q.get("key_label", "instance")]
+    if not q.get("agg"):
+        return [q.get("key_label", "instance")]
+    by = list(q.get("by", []))
+    return [b for b in by if b != "le"] if "quantile" in q else by
+
+
+def check(workload, world):
+    """Refuse, as the mix is loaded, what the window could not send or the
+    reference could not answer."""
+    for tmpl in workload["queries"]:
+        q = tmpl["query"]
+        if "quantile" in q:
+            if not (0 < q["quantile"] < 1 and q.get("agg") == "sum"
+                    and q["fn"] in RATE_FNS):
+                raise ValueError(
+                    f"{tmpl['name']}: a quantile needs 0 < q < 1 over "
+                    "\"agg\": \"sum\" of rate or increase")
+            if world.les is None and "le" not in q.get("by", []):
+                raise ValueError(f"{tmpl['name']}: a quantile over classic "
+                                 "bucket series needs \"le\" in \"by\"")
+        elif world.les is not None:
+            raise ValueError(f"{tmpl['name']}: a world of histograms is "
+                             "read through a quantile only")
+    if world.les is not None and workload.get("scrape"):
+        raise ValueError("a world of histograms is history only: live "
+                         "histogram writes are out of the harness's scope")
 
 
 def bind(q, values):

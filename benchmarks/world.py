@@ -5,6 +5,12 @@ from ``--seed``: the same seed gives the same arrays in both processes.
 one; ``backfill.py`` puts its first ``n_hist`` columns into the node,
 ``traffic.py`` sends the rest as live scrapes, ``reference.py`` answers
 queries from it.
+
+A world of histograms (``les`` given: the bucket bounds, ascending, ``+Inf``
+last) holds in ``vals`` the cumulative bucket counts, float64 ``[S, N, B]``,
+and in ``sums`` the histogram's ``sum`` column, float64 ``[S, N]``; its
+``count`` column is the ``+Inf`` bucket. Without ``les`` ``vals`` is float64
+``[S, N]``, one value a sample, and ``sums`` is None.
 """
 
 import numpy as np
@@ -12,12 +18,14 @@ import numpy as np
 
 class World:
     def __init__(self, *, schema, field, labels, ts, vals, n_hist, t0_ms,
-                 dt_ms, slack_ms):
+                 dt_ms, slack_ms, les=None, sums=None):
         self.schema = schema        # the program's schema name
         self.field = field          # the influx field that maps to it
         self.labels = labels        # per series: the full label map
         self.ts = ts                # int64 ms [S, N], sorted per row
-        self.vals = vals            # float64 [S, N]
+        self.vals = vals            # float64 [S, N], or [S, N, B] with les
+        self.les = les              # bucket bounds, +Inf last, or None
+        self.sums = sums            # float64 [S, N] with les, or None
         self.n_hist = n_hist        # columns [0, n_hist) are history
         self.t0_ms, self.dt_ms = t0_ms, dt_ms
         self.slack_ms = slack_ms    # |ts - tick| never exceeds it
