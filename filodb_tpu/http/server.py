@@ -1936,6 +1936,12 @@ class FiloHttpServer:
         "filodb_fused_holes_aggs_total":
             "Fused queries served by the grouped non-dense program "
             "(tiles with holes)",
+        "filodb_fused_hist_aggs_total":
+            "histogram_quantile queries of a histogram sum served by the "
+            "fused quantile program",
+        "filodb_fused_hist_refused_total":
+            "Queries of the fused histogram quantile shape the device "
+            "refused (the host served them), by reason",
         "filodb_mesh_dispatches_total":
             "Dispatches served from the mesh-resident sharded store",
         "filodb_mesh_refused_total":
@@ -2211,6 +2217,12 @@ class FiloHttpServer:
                  getattr(self.backend, "fused_aggs", 0))
             emit("fused_holes_aggs_total", {},
                  getattr(self.backend, "fused_holes_aggs", 0))
+            emit("fused_hist_aggs_total", {},
+                 getattr(self.backend, "fused_hist_aggs", 0))
+            for reason, n in sorted(getattr(self.backend,
+                                            "fused_hist_refused",
+                                            {}).items()):
+                emit("fused_hist_refused_total", {"reason": reason}, n)
             emit("mesh_dispatches_total", {},
                  getattr(self.backend, "mesh_dispatches", 0))
             for reason, n in sorted(getattr(self.backend, "mesh_refused",

@@ -335,6 +335,28 @@ def _h_aligned_tiles():
     return tiles, 8 * 64, 8
 
 
+@capacity_harness("tilestore-hist-tiles")
+def _h_hist_tiles():
+    """A histogram cohort of 12 buckets: valid bool + ts f64 a slot, and
+    raw, corrected and correction f64 a bucket value; a sample is one
+    bucket value of one slot."""
+    import numpy as np
+
+    from filodb_tpu.query import tilestore as tst
+    S, N, B = 8, 64, 12
+    rng = np.random.default_rng(_SEED)
+    base, dt = 1_000_000_000_000, 10_000
+    ts = base + np.arange(N, dtype=np.float64)[None, :] * dt \
+        + rng.integers(-2000, 2001, (S, N))
+    vals = np.cumsum(np.cumsum(rng.poisson(5.0, (S, N, B)), axis=2),
+                     axis=1).astype(np.float64)
+    les = tuple(float(2 ** i) for i in range(B - 1)) + (float("inf"),)
+    tiles = tst.HistTiles([{"i": str(i)} for i in range(S)], base, dt,
+                          np.ones((S, N), bool), ts, vals,
+                          np.zeros_like(vals), les)
+    return tiles, S * N * B, S
+
+
 @capacity_harness("tilestore-executable-constants")
 def _h_exec_constants():
     """Packed-executable cache entries retain the device constants

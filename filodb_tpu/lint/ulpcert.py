@@ -546,6 +546,71 @@ def _h_extrapolated_rate():
     return prod, ref, 0.0
 
 
+def _hist_world():
+    """Native latency histograms for the quantile harness: 24 series in 4
+    groups, the Prometheus client's 12 default bounds, 180 samples 10 s
+    apart (every second series +-2 s off the tick), Poisson(50)
+    observations a scrape under a log-normal, a reset of every bucket in
+    series 2 at sample 90 and one bucket falling in series 5 at sample
+    120. -> (series, tile-order group ids, groups, first step ms)."""
+    import numpy as np
+
+    from filodb_tpu.query.model import RawSeries
+    rng = np.random.default_rng(_SEED + 11)
+    les = np.array([.005, .01, .025, .05, .1, .25, .5, 1, 2.5, 5, 10,
+                    np.inf])
+    S, N, G, t0 = 24, 180, 4, 1_700_000_000_000
+    series = []
+    for s in range(S):
+        obs = rng.lognormal(np.log(0.02 * 2.0 ** (s % G)), 0.8,
+                            (N, 50))
+        per = (obs[..., None] <= les).sum(axis=1).astype(np.float64)
+        counts = np.cumsum(per, axis=0)
+        if s == 2:
+            counts[90:] -= counts[89]
+        if s == 5:
+            counts[120:, 3] -= 1.0
+        ts = t0 + np.arange(N, dtype=np.int64) * 10_000
+        if s % 2:
+            ts = ts + rng.integers(-2000, 2001, N)
+        series.append(RawSeries({"g": str(s % G), "i": str(s)}, ts, counts,
+                                is_counter=True, bucket_les=les))
+    return series, np.arange(S) % G, G, t0 + 1_200_000
+
+
+@precision_harness("hist-quantile")
+def _h_hist_quantile():
+    """The fused quantile program over bucket-axis tiles vs the host path
+    (``periodic_samples`` -> ``_aggregate_hist_sum`` ->
+    ``histogram_quantile``, numpy f64) at q 0.1, 0.5 and 0.99 of rate
+    and increase; the first window starts at series 2's reset."""
+    import numpy as np
+
+    from filodb_tpu.query import engine as eng
+    from filodb_tpu.query import tilestore as tst
+    from filodb_tpu.query.model import RangeParams, clip_series
+    series, gids, G, first = _hist_world()
+    tiles, idx = tst.build_aligned_tiles(series)
+    window, step, nsteps = 300_000, 30_000, 16
+    steps = RangeParams(first, step, first + (nsteps - 1) * step).steps
+    grid = np.array([first - window, first, step, tiles.num_slots,
+                     tiles.base_ms, tiles.dt_ms], np.int64)
+    arrs = tst._tiles_arrays_hist(tiles)
+    prod, ref = [], []
+    for func in ("rate", "increase"):
+        fn = _jit_eval(tst._hist_quantile_program, func, nsteps, G)
+        host = eng._aggregate_hist_sum(eng.periodic_samples(
+            clip_series(series, first - window, int(steps[-1])),
+            RangeParams(first, step, int(steps[-1])), func, window),
+            ("g",), ())
+        for q in (0.1, 0.5, 0.99):
+            prod.append(np.asarray(fn(arrs, grid,
+                                      np.asarray(gids[idx], np.int32),
+                                      np.asarray(tiles.les), np.float64(q))))
+            ref.append(eng.histogram_quantile(host, q).values.T)
+    return np.stack(prod), np.stack(ref), 0.0
+
+
 @precision_harness("append-carry-exact")
 def _h_append_carry():
     """Donated append vs from-scratch rebuild, reset-free block:

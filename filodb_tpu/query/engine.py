@@ -1423,6 +1423,9 @@ class QueryEngine:
             return scalar_vector_op(grid, scalar, plan.op, plan.scalar_is_lhs,
                                     plan.return_bool)
         if isinstance(plan, lp.ApplyInstantFunction):
+            fused = self._try_fused_hist_quantile(plan)
+            if fused is not None:
+                return fused
             grid = self._eval(plan.inner)
             args = [eval_scalar(a, self).values[0] if not isinstance(
                 a, (int, float)) else a for a in plan.func_args]
@@ -1479,31 +1482,48 @@ class QueryEngine:
         ``filodb_aligned_{fast,slide,exact}_evals_total``. A selection
         with holes that is served counts in
         ``filodb_fused_holes_aggs_total``."""
-        if self.backend is None or plan.op not in ("sum", "count", "avg"):
+        if not self._fused_agg_shape(plan, ("sum", "count", "avg"),
+                                     ("rate", "increase", "delta")):
             return None
-        if plan.params:
-            return None
+        series = self._select_whole(plan.inner)
+        # taken once a request and handed down: on a memoised selection
+        # nothing below walks the series again
+        facts = selection_facts(series) if series else None
+        return self._aggregate_selected(plan, series, facts)
+
+    def _fused_agg_shape(self, plan, ops, functions) -> bool:
+        """Is ``plan`` an ``Aggregate`` of one of ``ops``, without
+        parameters, over one of the range ``functions`` of a raw
+        selection, with no ``@`` and no function arguments."""
+        if self.backend is None or not isinstance(plan, lp.Aggregate) \
+                or plan.op not in ops or plan.params:
+            return False
         inner = plan.inner
-        if not isinstance(inner, lp.PeriodicSeriesWithWindowing):
-            return None
-        if inner.at_ms is not None or inner.func_args or \
-                inner.function not in ("rate", "increase", "delta"):
-            return None
-        raw = inner.raw
-        if not isinstance(raw, lp.RawSeriesPlan):
-            return None
-        fetch_start = inner.start_ms - inner.window_ms - inner.offset_ms
-        fetch_end = (inner.end_ms - inner.offset_ms if inner.offset_ms
-                     else inner.end_ms)
-        series = select_raw_series(
-            self.shards, raw.filters, fetch_start, fetch_end, raw.column,
-            self.stats, full=True, limits=self.limits)
+        return (isinstance(inner, lp.PeriodicSeriesWithWindowing)
+                and inner.at_ms is None and not inner.func_args
+                and inner.function in functions
+                and isinstance(inner.raw, lp.RawSeriesPlan))
+
+    @staticmethod
+    def _fetch_span(inner) -> Tuple[int, int]:
+        """[start, end] ms of the samples the windows of ``inner`` read."""
+        return (inner.start_ms - inner.window_ms - inner.offset_ms,
+                inner.end_ms - inner.offset_ms if inner.offset_ms
+                else inner.end_ms)
+
+    def _select_whole(self, inner) -> List[RawSeries]:
+        """The ``full=True`` selection the windows of ``inner`` read."""
+        return select_raw_series(
+            self.shards, inner.raw.filters, *self._fetch_span(inner),
+            inner.raw.column, self.stats, full=True, limits=self.limits)
+
+    def _aggregate_selected(self, plan, series, facts) -> GridResult:
+        """``_try_fused_agg``'s answer over a selection already made:
+        the fused program where the backend takes it, else the range
+        function and ``aggregate()`` over the same series."""
+        inner = plan.inner
         params = RangeParams(inner.start_ms, inner.step_ms, inner.end_ms)
-        res = facts = None
-        if series:
-            # taken once a request and handed down: on a memoised
-            # selection nothing below walks the series again
-            facts = selection_facts(series)
+        res = None
         if facts is not None and not facts.any_hist:
             with obs_trace.span("group-keys"):
                 gids, gkeys = _selection_groups(series, tuple(plan.by),
@@ -1532,10 +1552,51 @@ class QueryEngine:
                 inner.offset_ms, facts)
         if grid is None:
             grid = periodic_samples(
-                clip_series(series, fetch_start, fetch_end), params,
+                clip_series(series, *self._fetch_span(inner)), params,
                 inner.function, inner.window_ms, (), inner.offset_ms)
         return aggregate(grid, plan.op, (), tuple(plan.by),
                          tuple(plan.without))
+
+    def _try_fused_hist_quantile(self, plan) -> Optional[GridResult]:
+        """``histogram_quantile(q, sum by (g) (rate|increase(h[w])))``
+        with a literal ``q``: ONE selection, and where it holds native
+        histogram columns the backend's fused quantile program
+        (``TpuBackend.fused_hist_quantile``: only [T, G] leaves the chip;
+        it refuses, and counts, what its tiles cannot hold).
+        Where it answers None, or the selection holds no such histograms,
+        the same selection goes the way ``_eval`` would have taken it (no
+        second fetch, stats counted once): ``_try_fused_agg``'s answer of
+        the sum, then ``histogram_quantile`` on the host, which is the
+        plain path of native histograms (``periodic_samples`` ->
+        ``_aggregate_hist_sum`` -> ``histogram_quantile``) and keeps a
+        classic ``le`` sum on the counter fused path. None for any other
+        plan shape."""
+        if plan.function != "histogram_quantile" \
+                or len(plan.func_args) != 1 \
+                or not isinstance(plan.func_args[0], (int, float)) \
+                or not self._fused_agg_shape(plan.inner, ("sum",),
+                                             ("rate", "increase")):
+            return None
+        q = float(plan.func_args[0])
+        agg, inner = plan.inner, plan.inner.inner
+        series = self._select_whole(inner)
+        facts = selection_facts(series) if series else None
+        if facts is not None and facts.any_hist:
+            with obs_trace.span("group-keys"):
+                gids, gkeys = _selection_groups(series, tuple(agg.by),
+                                                tuple(agg.without))
+            steps = RangeParams(inner.start_ms, inner.step_ms,
+                                inner.end_ms).steps
+            res = self.backend.fused_hist_quantile(
+                series, inner.function, steps, inner.window_ms,
+                inner.offset_ms, gids, len(gkeys), q, facts)
+            if res is not None:
+                with obs_trace.span("aggregate", op="histogram_quantile",
+                                    path="fused-hist"):
+                    return GridResult(steps, gkeys,
+                                      res.T.astype(np.float64))
+        grid = self._aggregate_selected(agg, series, facts)
+        return instant_function(grid, plan.function, [q])
 
     def _periodic(self, raw: lp.RawSeriesPlan, start_ms, step_ms, end_ms,
                   function, window_ms, func_args, offset_ms) -> GridResult:

@@ -266,13 +266,16 @@ class SelectionFacts:
                   without ``use_snap``);
     ``tail_min``  the earliest timestamp beyond any series' chunk prefix
                   (None: no tail anywhere), from the facts: nothing is read;
-    ``any_hist``  is any series a histogram.
+    ``any_hist``  is any series a histogram;
+    ``les``       the bucket bounds every series shares, as a tuple, where
+                  every series is a histogram of one bucket scheme (None
+                  else: a selection a bucket-axis tile cannot hold).
 
     They are as fresh as the handles they were read from: a read may
     rewrite a handle's facts (a partition evicted or paged in under it), so
     whoever reads the samples makes them again afterwards."""
 
-    __slots__ = ("key", "ident", "tail_min", "any_hist")
+    __slots__ = ("key", "ident", "tail_min", "any_hist", "les")
 
     def __init__(self, series: Sequence[RawSeries]):
         keys = [s.snapshot_key for s in series]
@@ -286,6 +289,7 @@ class SelectionFacts:
                  if t is not None]
         self.tail_min = min(tails) if tails else None
         self.any_hist = True in [s.is_hist for s in series]
+        self.les = _one_scheme(series) if self.any_hist else None
 
     @property
     def use_snap(self) -> bool:
@@ -298,6 +302,21 @@ class SelectionFacts:
         if tm is None or cov_min_ms is not None and cov_min_ms < tm:
             return cov_min_ms
         return tm
+
+
+def _one_scheme(series: Sequence[RawSeries]) -> Optional[Tuple[float, ...]]:
+    """The bucket bounds of a selection whose every series is a histogram
+    of one scheme, or None."""
+    first = None
+    for s in series:
+        les = s.bucket_les
+        if not s.is_hist or les is None:
+            return None
+        if first is None:
+            first = les
+        elif les is not first and not np.array_equal(les, first):
+            return None
+    return tuple(np.asarray(first, np.float64).tolist())
 
 
 def selection_facts(series: Sequence[RawSeries]) -> SelectionFacts:

@@ -42,6 +42,7 @@ import jax.numpy as jnp  # noqa: E402
 from filodb_tpu.lint.capacity import capacity
 from filodb_tpu.lint.contracts import kernel_contract
 from filodb_tpu.lint.numerics import order_insensitive, precision  # noqa: F401
+from filodb_tpu.memory import histogram as bh
 from filodb_tpu.query import pallas_kernels as pk
 from filodb_tpu.query.cumsum import cumsum_f64
 from filodb_tpu.query.model import RawSeries
@@ -92,14 +93,17 @@ class AlignedTiles:
         self.keys = keys
         self.base_ms = int(base_ms)          # time of slot 0
         self.dt_ms = int(dt_ms)
-        S, N = vals.shape
+        S, N = vals.shape[:2]
         self.num_slots = N
         self.valid = jnp.asarray(valid)                      # [S,N] bool
         # true timestamps as f64 ms (exact to 2^53); invalid -> NaN so
         # boundary conditions (ts <= wend) are false on gaps
         self.ts = jnp.where(self.valid, jnp.asarray(ts_true, jnp.float64),
                             jnp.nan)
-        self.vals = jnp.where(self.valid, jnp.asarray(vals), 0.0)
+        # [S,N], or [S,N,B] for histograms (HistTiles)
+        self.vals = jnp.where(
+            self.valid if vals.ndim == 2 else self.valid[..., None],
+            jnp.asarray(vals), 0.0)
         self._channels: Dict[str, jnp.ndarray] = {}
         self._ff: Dict[str, jnp.ndarray] = {}
         self._bf: Dict[str, jnp.ndarray] = {}
@@ -490,6 +494,93 @@ class AlignedTiles:
         return self._jitter
 
 
+@capacity(
+    "tilestore-hist-tiles", bytes_per_sample=24.75,
+    reason="a histogram cohort prices its bucket axis: a sample is ONE "
+           "bucket value of one slot, 8 B in each of the three f64 tiles "
+           "(raw values [S, N, B], the corrected channel and the "
+           "correction slot-major [N, B*S]), and the slot's valid bool + "
+           "ts f64 (9 B) shared by its B buckets: 24 + 9/B B a bucket "
+           "value, priced at the Prometheus client's default scheme of 12 "
+           "buckets (24.75 B); the int32 timestamps and a holed cohort's "
+           "fills are lazy warm caches, as for counters")
+class HistTiles(AlignedTiles):
+    """A cohort of native histogram series of one bucket scheme (``les``,
+    ``+Inf`` last) sharing cadence dt: ``valid`` and ``ts`` [S, N] as for
+    counters, ``vals`` [S, N, B], and the two channels the fused quantile
+    program reads, slot-major and bucket-major, [N, B*S] (column ``b*S +
+    s`` is bucket b of series s: a slot's row is B runs of the S series,
+    as lane-wide as a counter tile's, so no layout change is asked of a
+    program that takes rows): the counter-corrected buckets ``t_cv`` and
+    the correction itself ``t_corr``. Both follow FiloDB's histogram rule
+    (memory/histogram.py ``hist_counter_correction``): a row where ANY
+    bucket fell against the row before adds back the WHOLE previous
+    histogram, cumulatively; a chunk's drop table is taken where it has
+    one. ``t_corr`` holds, at a slot with no sample, the correction of
+    the next sample (a backward fill), so the first sample at or after
+    any instant reads it where the value channel's backward fill reads
+    the value."""
+
+    def __init__(self, keys, base_ms, dt_ms, valid, ts_true, vals, corr,
+                 les):
+        super().__init__(keys, base_ms, dt_ms, valid, ts_true, vals)
+        self.les = tuple(les)
+        self.num_buckets = len(self.les)
+        cv = np.where(valid[..., None], vals + corr, 0.0)
+        self.t_cv = jnp.asarray(_slot_major(cv))
+        self.t_corr = jnp.asarray(_slot_major(_bfill_rows(corr, valid)))
+
+    def t_fill(self, kind: str) -> jnp.ndarray:
+        """[N, B*S] forward ("ff") or backward ("bf") fill of ``t_cv``
+        over the slots with no sample (NaN where none is there); dense
+        tiles alias the channel."""
+        if self._dense:
+            return self.t_cv
+        c = self._tch.get("hist_" + kind)
+        if c is None:
+            if kind == "ff":
+                if self._jl is None:
+                    self._jl = _ffill_idx(self.valid)
+                idx = self._jl.T + 1                    # [N, S], 0 = none
+                src = jnp.concatenate(
+                    [jnp.full_like(self.t_cv[:1], jnp.nan), self.t_cv])
+            else:
+                if self._jf is None:
+                    rev = jnp.flip(self.valid, axis=1)
+                    self._jf = (self.valid.shape[1] - 1
+                                - jnp.flip(_ffill_idx(rev), axis=1)).astype(
+                                    jnp.int32)
+                idx = jnp.clip(self._jf, 0, self.num_slots).T
+                src = jnp.concatenate(
+                    [self.t_cv, jnp.full_like(self.t_cv[:1], jnp.nan)])
+            c = jnp.take_along_axis(
+                src, jnp.tile(idx.astype(jnp.int32), (1, self.num_buckets)),
+                axis=0)
+            self._tch["hist_" + kind] = c
+        return c
+
+
+def _slot_major(a: np.ndarray) -> np.ndarray:
+    """[S, N, B] -> [N, B*S], column ``b*S + s``."""
+    S, N, B = a.shape
+    return np.ascontiguousarray(a.transpose(1, 2, 0).reshape(N, B * S))
+
+
+def _bfill_rows(corr: np.ndarray, valid: np.ndarray) -> np.ndarray:
+    """[S, N, B] with every slot that holds no sample given the value of
+    the next slot that does (the last sample's past the end)."""
+    out = np.empty_like(corr)
+    for r in range(valid.shape[0]):
+        pos = np.flatnonzero(valid[r])
+        if not pos.size:
+            out[r] = 0.0
+            continue
+        nxt = np.minimum(np.searchsorted(pos, np.arange(valid.shape[1])),
+                         pos.size - 1)
+        out[r] = corr[r, pos[nxt]]
+    return out
+
+
 _SENT_LO = -(2 ** 31)           # "no sample at or before this slot"
 _SENT_HI = 2 ** 31 - 1          # "no sample at or after this slot"
 
@@ -527,8 +618,12 @@ def _align_rows(series: Sequence[RawSeries], dt: int):
     rows, aligned_idx = [], []
     lo = hi = None
     for i, s in enumerate(series):
-        m = ~np.isnan(s.values)
-        ts, vals = s.ts[m], s.values[m]
+        if s.values.ndim == 2:
+            # a histogram row is a sample: slots from the timestamps alone
+            ts, vals = s.ts, s.values
+        else:
+            m = ~np.isnan(s.values)
+            ts, vals = s.ts[m], s.values[m]
         if ts.size == 0:
             continue
         slots = np.round(ts / dt).astype(np.int64)
@@ -553,6 +648,14 @@ def build_aligned_tiles(series: Sequence[RawSeries],
     half the series align or cadence can't be established."""
     if not series:
         return None, []
+    les = None
+    if series[0].is_hist:
+        # a bucket-axis cohort: every series a histogram of one scheme
+        les = series[0].bucket_les
+        if les is None or any(
+                not s.is_hist or s.bucket_les is None
+                or not np.array_equal(s.bucket_les, les) for s in series):
+            return None, []
     dt_cands = _estimate_dt_candidates(series)
     if not dt_cands:
         return None, []
@@ -569,6 +672,8 @@ def build_aligned_tiles(series: Sequence[RawSeries],
     base = int(lo * dt)
     N = int(hi - lo + 1)
     S = len(rows)
+    if les is not None:
+        return _hist_tiles(series, rows, les, lo, N, base, dt), aligned_idx
     valid = np.zeros((S, N), dtype=bool)
     ts_true = np.zeros((S, N), dtype=np.float64)
     vals_g = np.zeros((S, N), dtype=np.float64)
@@ -580,6 +685,28 @@ def build_aligned_tiles(series: Sequence[RawSeries],
         vals_g[r, pos] = vals
         keys.append(dict(series[i].labels))
     return AlignedTiles(keys, base, dt, valid, ts_true, vals_g), aligned_idx
+
+
+def _hist_tiles(series, rows, les, lo, N, base, dt) -> HistTiles:
+    """``build_aligned_tiles``' bucket-axis cohort; each series' reset
+    correction is taken over its own rows, as the host path takes it."""
+    S, B = len(rows), len(les)
+    valid = np.zeros((S, N), dtype=bool)
+    ts_true = np.zeros((S, N), dtype=np.float64)
+    vals_g = np.zeros((S, N, B), dtype=np.float64)
+    corr_g = np.zeros((S, N, B), dtype=np.float64)
+    keys = []
+    for r, (i, slots, ts, vals) in enumerate(rows):
+        s = series[i]
+        pos = slots - lo
+        valid[r, pos] = True
+        ts_true[r, pos] = ts
+        vals_g[r, pos] = vals
+        if s.is_counter:
+            corr_g[r, pos] = bh.hist_counter_correction(
+                vals, drop_rows=s.hist_drop_rows)
+        keys.append(dict(s.labels))
+    return HistTiles(keys, base, dt, valid, ts_true, vals_g, corr_g, les)
 
 
 # ---------------------------------------------------------------------------
@@ -918,9 +1045,30 @@ def _eval_counter_fast(func: str, nsteps: int, arrs: Dict[str, jnp.ndarray],
     Results match the exact-f64 evaluator to ~1e-6 relative (a few f32
     ulps from the extrapolation factor). The dispatcher guards that the
     query grid fits int32 ms relative to base; wider grids take the
-    exact path."""
+    exact path.
+
+    Histogram channels (``HistTiles``: values [N, B*S]) carry the bucket
+    axis through every take and give [T, B, S] **f64**: the timestamps,
+    counts and selects are the series' own, shared by its buckets, and
+    the epilogue is the f64 formula (``_extrapolated_rate``), since a
+    quantile divides the rates' error by a bucket's share of the total.
+    With them comes ``corr``, and the buckets count from the query's
+    first window's start, as FiloDB reads them: the correction the tile
+    holds at the first sample at or after ``w0s`` is taken off both
+    boundary values (the zero point moves, the delta does not)."""
     N = num_slots
     dense = "ps_ones" not in arrs
+    hist = "corr" in arrs
+    TK = jax.named_scope("window_take")(
+        lambda a, k: jnp.take(a, k, axis=0))                # [T, S] rows
+    TV, ax = TK, (lambda a: a)
+    if hist:
+        # value rows [T, B*S] as [T, B, S]: masks and times of a series
+        # broadcast over its buckets
+        S = arrs["tsr"].shape[1]
+        B = arrs["ff_v"].shape[1] // S
+        TV = lambda a, k: TK(a, k).reshape(-1, B, S)        # noqa: E731
+        ax = lambda a: a[:, None]                           # noqa: E731
     t = jnp.arange(nsteps, dtype=jnp.int64)
     wend = w0e + t * step
     wstart = w0s + t * step
@@ -928,8 +1076,6 @@ def _eval_counter_fast(func: str, nsteps: int, arrs: Dict[str, jnp.ndarray],
     k_lo = jnp.ceil((wstart - base - dt / 2.0) / dt).astype(jnp.int64)
     wend_r = (wend - base).astype(jnp.int32)[:, None]       # guarded i32
     wstart_r = (wstart - base).astype(jnp.int32)[:, None]
-    TK = jax.named_scope("window_take")(
-        lambda a, k: jnp.take(a, k, axis=0))                # [T, S] rows
 
     kc = jnp.clip(k_hi, 0, N - 1).astype(jnp.int32)         # == khx
     kp = jnp.clip(k_hi - 1, 0, N - 1).astype(jnp.int32)
@@ -950,11 +1096,11 @@ def _eval_counter_fast(func: str, nsteps: int, arrs: Dict[str, jnp.ndarray],
         tsb_kn = TK(arrs["bf_tsr"], kn)
         raw_kc = TK(arrs["tsr"], kc)
         raw_kcl = TK(arrs["tsr"], kcl)
-    v_kc = TK(arrs["ff_v"], kc)
-    v_kp = TK(arrs["ff_v"], kp)
+    v_kc = TV(arrs["ff_v"], kc)
+    v_kp = TV(arrs["ff_v"], kp)
     bf_v = arrs["ff_v"] if dense else arrs["bf_v"]
-    v_kcl = TK(bf_v, kcl)
-    v_kn = TK(bf_v, kn)
+    v_kcl = TV(bf_v, kcl)
+    v_kn = TV(bf_v, kn)
 
     # counts: slot arithmetic (dense) / prefix diff, minus edge-slot
     # samples that jitter outside the window
@@ -979,12 +1125,21 @@ def _eval_counter_fast(func: str, nsteps: int, arrs: Dict[str, jnp.ndarray],
     none_hi = (k_hi < 0)[:, None]
     use1 = ts_kc <= wend_r
     t2 = jnp.where(use1, ts_kc, ts_kp)
-    v2 = jnp.where(none_hi, jnp.nan, jnp.where(use1, v_kc, v_kp))
+    v2 = jnp.where(ax(none_hi), jnp.nan,
+                   jnp.where(ax(use1), v_kc, v_kp))
     # first sample >= wstart
     none_lo = (k_lo > N - 1)[:, None]
     useb = tsb_kcl >= wstart_r
     t1 = jnp.where(useb, tsb_kcl, tsb_kn)
-    v1 = jnp.where(none_lo, jnp.nan, jnp.where(useb, v_kcl, v_kn))
+    v1 = jnp.where(ax(none_lo), jnp.nan,
+                   jnp.where(ax(useb), v_kcl, v_kn))
+    if hist:
+        # the first window's first sample: the same 2-candidate select
+        c0 = jnp.where(useb[0], arrs["corr"][kcl[0]].reshape(B, S),
+                       arrs["corr"][kn[0]].reshape(B, S))   # [B, S]
+        return _extrapolated_rate(
+            ax(wstart_r), ax(wend_r), ax(counts), ax(t1), v1 - c0, ax(t2),
+            v2 - c0, True, func == "rate")
 
     return _f32_epilogue(func, counts, t1, v1, t2, v2, wstart_r, wend_r,
                          (w0e - w0s).astype(jnp.float32) / 1000.0)
@@ -1478,6 +1633,133 @@ def _groupsum_holes(tiles: AlignedTiles, func: str, steps: np.ndarray,
            tuple(arrs["tsr"].shape))
     fn = _jit_lookup(_EVAL_T_JIT, key, lambda: jax.jit(
         _bind(_groupsum_holes_program, func, nsteps, G)),
+        site="groupsum", cost_args=args)
+    return fn(*args)
+
+
+def _tiles_arrays_hist(tiles: HistTiles) -> Dict[str, jnp.ndarray]:
+    """Channels of the fused quantile program: ``_tiles_arrays_fast``'s,
+    the value channels with the bucket axis, and the correction."""
+    if tiles._dense:
+        return {"tsr": tiles.t_tsr_i32(), "ff_v": tiles.t_cv,
+                "corr": tiles.t_corr}
+    return {
+        "tsr": tiles.t_tsr_i32(),
+        "ones": tiles.t_ones_i8(),
+        "ps_ones": tiles.t_ps_ones_i32(),
+        "ff_tsr": tiles.t_ff_tsr_i32(),
+        "bf_tsr": tiles.t_bf_tsr_i32(),
+        "ff_v": tiles.t_fill("ff"),
+        "bf_v": tiles.t_fill("bf"),
+        "corr": tiles.t_corr,
+    }
+
+
+def _bucket_quantile(q, les, h):
+    """``histogram_quantile`` (memory/histogram.py ``quantile``, which is
+    Prometheus's ``bucketQuantile``) at every (step, group) at once: ``h``
+    [T, G, B] cumulative, NaN where the group has no point; ``les`` [B],
+    ``+Inf`` last. The bucket is the first whose count reaches
+    ``rank = q * total`` (the last one's count is ``total``); in the
+    ``+Inf`` bucket the second-highest bound, in a first bucket bounded
+    at or below 0 that bound, in an empty bucket its upper bound, else
+    linear inside it, from 0 for the first. NaN where ``total`` is 0 or
+    NaN; a ``q`` outside [0, 1] gives -Inf or +Inf."""
+    B = h.shape[-1]
+    total = h[..., -1]
+    rank = q * total
+    reach = jnp.concatenate(
+        [h[..., :-1] >= rank[..., None],
+         jnp.ones(h.shape[:-1] + (1,), bool)], axis=-1)
+    b = jnp.argmax(reach, axis=-1)                          # [T, G]
+    at = jnp.arange(B)
+
+    def pick(x, i):
+        # x[.., i] by a masked sum over the buckets: no gather
+        return jnp.sum(jnp.where(at == i[..., None], x, 0.0), axis=-1)
+    c_end, le_end = pick(h, b), pick(les, b)
+    c_start, le_start = pick(h, b - 1), pick(les, b - 1)   # 0 where b = 0
+    inside = le_start + (le_end - le_start) * (rank - c_start) \
+        / (c_end - c_start)
+    out = jnp.where(c_end == c_start, le_end, inside)
+    out = jnp.where((b == 0) & (les[0] <= 0), les[0], out)
+    out = jnp.where(b == B - 1, les[B - 2], out)
+    out = jnp.where(total == 0, jnp.nan, out)
+    out = jnp.where((q >= 0) & (q <= 1), out,
+                    jnp.where(q > 1, jnp.inf, -jnp.inf))
+    return jnp.where(jnp.isnan(total), jnp.nan, out)
+
+
+@precision(
+    "hist-quantile", bits=31, rel_ulps=256, compensated=True,
+    reason="int32 relative timestamps under counters_batch_family's "
+           "span guard (exact); everything after them is f64: the "
+           "boundary deltas, the extrapolation formula, the masked group "
+           "sums (an f64 accumulator) and the quantile, whose interpolation divides the rank's "
+           "rounding by the bucket's share of the total — certified "
+           "against the host path (periodic_samples, _aggregate_hist_sum, "
+           "histogram_quantile in numpy f64) over every bucket a p99, a "
+           "median and a tenth percentile land in")
+def _hist_quantile_program(func: str, nsteps: int, G: int, arrs, grid,
+                           ids, les, q):
+    """``histogram_quantile(q, sum by (g) (rate|increase(h[w])))`` over a
+    histogram cohort as ONE traceable program (jitted once per static
+    tuple by ``hist_quantile_groupsum``): the evaluator's [T, B, S] rates
+    (``_eval_counter_fast``'s takes, the f64 formula: NaN where a window
+    holds fewer than two samples), masked f64 sums by group to [T, G, B]
+    (not an f64 dot: the chip has no f64 matmul; a bucket of a group no
+    series has a rate in is NaN, as the host's ``_aggregate_hist_sum``),
+    then ``_bucket_quantile`` to [T, G] f64. ``grid`` is int64[6] as
+    ``_groupsum_holes_program``'s; ``ids`` int32 [S] in tile order, an id
+    outside [0, G) names no group; ``les`` f64 [B] and ``q`` f64 are
+    runtime values, so one executable serves every quantile and every
+    scheme of B buckets."""
+    w0s, w0e, step, num_slots, base, dt = grid
+    rates = _eval_counter_fast(func, nsteps, arrs, num_slots, base, dt,
+                               w0s, w0e, step)              # [T, B, S]
+    member = ids[None, :] == jnp.arange(G, dtype=jnp.int32)[:, None]
+    ok = ~jnp.isnan(rates)[:, None] & member[None, :, None, :]
+    sums = jnp.sum(jnp.where(ok, rates[:, None], 0.0), axis=3,
+                   dtype=jnp.float64)                       # [T, G, B]
+    cnts = jnp.sum(ok, axis=3, dtype=jnp.int32)
+    return _bucket_quantile(q, les, jnp.where(cnts > 0, sums, jnp.nan))
+
+
+@kernel_contract(
+    "hist_quantile_dispatch", kind="dispatch",
+    rel_time_bits=31, span_guard="counters_batch_family",
+    notes="the fused histogram quantile: the f32-hybrid evaluator's "
+          "takes with a bucket axis and the f64 formula, masked group "
+          "sums and the quantile in one program where "
+          "counters_batch_family says the grid fits int32 ms relative to "
+          "the tile base; _eval_counter_fast clips its own "
+          "indices. One cached executable (site groupsum) per (func, "
+          "nsteps, G, channel shape) takes int64[6], the group ids, les "
+          "and q")
+def hist_quantile_groupsum(tiles: HistTiles, func: str, steps: np.ndarray,
+                           window_ms: int, gids, G: int, q: float,
+                           offset_ms: int = 0):
+    """``histogram_quantile(q, sum by (g) (rate|increase(h[w])))`` fused
+    on device -> f64 [T, G] (a device array), or None for a grid wider
+    than int32 ms from the tile base (the caller serves it on the host).
+    ``gids``: the group id in [0, G) of every series of the tiles, in tile
+    order."""
+    assert func in ("rate", "increase")
+    nsteps = steps.size
+    if nsteps < 1 or counters_batch_family(
+            tiles, func, steps, window_ms, offset_ms) == ("t",):
+        return None
+    w0e = int(steps[0] - offset_ms)
+    step = int(steps[1] - steps[0]) if nsteps > 1 else 1
+    arrs = _tiles_arrays_hist(tiles)
+    grid = np.array([w0e - window_ms, w0e, step, tiles.num_slots,
+                     tiles.base_ms, tiles.dt_ms], np.int64)
+    args = (arrs, grid, np.asarray(gids, np.int32),
+            np.asarray(tiles.les, np.float64), np.float64(q))
+    key = ("groupsum", "hist", func, nsteps, G,
+           tuple(arrs["ff_v"].shape), "ps_ones" in arrs)
+    fn = _jit_lookup(_EVAL_T_JIT, key, lambda: jax.jit(
+        _bind(_hist_quantile_program, func, nsteps, G)),
         site="groupsum", cost_args=args)
     return fn(*args)
 

@@ -542,6 +542,13 @@ class TpuBackend:
         # that the gate refused over tiles with holes
         self.fused_refused = 0
         self.fused_refused_gaps = 0
+        # histogram_quantile of a histogram sum served by the fused
+        # quantile program, and the fused_hist_quantile calls that came
+        # back None, by reason (not in fused_aggs / fused_refused: those
+        # count counter group sums)
+        self.fused_hist_aggs = 0
+        self.fused_hist_refused = {"cpu": 0, "tiles": 0, "tail": 0,
+                                   "grid": 0}
         # counter queries per aligned evaluator family
         # (tst.counters_batch_family; a batch of B counts B)
         self.aligned_evals = {"fast": 0, "slide": 0, "t": 0}
@@ -781,6 +788,13 @@ class TpuBackend:
     def _prefix_len(s) -> int:
         return s.chunk_len if s.chunk_len >= 0 else s.ts.size
 
+    @classmethod
+    def _prefix_drops(cls, s) -> Optional[np.ndarray]:
+        """A histogram's drop table cut to its chunk prefix (None: the
+        tile build detects the resets itself)."""
+        dr = s.hist_drop_rows
+        return None if dr is None else dr[dr < cls._prefix_len(s)]
+
     def _build_tile_entry(self, series):
         """Build one tile-cache entry over the series' immutable chunk
         prefixes -> (entry, the selection's facts as they read AFTER the
@@ -799,7 +813,9 @@ class TpuBackend:
         prefix = [
             RawSeries(s.labels, s.ts[:self._prefix_len(s)],
                       s.values[:self._prefix_len(s)], s.is_counter,
-                      s.bucket_les)
+                      s.bucket_les,
+                      hist_drop_rows=self._prefix_drops(s)
+                      if s.is_hist else None)
             for s in series
         ]
         facts = SelectionFacts(series)
@@ -819,7 +835,9 @@ class TpuBackend:
                "(valid bool + ts f64 + vals f64 = 17 B per slot) over "
                "the selection's immutable chunk prefix, FIFO-capped "
                "at _TILE_CACHE_MAX entries; warm channel caches on "
-               "the retained cohort are priced by the tilestore claim")
+               "the retained cohort are priced by the tilestore claim, "
+               "and a histogram cohort (HistTiles, a bucket axis) by "
+               "its own, tilestore-hist-tiles")
     def _insert_tile_entry(self, key, ident, entry) -> None:
         with self._tile_lock:
             while len(self._tile_cache) >= self._TILE_CACHE_MAX:
@@ -1214,6 +1232,61 @@ class TpuBackend:
             sums, cnts = np.asarray(res[0]), np.asarray(res[1])
             transfer_counts.d2h_bytes += sums.nbytes + cnts.nbytes
             return sums[:T], cnts[:T]
+
+    def fused_hist_quantile(self, series, func: str, steps: np.ndarray,
+                            window_ms: int, offset_ms: int,
+                            gids: np.ndarray, G: int, q: float,
+                            facts: Optional[SelectionFacts] = None
+                            ) -> Optional[np.ndarray]:
+        """``histogram_quantile(q, sum by (g) (rate|increase(h[w])))``
+        over native histogram columns fused on device: one cached program
+        over the selection's bucket-axis tiles (``tst.HistTiles``, from
+        the same tile cache, keyed by the facts) takes the rates bucket by
+        bucket, sums them by group and takes the quantile, and only the
+        [T, G] answer leaves the chip. Returns f64 [T, G] numpy, or None
+        (the caller serves the query on the host over the same
+        selection). Counts in ``filodb_fused_hist_aggs_total``, a None in
+        ``filodb_fused_hist_refused_total{reason}``: ``cpu`` (a CPU node
+        without the interpreted kernels), ``tiles`` (not one bucket
+        scheme, no shared cadence, or a scheme the program cannot answer:
+        fewer than two buckets, or no ``+Inf`` last), ``tail`` (a window reaches the
+        write-buffer tail) and ``grid`` (wider than int32 ms from the
+        tile base). The mesh store is not asked."""
+        why, res = self._fused_hist_quantile(series, func, steps,
+                                             window_ms, offset_ms, gids, G,
+                                             q, facts)
+        if res is None:
+            self.fused_hist_refused[why] += 1
+        return res
+
+    def _fused_hist_quantile(self, series, func, steps, window_ms,
+                             offset_ms, gids, G, q, facts):
+        if jax.default_backend() == "cpu" and not FUSED_GROUPSUM_INTERPRET:
+            return "cpu", None
+        if facts is None:
+            facts = selection_facts(series)
+        les = facts.les
+        if les is None or len(les) < 2 or les[-1] != np.inf:
+            return "tiles", None
+        entry, facts = self._tile_entry(series, facts)
+        tiles, idx = entry.tiles, entry.idx
+        if not isinstance(tiles, tst.HistTiles) or len(idx) != len(series):
+            return "tiles", None
+        with obs_trace.span("fused-eligibility", series=len(series)):
+            if not self._fused_covered(entry, facts, steps, offset_ms):
+                return "tail", None
+        with obs_trace.span("onehot", groups=G):
+            gvec = entry.tile_order(gids)
+        with obs_trace.span("device-dispatch", path="fused-hist"):
+            res = tst.hist_quantile_groupsum(tiles, func, steps, window_ms,
+                                             gvec, G, q, offset_ms)
+        if res is None:
+            return "grid", None
+        self.fused_hist_aggs += 1
+        with obs_trace.span("device-sync"):
+            out = np.asarray(res)
+            transfer_counts.d2h_bytes += out.nbytes
+        return None, out
 
     @staticmethod
     def _fused_covered(entry, facts, steps: np.ndarray,

@@ -281,6 +281,57 @@ def test_groupsum_over_holes_compiles(one_chip, monkeypatch, func):
     assert re.search(r"ENTRY[^\n]*->\s*\(f32\[31,16\][^\n]*f32\[31,16\]", text)
 
 
+@pytest.mark.parametrize("dense", [True, False])
+def test_hist_quantile_program_compiles(one_chip, monkeypatch, dense):
+    """What serves ``histogram_quantile(q, sum by (job) (rate(h[5m])))``
+    over native histograms: the dispatcher's ONE jitted program (the
+    evaluator with a bucket axis, masked f64 group sums, the quantile),
+    built by the dispatcher's own ``build``, at the histogram cell's size:
+    256 series x 728 slots x 12 buckets, 16 groups, 31 steps. No f64
+    matmul (the compiler spells one as loops) and only [T, G] f64 leaves
+    it."""
+    s_cell, n_cell, t_cell, b = 256, 728, 31, 12
+    rng = np.random.default_rng(13)
+    ts = (BASE + np.arange(n_cell, dtype=np.float64)[None, :] * DT
+          + rng.integers(-2000, 2001, (S_HOST, n_cell)))
+    vals = np.cumsum(rng.poisson(3.0, (S_HOST, n_cell, b)), axis=1).astype(
+        np.float64)
+    valid = np.ones((S_HOST, n_cell), bool)
+    if not dense:
+        valid[3::4, 100:104] = False
+    les = tuple(float(2 ** i) for i in range(b - 1)) + (float("inf"),)
+    tiles = tst.HistTiles([{} for _ in range(S_HOST)], BASE, DT, valid, ts,
+                          vals, np.zeros_like(vals), les)
+    seen = {}
+
+    def capture(cache, key, build, site="tilestore", cost_args=None):
+        seen.update(key=key, build=build, args=cost_args, site=site)
+        return lambda *a: None
+    monkeypatch.setattr(tst, "_jit_lookup", capture)
+    steps = BASE + 600_000 + np.arange(t_cell, dtype=np.int64) * STEP
+    assert tst.hist_quantile_groupsum(tiles, "rate", steps, W,
+                                      np.arange(S_HOST) % G, G,
+                                      0.99) is None
+    monkeypatch.undo()
+    assert seen["site"] == "groupsum"
+    assert seen["key"] == ("groupsum", "hist", "rate", t_cell, G,
+                           (n_cell, b * S_HOST), not dense)
+
+    def sds(a):
+        a = np.asarray(a) if not hasattr(a, "shape") else a
+        wide = {S_HOST: s_cell, b * S_HOST: b * s_cell}
+        shape = tuple(wide.get(d, d) for d in a.shape)
+        return jax.ShapeDtypeStruct(shape, a.dtype, sharding=one_chip)
+    compiled = seen["build"]().lower(
+        *jax.tree_util.tree_map(sds, seen["args"])).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text and " while(" not in text
+    # the channels are taken by rows where they lie: no copy of a whole
+    # [slots, buckets x series] channel into another layout
+    assert not re.search(r"copy\(f64\[%d,%d\]" % (n_cell, b * s_cell), text)
+    assert re.search(r"ENTRY[^\n]*->\s*f64\[31,16\]", text)
+
+
 def test_eval_counter_slide_compiles(one_chip, jittered):
     st = STEP // DT
     arrs = tst._tiles_arrays_slide(jittered, "rate", st)
