@@ -1965,6 +1965,9 @@ class FiloHttpServer:
             "Counter queries served by the aligned all-f64 evaluator",
         "filodb_device_to_host_bytes_total":
             "Bytes the device-sync stages brought to the host",
+        "filodb_host_to_device_puts_total":
+            "Device buffers that calls of cached executables made from "
+            "host values (an argument on four devices counts four)",
         "filodb_select_series_total":
             "Series handles handed out by whole-series selections",
         "filodb_select_series_read_total":
@@ -2245,6 +2248,8 @@ class FiloHttpServer:
             emit("aligned_exact_evals_total", {}, evals.get("t", 0))
             emit("device_to_host_bytes_total", {},
                  transfer_counts.d2h_bytes)
+            emit("host_to_device_puts_total", {},
+                 obs_devprof.put_counts.h2d_puts)
             # serving fast path: compiled-executable reuse (shape
             # buckets) + micro-batcher occupancy
             exec_stats = getattr(self.backend, "executable_cache_stats",

@@ -593,8 +593,6 @@ def _h_hist_quantile():
     tiles, idx = tst.build_aligned_tiles(series)
     window, step, nsteps = 300_000, 30_000, 16
     steps = RangeParams(first, step, first + (nsteps - 1) * step).steps
-    grid = np.array([first - window, first, step, tiles.num_slots,
-                     tiles.base_ms, tiles.dt_ms], np.int64)
     arrs = tst._tiles_arrays_hist(tiles)
     prod, ref = [], []
     for func in ("rate", "increase"):
@@ -604,9 +602,10 @@ def _h_hist_quantile():
             RangeParams(first, step, int(steps[-1])), func, window),
             ("g",), ())
         for q in (0.1, 0.5, 0.99):
-            prod.append(np.asarray(fn(arrs, grid,
-                                      np.asarray(gids[idx], np.int32),
-                                      np.asarray(tiles.les), np.float64(q))))
+            grid = np.array([first - window, first, step], np.int64)
+            prod.append(np.asarray(fn(arrs, tiles.t_consts(), tiles.t_les(),
+                                      tiles.t_q(q), grid,
+                                      np.asarray(gids[idx], np.int32))))
             ref.append(eng.histogram_quantile(host, q).values.T)
     return np.stack(prod), np.stack(ref), 0.0
 
@@ -691,8 +690,8 @@ def _h_grouped_pair(ndev: int):
     run = _build_grouped_pair_eval(_shard_mesh(ndev), "rate",
                                    g["nsteps"], 3)
     sums, cnts = run(jnp.asarray(tsr), jnp.asarray(v),
-                     jnp.asarray(gids), np.int64(g["num_slots"]),
-                     np.int64(g["base"]), np.int64(g["dt"]),
-                     np.int64(g["w0s"]), np.int64(g["w0e"]),
-                     np.int64(g["step"]))
+                     jnp.asarray(gids),
+                     np.array([g["num_slots"], g["base"], g["dt"]],
+                              np.int64),
+                     np.array([g["w0s"], g["w0e"], g["step"]], np.int64))
     return np.asarray(sums), np.asarray(cnts)

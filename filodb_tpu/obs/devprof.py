@@ -52,6 +52,8 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+import numpy as np
+
 from filodb_tpu.lint.locks import guarded_by
 from filodb_tpu.obs import trace as obs_trace
 
@@ -338,13 +340,57 @@ def _register_collector() -> None:
 _register_collector()
 
 
+class _PutCounts:
+    """``filodb_host_to_device_puts_total``: device buffers that calls of
+    cached executables made from host values (a numpy array or scalar, a
+    Python number), one per device the executable runs on, so that an
+    argument replicated over four devices counts four. Plain adds, like
+    ``filodb_device_to_host_bytes_total``."""
+
+    __slots__ = ("h2d_puts",)
+
+    def __init__(self):
+        self.h2d_puts = 0
+
+
+put_counts = _PutCounts()
+
+
+def host_args(args) -> int:
+    """How many of a call's arguments (tuples, lists and dicts looked
+    into) are host values that the call puts on the device: numpy arrays
+    and scalars and Python numbers; a device array is already there and
+    a string is a static argument."""
+    n = 0
+    for a in args:
+        if isinstance(a, (tuple, list)):
+            n += host_args(a)
+        elif isinstance(a, dict):
+            n += host_args(a.values())
+        elif isinstance(a, (np.ndarray, np.generic, int, float)):
+            n += 1
+    return n
+
+
+def _device_count(compiled) -> int:
+    """Devices a compiled executable runs on (1 where it cannot say)."""
+    try:
+        shardings = compiled.input_shardings[0]
+    except (AttributeError, IndexError, TypeError):
+        return 1
+    return max((len(s.device_set) for s in shardings
+                if hasattr(s, "device_set")), default=1)
+
+
 class ProfiledExecutable:
     """The object the tilestore dispatch tables cache: AOT-compiled
     primary dispatch for the build shape + jit fallback for churned
     shapes, with per-call profiling and an ``executable`` trace event
-    (no-op when untraced) carrying identity + disposition."""
+    (no-op when untraced) carrying identity + disposition. Every call adds
+    the buffers it makes from host values to ``put_counts``."""
 
-    __slots__ = ("fn", "site", "key", "key_s", "_compiled", "_sig")
+    __slots__ = ("fn", "site", "key", "key_s", "_compiled", "_sig",
+                 "_ndev")
 
     def __init__(self, fn, site: str, key: Tuple,
                  compiled=None, sig: Optional[Tuple] = None):
@@ -354,8 +400,10 @@ class ProfiledExecutable:
         self.key_s = key_str(key)
         self._compiled = compiled
         self._sig = sig
+        self._ndev = 1 if compiled is None else _device_count(compiled)
 
     def __call__(self, *args):
+        put_counts.h2d_puts += host_args(args) * self._ndev
         sig = arg_sig(args)
         if self._compiled is not None and sig == self._sig:
             try:

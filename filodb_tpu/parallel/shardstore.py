@@ -129,6 +129,20 @@ def _append_step(tsr, v, cv, new_tsr, new_v, n_filled):
 # Sharded evaluator programs (compiled per (func, grid shape, mesh shape))
 # ---------------------------------------------------------------------------
 
+def _scalars(consts, grid):
+    """What every program of the store takes besides its channels, as the
+    evaluator's six arguments: the placement's ``consts`` int64[3]
+    (n_filled, base_ms, dt_ms; on the devices, replicated, since the
+    placement was built or last appended to) and the request's ``grid``,
+    the one host array a call hands over: int64[3] (w0s, w0e, step), or
+    int64[3, B] for a batch (a row of w0s, one of w0e, the step in every
+    column of the third)."""
+    n, base, dt = consts[0], consts[1], consts[2]
+    if grid.ndim == 2:
+        return n, base, dt, grid[0], grid[1], grid[2, 0]
+    return n, base, dt, grid[0], grid[1], grid[2]
+
+
 def _sharded_counter_check():
     """Abstract check under a minimal 1x1 ('shard','time') mesh: the
     shard_map body traces on CPU, nothing executes."""
@@ -139,8 +153,8 @@ def _sharded_counter_check():
     out = jax.eval_shape(
         fn, jax.ShapeDtypeStruct((64, 8), jnp.int32),
         jax.ShapeDtypeStruct((64, 8), jnp.float64),
-        np.int64(64), np.int64(0), np.int64(10_000),
-        np.int64(100_000), np.int64(400_000), np.int64(60_000))
+        np.array([64, 0, 10_000], np.int64),
+        np.array([100_000, 400_000, 60_000], np.int64))
     if tuple(out.shape) != (16, 8) or str(out.dtype) != "float32":
         return f"sharded counter eval {out.shape}/{out.dtype} != (16,8) f32"
     return None
@@ -156,7 +170,8 @@ def _sharded_counter_check():
           "bit-for-bit the single-device _eval_counter_fast values")
 def _build_counter_eval(mesh: Mesh, func: str, nsteps_local: int,
                         batch: int):
-    """One jitted sharded program: [N, S] resident channels ->
+    """One jitted sharded program: [N, S] resident channels, the
+    placement's constants and the request's grid (``_scalars``) ->
     [T, S] (batch == 0) or [B, T, S] (batch == B) windowed counter
     grids. ``batch`` members vmap over the grid scalars exactly like
     the single-device evaluate_counters_t_batch family."""
@@ -164,7 +179,8 @@ def _build_counter_eval(mesh: Mesh, func: str, nsteps_local: int,
 
     s_axis, t_axis = mesh.axis_names[:2]
 
-    def counter_body(tsr, vv, n, base, dt, w0s, w0e, step):
+    def counter_body(tsr, vv, consts, grid):
+        n, base, dt, w0s, w0e, step = _scalars(consts, grid)
         # this device's slice of the output step grid rides the time
         # axis (sequence parallel): offset the window scalars
         t_off = (jax.lax.axis_index(t_axis).astype(jnp.int64)
@@ -177,25 +193,14 @@ def _build_counter_eval(mesh: Mesh, func: str, nsteps_local: int,
                                             step))(w0s, w0e)
         return ev(w0s + t_off, w0e + t_off, step)
 
-    if batch:
-        @jax.jit
-        def run_b(tsr, vv, n, base, dt, w0s, w0e, step):
-            inner = jax.shard_map(
-                counter_body, mesh=mesh,
-                in_specs=(P(None, s_axis), P(None, s_axis), P(), P(), P(),
-                          P(None), P(None), P()),
-                out_specs=P(None, t_axis, s_axis))
-            return inner(tsr, vv, n, base, dt, w0s, w0e, step)
-        return run_b
-
     @jax.jit
-    def run(tsr, vv, n, base, dt, w0s, w0e, step):
+    def run(tsr, vv, consts, grid):
         inner = jax.shard_map(
             counter_body, mesh=mesh,
-            in_specs=(P(None, s_axis), P(None, s_axis), P(), P(), P(),
-                      P(), P(), P()),
-            out_specs=P(t_axis, s_axis))
-        return inner(tsr, vv, n, base, dt, w0s, w0e, step)
+            in_specs=(P(None, s_axis), P(None, s_axis), P(), P()),
+            out_specs=(P(None, t_axis, s_axis) if batch
+                       else P(t_axis, s_axis)))
+        return inner(tsr, vv, consts, grid)
     return run
 
 
@@ -204,14 +209,16 @@ def _build_aligned_eval(mesh: Mesh, func: str, nsteps_local: int,
     """Sharded program for the non-counter aligned families: the SAME
     _eval_core body as the single-device dispatch, series on the shard
     axis, output steps on the time axis -> [S, T] f64 (or [B, S, T]).
-    ``arr_keys`` is the channel-set signature ((name, ndim), ...)."""
+    ``arr_keys`` is the channel-set signature ((name, ndim), ...); the
+    scalars come as ``_scalars`` says."""
     from filodb_tpu.query.tilestore import _eval_core
 
     s_axis, t_axis = mesh.axis_names[:2]
     arr_specs = {k: (P(s_axis) if nd == 1 else P(s_axis, None))
                  for k, nd in arr_keys}
 
-    def aligned_body(arrs, n, base, dt, w0s, w0e, step):
+    def aligned_body(arrs, consts, grid):
+        n, base, dt, w0s, w0e, step = _scalars(consts, grid)
         t_off = (jax.lax.axis_index(t_axis).astype(jnp.int64)
                  * nsteps_local * step)
         ev = functools.partial(_eval_core, func, nsteps_local, arrs,
@@ -221,24 +228,14 @@ def _build_aligned_eval(mesh: Mesh, func: str, nsteps_local: int,
                                             step))(w0s, w0e)
         return ev(w0s + t_off, w0e + t_off, step)
 
-    if batch:
-        @jax.jit
-        def run_b(arrs, n, base, dt, w0s, w0e, step):
-            inner = jax.shard_map(
-                aligned_body, mesh=mesh,
-                in_specs=(arr_specs, P(), P(), P(),
-                          P(None), P(None), P()),
-                out_specs=P(None, s_axis, t_axis))
-            return inner(arrs, n, base, dt, w0s, w0e, step)
-        return run_b
-
     @jax.jit
-    def run(arrs, n, base, dt, w0s, w0e, step):
+    def run(arrs, consts, grid):
         inner = jax.shard_map(
             aligned_body, mesh=mesh,
-            in_specs=(arr_specs, P(), P(), P(), P(), P(), P()),
-            out_specs=P(s_axis, t_axis))
-        return inner(arrs, n, base, dt, w0s, w0e, step)
+            in_specs=(arr_specs, P(), P()),
+            out_specs=(P(None, s_axis, t_axis) if batch
+                       else P(s_axis, t_axis)))
+        return inner(arrs, consts, grid)
     return run
 
 
@@ -254,12 +251,15 @@ def _build_grouped_pair_eval(mesh: Mesh, func: str, nsteps_local: int,
     """The fused-groupsum contract from resident tiles: per-device
     windowed counter evaluation + masked sum by group, psum over the shard
     axis -> (sums [T, G], counts [T, G]) f64 — sums meaningful where
-    counts > 0, exactly the Pallas group-sum kernel's return shape."""
+    counts > 0, exactly the Pallas group-sum kernel's return shape. The
+    program takes the resident channels and group ids, the placement's
+    constants and the request's grid (``_scalars``): one host array."""
     from filodb_tpu.query.tilestore import _eval_counter_fast
 
     s_axis, t_axis = mesh.axis_names[:2]
 
-    def grouped_pair_body(tsr, vv, gids, n, base, dt, w0s, w0e, step):
+    def grouped_pair_body(tsr, vv, gids, consts, grid):
+        n, base, dt, w0s, w0e, step = _scalars(consts, grid)
         t_off = (jax.lax.axis_index(t_axis).astype(jnp.int64)
                  * nsteps_local * step)
         arrs = {"tsr": tsr, "ff_v": vv}
@@ -279,13 +279,13 @@ def _build_grouped_pair_eval(mesh: Mesh, func: str, nsteps_local: int,
         return (jax.lax.psum(sums, s_axis), jax.lax.psum(cnts, s_axis))
 
     @jax.jit
-    def run(tsr, vv, gids, n, base, dt, w0s, w0e, step):
+    def run(tsr, vv, gids, consts, grid):
         inner = jax.shard_map(
             grouped_pair_body, mesh=mesh,
-            in_specs=(P(None, s_axis), P(None, s_axis), P(s_axis),
-                      P(), P(), P(), P(), P(), P()),
+            in_specs=(P(None, s_axis), P(None, s_axis), P(s_axis), P(),
+                      P()),
             out_specs=(P(t_axis, None), P(t_axis, None)))
-        return inner(tsr, vv, gids, n, base, dt, w0s, w0e, step)
+        return inner(tsr, vv, gids, consts, grid)
     return run
 
 
@@ -294,14 +294,15 @@ def _build_grouped_eval(mesh: Mesh, func: str, nsteps_local: int,
     """Grouped counter aggregation from resident tiles: per-device
     windowed evaluation, then the one-hot [S, G] matmul + psum
     collective (mesh._grouped_reduce — ReduceAggregateExec as a
-    collective) -> [G, T]."""
+    collective) -> [G, T]; the scalars come as ``_scalars`` says."""
     from filodb_tpu.query.tilestore import _eval_counter_fast
 
     s_axis, t_axis = mesh.axis_names[:2]
 
     @functools.partial(jax.jit, static_argnames=("agg",))
-    def run(agg, tsr, vv, gids, n, base, dt, w0s, w0e, step):
-        def grouped_body(tsr, vv, gids, n, base, dt, w0s, w0e, step):
+    def run(agg, tsr, vv, gids, consts, grid):
+        def grouped_body(tsr, vv, gids, consts, grid):
+            n, base, dt, w0s, w0e, step = _scalars(consts, grid)
             t_off = (jax.lax.axis_index(t_axis).astype(jnp.int64)
                      * nsteps_local * step)
             arrs = {"tsr": tsr, "ff_v": vv}
@@ -312,10 +313,10 @@ def _build_grouped_eval(mesh: Mesh, func: str, nsteps_local: int,
                                    num_groups, agg)
         inner = jax.shard_map(
             grouped_body, mesh=mesh,
-            in_specs=(P(None, s_axis), P(None, s_axis), P(s_axis),
-                      P(), P(), P(), P(), P(), P()),
+            in_specs=(P(None, s_axis), P(None, s_axis), P(s_axis), P(),
+                      P()),
             out_specs=P(None, t_axis))
-        return inner(tsr, vv, gids, n, base, dt, w0s, w0e, step)
+        return inner(tsr, vv, gids, consts, grid)
     return run
 
 
@@ -332,12 +333,14 @@ def _next_pow2(n: int, lo: int = 8) -> int:
 
 @capacity(
     "shardstore-resident-channels", bytes_per_sample=20.0, sharded=True,
+    overhead_bytes=24,
     reason="the resident store keeps three [cap, S_pad] slot-major "
            "channels — int32 relative timestamps (4 B) + raw f64 "
            "values (8 B) + counter-corrected f64 values (8 B) = 20 B "
            "per PADDED slot (pow2 slot capacity, shard-aligned series "
-           "pad); the non-counter _aligned placements are transient "
-           "per-family row sets cleared on every refresh")
+           "pad) — and its programs' constants, int64[3] (24 B); the "
+           "non-counter _aligned placements are transient per-family "
+           "row sets cleared on every refresh")
 class ShardedTiles:
     """One aligned-tile cohort resident across the mesh: capacity-padded
     [cap, S_pad] slot-major channels (int32 relative timestamps, raw
@@ -377,6 +380,7 @@ class ShardedTiles:
         # the padded group ids on the devices, per tile-order vector the
         # backend's tile entry handed out (``_row_gids``)
         self._gids = PerGrouping()
+        self._put_consts()
         # runtime residency accounting: live device bytes under the
         # filodb_device_memory_bytes{family,shard} gauge, dropped when
         # the store is collected
@@ -385,6 +389,14 @@ class ShardedTiles:
                          id(self))
         weakref.finalize(self, drop_resident, *self._res_key)
         self._record_residency()
+
+    def _put_consts(self) -> None:
+        """n_filled, base_ms and dt_ms on the devices, replicated: the
+        programs' ``consts`` (``_scalars``). Put where the placement is
+        built and again where ``append_slots`` moves ``n_filled``."""
+        self._consts = jax.device_put(
+            np.array([self.n_filled, self.base_ms, self.dt_ms], np.int64),
+            NamedSharding(self.mesh, P()))
 
     def _record_residency(self) -> None:
         nbytes = int(self._tsr.nbytes + self._v.nbytes + self._cv.nbytes)
@@ -416,12 +428,26 @@ class ShardedTiles:
                 and int(steps[-1] - offset_ms) - self.base_ms < _SENT_HI)
 
     def _grid(self, steps: np.ndarray, window_ms: int, offset_ms: int):
+        """-> (steps a device of the time axis computes, the request's
+        ``grid``: int64[3] w0s, w0e, step)."""
         nsteps = steps.size
         T_pad = -(-nsteps // self.n_time) * self.n_time
-        w0e = np.int64(steps[0] - offset_ms)
-        w0s = np.int64(w0e - window_ms)
-        step = np.int64(steps[1] - steps[0]) if nsteps > 1 else np.int64(1)
-        return T_pad // self.n_time, w0s, w0e, step
+        w0e = int(steps[0] - offset_ms)
+        step = int(steps[1] - steps[0]) if nsteps > 1 else 1
+        return T_pad // self.n_time, np.array([w0e - window_ms, w0e, step],
+                                              np.int64)
+
+    def _batch_grid(self, nsteps: int, step: int, w0s_list: Sequence[int],
+                    w0e_list: Sequence[int]):
+        """-> (steps a device computes, the batch's ``grid``: int64[3,
+        B_pad], rows w0s, w0e and the step, B padded to a power of
+        two)."""
+        from filodb_tpu.query.tilestore import _pad_pow2
+        w0s_v = _pad_pow2(list(w0s_list))
+        grid = np.stack([w0s_v, _pad_pow2(list(w0e_list)),
+                         np.full(w0s_v.shape, step)]).astype(np.int64)
+        T_pad = -(-nsteps // self.n_time) * self.n_time
+        return T_pad // self.n_time, grid
 
     def _mesh_key(self) -> Tuple:
         return (int(self.mesh.shape[self.mesh.axis_names[0]]),
@@ -434,11 +460,9 @@ class ShardedTiles:
         """rate/increase/delta from the resident store -> device
         [T, S] f32 (callers slice/transpose; values bit-for-bit the
         single-device fast-path's)."""
-        t_local, w0s, w0e, step = self._grid(steps, window_ms, offset_ms)
+        t_local, grid = self._grid(steps, window_ms, offset_ms)
         vv = self._cv if func in ("rate", "increase") else self._v
-        args = (self._tsr, vv, np.int64(self.n_filled),
-                np.int64(self.base_ms), np.int64(self.dt_ms), w0s, w0e,
-                step)
+        args = (self._tsr, vv, self._consts, grid)
         key = ("mesh-fast", func, t_local, self._mesh_key())
         fn = _jit_lookup(key, lambda: _build_counter_eval(
             self.mesh, func, t_local, batch=0), cost_args=args)
@@ -450,16 +474,10 @@ class ShardedTiles:
         """One sharded dispatch computing B counter grids -> device
         [B_pad, T, S] (callers slice [:len(w0s_list)]) — the
         mesh-shaped micro-batch."""
-        from filodb_tpu.query.tilestore import _pad_pow2
-        w0s_v = jnp.asarray(_pad_pow2(list(w0s_list)))
-        w0e_v = jnp.asarray(_pad_pow2(list(w0e_list)))
-        b_pad = int(w0s_v.shape[0])
-        T_pad = -(-nsteps // self.n_time) * self.n_time
-        t_local = T_pad // self.n_time
+        t_local, grid = self._batch_grid(nsteps, step, w0s_list, w0e_list)
+        b_pad = int(grid.shape[1])
         vv = self._cv if func in ("rate", "increase") else self._v
-        args = (self._tsr, vv, np.int64(self.n_filled),
-                np.int64(self.base_ms), np.int64(self.dt_ms), w0s_v,
-                w0e_v, np.int64(step))
+        args = (self._tsr, vv, self._consts, grid)
         key = ("mesh-fast-b", func, t_local, b_pad, self._mesh_key())
         fn = _jit_lookup(key, lambda: _build_counter_eval(
             self.mesh, func, t_local, batch=b_pad), cost_args=args)
@@ -493,11 +511,10 @@ class ShardedTiles:
                      window_ms: int, offset_ms: int = 0) -> jnp.ndarray:
         """Non-counter aligned families from sharded channels ->
         device [S, T] f64, bit-for-bit the single-device _eval_core."""
-        t_local, w0s, w0e, step = self._grid(steps, window_ms, offset_ms)
+        t_local, grid = self._grid(steps, window_ms, offset_ms)
         arrs = self._aligned_arrs(tiles, func)
         sig = tuple(sorted((k, v.ndim) for k, v in arrs.items()))
-        args = (arrs, np.int64(self.n_filled), np.int64(self.base_ms),
-                np.int64(self.dt_ms), w0s, w0e, step)
+        args = (arrs, self._consts, grid)
         key = ("mesh-aligned", func, t_local, sig, self._mesh_key())
         fn = _jit_lookup(key, lambda: _build_aligned_eval(
             self.mesh, func, t_local, 0, sig), cost_args=args)
@@ -506,16 +523,11 @@ class ShardedTiles:
     def eval_aligned_batch(self, tiles, func: str, nsteps: int, step: int,
                            w0s_list: Sequence[int],
                            w0e_list: Sequence[int]) -> jnp.ndarray:
-        from filodb_tpu.query.tilestore import _pad_pow2
-        w0s_v = jnp.asarray(_pad_pow2(list(w0s_list)))
-        w0e_v = jnp.asarray(_pad_pow2(list(w0e_list)))
-        b_pad = int(w0s_v.shape[0])
-        T_pad = -(-nsteps // self.n_time) * self.n_time
-        t_local = T_pad // self.n_time
+        t_local, grid = self._batch_grid(nsteps, step, w0s_list, w0e_list)
+        b_pad = int(grid.shape[1])
         arrs = self._aligned_arrs(tiles, func)
         sig = tuple(sorted((k, v.ndim) for k, v in arrs.items()))
-        args = (arrs, np.int64(self.n_filled), np.int64(self.base_ms),
-                np.int64(self.dt_ms), w0s_v, w0e_v, np.int64(step))
+        args = (arrs, self._consts, grid)
         key = ("mesh-aligned-b", func, t_local, b_pad, sig,
                self._mesh_key())
         fn = _jit_lookup(key, lambda: _build_aligned_eval(
@@ -543,12 +555,10 @@ class ShardedTiles:
         """sum/count/avg/min/max by (g) of rate/increase/delta straight
         off the resident store: one-hot matmul + psum over the shard
         axis -> [G, T] numpy."""
-        t_local, w0s, w0e, step = self._grid(steps, window_ms, offset_ms)
+        t_local, grid = self._grid(steps, window_ms, offset_ms)
         vv = self._cv if func in ("rate", "increase") else self._v
-        args = (self._tsr, vv, self._row_gids(gids),
-                np.int64(self.n_filled), np.int64(self.base_ms),
-                np.int64(self.dt_ms), w0s, w0e, step)
-        args = (agg,) + args
+        args = (agg, self._tsr, vv, self._row_gids(gids), self._consts,
+                grid)
         key = ("mesh-grouped", func, agg, t_local, num_groups,
                self._mesh_key())
         fn = _jit_lookup(key, lambda: _build_grouped_eval(
@@ -563,12 +573,12 @@ class ShardedTiles:
         and cuts to ``steps.size`` rows (``eval_grouped_pair`` does
         both). The program, its key and its arguments' shapes and dtypes
         are the same whether ``gids`` was on the devices already
-        (``_row_gids``) or is put now."""
-        t_local, w0s, w0e, step = self._grid(steps, window_ms, offset_ms)
+        (``_row_gids``) or is put now. With the ids on the devices the
+        call hands over one host array, the request's grid: the channels
+        and the constants (``_scalars``) stay with the placement."""
+        t_local, grid = self._grid(steps, window_ms, offset_ms)
         vv = self._cv if func in ("rate", "increase") else self._v
-        args = (self._tsr, vv, self._row_gids(gids),
-                np.int64(self.n_filled), np.int64(self.base_ms),
-                np.int64(self.dt_ms), w0s, w0e, step)
+        args = (self._tsr, vv, self._row_gids(gids), self._consts, grid)
         key = ("mesh-grouped-pair", func, t_local, num_groups,
                self._mesh_key())
         fn = _jit_lookup(key, lambda: _build_grouped_pair_eval(
@@ -628,6 +638,7 @@ class ShardedTiles:
             jax.device_put(new_tsr, col), jax.device_put(new_v, col),
             np.int64(self.n_filled))
         self.n_filled = n_new
+        self._put_consts()
         self._aligned.clear()   # row-major placements are per-snapshot
         # the new tiles come with a tile entry, and so tile-order vectors,
         # of their own: the old ones' copies would never be asked for

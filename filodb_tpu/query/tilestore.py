@@ -301,6 +301,18 @@ class AlignedTiles:
             self._tch["bf_tsr_i32"] = c
         return c
 
+    def t_consts(self) -> jnp.ndarray:
+        """int64[3] on the device: num_slots, base_ms, dt_ms, what the
+        fused programs over holes and histograms read of the tiles
+        besides their channels; put once, so that a request hands over
+        its grid alone."""
+        c = self._tch.get("consts")
+        if c is None:
+            c = jax.device_put(np.array(
+                [self.num_slots, self.base_ms, self.dt_ms], np.int64))
+            self._tch["consts"] = c
+        return c
+
     def t_ones_i8(self) -> jnp.ndarray:
         c = self._tch.get("ones_i8")
         if c is None:
@@ -529,6 +541,30 @@ class HistTiles(AlignedTiles):
         cv = np.where(valid[..., None], vals + corr, 0.0)
         self.t_cv = jnp.asarray(_slot_major(cv))
         self.t_corr = jnp.asarray(_slot_major(_bfill_rows(corr, valid)))
+        self._tq: Dict[float, jnp.ndarray] = {}
+
+    def t_les(self) -> jnp.ndarray:
+        """f64 [B] on the device: the bounds as the fused quantile program
+        reads them, put once (a lazy cache, like ``t_consts``)."""
+        c = self._tch.get("les")
+        if c is None:
+            c = jax.device_put(np.asarray(self.les, np.float64))
+            self._tch["les"] = c
+        return c
+
+    def t_q(self, q: float) -> jnp.ndarray:
+        """``q`` as an f64 on the device, put once a value (at most
+        ``_Q_KEPT`` values a cohort, then all go): the quantile is a
+        runtime argument of the one executable, and a board asks for a
+        few. Not bits in the request's int64 grid: the chip's emulated
+        f64 takes 0.99 back from its bits to another value than it takes
+        0.99 over from the host."""
+        c = self._tq.get(q)
+        if c is None:
+            if len(self._tq) >= _Q_KEPT:
+                self._tq.clear()
+            c = self._tq.setdefault(q, jax.device_put(np.float64(q)))
+        return c
 
     def t_fill(self, kind: str) -> jnp.ndarray:
         """[N, B*S] forward ("ff") or backward ("bf") fill of ``t_cv``
@@ -558,6 +594,10 @@ class HistTiles(AlignedTiles):
                 axis=0)
             self._tch["hist_" + kind] = c
         return c
+
+
+# quantile values a histogram cohort keeps on the device (HistTiles.t_q)
+_Q_KEPT = 8
 
 
 def _slot_major(a: np.ndarray) -> np.ndarray:
@@ -1462,6 +1502,29 @@ def _group_onehot(ids, G: int):
             ).astype(jnp.float32)
 
 
+def _ids_arg(gids, n: int):
+    """Tile-order group ids as a fused program takes them: int32[n], -1
+    (no group) past the series. A device array is taken as it is:
+    ``fused_group_ids`` made it so."""
+    if isinstance(gids, jax.Array):
+        return gids
+    ids = np.full(n, -1, np.int32)
+    ids[:len(gids)] = gids
+    return ids
+
+
+def fused_group_ids(tiles: AlignedTiles, gvec) -> jax.Array:
+    """``gvec`` (tile order) padded for the tiles' fused program (to the
+    Pallas kernel's lane tile over dense counter tiles, not at all over
+    holes and histograms) and put on the device: the backend's tile entry
+    keeps it per grouping, so that a request of the grouping sends no
+    ids."""
+    S = len(tiles.keys)
+    n = (-(-S // pk._GS_SS) * pk._GS_SS
+         if tiles._dense and not isinstance(tiles, HistTiles) else S)
+    return jax.device_put(_ids_arg(gvec, n))
+
+
 def _groupsum_program(func: str, st: int, dspan: int, hi_mode: int,
                       lo_mode: int, exact_branch: bool, nsteps: int,
                       G: int, interpret: bool, v_p, base, params, ids):
@@ -1476,19 +1539,21 @@ def _groupsum_program(func: str, st: int, dspan: int, hi_mode: int,
         v_p, base, _group_onehot(ids, G), params, interpret=interpret)
 
 
-def _groupsum_holes_program(func: str, nsteps: int, G: int, arrs, grid,
-                            ids):
+def _groupsum_holes_program(func: str, nsteps: int, G: int, arrs, consts,
+                            grid, ids):
     """The fused group-sum over tiles with holes as ONE traceable program
     (jitted once per static tuple by groupsum_counters): the non-dense
     f32-hybrid evaluator's [T, S] rates, NaN where a window holds fewer
     than two samples, then the Pallas kernel's own epilogue — the masked
     one-hot matmul to [T, G] sums and counts, f32 at HIGHEST precision
     (the MXU's default bf16 input truncation is another answer), so a
-    selection sums the same way with holes and without. ``grid`` is
-    int64[6]: w0s, w0e, step, then the tile's num_slots, base_ms, dt_ms;
-    ``ids`` int32 [S] in tile order, an id outside [0, G) names no
-    group."""
-    w0s, w0e, step, num_slots, base, dt = grid
+    selection sums the same way with holes and without. ``consts`` is
+    the tiles' int64[3] on the device (``t_consts``: num_slots, base_ms,
+    dt_ms); ``grid`` the request's int64[3]: w0s, w0e, step; ``ids``
+    int32 [S] in tile order, an id outside [0, G) names no group (on the
+    device where the backend's tile entry keeps them)."""
+    num_slots, base, dt = consts[0], consts[1], consts[2]
+    w0s, w0e, step = grid[0], grid[1], grid[2]
     out = _eval_counter_fast(func, nsteps, arrs, num_slots, base, dt,
                              w0s, w0e, step)
     ok = ~jnp.isnan(out)
@@ -1509,8 +1574,10 @@ def _groupsum_holes_program(func: str, nsteps: int, G: int, arrs, grid,
           "divisibility, dspan cap, full VMEM re-budget (accumulators + "
           "DMA scratch + onehot + base) all decide BEFORE the executable "
           "table is asked; then one cached executable (site groupsum) "
-          "per static tuple takes the query's five scalars and its "
-          "group ids. Tiles with holes: _groupsum_holes")
+          "per static tuple takes the query's five scalars as one "
+          "int32[5] host vector, and its group ids, which the backend's "
+          "tile entry keeps on the device. Tiles with holes: "
+          "_groupsum_holes")
 def groupsum_counters(tiles: AlignedTiles, func: str, steps: np.ndarray,
                       window_ms: int, gids, G: int, offset_ms: int = 0,
                       interpret: bool = False):
@@ -1524,9 +1591,10 @@ def groupsum_counters(tiles: AlignedTiles, func: str, steps: np.ndarray,
     matmul, over two layouts of the same samples.
 
     ``gids``: the group id in [0, G) of every series of the tiles, in
-    tile order. One cached executable per (func, grid statics, tile
-    shapes, G) serves every query of that shape: a query sends one
-    small integer vector and its group ids, nothing is traced or
+    tile order, or ``fused_group_ids`` of them (on the device, padded:
+    what the backend's tile entry hands over). One cached executable per
+    (func, grid statics, tile shapes, G) serves every query of that
+    shape: a query sends one small integer vector, nothing is traced or
     compiled again (``_jit_lookup``: exec-cache hits/misses,
     ``kernel-build``).
 
@@ -1584,8 +1652,7 @@ def groupsum_counters(tiles: AlignedTiles, func: str, steps: np.ndarray,
     S_pad = -(-S // pk._GS_SS) * pk._GS_SS
     v_p = tiles.t_perm_fixed_tiled(vch, st)
     base = tiles.t_fixed_base(vch)
-    ids = np.full(S_pad, -1, np.int32)
-    ids[:S] = gids
+    ids = _ids_arg(gids, S_pad)
     params = np.array([k_l0, w0e - tiles.base_ms, window_ms, step, nsteps],
                       np.int32)
     exact_branch = pk.groupsum_exact_branch(window_ms, st, dspan)
@@ -1611,7 +1678,9 @@ def groupsum_counters(tiles: AlignedTiles, func: str, steps: np.ndarray,
           "to the tile base; _eval_counter_fast clips its own indices, "
           "so none of the kernel's shape conditions is asked. One "
           "cached executable (site groupsum) per (func, nsteps, G, "
-          "channel shape) takes int64[6] and the group ids")
+          "channel shape) takes the query's int64[3] grid as its one "
+          "host array: the tiles' constants and the group ids are on "
+          "the device")
 def _groupsum_holes(tiles: AlignedTiles, func: str, steps: np.ndarray,
                     window_ms: int, gids, G: int, offset_ms: int):
     """``groupsum_counters`` over tiles that are not dense -> (sums f32
@@ -1626,9 +1695,8 @@ def _groupsum_holes(tiles: AlignedTiles, func: str, steps: np.ndarray,
     w0e = int(steps[0] - offset_ms)
     step = int(steps[1] - steps[0]) if nsteps > 1 else 1
     arrs = _tiles_arrays_fast(tiles, func)
-    grid = np.array([w0e - window_ms, w0e, step, tiles.num_slots,
-                     tiles.base_ms, tiles.dt_ms], np.int64)
-    args = (arrs, grid, np.asarray(gids, np.int32))
+    grid = np.array([w0e - window_ms, w0e, step], np.int64)
+    args = (arrs, tiles.t_consts(), grid, _ids_arg(gids, len(tiles.keys)))
     key = ("groupsum", "holes", func, nsteps, G,
            tuple(arrs["tsr"].shape))
     fn = _jit_lookup(_EVAL_T_JIT, key, lambda: jax.jit(
@@ -1700,8 +1768,8 @@ def _bucket_quantile(q, les, h):
            "against the host path (periodic_samples, _aggregate_hist_sum, "
            "histogram_quantile in numpy f64) over every bucket a p99, a "
            "median and a tenth percentile land in")
-def _hist_quantile_program(func: str, nsteps: int, G: int, arrs, grid,
-                           ids, les, q):
+def _hist_quantile_program(func: str, nsteps: int, G: int, arrs, consts,
+                           les, q, grid, ids):
     """``histogram_quantile(q, sum by (g) (rate|increase(h[w])))`` over a
     histogram cohort as ONE traceable program (jitted once per static
     tuple by ``hist_quantile_groupsum``): the evaluator's [T, B, S] rates
@@ -1709,12 +1777,14 @@ def _hist_quantile_program(func: str, nsteps: int, G: int, arrs, grid,
     holds fewer than two samples), masked f64 sums by group to [T, G, B]
     (not an f64 dot: the chip has no f64 matmul; a bucket of a group no
     series has a rate in is NaN, as the host's ``_aggregate_hist_sum``),
-    then ``_bucket_quantile`` to [T, G] f64. ``grid`` is int64[6] as
-    ``_groupsum_holes_program``'s; ``ids`` int32 [S] in tile order, an id
-    outside [0, G) names no group; ``les`` f64 [B] and ``q`` f64 are
-    runtime values, so one executable serves every quantile and every
-    scheme of B buckets."""
-    w0s, w0e, step, num_slots, base, dt = grid
+    then ``_bucket_quantile`` to [T, G] f64. ``consts`` and ``ids`` as
+    ``_groupsum_holes_program``'s, ``les`` f64 [B] and ``q`` f64 the
+    tiles' (``t_les``, ``t_q``), all four on the device; ``grid`` the
+    request's int64[3]: w0s, w0e, step. ``les`` and ``q`` are runtime
+    values, so one executable serves every quantile and every scheme of B
+    buckets."""
+    num_slots, base, dt = consts[0], consts[1], consts[2]
+    w0s, w0e, step = grid[0], grid[1], grid[2]
     rates = _eval_counter_fast(func, nsteps, arrs, num_slots, base, dt,
                                w0s, w0e, step)              # [T, B, S]
     member = ids[None, :] == jnp.arange(G, dtype=jnp.int32)[:, None]
@@ -1734,8 +1804,9 @@ def _hist_quantile_program(func: str, nsteps: int, G: int, arrs, grid,
           "counters_batch_family says the grid fits int32 ms relative to "
           "the tile base; _eval_counter_fast clips its own "
           "indices. One cached executable (site groupsum) per (func, "
-          "nsteps, G, channel shape) takes int64[6], the group ids, les "
-          "and q")
+          "nsteps, G, channel shape) takes the query's int64[3] grid "
+          "as its one host array: the tiles' constants, les, q (kept "
+          "a value) and the group ids are on the device")
 def hist_quantile_groupsum(tiles: HistTiles, func: str, steps: np.ndarray,
                            window_ms: int, gids, G: int, q: float,
                            offset_ms: int = 0):
@@ -1743,7 +1814,7 @@ def hist_quantile_groupsum(tiles: HistTiles, func: str, steps: np.ndarray,
     on device -> f64 [T, G] (a device array), or None for a grid wider
     than int32 ms from the tile base (the caller serves it on the host).
     ``gids``: the group id in [0, G) of every series of the tiles, in tile
-    order."""
+    order, or ``fused_group_ids`` of them."""
     assert func in ("rate", "increase")
     nsteps = steps.size
     if nsteps < 1 or counters_batch_family(
@@ -1752,10 +1823,9 @@ def hist_quantile_groupsum(tiles: HistTiles, func: str, steps: np.ndarray,
     w0e = int(steps[0] - offset_ms)
     step = int(steps[1] - steps[0]) if nsteps > 1 else 1
     arrs = _tiles_arrays_hist(tiles)
-    grid = np.array([w0e - window_ms, w0e, step, tiles.num_slots,
-                     tiles.base_ms, tiles.dt_ms], np.int64)
-    args = (arrs, grid, np.asarray(gids, np.int32),
-            np.asarray(tiles.les, np.float64), np.float64(q))
+    grid = np.array([w0e - window_ms, w0e, step], np.int64)
+    args = (arrs, tiles.t_consts(), tiles.t_les(), tiles.t_q(q), grid,
+            _ids_arg(gids, len(tiles.keys)))
     key = ("groupsum", "hist", func, nsteps, G,
            tuple(arrs["ff_v"].shape), "ps_ones" in arrs)
     fn = _jit_lookup(_EVAL_T_JIT, key, lambda: jax.jit(

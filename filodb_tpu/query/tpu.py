@@ -425,10 +425,10 @@ class _TileEntry:
     plus the coverage bound that makes stale serves correct."""
 
     __slots__ = ("tiles", "idx", "prefix_has_nan", "refs", "cov_min_ms",
-                 "built_ends", "key", "ident_key", "gvecs")
+                 "built_ends", "key", "ident_key", "gvecs", "dvecs")
 
     def __init__(self, tiles, idx, prefix_has_nan, refs, cov_min_ms,
-                 built_ends=(), ident_key=None, gvecs=None):
+                 built_ends=(), ident_key=None, gvecs=None, dvecs=None):
         self.tiles = tiles
         # selection index of each tile row, int64 from the build on
         self.idx = np.asarray(idx, dtype=np.int64)
@@ -439,8 +439,11 @@ class _TileEntry:
         self.built_ends = built_ends
         self.key = None                 # the cache's own key object
         self.ident_key = ident_key
-        # the tile-order group ids per grouping the memo handed out
+        # the tile-order group ids per grouping the memo handed out, on
+        # the host and, padded for the one-device fused program, on the
+        # device
         self.gvecs = PerGrouping() if gvecs is None else gvecs
+        self.dvecs = PerGrouping() if dvecs is None else dvecs
 
     def _gather(self, gids: np.ndarray) -> np.ndarray:
         gvec = gids[self.idx]
@@ -451,8 +454,18 @@ class _TileEntry:
 
     def tile_order(self, gids) -> np.ndarray:
         """``gids[idx]``: the group ids in tile order, which both fused
-        device paths take; gathered once per frozen ``gids``."""
+        device paths take (the mesh store keeps its device copy by this
+        array's identity, ``device_ids`` by the memo's); gathered once per
+        frozen ``gids``."""
         return self.gvecs.get(gids, self._gather)
+
+    def device_ids(self, gids):
+        """``tile_order(gids)`` padded for the tiles' one-device fused
+        program and put on the device (``tst.fused_group_ids``): once per
+        frozen ``gids``, so that a request sends its grid alone. They go
+        with the entry."""
+        return self.dvecs.get(gids, lambda g: tst.fused_group_ids(
+            self.tiles, self.tile_order(g)))
 
     def stale_view(self, series) -> "_TileEntry":
         """This entry serving ``series``, a NEWER snapshot of the same
@@ -470,7 +483,7 @@ class _TileEntry:
                 bound = t if bound is None else min(bound, t)
         return _TileEntry(self.tiles, self.idx, self.prefix_has_nan,
                           self.refs, bound, self.built_ends,
-                          self.ident_key, self.gvecs)
+                          self.ident_key, self.gvecs, self.dvecs)
 
 
 class _PackedMember:
@@ -1205,12 +1218,13 @@ class TpuBackend:
             # the mesh store places dense tiles only
             self.fused_refused_gaps += not tiles._dense
             return None
-        # the group ids in tile order, which both device paths take: a
-        # lookup where the memo handed the grouping out, a gather else
-        # (the stage's name is one the benchmark's dispatch_host_ms row
-        # reads)
+        # the group ids in tile order, which both device paths take (the
+        # one-device programs from the device): a lookup where the memo
+        # handed the grouping out, a gather else (the stage's name is one
+        # the benchmark's dispatch_host_ms row reads)
         with obs_trace.span("onehot", groups=G):
-            gvec = entry.tile_order(gids)
+            gvec = (entry.tile_order(gids) if mesh_st is not None
+                    else entry.device_ids(gids))
         if mesh_st is not None:
             self.fused_aggs += 1
             self.mesh_dispatches += 1
@@ -1276,7 +1290,7 @@ class TpuBackend:
             if not self._fused_covered(entry, facts, steps, offset_ms):
                 return "tail", None
         with obs_trace.span("onehot", groups=G):
-            gvec = entry.tile_order(gids)
+            gvec = entry.device_ids(gids)
         with obs_trace.span("device-dispatch", path="fused-hist"):
             res = tst.hist_quantile_groupsum(tiles, func, steps, window_ms,
                                              gvec, G, q, offset_ms)

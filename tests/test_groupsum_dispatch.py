@@ -1,7 +1,7 @@
 """The fused group-sum as ONE cached executable (tilestore.groupsum_counters
 -> ``_jit_lookup`` -> ``_groupsum_program``): a query sends five int32
-scalars and its group ids; nothing is traced or compiled again for a shape
-the table has. Interpret-mode Pallas under ``jit`` on the CPU: counts and
+scalars and its group ids (the served path keeps the ids on the device);
+nothing is traced or compiled again for a shape the table has. Interpret-mode Pallas under ``jit`` on the CPU: counts and
 bits, never a time.
 
 (a) one miss then hits over grid positions and group vectors, the kernel
@@ -463,8 +463,10 @@ def test_holes_one_miss_then_hits_over_positions_groups_and_tiles(
 
 
 def test_holes_send_one_int64_vector_and_the_ids(holed, monkeypatch):
-    """PR 29's rule: numpy in, no scalar becomes a device array on the
-    way, the seven channels are the aligned path's cached ones."""
+    """The request's grid is one numpy int64[3]; the tiles' constants are
+    their cached device array, and the seven channels the aligned path's
+    cached ones. Ids handed over as numpy go as numpy (the backend's tile
+    entry hands over device ids)."""
     seen = {}
     real = tst._jit_lookup
 
@@ -479,10 +481,12 @@ def test_holes_send_one_int64_vector_and_the_ids(holed, monkeypatch):
     assert seen["site"] == "groupsum"
     assert seen["key"] == ("groupsum", "holes", "rate", 12, G_H,
                            (N_H, S_H))
-    arrs, grid, ids = seen["args"]
+    arrs, consts, grid, ids = seen["args"]
     assert type(grid) is np.ndarray and grid.dtype == np.int64
     w0e = int(steps[0]) - 60_000
-    assert grid.tolist() == [w0e - W, w0e, 60_000, N_H, BASE, DT]
+    assert grid.tolist() == [w0e - W, w0e, 60_000]
+    assert consts is holed.t_consts()
+    assert np.asarray(consts).tolist() == [N_H, BASE, DT]
     assert type(ids) is np.ndarray and ids.dtype == np.int32
     assert ids.tolist() == gid.tolist()
     cached = tst._tiles_arrays_fast(holed, "rate")

@@ -266,15 +266,17 @@ def test_groupsum_over_holes_compiles(one_chip, monkeypatch, func):
     assert seen["site"] == "groupsum"
     assert seen["key"] == ("groupsum", "holes", func, t_cell, G,
                            (n_cell, S_HOST))
-    arrs, grid, ids = seen["args"]
-    assert (grid.dtype, grid.shape) == (np.int64, (6,))
+    arrs, consts, grid, ids = seen["args"]
+    assert (consts.dtype, consts.shape) == (np.int64, (3,))
+    assert type(grid) is np.ndarray
+    assert (grid.dtype, grid.shape) == (np.int64, (3,))
     assert (ids.dtype, ids.shape) == (np.int32, (S_HOST,))
 
     def sds(a):
         shape = tuple(s_cell if d == S_HOST else d for d in a.shape)
         return jax.ShapeDtypeStruct(shape, a.dtype, sharding=one_chip)
     compiled = seen["build"]().lower(
-        *jax.tree_util.tree_map(sds, (arrs, grid, ids))).compile()
+        *jax.tree_util.tree_map(sds, (arrs, consts, grid, ids))).compile()
     text = compiled.as_text()
     # no Pallas kernel in this program, and what leaves it is [T, G] f32
     assert "tpu_custom_call" not in text
@@ -367,11 +369,11 @@ def test_grouped_pair_on_four_chips_is_sharded_with_collective(topo):
     col = NamedSharding(mesh, P(None, "shard"))
     row = NamedSharding(mesh, P("shard"))
     rep = NamedSharding(mesh, P())
-    i64 = jax.ShapeDtypeStruct((), jnp.int64, sharding=rep)
+    i64x3 = jax.ShapeDtypeStruct((3,), jnp.int64, sharding=rep)
     args = (jax.ShapeDtypeStruct((cap, S), jnp.int32, sharding=col),
             jax.ShapeDtypeStruct((cap, S), jnp.float64, sharding=col),
             jax.ShapeDtypeStruct((S,), jnp.int32, sharding=row),
-            i64, i64, i64, i64, i64, i64)
+            i64x3, i64x3)
     compiled = _build_grouped_pair_eval(mesh, "rate", T, G).lower(
         *args).compile()
     # the psum over the shard axis: an all-reduce, which this compiler
