@@ -327,6 +327,18 @@ def _h_shardstore(ndev: int):
     return st, st.cap * st.S_pad, st.S_pad
 
 
+@capacity_harness("shardstore-resident-hist-channels")
+def _h_shardstore_hist(ndev: int):
+    """A histogram placement of 12 buckets: [cap, S_pad] int32 rel-ts and
+    two [cap, BP, S_pad] channels of three f32 parts each (counts with a
+    tenth in them, the most a placement holds), (24 BP + 4) / B B a bucket
+    value of a padded slot, and its constants, at every mesh width."""
+    from filodb_tpu.parallel.shardstore import ShardedHistTiles
+    tiles = _seed_hist_tiles(S=10, N=56, frac=0.1)  # cap 64, S pads 4, 8
+    st = ShardedHistTiles(_shard_mesh(ndev), tiles)
+    return st, st.cap * st.S_pad * st.B, st.S_pad
+
+
 @capacity_harness("tilestore-aligned-tiles")
 def _h_aligned_tiles():
     """Single-device aligned tiles: valid bool + ts f64 + vals f64 =
@@ -335,26 +347,31 @@ def _h_aligned_tiles():
     return tiles, 8 * 64, 8
 
 
-@capacity_harness("tilestore-hist-tiles")
-def _h_hist_tiles():
-    """A histogram cohort of 12 buckets: valid bool + ts f64 a slot, and
-    raw, corrected and correction f64 a bucket value; a sample is one
-    bucket value of one slot."""
+def _seed_hist_tiles(S: int = 8, N: int = 64, B: int = 12,
+                     frac: float = 0.0):
+    """Dense histogram tiles: S series x N slots x B buckets; ``frac`` is
+    added to every count and is every correction."""
     import numpy as np
 
     from filodb_tpu.query import tilestore as tst
-    S, N, B = 8, 64, 12
     rng = np.random.default_rng(_SEED)
     base, dt = 1_000_000_000_000, 10_000
     ts = base + np.arange(N, dtype=np.float64)[None, :] * dt \
         + rng.integers(-2000, 2001, (S, N))
     vals = np.cumsum(np.cumsum(rng.poisson(5.0, (S, N, B)), axis=2),
-                     axis=1).astype(np.float64)
+                     axis=1).astype(np.float64) + frac
     les = tuple(float(2 ** i) for i in range(B - 1)) + (float("inf"),)
-    tiles = tst.HistTiles([{"i": str(i)} for i in range(S)], base, dt,
-                          np.ones((S, N), bool), ts, vals,
-                          np.zeros_like(vals), les)
-    return tiles, S * N * B, S
+    return tst.HistTiles([{"i": str(i)} for i in range(S)], base, dt,
+                         np.ones((S, N), bool), ts, vals,
+                         np.full_like(vals, frac), les)
+
+
+@capacity_harness("tilestore-hist-tiles")
+def _h_hist_tiles():
+    """A histogram cohort of 12 buckets: valid bool + ts f64 a slot, and
+    raw, corrected and correction f64 a bucket value; a sample is one
+    bucket value of one slot."""
+    return _seed_hist_tiles(S=8, N=64, B=12), 8 * 64 * 12, 8
 
 
 @capacity_harness("tilestore-executable-constants")

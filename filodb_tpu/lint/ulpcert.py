@@ -675,6 +675,27 @@ def _h_grouped_reduce(ndev: int):
                  for o in f(jnp.asarray(local), jnp.asarray(gids)))
 
 
+@order_harness("hist-bucket-psum")
+def _h_hist_bucket_psum(ndev: int):
+    """The mesh store's fused quantile over ``_hist_world``'s tiles placed
+    on ``ndev`` devices (24 series: 24, 12, 6 and 3 a device), rate and
+    increase at q 0.1, 0.5 and 0.99: only the psum's order moves."""
+    import numpy as np
+
+    from filodb_tpu.parallel.shardstore import ShardedHistTiles
+    from filodb_tpu.query import tilestore as tst
+    series, gids, G, first = _hist_world()
+    tiles, idx = tst.build_aligned_tiles(series)
+    st = ShardedHistTiles(_shard_mesh(ndev), tiles)
+    steps = first + np.arange(16, dtype=np.int64) * 30_000
+    gvec = np.asarray(gids[idx], np.int32)
+    sums = {func: np.asarray(st.dispatch_hist_quantile(
+        func, steps, 300_000, gvec, G))[:16]
+        for func in ("rate", "increase")}
+    return tuple(st.quantile(sums[func], q)
+                 for func in ("rate", "increase") for q in (0.1, 0.5, 0.99))
+
+
 @order_harness("grouped-pair-psum")
 def _h_grouped_pair(ndev: int):
     import numpy as np

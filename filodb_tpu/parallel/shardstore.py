@@ -29,13 +29,24 @@ lower through ``shard_map``:
     are capacity-padded and a flush appends its new slot columns via a
     ``donate_argnums`` jit (``_append_step``) — the donated buffers are
     reused in place, no re-placement, no second copy of a multi-GB
-    store during rebuild.
+    store during rebuild;
+  * a histogram cohort (query/tilestore.py HistTiles) has a placement
+    of its own (``ShardedHistTiles``): the corrected buckets and the
+    correction, each device holding every bucket of its own series,
+    serve ``histogram_quantile(q, sum by (g) (rate|increase(h[w])))``
+    with ONE program (``_build_hist_quantile_eval``): the one-chip
+    program's evaluator and partial sums on every device and a ``psum``
+    of the [T, G, B] bucket sums and counts; the host then takes the
+    quantile of the summed buckets, which is not linear and so comes
+    after the psum.
 
 Escape hatches: tiles must be dense (every slot valid) with the tile
 span in int32 ms — exactly the fast-family eligibility of the
 single-device dispatcher — and a query whose grid leaves the int32
 range (or whose tiles never qualified) falls back to the single-device
-tilestore path unchanged.
+tilestore path unchanged. A histogram placement has no donated append:
+a flush drops it (an eviction) and the next request places the new
+tiles.
 """
 
 from __future__ import annotations
@@ -320,6 +331,129 @@ def _build_grouped_eval(mesh: Mesh, func: str, nsteps_local: int,
     return run
 
 
+def _take_rows(a: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """``a[idx]`` along the first axis for an index array of any shape,
+    the indices promised in bounds (the host clipped them): a plain gather,
+    without the bounds and negative-index selects ``jnp.take`` adds."""
+    dims = jax.lax.GatherDimensionNumbers(
+        offset_dims=tuple(range(idx.ndim, idx.ndim + a.ndim - 1)),
+        collapsed_slice_dims=(0,), start_index_map=(0,))
+    return jax.lax.gather(a, idx[..., None], dims, (1,) + a.shape[1:],
+                          mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+
+
+# a histogram request's plan (``ShardedHistTiles._plan``): eight runs of
+# T_l, one value a window each, then the query's first window's two
+# candidate slots and its start
+(_PL_KC, _PL_KP, _PL_KCL, _PL_KN, _PL_COUNTS, _PL_FLAGS, _PL_WS,
+ _PL_WE) = range(8)
+_PL_RUNS = 8
+# _PL_FLAGS bits: the window's last/first slot is a real slot, no sample
+# at or before the window's end, none at or after its start
+_FL_HI_OK, _FL_LO_OK, _FL_NONE_HI, _FL_NONE_LO = 1, 2, 4, 8
+
+
+@order_insensitive(
+    "hist-bucket-psum", tolerance=1e-12,
+    reason="the [T, G, B] bucket sums are f64 per-device masked-sum "
+           "partials psummed over the shard axis (counts are exact "
+           "int32); the quantile after the psum divides the sums' "
+           "regrouping by a bucket's share of the total, a few hundred "
+           "f64 ulps at most — certified at 1/2/4/8 virtual devices")
+def _build_hist_quantile_eval(mesh: Mesh, func: str, nsteps_local: int,
+                              num_groups: int, num_buckets: int):
+    """The device half of ``histogram_quantile(q, sum by (g)
+    (rate|increase(h[w])))`` from a resident histogram placement
+    (``ShardedHistTiles``) -> [T, G, B] f64, the bucket sums of every
+    group over every device's series, NaN where no series of the group
+    has a rate (as the host's ``_aggregate_hist_sum``).
+
+    Per device, over its own series: the four boundary rows of every
+    window taken in one gather a channel part (the request's plan names
+    them), the one-chip evaluator's two-candidate selects and zero point
+    (``tilestore._eval_counter_fast``'s histogram branch, the same
+    operations), ``tilestore._extrapolated_rate``, then
+    ``tilestore.hist_group_partials``; then a ``psum`` of the [T_l, G, B]
+    sums and counts over the shard axis. The quantile, which is not linear,
+    is the caller's (``ShardedHistTiles.quantile``). What the one-chip
+    program computes on the device from the grid, the slot of each
+    window's boundaries, the host computes exactly in integers
+    (``_plan``), and a channel waits as the f32 parts its values need
+    (``ShardedHistTiles``), so that no request splits a whole f64 channel:
+    a traced run records every device op of every launch on every chip,
+    and this keeps a launch at 45 of them a chip with one part a channel,
+    53 with two (``tests/test_tpu_compile.py`` holds the counts).
+
+    The program takes the resident channels (each a tuple of its parts)
+    and group ids and the request's plan: one host array."""
+    from filodb_tpu.query.tilestore import (_extrapolated_rate,
+                                            hist_group_partials)
+
+    s_axis, t_axis = mesh.axis_names[:2]
+    # with steps over a time axis a device's first window is not the
+    # query's: the zero point is read at the query's own first window
+    own_first = int(mesh.shape[t_axis]) > 1
+
+    def rows(parts, idx):
+        # the f64 rows of a channel kept as f32 parts: their exact sum
+        out = _take_rows(parts[0], idx).astype(jnp.float64)
+        for part in parts[1:]:
+            out = out + _take_rows(part, idx).astype(jnp.float64)
+        return out
+
+    def hist_sums_body(tsr, cv, corr, gids, plan):
+        T = nsteps_local
+
+        def run(i):
+            return plan[i * T:(i + 1) * T]
+        k4 = plan[:4 * T]                                    # [4 T_l]
+        ws, we = run(_PL_WS)[:, None], run(_PL_WE)[:, None]
+        flags = run(_PL_FLAGS)
+        ts_kc, ts_kp, tsb_kcl, tsb_kn = (
+            _take_rows(tsr, k4).reshape(4, T, -1))           # [T_l, S_l]
+        v_kc, v_kp, v_kcl, v_kn = rows(cv, k4).reshape(
+            (4, T) + cv[0].shape[1:])                        # [T_l, BP, S_l]
+        k0 = plan[_PL_RUNS * T:_PL_RUNS * T + 2]
+        c2 = rows(corr, k0)                                  # [2, BP, S_l]
+
+        def bit(b):
+            return ((flags & b) > 0)[:, None]
+        over = bit(_FL_HI_OK) & (ts_kc > we)
+        under = bit(_FL_LO_OK) & (tsb_kcl < ws)
+        counts = run(_PL_COUNTS)[:, None] - over.astype(jnp.int32) \
+            - under.astype(jnp.int32)
+        ax = lambda a: a[:, None]                           # noqa: E731
+        use1 = ts_kc <= we
+        t2 = jnp.where(use1, ts_kc, ts_kp)
+        v2 = jnp.where(ax(bit(_FL_NONE_HI)), jnp.nan,
+                       jnp.where(ax(use1), v_kc, v_kp))
+        useb = tsb_kcl >= ws
+        t1 = jnp.where(useb, tsb_kcl, tsb_kn)
+        v1 = jnp.where(ax(bit(_FL_NONE_LO)), jnp.nan,
+                       jnp.where(ax(useb), v_kcl, v_kn))
+        first = (_take_rows(tsr, k0[:1])[0] >= plan[_PL_RUNS * T + 2]
+                 if own_first else useb[0])
+        c0 = jnp.where(first, c2[0], c2[1])                 # [BP, S_l]
+        rates = _extrapolated_rate(ax(ws), ax(we), ax(counts), ax(t1),
+                                   v1 - c0, ax(t2), v2 - c0, True,
+                                   func == "rate")         # [T_l, BP, S_l]
+        sums, cnts = hist_group_partials(rates, gids, num_groups)
+        sums = jax.lax.psum(sums[..., :num_buckets], s_axis)
+        cnts = jax.lax.psum(cnts[..., :num_buckets], s_axis)
+        return jnp.where(cnts > 0, sums, jnp.nan)
+
+    @jax.jit
+    def run(tsr, cv, corr, gids, plan):
+        cv_s, corr_s = (tuple(P(None, None, s_axis) for _ in c)
+                        for c in (cv, corr))
+        inner = jax.shard_map(
+            hist_sums_body, mesh=mesh,
+            in_specs=(P(None, s_axis), cv_s, corr_s, P(s_axis), P(t_axis)),
+            out_specs=P(t_axis, None, None))
+        return inner(tsr, cv, corr, gids, plan)
+    return run
+
+
 # ---------------------------------------------------------------------------
 # The resident store
 # ---------------------------------------------------------------------------
@@ -348,6 +482,9 @@ class ShardedTiles:
     mesh axis. Immutable except through :meth:`append_slots` (the
     donated refresh)."""
 
+    # the filodb_device_memory_bytes{family} this placement counts under
+    FAMILY = "shardstore-resident-channels"
+
     def __init__(self, mesh: Mesh, tiles) -> None:
         self.mesh = mesh
         self.base_ms = int(tiles.base_ms)
@@ -361,22 +498,11 @@ class ShardedTiles:
         self.S_pad = -(-S // n_shard) * n_shard
         self.cap = _next_pow2(N, 64)
         self.n_filled = N
-        col = NamedSharding(mesh, P(None, mesh.axis_names[0]))
-        self._col_sharding = col
-
-        def place(host_nx_s, dtype):
-            buf = np.zeros((self.cap, self.S_pad), dtype=dtype)
-            buf[:N, :S] = host_nx_s
-            return jax.device_put(buf, col)
-
+        self._col_sharding = NamedSharding(mesh, P(None, mesh.axis_names[0]))
         ts = np.asarray(tiles.ts, dtype=np.float64)             # [S, N]
-        self._tsr = place((ts - self.base_ms).T.astype(np.int32), np.int32)
-        v = np.asarray(tiles.channel("v"), dtype=np.float64)
-        self._v = place(v.T, np.float64)
-        cv = np.asarray(tiles.channel("cv"), dtype=np.float64)
-        self._cv = place(cv.T, np.float64)
-        # non-counter aligned channel placements, per function family
-        self._aligned: Dict[Tuple, Dict[str, jnp.ndarray]] = {}
+        self._tsr = self._place_cols(
+            (ts - self.base_ms).T.astype(np.int32), self.S_pad)
+        self._place_channels(tiles)
         # the padded group ids on the devices, per tile-order vector the
         # backend's tile entry handed out (``_row_gids``)
         self._gids = PerGrouping()
@@ -385,10 +511,26 @@ class ShardedTiles:
         # filodb_device_memory_bytes{family,shard} gauge, dropped when
         # the store is collected
         ensure_residency_collector()
-        self._res_key = ("shardstore-resident-channels", str(n_shard),
-                         id(self))
+        self._res_key = (self.FAMILY, str(n_shard), id(self))
         weakref.finalize(self, drop_resident, *self._res_key)
         self._record_residency()
+
+    def _place_cols(self, host_nx_c: np.ndarray, width: int) -> jnp.ndarray:
+        """``host_nx_c`` [N, C] (C <= ``width``) zero-padded to [cap,
+        width] and put on the mesh with its columns sharded over the
+        first axis."""
+        buf = np.zeros((self.cap, width), dtype=host_nx_c.dtype)
+        buf[:host_nx_c.shape[0], :host_nx_c.shape[1]] = host_nx_c
+        return jax.device_put(buf, self._col_sharding)
+
+    def _place_channels(self, tiles) -> None:
+        """The counter channels beside ``_tsr``: raw and corrected f64."""
+        v = np.asarray(tiles.channel("v"), dtype=np.float64)
+        self._v = self._place_cols(v.T, self.S_pad)
+        cv = np.asarray(tiles.channel("cv"), dtype=np.float64)
+        self._cv = self._place_cols(cv.T, self.S_pad)
+        # non-counter aligned channel placements, per function family
+        self._aligned: Dict[Tuple, Dict[str, jnp.ndarray]] = {}
 
     def _put_consts(self) -> None:
         """n_filled, base_ms and dt_ms on the devices, replicated: the
@@ -398,11 +540,15 @@ class ShardedTiles:
             np.array([self.n_filled, self.base_ms, self.dt_ms], np.int64),
             NamedSharding(self.mesh, P()))
 
+    def _buffers(self):
+        """The resident channels, for the residency gauge."""
+        yield from (self._tsr, self._v, self._cv)
+        for placed in self._aligned.values():
+            yield from placed.values()
+
     def _record_residency(self) -> None:
-        nbytes = int(self._tsr.nbytes + self._v.nbytes + self._cv.nbytes)
-        nbytes += sum(int(a.nbytes) for placed in self._aligned.values()
-                      for a in placed.values())
-        record_resident(*self._res_key, nbytes)
+        record_resident(*self._res_key,
+                        sum(int(a.nbytes) for a in self._buffers()))
 
     # -- eligibility -------------------------------------------------------
 
@@ -647,6 +793,145 @@ class ShardedTiles:
         return True
 
 
+@capacity(
+    "shardstore-resident-hist-channels", bytes_per_sample=32.34,
+    sharded=True, overhead_bytes=24,
+    reason="a histogram placement prices its bucket axis: a sample is ONE "
+           "bucket value of one PADDED slot (pow2 slot capacity, "
+           "shard-aligned series pad), kept as at most three f32 parts "
+           "in each of the corrected-bucket and correction channels "
+           "[cap, BP, S_pad], whose bucket axis pads to the chip's 8-row "
+           "tile (BP 16 for 12 buckets): 24 * BP / B B, plus the slot's "
+           "int32 relative timestamp (4 B) shared by its B buckets: (24 "
+           "* BP + 4) / B, priced at the Prometheus client's 12 default "
+           "buckets (32.34 B; integer counts below 2**24 keep one part a "
+           "channel, a third of that, below 2**48 two); besides, the "
+           "base class's constants int64[3] (24 B). The group ids come "
+           "with requests, as for counters")
+class ShardedHistTiles(ShardedTiles):
+    """A histogram cohort (``tilestore.HistTiles``) resident across the
+    mesh, for the fused quantile: ``_tsr`` [cap, S_pad] as for counters,
+    and the corrected buckets and the correction [cap, BP, S_pad] with the
+    series sharded over the first axis, so each device holds every bucket
+    of its own S_l series. The bucket axis pads to BP, a multiple of the
+    chip's 8-row tile (the padded buckets are zeros and never summed):
+    with a gather along the slots the rows then come out as [.., BP, S_l]
+    with no layout change. Each channel is kept as the f32 parts whose
+    sum is exactly its f64 values (``f32(x)``, then ``f32`` of what is
+    left, as long as something is: integer counts below 2**24 keep one
+    part, below 2**48 two, any finite value three), so that no request
+    splits a whole channel again; the program adds the parts of the rows
+    it takes. The bounds stay on the host, for ``quantile``.
+
+    It serves ``dispatch_hist_quantile`` alone: the counter programs of
+    the base class are not for it. No donated append exists for
+    histograms yet: ``append_slots`` refuses, so a flush drops the
+    placement (``ShardedTileEvaluator.refresh`` counts it as an eviction)
+    and the next request over the new tiles places them again."""
+
+    FAMILY = "shardstore-resident-hist-channels"
+
+    def _place_channels(self, tiles) -> None:
+        B = int(tiles.num_buckets)
+        self.B, self.BP = B, -(-B // 8) * 8
+        self.S_l = self.S_pad // int(self.mesh.shape[self.mesh.axis_names[0]])
+        self.les = np.asarray(tiles.les, np.float64)
+        sharding = NamedSharding(self.mesh,
+                                 P(None, None, self.mesh.axis_names[0]))
+
+        def parts(ch):
+            # [N, B*S] (column b*S + s) -> [cap, BP, S_pad] f64, then the
+            # f32 parts whose sum is exactly it (as few as its values
+            # need: one for integers below 2**24, two below 2**48, three
+            # for any finite value), each put with the series sharded
+            x = np.zeros((self.cap, self.BP, self.S_pad), np.float64)
+            x[:self.n_filled, :B, :self.S] = np.asarray(
+                ch, np.float64).reshape(self.n_filled, B, self.S)
+            kept = [x.astype(np.float32)]
+            rest = np.where(np.isfinite(kept[0]), x - kept[0], 0.0)
+            while rest.any() and len(kept) < 3:
+                kept.append(rest.astype(np.float32))
+                rest = rest - kept[-1]
+            return tuple(jax.device_put(h, sharding) for h in kept)
+        self._cv = parts(tiles.t_cv)
+        self._corr = parts(tiles.t_corr)
+
+    def _buffers(self):
+        yield self._tsr
+        yield from self._cv
+        yield from self._corr
+
+    def append_slots(self, tiles_new) -> bool:
+        """No donated append for histograms: the caller places anew."""
+        return False
+
+    def _plan(self, steps: np.ndarray, window_ms: int, offset_ms: int):
+        """-> (steps a device of the time axis computes, the request's
+        plan: int32 [n_time * (8 T_l + 3)], the block of each device of
+        the time axis in turn). For every window, in ms from the tile base
+        and slots of the placement, what ``tilestore._eval_counter_fast``
+        computes from the grid on the device, here exactly in integers,
+        each a run of T_l: the slots nearest its end (``k_hi = floor((we +
+        dt/2) / dt)``) and its start (``k_lo = ceil((ws - dt/2) / dt)``)
+        and their neighbours, clipped to the filled slots; the slot count
+        between them; the flags ``_FL_*``; the window's start and end.
+        Then the query's first window's two candidate slots and its start,
+        where the buckets' zero point is read. ``query_fits`` has to hold:
+        every start and end is int32."""
+        t_local, grid = self._grid(steps, window_ms, offset_ms)
+        N, dt = self.n_filled, self.dt_ms
+        t = np.arange(t_local * self.n_time, dtype=np.int64)
+        ws = int(grid[0]) - self.base_ms + t * int(grid[2])
+        we = int(grid[1]) - self.base_ms + t * int(grid[2])
+        k_hi = (2 * we + dt) // (2 * dt)
+        k_lo = -((dt - 2 * ws) // (2 * dt))
+        hi_ok = (k_hi >= 0) & (k_hi <= N - 1)
+        lo_ok = (k_lo >= 0) & (k_lo <= N - 1)
+        runs = np.empty((_PL_RUNS, t.size), np.int64)
+        runs[_PL_KC] = np.clip(k_hi, 0, N - 1)
+        runs[_PL_KP] = np.clip(k_hi - 1, 0, N - 1)
+        runs[_PL_KCL] = np.clip(k_lo, 0, N - 1)
+        runs[_PL_KN] = np.clip(k_lo + 1, 0, N - 1)
+        runs[_PL_COUNTS] = np.clip(k_hi, -1, N - 1) + 1 - np.clip(k_lo, 0, N)
+        runs[_PL_FLAGS] = (hi_ok * _FL_HI_OK + lo_ok * _FL_LO_OK
+                           + (k_hi < 0) * _FL_NONE_HI
+                           + (k_lo > N - 1) * _FL_NONE_LO)
+        runs[_PL_WS], runs[_PL_WE] = ws, we
+        blocks = runs.reshape(_PL_RUNS, self.n_time, t_local).transpose(1, 0, 2)
+        first = np.broadcast_to(
+            np.array([runs[_PL_KCL, 0], runs[_PL_KN, 0], ws[0]]),
+            (self.n_time, 3))
+        plan = np.concatenate([blocks.reshape(self.n_time, -1), first], 1)
+        return t_local, plan.astype(np.int32).reshape(-1)
+
+    def dispatch_hist_quantile(self, func: str, steps: np.ndarray,
+                               window_ms: int, gids: np.ndarray,
+                               num_groups: int, offset_ms: int = 0):
+        """Enqueue the bucket sums off the resident placement -> device
+        f64 [T_pad, G, B]; the caller syncs, cuts to ``steps.size`` rows
+        and takes the quantile (``quantile``). ``gids`` in tile order
+        (``_row_gids``); the call hands over one host array, the request's
+        plan (``query_fits`` has to hold)."""
+        t_local, plan = self._plan(steps, window_ms, offset_ms)
+        args = (self._tsr, self._cv, self._corr, self._row_gids(gids), plan)
+        key = ("mesh-hist-sums", func, t_local, num_groups,
+               tuple(self._cv[0].shape), len(self._cv), len(self._corr),
+               self._mesh_key())
+        fn = _jit_lookup(key, lambda: _build_hist_quantile_eval(
+            self.mesh, func, t_local, num_groups, self.B), cost_args=args)
+        return fn(*args)
+
+    def quantile(self, h: np.ndarray, q: float) -> np.ndarray:
+        """``histogram_quantile(q, ..)`` of the synced bucket sums [T, G, B]
+        -> [T, G] f64, on the host: ``tilestore._bucket_quantile`` in
+        numpy f64. After the psum this is a few thousand values, and on
+        the chip it would be some twenty more ops a launch on every
+        device."""
+        from filodb_tpu.query.tilestore import _bucket_quantile
+        with np.errstate(divide="ignore", invalid="ignore"):
+            return _bucket_quantile(q, self.les, h, xp=np)
+
+
 # ---------------------------------------------------------------------------
 # Placement cache (the evaluator the backend holds)
 # ---------------------------------------------------------------------------
@@ -681,8 +966,9 @@ class ShardedTileEvaluator:
         return int(self.mesh.devices.size)
 
     def place(self, tiles) -> Optional[ShardedTiles]:
-        """The resident placement for ``tiles`` (built on first sight),
-        or None when the tiles don't qualify."""
+        """The resident placement for ``tiles`` (built on first sight): a
+        ``ShardedHistTiles`` for a histogram cohort, else a
+        ``ShardedTiles``; None when the tiles don't qualify."""
         if tiles is None or not ShardedTiles.tiles_eligible(tiles):
             return None
         key = id(tiles)
@@ -690,8 +976,11 @@ class ShardedTileEvaluator:
             got = self._placed.get(key)
             if got is not None:
                 return got[1]
+        from filodb_tpu.query.tilestore import HistTiles
+        kind = (ShardedHistTiles if isinstance(tiles, HistTiles)
+                else ShardedTiles)
         with obs_trace.span("mesh-place", series=len(tiles.keys)):
-            placed = ShardedTiles(self.mesh, tiles)
+            placed = kind(self.mesh, tiles)
 
         def _drop(_ref, *, _self=self, _key=key):
             with _self._lock:
@@ -710,9 +999,14 @@ class ShardedTileEvaluator:
         """Cross-flush hand-over: move the old tiles' placement onto
         the freshly-built tiles via the donated append when compatible
         (zero-copy in HBM); otherwise drop it (the next query
-        re-places). Returns True when the donated path served."""
+        re-places). A histogram placement, which has no donated append,
+        is dropped and counted in ``evictions``. Returns True when the
+        donated path served."""
         with self._lock:
             got = self._placed.pop(id(old_tiles), None)
+            if got is not None and isinstance(got[1], ShardedHistTiles):
+                self.evictions += 1
+                return False
         if got is None or new_tiles is None:
             return False
         placed = got[1]

@@ -1723,7 +1723,7 @@ def _tiles_arrays_hist(tiles: HistTiles) -> Dict[str, jnp.ndarray]:
     }
 
 
-def _bucket_quantile(q, les, h):
+def _bucket_quantile(q, les, h, xp=jnp):
     """``histogram_quantile`` (memory/histogram.py ``quantile``, which is
     Prometheus's ``bucketQuantile``) at every (step, group) at once: ``h``
     [T, G, B] cumulative, NaN where the group has no point; ``les`` [B],
@@ -1732,30 +1732,31 @@ def _bucket_quantile(q, les, h):
     ``+Inf`` bucket the second-highest bound, in a first bucket bounded
     at or below 0 that bound, in an empty bucket its upper bound, else
     linear inside it, from 0 for the first. NaN where ``total`` is 0 or
-    NaN; a ``q`` outside [0, 1] gives -Inf or +Inf."""
+    NaN; a ``q`` outside [0, 1] gives -Inf or +Inf. ``xp`` is ``jnp`` in
+    a device program, or ``numpy`` for the same steps on the host."""
     B = h.shape[-1]
     total = h[..., -1]
     rank = q * total
-    reach = jnp.concatenate(
+    reach = xp.concatenate(
         [h[..., :-1] >= rank[..., None],
-         jnp.ones(h.shape[:-1] + (1,), bool)], axis=-1)
-    b = jnp.argmax(reach, axis=-1)                          # [T, G]
-    at = jnp.arange(B)
+         xp.ones(h.shape[:-1] + (1,), bool)], axis=-1)
+    b = xp.argmax(reach, axis=-1)                           # [T, G]
+    at = xp.arange(B)
 
     def pick(x, i):
         # x[.., i] by a masked sum over the buckets: no gather
-        return jnp.sum(jnp.where(at == i[..., None], x, 0.0), axis=-1)
+        return xp.sum(xp.where(at == i[..., None], x, 0.0), axis=-1)
     c_end, le_end = pick(h, b), pick(les, b)
     c_start, le_start = pick(h, b - 1), pick(les, b - 1)   # 0 where b = 0
     inside = le_start + (le_end - le_start) * (rank - c_start) \
         / (c_end - c_start)
-    out = jnp.where(c_end == c_start, le_end, inside)
-    out = jnp.where((b == 0) & (les[0] <= 0), les[0], out)
-    out = jnp.where(b == B - 1, les[B - 2], out)
-    out = jnp.where(total == 0, jnp.nan, out)
-    out = jnp.where((q >= 0) & (q <= 1), out,
-                    jnp.where(q > 1, jnp.inf, -jnp.inf))
-    return jnp.where(jnp.isnan(total), jnp.nan, out)
+    out = xp.where(c_end == c_start, le_end, inside)
+    out = xp.where((b == 0) & (les[0] <= 0), les[0], out)
+    out = xp.where(b == B - 1, les[B - 2], out)
+    out = xp.where(total == 0, xp.nan, out)
+    out = xp.where((q >= 0) & (q <= 1), out,
+                   xp.where(q > 1, xp.inf, -xp.inf))
+    return xp.where(xp.isnan(total), xp.nan, out)
 
 
 @precision(
@@ -1774,24 +1775,42 @@ def _hist_quantile_program(func: str, nsteps: int, G: int, arrs, consts,
     histogram cohort as ONE traceable program (jitted once per static
     tuple by ``hist_quantile_groupsum``): the evaluator's [T, B, S] rates
     (``_eval_counter_fast``'s takes, the f64 formula: NaN where a window
-    holds fewer than two samples), masked f64 sums by group to [T, G, B]
-    (not an f64 dot: the chip has no f64 matmul; a bucket of a group no
-    series has a rate in is NaN, as the host's ``_aggregate_hist_sum``),
-    then ``_bucket_quantile`` to [T, G] f64. ``consts`` and ``ids`` as
-    ``_groupsum_holes_program``'s, ``les`` f64 [B] and ``q`` f64 the
+    holds fewer than two samples), ``hist_group_partials`` to [T, G, B],
+    then ``hist_quantile_epilogue`` to [T, G] f64. ``consts`` and ``ids``
+    as ``_groupsum_holes_program``'s, ``les`` f64 [B] and ``q`` f64 the
     tiles' (``t_les``, ``t_q``), all four on the device; ``grid`` the
     request's int64[3]: w0s, w0e, step. ``les`` and ``q`` are runtime
     values, so one executable serves every quantile and every scheme of B
-    buckets."""
+    buckets. The mesh store's program (``parallel/shardstore.py``) runs
+    ``hist_group_partials`` on every device and a ``psum`` of its
+    partials; its caller takes ``_bucket_quantile`` of the sums on the
+    host."""
     num_slots, base, dt = consts[0], consts[1], consts[2]
     w0s, w0e, step = grid[0], grid[1], grid[2]
     rates = _eval_counter_fast(func, nsteps, arrs, num_slots, base, dt,
                                w0s, w0e, step)              # [T, B, S]
+    sums, cnts = hist_group_partials(rates, ids, G)
+    return hist_quantile_epilogue(sums, cnts, les, q)
+
+
+def hist_group_partials(rates, ids, G: int):
+    """[T, B, S] rates and the series' group ids -> (sums [T, G, B] f64,
+    cnts [T, G, B] int32): masked f64 sums by group (not an f64 dot: the
+    chip has no f64 matmul) and how many series had a rate there. An id
+    outside [0, G), such as a padding row's -1, is no group's."""
     member = ids[None, :] == jnp.arange(G, dtype=jnp.int32)[:, None]
     ok = ~jnp.isnan(rates)[:, None] & member[None, :, None, :]
     sums = jnp.sum(jnp.where(ok, rates[:, None], 0.0), axis=3,
                    dtype=jnp.float64)                       # [T, G, B]
     cnts = jnp.sum(ok, axis=3, dtype=jnp.int32)
+    return sums, cnts
+
+
+def hist_quantile_epilogue(sums, cnts, les, q):
+    """``hist_group_partials``' (summed over every series) -> [T, G] f64:
+    a bucket of a group no series has a rate in is NaN, as the host's
+    ``_aggregate_hist_sum``, then ``_bucket_quantile``. Not linear, so it
+    runs after any sum over devices."""
     return _bucket_quantile(q, les, jnp.where(cnts > 0, sums, jnp.nan))
 
 

@@ -1257,15 +1257,25 @@ class TpuBackend:
         over the selection's bucket-axis tiles (``tst.HistTiles``, from
         the same tile cache, keyed by the facts) takes the rates bucket by
         bucket, sums them by group and takes the quantile, and only the
-        [T, G] answer leaves the chip. Returns f64 [T, G] numpy, or None
-        (the caller serves the query on the host over the same
-        selection). Counts in ``filodb_fused_hist_aggs_total``, a None in
+        [T, G] answer leaves the chip. On a node with a mesh store the
+        store is asked first: its placement of the tiles
+        (``ShardedHistTiles``) runs the same bodies on every device over
+        its own series, with a ``psum`` of the [T, G, B] partials, and the
+        host takes the quantile of the [T, G, B] sums that leave the
+        mesh; also in ``filodb_mesh_dispatches_total``. What it
+        turns down counts in ``filodb_mesh_refused_total{reason}``
+        (``tiles``: holes, or a span past int32 ms; ``grid``: a grid that
+        leaves int32 ms from the tile base) and the one-chip program
+        serves. Returns f64 [T, G] numpy, or None (the caller serves the
+        query on the host over the same selection). Counts in
+        ``filodb_fused_hist_aggs_total``, a None in
         ``filodb_fused_hist_refused_total{reason}``: ``cpu`` (a CPU node
-        without the interpreted kernels), ``tiles`` (not one bucket
-        scheme, no shared cadence, or a scheme the program cannot answer:
-        fewer than two buckets, or no ``+Inf`` last), ``tail`` (a window reaches the
+        without the interpreted kernels and without a mesh store that
+        took the query), ``tiles`` (not one bucket scheme, no shared
+        cadence, or a scheme the program cannot answer: fewer than two
+        buckets, or no ``+Inf`` last), ``tail`` (a window reaches the
         write-buffer tail) and ``grid`` (wider than int32 ms from the
-        tile base). The mesh store is not asked."""
+        tile base)."""
         why, res = self._fused_hist_quantile(series, func, steps,
                                              window_ms, offset_ms, gids, G,
                                              q, facts)
@@ -1275,7 +1285,9 @@ class TpuBackend:
 
     def _fused_hist_quantile(self, series, func, steps, window_ms,
                              offset_ms, gids, G, q, facts):
-        if jax.default_backend() == "cpu" and not FUSED_GROUPSUM_INTERPRET:
+        on_cpu = jax.default_backend() == "cpu" \
+            and not FUSED_GROUPSUM_INTERPRET
+        if on_cpu and self.mesh_eval is None:
             return "cpu", None
         if facts is None:
             facts = selection_facts(series)
@@ -1289,18 +1301,43 @@ class TpuBackend:
         with obs_trace.span("fused-eligibility", series=len(series)):
             if not self._fused_covered(entry, facts, steps, offset_ms):
                 return "tail", None
+            mesh_st = None
+            if self.mesh_eval is not None and steps.size >= 1:
+                # (a placement the store has to build is the mesh-place
+                # stage, a child of this one)
+                mesh_st = self.mesh_eval.place(tiles)
+                why_not = "tiles" if mesh_st is None else (
+                    None if mesh_st.query_fits(steps, window_ms, offset_ms)
+                    else "grid")
+                if why_not is not None:
+                    self.mesh_refused[why_not] += 1
+                    mesh_st = None
+        if mesh_st is None and on_cpu:
+            return "cpu", None
         with obs_trace.span("onehot", groups=G):
-            gvec = entry.device_ids(gids)
-        with obs_trace.span("device-dispatch", path="fused-hist"):
-            res = tst.hist_quantile_groupsum(tiles, func, steps, window_ms,
-                                             gvec, G, q, offset_ms)
-        if res is None:
-            return "grid", None
+            gvec = (entry.tile_order(gids) if mesh_st is not None
+                    else entry.device_ids(gids))
+        if mesh_st is not None:
+            self.mesh_dispatches += 1
+            with obs_trace.span("device-dispatch", path="mesh-fused-hist"):
+                res = mesh_st.dispatch_hist_quantile(
+                    func, steps, window_ms, gvec, G, offset_ms)
+        else:
+            with obs_trace.span("device-dispatch", path="fused-hist"):
+                res = tst.hist_quantile_groupsum(tiles, func, steps,
+                                                 window_ms, gvec, G, q,
+                                                 offset_ms)
+            if res is None:
+                return "grid", None
         self.fused_hist_aggs += 1
         with obs_trace.span("device-sync"):
             out = np.asarray(res)
             transfer_counts.d2h_bytes += out.nbytes
-        return None, out
+        if mesh_st is None:
+            return None, out[:steps.size]
+        with obs_trace.span("aggregate", op="histogram_quantile",
+                            path="mesh-fused-hist"):
+            return None, mesh_st.quantile(out[:steps.size], q)
 
     @staticmethod
     def _fused_covered(entry, facts, steps: np.ndarray,

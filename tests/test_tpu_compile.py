@@ -387,3 +387,70 @@ def test_grouped_pair_on_four_chips_is_sharded_with_collective(topo):
     whole = cap * S * (4 + 8) + S * 4
     per_device = compiled.memory_analysis().argument_size_in_bytes
     assert 0.2 * whole < per_device < 0.3 * whole, (per_device, whole)
+
+
+# what a launch's trace records: one event a device op of the entry
+# computation, so ``_device_ops`` counts those and skips what runs no op
+_NO_OP = {"parameter", "constant", "get-tuple-element", "tuple", "bitcast",
+          "after-all", "partition-id", "replica-id"}
+_OP_ALWAYS = {"fusion", "custom-call", "copy", "copy-start", "copy-done",
+              "reduce", "reshape", "transpose", "gather", "slice",
+              "concatenate", "pad", "broadcast", "dynamic-slice"}
+
+
+def _device_ops(text: str) -> int:
+    """Device ops a launch of the compiled program runs (an op of one
+    element that is neither a fusion nor a copy nor a collective is folded
+    into the scalar unit's work and left out)."""
+    entry = re.search(r"\nENTRY [^\n]*\{\n(.*?)\n\}", text, re.S).group(1)
+    n = 0
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\(.*?\)|\S+) ([\w\-]+)\(",
+                     line)
+        if m is None or m.group(2) in _NO_OP:
+            continue
+        dims = re.findall(r"\w+\[([\d,]*)\]", m.group(1))
+        elems = max((int(np.prod([int(x) for x in d.split(",") if x]))
+                     for d in dims), default=1)
+        if m.group(2) in _OP_ALWAYS or m.group(2).startswith("all-") \
+                or elems > 1:
+            n += 1
+    return n
+
+
+@pytest.mark.parametrize("parts,ops_at_most", [(1, 45), (2, 53), (3, 61)])
+def test_hist_quantile_on_four_chips_is_sharded_with_collective(
+        topo, parts, ops_at_most):
+    """The mesh store's bucket sums over a 128-shard node's histogram
+    fleet: 6,144 series x 12 buckets (16 with the pad), 1,024 padded slots,
+    16 groups, 31 steps, each chip a quarter of the channels, each channel
+    in one f32 part (integer counts below 2**24), two or three; the
+    [T, G, B] partials go
+    through a collective, no f64 matmul comes back, and only [T, G, B] f64
+    leaves it. A traced run records every device op of every launch on
+    every chip, and the harness reads the trace in a bounded time: the
+    ops a launch are held where they are."""
+    from filodb_tpu.parallel.shardstore import _build_hist_quantile_eval
+    mesh = Mesh(np.asarray(topo.devices).reshape(4, 1), ("shard", "time"))
+    s_cell, b, bp, cap, t_cell = 6144, 12, 16, 1024, 31
+    sds = jax.ShapeDtypeStruct
+    part = sds((cap, bp, s_cell), jnp.float32,
+               sharding=NamedSharding(mesh, P(None, None, "shard")))
+    args = (sds((cap, s_cell), jnp.int32,
+                sharding=NamedSharding(mesh, P(None, "shard"))),
+            (part,) * parts, (part,) * parts,
+            sds((s_cell,), jnp.int32, sharding=NamedSharding(mesh,
+                                                             P("shard"))),
+            sds((8 * t_cell + 3,), jnp.int32,
+                sharding=NamedSharding(mesh, P("time"))))
+    compiled = _build_hist_quantile_eval(mesh, "rate", t_cell, G, b).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert any(c in text for c in ("all-reduce", "all-gather")), \
+        "no cross-chip collective in the histogram program"
+    assert "tpu_custom_call" not in text and " while(" not in text
+    assert re.search(r"ENTRY[^\n]*->\s*f64\[31,16,12\]", text)
+    assert _device_ops(text) <= ops_at_most
+    whole = cap * s_cell * (4 + 2 * parts * 4 * bp) + s_cell * 4
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    assert 0.2 * whole < per_device < 0.3 * whole, (per_device, whole)
