@@ -1965,6 +1965,9 @@ class FiloHttpServer:
             "Counter queries served by the aligned all-f64 evaluator",
         "filodb_device_to_host_bytes_total":
             "Bytes the device-sync stages brought to the host",
+        "filodb_device_to_host_arrays_total":
+            "Arrays the device-sync stages brought to the host, one a "
+            "transfer",
         "filodb_host_to_device_puts_total":
             "Device buffers that calls of cached executables made from "
             "host values (an argument on four devices counts four)",
@@ -2248,6 +2251,8 @@ class FiloHttpServer:
             emit("aligned_exact_evals_total", {}, evals.get("t", 0))
             emit("device_to_host_bytes_total", {},
                  transfer_counts.d2h_bytes)
+            emit("device_to_host_arrays_total", {},
+                 transfer_counts.d2h_arrays)
             emit("host_to_device_puts_total", {},
                  obs_devprof.put_counts.h2d_puts)
             # serving fast path: compiled-executable reuse (shape

@@ -710,9 +710,10 @@ def _h_grouped_pair(ndev: int):
     tsr = (ts - g["base"]).astype(np.int32)
     run = _build_grouped_pair_eval(_shard_mesh(ndev), "rate",
                                    g["nsteps"], 3)
-    sums, cnts = run(jnp.asarray(tsr), jnp.asarray(v),
-                     jnp.asarray(gids),
-                     np.array([g["num_slots"], g["base"], g["dt"]],
-                              np.int64),
-                     np.array([g["w0s"], g["w0e"], g["step"]], np.int64))
-    return np.asarray(sums), np.asarray(cnts)
+    out = np.asarray(run(jnp.asarray(tsr), jnp.asarray(v),
+                         jnp.asarray(gids),
+                         np.array([g["num_slots"], g["base"], g["dt"]],
+                                  np.int64),
+                         np.array([g["w0s"], g["w0e"], g["step"]],
+                                  np.int64)))
+    return out[0], out[1]

@@ -260,11 +260,13 @@ def _build_aligned_eval(mesh: Mesh, func: str, nsteps_local: int,
 def _build_grouped_pair_eval(mesh: Mesh, func: str, nsteps_local: int,
                              num_groups: int):
     """The fused-groupsum contract from resident tiles: per-device
-    windowed counter evaluation + masked sum by group, psum over the shard
-    axis -> (sums [T, G], counts [T, G]) f64 — sums meaningful where
-    counts > 0, exactly the Pallas group-sum kernel's return shape. The
-    program takes the resident channels and group ids, the placement's
-    constants and the request's grid (``_scalars``): one host array."""
+    windowed counter evaluation + masked sum by group, the partials
+    stacked and ONE psum over the shard axis -> f64 [2, T, G], the sums
+    at [0] (meaningful where the count > 0) and the counts at [1],
+    exactly the one-chip fused programs' return shape: one collective a
+    launch and one buffer to sync. The program takes the resident
+    channels and group ids, the placement's constants and the request's
+    grid (``_scalars``): one host array."""
     from filodb_tpu.query.tilestore import _eval_counter_fast
 
     s_axis, t_axis = mesh.axis_names[:2]
@@ -287,7 +289,7 @@ def _build_grouped_pair_eval(mesh: Mesh, func: str, nsteps_local: int,
         sums = jnp.sum(jnp.where(ok, local[:, None, :], 0.0),
                        axis=2, dtype=jnp.float64)
         cnts = jnp.sum(ok, axis=2, dtype=jnp.int32).astype(jnp.float64)
-        return (jax.lax.psum(sums, s_axis), jax.lax.psum(cnts, s_axis))
+        return jax.lax.psum(jnp.stack([sums, cnts]), s_axis)
 
     @jax.jit
     def run(tsr, vv, gids, consts, grid):
@@ -295,7 +297,7 @@ def _build_grouped_pair_eval(mesh: Mesh, func: str, nsteps_local: int,
             grouped_pair_body, mesh=mesh,
             in_specs=(P(None, s_axis), P(None, s_axis), P(s_axis), P(),
                       P()),
-            out_specs=(P(t_axis, None), P(t_axis, None)))
+            out_specs=P(None, t_axis, None))
         return inner(tsr, vv, gids, consts, grid)
     return run
 
@@ -715,9 +717,10 @@ class ShardedTiles:
                               window_ms: int, gids: np.ndarray,
                               num_groups: int, offset_ms: int = 0):
         """Enqueue the fused `sum by (g)` program off the resident store
-        -> device (sums [T_pad, G], counts [T_pad, G]); the caller syncs
-        and cuts to ``steps.size`` rows (``eval_grouped_pair`` does
-        both). The program, its key and its arguments' shapes and dtypes
+        -> ONE device array f64 [2, T_pad, G], the sums at [0] and the
+        counts at [1]; the caller syncs it once and cuts to
+        ``steps.size`` rows (``eval_grouped_pair`` does both). The
+        program, its key and its arguments' shapes and dtypes
         are the same whether ``gids`` was on the devices already
         (``_row_gids``) or is put now. With the ids on the devices the
         call hands over one host array, the request's grid: the channels
@@ -738,10 +741,10 @@ class ShardedTiles:
         """Fused `sum by (g)` contract off the resident store ->
         (sums [T, G], counts [T, G]) numpy, matching the Pallas
         group-sum kernel's return shape (TpuBackend.fused_groupsum)."""
-        sums, cnts = self.dispatch_grouped_pair(
-            func, steps, window_ms, gids, num_groups, offset_ms)
+        out = np.asarray(self.dispatch_grouped_pair(
+            func, steps, window_ms, gids, num_groups, offset_ms))
         T = steps.size
-        return np.asarray(sums)[:T], np.asarray(cnts)[:T]
+        return out[0, :T], out[1, :T]
 
     # -- the donated refresh ----------------------------------------------
 

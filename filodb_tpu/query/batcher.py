@@ -138,13 +138,16 @@ class DeviceExecutor:
 
 class _TransferCounts:
     """``filodb_device_to_host_bytes_total``: bytes the ``device-sync``
-    stages brought to the host (here and at the backend's two syncs of
-    its own). Plain adds, like the backend's counters."""
+    stages brought to the host (here and at the backend's syncs of its
+    own), and ``filodb_device_to_host_arrays_total``: the arrays they
+    materialised, one a transfer. Plain adds, like the backend's
+    counters."""
 
-    __slots__ = ("d2h_bytes",)
+    __slots__ = ("d2h_bytes", "d2h_arrays")
 
     def __init__(self):
         self.d2h_bytes = 0
+        self.d2h_arrays = 0
 
 
 transfer_counts = _TransferCounts()
@@ -175,6 +178,7 @@ class SplitResult:
                 self._host = np.asarray(self._stacked)
                 if self._host is not self._stacked:     # came off a device
                     transfer_counts.d2h_bytes += self._host.nbytes
+                    transfer_counts.d2h_arrays += 1
                 self._stacked = None
         if self._split is not None:
             return self._split(self._host, i)

@@ -119,7 +119,7 @@ def _groupsum_kernel(func: str, st: int, dspan: int, hi_mode: int,
                      lo_mode: int, exact_branch: bool, n_ttiles: int,
                      mlen: int, tt: int, nbuf: int,
                      params_ref, v_ref, base_ref, oh_ref,
-                     sum_ref, cnt_ref, v_scr, sems):
+                     out_ref, v_scr, sems):
     """Grid: (n_s,) sequential. params (SMEM, i32):
     [kl0, w0e_rel, window, step, T]."""
     si = pl.program_id(0)
@@ -171,8 +171,7 @@ def _groupsum_kernel(func: str, st: int, dspan: int, hi_mode: int,
 
     @pl.when(si == 0)
     def _():
-        sum_ref[:] = jnp.zeros_like(sum_ref)
-        cnt_ref[:] = jnp.zeros_like(cnt_ref)
+        out_ref[...] = jnp.zeros_like(out_ref)
         # pipeline warm-up: fill nbuf-1 scratch slots ahead (global
         # tiles 0..nbuf-2, crossing program boundaries for tiny grids)
         for g in range(nbuf - 1):
@@ -323,12 +322,12 @@ def _groupsum_kernel(func: str, st: int, dspan: int, hi_mode: int,
         # HIGHEST: the MXU's default bf16 input truncation would round
         # every rate to 8 mantissa bits (bf16(0.1) = 0.10009765625)
         prec = jax.lax.Precision.HIGHEST
-        sum_ref[sl, :] += jnp.dot(local, oh,
-                                  preferred_element_type=jnp.float32,
-                                  precision=prec)
-        cnt_ref[sl, :] += jnp.dot(okf, oh,
-                                  preferred_element_type=jnp.float32,
-                                  precision=prec)
+        out_ref[0, sl, :] += jnp.dot(local, oh,
+                                     preferred_element_type=jnp.float32,
+                                     precision=prec)
+        out_ref[1, sl, :] += jnp.dot(okf, oh,
+                                     preferred_element_type=jnp.float32,
+                                     precision=prec)
 
     jax.lax.fori_loop(0, n_ttiles, t_loop, None)
 
@@ -346,10 +345,9 @@ def _groupsum_example():
 
 
 def _groupsum_expect(out):
-    want = ((256, 16), jnp.float32)
-    for o in out:
-        if tuple(o.shape) != want[0] or o.dtype != want[1]:
-            return f"output {o.shape}/{o.dtype} != {want}"
+    want = ((2, 256, 16), jnp.float32)
+    if tuple(out.shape) != want[0] or out.dtype != want[1]:
+        return f"output {out.shape}/{out.dtype} != {want}"
     return None
 
 
@@ -359,8 +357,8 @@ def counter_groupsum(func: str, st: int, dspan: int, hi_mode: int,
                      interpret: bool = False,
                      exact_branch: Optional[bool] = None):
     """sum by(group) of rate/increase/delta over stride-permuted dense
-    tiles -> (sums f32 [T, G], counts f32 [T, G]; sum is only meaningful
-    where count > 0).
+    tiles -> f32 [2, T, G]: the sums at [0], the counts at [1] (a sum is
+    only meaningful where its count > 0), the kernel's one output.
 
     v_p: the packed kernel channel [n_s, st, G_perm, 3*_GS_SS] i32 —
     plane 0 = int32 relative timestamps, planes 1-2 = the per-series
@@ -429,10 +427,8 @@ def groupsum_exact_branch(window: int, st: int, dspan: int) -> bool:
         Block("sems", (2, 3), "int32", space=SEM),
     ),
     outputs=(
-        Block("sums", (256, 256), "float32",
-              array_shape=(256, 256), index_map=lambda si: (0, 0)),
-        Block("cnts", (256, 256), "float32",
-              array_shape=(256, 256), index_map=lambda si: (0, 0)),
+        Block("out", (2, 256, 256), "float32",
+              array_shape=(2, 256, 256), index_map=lambda si: (0, 0, 0)),
     ),
     vmem_budget=14 << 20,
     rel_time_bits=31,
@@ -476,12 +472,8 @@ def groupsum_call(func: str, st: int, dspan: int, hi_mode: int,
             pl.BlockSpec((_GS_SS, G), lambda si, p: (si, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=(
-            pl.BlockSpec((T_pad, G), lambda si, p: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((T_pad, G), lambda si, p: (0, 0),
-                         memory_space=pltpu.VMEM),
-        ),
+        out_specs=pl.BlockSpec((2, T_pad, G), lambda si, p: (0, 0, 0),
+                               memory_space=pltpu.VMEM),
         scratch_shapes=[
             pltpu.VMEM((nbuf, nstreams, mlen, 3 * _GS_SS), jnp.int32),
             pltpu.SemaphoreType.DMA((nbuf, nstreams)),
@@ -491,21 +483,18 @@ def groupsum_call(func: str, st: int, dspan: int, hi_mode: int,
     def body(params, v_p, base, onehot, *, _k=functools.partial(
             _groupsum_kernel, func, st, dspan, hi_mode, lo_mode,
             bool(exact_branch), n_ttiles, mlen, tt, nbuf)):
-        def kern(params_ref, v_ref, base_ref, oh_ref,
-                 sum_ref, cnt_ref, v_scr, sems):
-            _k(params_ref, v_ref, base_ref[0], oh_ref,
-               sum_ref, cnt_ref, v_scr, sems)
+        def kern(params_ref, v_ref, base_ref, oh_ref, out_ref, v_scr,
+                 sems):
+            _k(params_ref, v_ref, base_ref[0], oh_ref, out_ref, v_scr,
+               sems)
         return pl.pallas_call(
             kern,
             grid_spec=grid_spec,
-            out_shape=(
-                jax.ShapeDtypeStruct((T_pad, G), jnp.float32),
-                jax.ShapeDtypeStruct((T_pad, G), jnp.float32),
-            ),
+            out_shape=jax.ShapeDtypeStruct((2, T_pad, G), jnp.float32),
             interpret=interpret,
             name="counter_groupsum",
         )(params, v_p, base, onehot)
 
     with jax.enable_x64(False):
-        sums, cnts = body(params, v_p, base, onehot)
-    return sums[:nsteps], cnts[:nsteps]
+        out = body(params, v_p, base, onehot)
+    return out[:, :nsteps]

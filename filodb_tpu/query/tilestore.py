@@ -1531,9 +1531,10 @@ def _groupsum_program(func: str, st: int, dspan: int, hi_mode: int,
     """The fused group-sum as ONE traceable program (jitted once per
     static tuple by groupsum_counters): the [S_pad, G] f32 one-hot from
     the int32 group ids (an id that names no group, the padding's -1,
-    gives an all-zero row), the Pallas kernel and its [:nsteps] slices.
-    Every input is explicitly typed, so the program is the same under
-    x64 on and off."""
+    gives an all-zero row) and the Pallas kernel, whose one output holds
+    the sums and the counts, f32 [2, nsteps, G], so that the host pulls
+    one buffer. Every input is explicitly typed, so the program is the
+    same under x64 on and off."""
     return pk.groupsum_call(
         func, st, dspan, hi_mode, lo_mode, exact_branch, nsteps,
         v_p, base, _group_onehot(ids, G), params, interpret=interpret)
@@ -1551,7 +1552,8 @@ def _groupsum_holes_program(func: str, nsteps: int, G: int, arrs, consts,
     the tiles' int64[3] on the device (``t_consts``: num_slots, base_ms,
     dt_ms); ``grid`` the request's int64[3]: w0s, w0e, step; ``ids``
     int32 [S] in tile order, an id outside [0, G) names no group (on the
-    device where the backend's tile entry keeps them)."""
+    device where the backend's tile entry keeps them). Sums and counts
+    leave stacked, ONE f32 [2, nsteps, G]."""
     num_slots, base, dt = consts[0], consts[1], consts[2]
     w0s, w0e, step = grid[0], grid[1], grid[2]
     out = _eval_counter_fast(func, nsteps, arrs, num_slots, base, dt,
@@ -1560,8 +1562,8 @@ def _groupsum_holes_program(func: str, nsteps: int, G: int, arrs, consts,
     onehot = _group_onehot(ids, G)
     dot = _functools.partial(jnp.dot, preferred_element_type=jnp.float32,
                              precision=jax.lax.Precision.HIGHEST)
-    return (dot(jnp.where(ok, out, jnp.float32(0.0)), onehot),
-            dot(ok.astype(jnp.float32), onehot))
+    return jnp.stack([dot(jnp.where(ok, out, jnp.float32(0.0)), onehot),
+                      dot(ok.astype(jnp.float32), onehot)])
 
 
 @kernel_contract(
@@ -1582,9 +1584,11 @@ def groupsum_counters(tiles: AlignedTiles, func: str, steps: np.ndarray,
                       window_ms: int, gids, G: int, offset_ms: int = 0,
                       interpret: bool = False):
     """`sum by (g) (rate/increase/delta(sel[w]))` fused on device ->
-    (sums f32 [T, G], counts f32 [T, G]), or None when the preconditions
-    don't hold (caller falls back to evaluate_counters_t + host/XLA
-    grouping). One gate, two programs, chosen from ``tiles._dense``:
+    ONE device array f32 [2, T, G], the sums at [0] and the counts at
+    [1] (a sum is meaningful where its count > 0), so that the caller
+    syncs one buffer; or None when the preconditions don't hold (caller
+    falls back to evaluate_counters_t + host/XLA grouping). One gate,
+    two programs, chosen from ``tiles._dense``:
     the Pallas group-sum kernel over dense tiles, the grouped non-dense
     f32-hybrid evaluator (``_groupsum_holes``) over tiles with holes.
     Both are boundary samples -> f32 extrapolation -> masked group
@@ -1683,9 +1687,9 @@ def groupsum_counters(tiles: AlignedTiles, func: str, steps: np.ndarray,
           "the device")
 def _groupsum_holes(tiles: AlignedTiles, func: str, steps: np.ndarray,
                     window_ms: int, gids, G: int, offset_ms: int):
-    """``groupsum_counters`` over tiles that are not dense -> (sums f32
-    [T, G], counts f32 [T, G]) from ``_groupsum_holes_program`` over the
-    seven cached channels of ``_tiles_arrays_fast`` (what the aligned
+    """``groupsum_counters`` over tiles that are not dense -> sums and
+    counts stacked, f32 [2, T, G], from ``_groupsum_holes_program`` over
+    the seven cached channels of ``_tiles_arrays_fast`` (what the aligned
     path holds resident already), or None for the exact all-f64
     ``("t",)`` family: a grid wider than int32 ms."""
     nsteps = steps.size

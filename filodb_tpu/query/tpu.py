@@ -743,6 +743,7 @@ class TpuBackend:
             with obs_trace.span("device-sync"):
                 host = np.asarray(dev)
                 transfer_counts.d2h_bytes += host.nbytes
+                transfer_counts.d2h_arrays += 1
                 host = host[:m.ts.shape[0], :m.nsteps]
             return SplitResult(host, 1, split=lambda h, i: h)
         offs = np.cumsum([0] + [m.ts.shape[0] for m in members])
@@ -1154,9 +1155,11 @@ class TpuBackend:
         table, the Pallas group-sum kernel over dense tiles or the
         grouped non-dense evaluator over tiles with holes
         (``filodb_fused_holes_aggs_total`` counts those apart), or the
-        mesh store's grouped collective. Returns (sums, cnts) as [T, G]
-        numpy or None when ineligible (caller falls back to the general
-        rangefn + aggregate path).
+        mesh store's grouped collective. Each returns sums and counts
+        stacked in ONE device array, so a request makes one
+        device-to-host transfer. Returns (sums, cnts) as [T, G] numpy
+        (two views of that one buffer) or None when ineligible (caller
+        falls back to the general rangefn + aggregate path).
 
         Every None counts in ``filodb_fused_refused_total``. The
         reasons, in the order they are looked at: not a counter
@@ -1242,10 +1245,11 @@ class TpuBackend:
             self.fused_aggs += 1
             self.fused_holes_aggs += not tiles._dense
         with obs_trace.span("device-sync"):
+            host = np.asarray(res)      # [2, T, G]: sums, counts
+            transfer_counts.d2h_bytes += host.nbytes
+            transfer_counts.d2h_arrays += 1
             T = steps.size
-            sums, cnts = np.asarray(res[0]), np.asarray(res[1])
-            transfer_counts.d2h_bytes += sums.nbytes + cnts.nbytes
-            return sums[:T], cnts[:T]
+            return host[0, :T], host[1, :T]
 
     def fused_hist_quantile(self, series, func: str, steps: np.ndarray,
                             window_ms: int, offset_ms: int,
@@ -1333,6 +1337,7 @@ class TpuBackend:
         with obs_trace.span("device-sync"):
             out = np.asarray(res)
             transfer_counts.d2h_bytes += out.nbytes
+            transfer_counts.d2h_arrays += 1
         if mesh_st is None:
             return None, out[:steps.size]
         with obs_trace.span("aggregate", op="histogram_quantile",

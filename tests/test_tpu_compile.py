@@ -159,8 +159,9 @@ def test_packed_endpoint_rate_compiles(one_chip, func):
 def test_counter_groupsum_compiles(one_chip, monkeypatch, jittered,
                                    jitter_ms, nstreams):
     """What a fused query runs on the chip: the dispatcher's ONE jitted
-    program (one-hot from the group ids, the Pallas kernel, the slices),
-    built by the dispatcher's own ``build`` with its own statics."""
+    program (one-hot from the group ids, the Pallas kernel, the slice),
+    built by the dispatcher's own ``build`` with its own statics. Sums
+    and counts leave it as ONE f32 [2, T, G] output, the kernel's own."""
     tiles = jittered if jitter_ms else _tiles(0)
     seen = {}
 
@@ -189,6 +190,12 @@ def test_counter_groupsum_compiles(one_chip, monkeypatch, jittered,
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     # the five scalars arrive as one vector: no program assembles them
     assert "concatenate" not in text
+    # one output, so one transfer to the host: the inputs' two copies in
+    # (start and done each), the one-hot, the kernel, the [:T] slice and
+    # the result's layout copy, 8 ops a launch (10 when the kernel had two
+    # outputs, each sliced and copied)
+    assert re.search(r"ENTRY[^\n]*->\s*f32\[2,100,16\]\s*\{", text)
+    assert _device_ops(text) <= 8
 
 
 # -- (c) the per-series aligned evaluators, as tilestore jits them ------------
@@ -278,9 +285,10 @@ def test_groupsum_over_holes_compiles(one_chip, monkeypatch, func):
     compiled = seen["build"]().lower(
         *jax.tree_util.tree_map(sds, (arrs, consts, grid, ids))).compile()
     text = compiled.as_text()
-    # no Pallas kernel in this program, and what leaves it is [T, G] f32
+    # no Pallas kernel in this program, and what leaves it is ONE f32
+    # [2, T, G]: the sums and the counts stacked, one transfer
     assert "tpu_custom_call" not in text
-    assert re.search(r"ENTRY[^\n]*->\s*\(f32\[31,16\][^\n]*f32\[31,16\]", text)
+    assert re.search(r"ENTRY[^\n]*->\s*f32\[2,31,16\]\s*\{", text)
 
 
 @pytest.mark.parametrize("dense", [True, False])
@@ -384,6 +392,14 @@ def test_grouped_pair_on_four_chips_is_sharded_with_collective(topo):
     # an f64 dot is spelled as loops over bf16 pieces, four fifths of the
     # device ops a request leaves in a trace (PERF.md section 6, PR 37)
     assert " while(" not in text, "an emulated f64 dot is back"
+    # sums and counts stacked before ONE psum: one output, one collective
+    # (two all-gathers in this compiler's spelling of an f64 psum, four
+    # with a psum each), 55 device ops a launch here where the program
+    # with two outputs made 59 (60 and 54 at cell 4's 24,576 series and
+    # 31 steps)
+    assert re.search(r"ENTRY[^\n]*->\s*f64\[2,100,16\]\s*\{", text)
+    assert text.count(" all-gather(") + text.count(" all-reduce(") <= 2
+    assert _device_ops(text) <= 55
     whole = cap * S * (4 + 8) + S * 4
     per_device = compiled.memory_analysis().argument_size_in_bytes
     assert 0.2 * whole < per_device < 0.3 * whole, (per_device, whole)
