@@ -1971,6 +1971,9 @@ class FiloHttpServer:
         "filodb_host_to_device_puts_total":
             "Device buffers that calls of cached executables made from "
             "host values (an argument on four devices counts four)",
+        "filodb_packed_host_arrays_total":
+            "Host values that launches of the packed window kernels "
+            "handed the device (two a launch)",
         "filodb_select_series_total":
             "Series handles handed out by whole-series selections",
         "filodb_select_series_read_total":
@@ -2255,6 +2258,8 @@ class FiloHttpServer:
                  transfer_counts.d2h_arrays)
             emit("host_to_device_puts_total", {},
                  obs_devprof.put_counts.h2d_puts)
+            emit("packed_host_arrays_total", {},
+                 obs_devprof.put_counts.packed_arrays)
             # serving fast path: compiled-executable reuse (shape
             # buckets) + micro-batcher occupancy
             exec_stats = getattr(self.backend, "executable_cache_stats",

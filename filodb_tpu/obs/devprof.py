@@ -344,13 +344,17 @@ class _PutCounts:
     """``filodb_host_to_device_puts_total``: device buffers that calls of
     cached executables made from host values (a numpy array or scalar, a
     Python number), one per device the executable runs on, so that an
-    argument replicated over four devices counts four. Plain adds, like
+    argument replicated over four devices counts four; and
+    ``filodb_packed_host_arrays_total``: host values that the packed
+    path's launches (``TpuBackend._packed_launch``) handed the device,
+    counted as ``host_args`` counts them. Plain adds, like
     ``filodb_device_to_host_bytes_total``."""
 
-    __slots__ = ("h2d_puts",)
+    __slots__ = ("h2d_puts", "packed_arrays")
 
     def __init__(self):
         self.h2d_puts = 0
+        self.packed_arrays = 0
 
 
 put_counts = _PutCounts()

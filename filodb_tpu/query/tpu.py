@@ -143,14 +143,6 @@ def pack_series(series: Sequence[RawSeries], drop_nan: bool = True
     return ts_pad, vals_pad, lens
 
 
-def _abstract(a):
-    """Array -> ShapeDtypeStruct for lazy cost probes (0-d scalars and
-    plain Python values stay concrete — tiny, and statics must be)."""
-    if getattr(a, "ndim", 0) > 0:
-        return _sds(tuple(a.shape), a.dtype)
-    return a
-
-
 def _lower_probe(jfn, *largs):
     """() -> Compiled over an abstract call signature: the on-demand
     cost-analysis probe for kernels that compile inside their own
@@ -159,21 +151,6 @@ def _lower_probe(jfn, *largs):
     def probe():
         return jfn.lower(*largs).compile()
     return probe
-
-
-def _pad_series_rows(ts: np.ndarray, vals: np.ndarray, lens: np.ndarray,
-                     s_bucket: int):
-    """Pad the series axis to a pow2 bucket (executable reuse): pad rows
-    are all-sentinel/empty, produce all-NaN outputs, and are sliced off
-    by the caller."""
-    S, N = ts.shape
-    ts2 = np.full((s_bucket, N), _TS_PAD, dtype=np.int64)
-    vals2 = np.zeros((s_bucket, N), dtype=np.float64)
-    lens2 = np.zeros(s_bucket, dtype=np.int32)
-    ts2[:S] = ts
-    vals2[:S] = vals
-    lens2[:S] = lens
-    return ts2, vals2, lens2
 
 
 # ---------------------------------------------------------------------------
@@ -414,6 +391,70 @@ def _gather_reduce(func: str, w_bound: int, g, in_win, scalar):
 
 _GATHER_FUNCS = frozenset({"min_over_time", "max_over_time",
                            "quantile_over_time"})
+
+# the four per-row columns after the timestamps in a packed launch's
+# int64 block: sample count, first window start, first window end, step
+_ROW_COLS = 4
+# what a pad row of the int64 block holds there: no samples, a 1 ms grid
+_PAD_ROW_COLS = (0, 0, 1, 1)
+
+
+def _launch_blocks(members, scalar: float):
+    """The two host arrays of one packed launch, every member stacked
+    along the series axis and the series axis padded to a power of two
+    (executable reuse; pad rows hold no sample and are sliced off):
+    int64 ``[S, N + 4]`` (each row's timestamps, ``_TS_PAD`` past its
+    samples, then its ``lens``, ``w0s``, ``w0e`` and ``step``) and f64
+    ``[S, N + 1]`` (each row's values, then the batch's ``scalar``).
+    -> (int block, f64 block, each member's first row)."""
+    N = members[0].ts.shape[1]
+    offs = [0]
+    for m in members:
+        offs.append(offs[-1] + m.ts.shape[0])
+    s_bucket = _next_pow2(offs[-1], 8)
+    ib = np.empty((s_bucket, N + _ROW_COLS), dtype=np.int64)
+    fb = np.zeros((s_bucket, N + 1), dtype=np.float64)
+    for m, o, e in zip(members, offs, offs[1:]):
+        ib[o:e, :N] = m.ts
+        ib[o:e, N] = m.lens
+        ib[o:e, N + 1:] = (m.w0s, m.w0e, m.step)
+        fb[o:e, :N] = m.vals
+    ib[offs[-1]:, :N] = _TS_PAD
+    ib[offs[-1]:, N:] = _PAD_ROW_COLS
+    fb[:, N] = scalar
+    return ib, fb, offs
+
+
+def _block_example(func, w_bound, S=8, N=64, nsteps=16):
+    return (func, w_bound, nsteps,
+            _sds((S, N + _ROW_COLS), jnp.int64),
+            _sds((S, N + 1), jnp.float64)), {}
+
+
+@kernel_contract(
+    "packed_window", kind="jit",
+    example=lambda: _block_example("max_over_time", 8),
+    expect=_grid_expect(8, 16),
+    notes="the packed launch's one entry: int64 [S, N+4] and f64 "
+          "[S, N+1] blocks sliced on the device into the per-row "
+          "arguments of window_gather / window_endpoint; output [S, T] f64")
+@functools.partial(jax.jit, static_argnames=("func", "w_bound", "nsteps"))
+def _packed_window(func: str, w_bound: int, nsteps: int, ib, fb):
+    """A packed launch: the two host blocks of ``_launch_blocks`` in, the
+    ``[S, nsteps]`` grid out. The blocks are sliced here into the
+    per-row arguments of ``_window_gather`` (``func`` in
+    ``_GATHER_FUNCS``, ``w_bound`` its static window bound) or
+    ``_window_endpoint``; every op of those is row-local, so a row's
+    answer does not depend on what else shares the launch."""
+    N = ib.shape[1] - _ROW_COLS
+    ts, lens = ib[:, :N], ib[:, N].astype(jnp.int32)
+    w0s, w0e, step = ib[:, N + 1], ib[:, N + 2], ib[:, N + 3]
+    vals, scalar = fb[:, :N], fb[0, N]
+    if func in _GATHER_FUNCS:
+        return _window_gather(func, w_bound, ts, vals, lens, w0s, w0e,
+                              step, nsteps, scalar)
+    return _window_endpoint(func, ts, vals, lens, w0s, w0e, step, nsteps,
+                            scalar)
 
 # tests set this to exercise the fused group-sum kernel in interpret
 # mode on the CPU test mesh; production CPU nodes leave it off
@@ -688,55 +729,42 @@ class TpuBackend:
             return res.get(0)
 
     @hot_path
-    def _packed_single(self, func, ts, vals, lens, w0s, w0e, step,
-                       t_bucket, scalar, w_bound):
-        """Single-query packed dispatch with pow2 shape bucketing: S and
-        the step count pad to buckets so repeat queries of nearby shapes
-        reuse compiled executables instead of retracing. Enqueue only:
-        returns the device array [S-bucket, t_bucket]; the caller's
-        ``device-sync`` stage brings ``[:S, :nsteps]`` to the host."""
-        S, N = ts.shape
-        s_bucket = _next_pow2(S, 8)
-        if s_bucket != S:
-            ts, vals, lens = _pad_series_rows(ts, vals, lens, s_bucket)
-        if func in _GATHER_FUNCS:
-            self._count_exec(
-                ("gather", func, s_bucket, N, t_bucket, w_bound),
-                probe=_lower_probe(_window_gather, func, w_bound,
-                                   _abstract(ts), _abstract(vals),
-                                   _abstract(lens), w0s, w0e, step,
-                                   t_bucket, scalar))
-            out = _window_gather(func, w_bound, ts, vals, lens,
-                                 w0s, w0e, step, t_bucket, scalar)
-        else:
-            self._count_exec(
-                ("endpoint", func, s_bucket, N, t_bucket),
-                probe=_lower_probe(_window_endpoint, func,
-                                   _abstract(ts), _abstract(vals),
-                                   _abstract(lens), w0s, w0e, step,
-                                   t_bucket, scalar))
-            out = _window_endpoint(func, ts, vals, lens,
-                                   w0s, w0e, step, t_bucket, scalar)
-        return out
+    def _packed_launch(self, func: str, w_bound: int, t_bucket: int,
+                       ib: np.ndarray, fb: np.ndarray):
+        """Hand one packed launch to the device: the two blocks of
+        ``_launch_blocks``, nothing else (``filodb_packed_host_arrays_total``
+        counts them), one executable a (func, series bucket, N, step
+        bucket, window bound) whatever the batch's size. Enqueue only:
+        returns the device array ``[S-bucket, t_bucket]``."""
+        self._count_exec(
+            ("packed", func, ib.shape[0], ib.shape[1] - _ROW_COLS,
+             t_bucket, w_bound),
+            probe=_lower_probe(_packed_window, func, w_bound, t_bucket,
+                               _sds(ib.shape, ib.dtype),
+                               _sds(fb.shape, fb.dtype)))
+        devprof.put_counts.packed_arrays += devprof.host_args((ib, fb))
+        return _packed_window(func, w_bound, t_bucket, ib, fb)
 
     def _packed_run(self, func: str, t_bucket: int, scalar: float,
                     members) -> object:
         """Execute one packed batch: stack member tiles along the series
         axis, dispatch ONE kernel with per-row window vectors, split by
-        per-query segment offsets. A batch of one takes the single-query
-        path (bit-for-bit identical; the parity test pins it)."""
+        per-query segment offsets. A batch of one is the same launch with
+        one member."""
         with obs_trace.span("device-dispatch", path="packed",
                             batch=len(members)):
             return self._packed_run_inner(func, t_bucket, scalar, members)
 
     def _packed_run_inner(self, func: str, t_bucket: int, scalar: float,
                           members) -> object:
+        ib, fb, offs = _launch_blocks(members, scalar)
+        dev = self._packed_launch(func, max(m.w_bound for m in members),
+                                  t_bucket, ib, fb)
+
+        def split(host: np.ndarray, i: int) -> np.ndarray:
+            return host[offs[i]:offs[i + 1], :members[i].nsteps]
+
         if len(members) == 1:
-            m = members[0]
-            dev = self._packed_single(func, m.ts, m.vals, m.lens,
-                                      np.int64(m.w0s), np.int64(m.w0e),
-                                      np.int64(m.step), t_bucket, scalar,
-                                      m.w_bound)
             # a batch of one syncs HERE, on the thread that dispatched
             # it (the executor's busy time is the gather window of the
             # next batch); device-sync is then a child of device-dispatch
@@ -744,56 +772,7 @@ class TpuBackend:
                 host = np.asarray(dev)
                 transfer_counts.d2h_bytes += host.nbytes
                 transfer_counts.d2h_arrays += 1
-                host = host[:m.ts.shape[0], :m.nsteps]
-            return SplitResult(host, 1, split=lambda h, i: h)
-        offs = np.cumsum([0] + [m.ts.shape[0] for m in members])
-        s_total = int(offs[-1])
-        s_bucket = _next_pow2(s_total, 8)
-        N = members[0].ts.shape[1]
-        ts = np.full((s_bucket, N), _TS_PAD, dtype=np.int64)
-        vals = np.zeros((s_bucket, N), dtype=np.float64)
-        lens = np.zeros(s_bucket, dtype=np.int32)
-        w0s_v = np.zeros(s_bucket, dtype=np.int64)
-        w0e_v = np.ones(s_bucket, dtype=np.int64)
-        step_v = np.ones(s_bucket, dtype=np.int64)
-        for m, o in zip(members, offs):
-            sl = slice(int(o), int(o) + m.ts.shape[0])
-            ts[sl] = m.ts
-            vals[sl] = m.vals
-            lens[sl] = m.lens
-            w0s_v[sl] = m.w0s
-            w0e_v[sl] = m.w0e
-            step_v[sl] = m.step
-        if func in _GATHER_FUNCS:
-            w_bound = max(m.w_bound for m in members)
-            self._count_exec(
-                ("gather-b", func, s_bucket, N, t_bucket, w_bound),
-                probe=_lower_probe(_window_gather, func, w_bound,
-                                   _abstract(ts), _abstract(vals),
-                                   _abstract(lens), _abstract(w0s_v),
-                                   _abstract(w0e_v), _abstract(step_v),
-                                   t_bucket, scalar))
-            dev = _window_gather(func, w_bound, ts, vals, lens,
-                                 jnp.asarray(w0s_v), jnp.asarray(w0e_v),
-                                 jnp.asarray(step_v), t_bucket, scalar)
-        else:
-            self._count_exec(
-                ("endpoint-b", func, s_bucket, N, t_bucket),
-                probe=_lower_probe(_window_endpoint, func,
-                                   _abstract(ts), _abstract(vals),
-                                   _abstract(lens), _abstract(w0s_v),
-                                   _abstract(w0e_v), _abstract(step_v),
-                                   t_bucket, scalar))
-            dev = _window_endpoint(func, ts, vals, lens,
-                                   jnp.asarray(w0s_v), jnp.asarray(w0e_v),
-                                   jnp.asarray(step_v), t_bucket, scalar)
-        sizes = [m.ts.shape[0] for m in members]
-        nst = [m.nsteps for m in members]
-
-        def split(host: np.ndarray, i: int) -> np.ndarray:
-            o = int(offs[i])
-            return host[o:o + sizes[i], :nst[i]]
-
+            return SplitResult(split(host, 0), 1, split=lambda h, i: h)
         return SplitResult(dev, len(members), split=split)
 
     _TILE_CACHE_MAX = 16

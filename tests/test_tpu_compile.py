@@ -30,6 +30,7 @@ from filodb_tpu.query import pallas_kernels as pk
 from filodb_tpu.query import tilestore as tst
 from filodb_tpu.query import tpu
 from filodb_tpu.query.cumsum import cumsum_f64
+from filodb_tpu.query.model import RawSeries
 
 S, N, G = 8192, 720, 16            # chip_smoke.py's default store
 S_HOST = pk._GS_SS                 # series of the host-side stand-in tiles
@@ -366,6 +367,38 @@ def test_packed_gather_max_over_time_compiles(one_chip):
         "max_over_time", w_bound, sh((s, n), jnp.int64),
         sh((s, n), jnp.float64), sh((s,), jnp.int32), i64, i64, i64,
         t_bucket, 0.0).compile()
+
+
+@pytest.mark.parametrize("s_bucket", [8, 16])
+def test_packed_launch_takes_two_arrays(one_chip, s_bucket):
+    """The packed launch at ``tsbs-devops.host-dashboards``' shapes: 390
+    samples at 10 s padded to N 512, 13 steps to a bucket of 16, the
+    window bound of a 300 s window over 10 s samples, one or two members'
+    rows. ONE int64 and ONE f64 block go in, ONE f64 grid comes out. The
+    blocks are sliced on the device into the kernel's seven arguments:
+    61 device ops a launch where the separate-argument batch took 56 (and
+    a lone member, with scalar grids, 51); at most the seven slices more."""
+    n, t_bucket = 512, 16
+    ts = np.arange(390, dtype=np.int64) * 10_000
+    w_bound = tpu.TpuBackend._window_sample_bound(
+        [RawSeries({}, ts, np.zeros(390))], W, n)
+    assert w_bound == 32
+    sh = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    text = tpu._packed_window.lower(
+        "max_over_time", w_bound, t_bucket,
+        sh((s_bucket, n + 4), jnp.int64),
+        sh((s_bucket, n + 1), jnp.float64)).compile().as_text()
+    entry = re.search(r"\nENTRY ([^\n]*)", text).group(1)
+    assert re.search(rf"\(ib[\w.]*: s64\[{s_bucket},516\], "
+                     rf"fb[\w.]*: f64\[{s_bucket},513\]\) -> "
+                     rf"f64\[{s_bucket},16\]", entry), entry
+    vec = sh((s_bucket,), jnp.int64)
+    separate = tpu._window_gather.lower(
+        "max_over_time", w_bound, sh((s_bucket, n), jnp.int64),
+        sh((s_bucket, n), jnp.float64), sh((s_bucket,), jnp.int32),
+        vec, vec, vec, t_bucket, sh((), jnp.float64)).compile().as_text()
+    assert _device_ops(text) <= _device_ops(separate) + 7
+    assert _device_ops(text) <= 61
 
 
 # -- (d) the path across chips: sharded, with a collective --------------------
