@@ -60,9 +60,10 @@ TAIL = 30                          # scrapes sent as live ingest, after the flus
 DATASET = "timeseries"
 NUM_SHARDS, GROUPS = 4, 2
 FLUSH_S = 2.0
-# tolerances the parity tests already use: the fused kernel and the
-# aligned counter evaluators end in an f32 epilogue (test_groupsum_kernel
-# rtol 1e-5); everything else is f64 to a few ulps
+# tolerances the parity tests already use: the fused group-sum and the
+# aligned counter evaluators end in an f32 epilogue (test_missed_scrapes
+# rtol 1e-6, test_groupsum_dispatch 2e-6); everything else is f64 to a
+# few ulps
 RTOL_F32, RTOL_F64 = 1e-5, 1e-12
 
 _failed = []
@@ -221,7 +222,7 @@ def served_by(delta):
     if delta.get("filodb_mesh_dispatches_total"):
         paths.append("mesh-resident sharded store")
     elif delta.get("filodb_fused_aggs_total"):
-        paths.append("fused group-sum kernel")
+        paths.append("fused group-sum")
     if delta.get("filodb_device_execute_seconds_count"):
         tiles = (delta.get("filodb_tile_builds_total")
                  or delta.get("filodb_tile_cache_hits_total"))

@@ -1934,8 +1934,7 @@ class FiloHttpServer:
         "filodb_fused_aggs_total":
             "Queries served by a fused group-sum program",
         "filodb_fused_holes_aggs_total":
-            "Fused queries served by the grouped non-dense program "
-            "(tiles with holes)",
+            "Fused queries served over tiles with holes",
         "filodb_fused_hist_aggs_total":
             "histogram_quantile queries of a histogram sum served by the "
             "fused quantile program",
@@ -2220,7 +2219,7 @@ class FiloHttpServer:
                  getattr(self.backend, "tile_builds", 0))
             emit("tile_cache_hits_total", {},
                  getattr(self.backend, "tile_hits", 0))
-            # which path served: fused group-sum kernel dispatches and
+            # which path served: fused group-sum dispatches and
             # sharded (mesh-resident) dispatches
             emit("fused_aggs_total", {},
                  getattr(self.backend, "fused_aggs", 0))

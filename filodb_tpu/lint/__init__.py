@@ -1,10 +1,11 @@
 """graftlint: static analysis for the invariants this repo's hot path
 lives by.
 
-The Pallas/JAX hot loop is hand-budgeted — VMEM footprints, (8, 128)
-trailing-dim tiling, the int31 relative-timestamp span guard, exact
-f64->3xf32 splits — and the threaded layers (memstore, ingest streams,
-gRPC service, resilience) grow locks organically. Those invariants
+The JAX hot loop is hand-budgeted — the int31 relative-timestamp span
+guard, exact f64->3xf32 splits, and for any Pallas kernel its VMEM
+footprint and (8, 128) trailing-dim tiling — and the threaded layers
+(memstore, ingest streams, gRPC service, resilience) grow locks
+organically. Those invariants
 historically lived in docstrings and in the builder's head; graftlint
 makes them *checked*, on every PR, on CPU-only CI, before anything
 touches a TPU.

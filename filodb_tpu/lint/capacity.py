@@ -30,8 +30,8 @@ The claim model is affine in the store's logical contents:
 
 ``bytes_per_sample`` must price the PADDED layout (pow2 slot capacity,
 shard-aligned series padding) — the certifier measures real buffers,
-and padding is real HBM. The certified per-family budgets feed the
-``CAPACITY.json`` ledger emitted by ``bench.py`` (projected resident
+and padding is real HBM. The certified per-family budgets feed
+:func:`filodb_tpu.lint.memcert.capacity_ledger` (projected resident
 series per 16 GB chip), the baseline the compressed-chunks work must
 move.
 

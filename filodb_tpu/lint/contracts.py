@@ -1,10 +1,10 @@
 """Kernel contract declarations.
 
 A :func:`kernel_contract` decorator sits on every device-kernel entry
-point in the package — the two real ``pallas_call`` wrappers, the jitted
-XLA kernels, the shard_map collectives, and the host-side dispatchers
-that gate them — and states, in one checkable place, what the docstrings
-used to promise:
+point in the package — any ``pallas_call`` wrapper, the jitted XLA
+kernels, the shard_map collectives, and the host-side dispatchers that
+gate them — and states, in one checkable place, what the docstrings used
+to promise:
 
   * block shapes, dtypes, and memory spaces (Pallas kinds), plus the
     worst-case configuration the dispatcher will admit;

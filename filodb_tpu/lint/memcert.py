@@ -25,10 +25,12 @@ surface as error-severity ``capacity-certification`` findings in the
 tier-1 gate. Results are memoized per process (claims are fixed at
 import time) so repeated ``run_lint`` calls pay the build cost once.
 
-:func:`capacity_ledger` renders the certified inventory for
-``CAPACITY.json`` (emitted by ``bench.py``): per family, the certified
-bytes budget and the projected resident series per 16 GB chip — the
-baseline number the compressed-chunks work must move.
+:func:`capacity_ledger` is the certified inventory: per family, the
+certified bytes budget and the projected resident series per 16 GB chip
+— the baseline number the compressed-chunks work must move. The
+``CAPACITY.json`` at the repository root is a copy of it that a
+kernel-timing script, since removed, last wrote at commit ``1e6f2ee``;
+nothing writes or reads that file now.
 """
 
 from __future__ import annotations

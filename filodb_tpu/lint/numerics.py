@@ -184,7 +184,6 @@ def order_claim(name: str) -> OrderClaim:
 # anything device-side
 ANNOTATED_MODULES: Tuple[str, ...] = (
     "filodb_tpu.query.tilestore",
-    "filodb_tpu.query.pallas_kernels",
     "filodb_tpu.query.tpu",
     "filodb_tpu.parallel.mesh",
     "filodb_tpu.parallel.shardstore",

@@ -31,18 +31,11 @@ dicts — and holds every escape to the ``@capacity`` bytes budgets of
     pow2-capacity-padded (``_next_pow2``/``_pad_pow2`` in the shape)
     when the unpadded slice would do; ``@capacity`` on the site
     declares the padding priced and exempts it.
-  * ``vmem-frontier-budget`` — unify the ``_gs_pipeline``
-    tile/DMA-buffer frontier arithmetic with the kernel contracts:
-    a ``vmem_budget`` parameter must stay under the physical
-    per-core VMEM (:data:`filodb_tpu.lint.contracts.VMEM_BYTES`), the
-    chooser must actually TEST against its declared budget, and —
-    when the kernel module is in the lint set — an independent
-    re-derivation of the footprint sweeps the chooser's whole
-    (step-tile, pipeline-depth) grid: every configuration the chooser
-    returns must fit both the declared budget and the kernel
-    contract's, and the chooser must not reject a workload whose
-    minimal configuration fits (a premature host fallback is a silent
-    10x).
+  * ``vmem-frontier-budget`` — a kernel's tile/DMA-buffer frontier
+    chooser against the kernel contracts: a ``vmem_budget`` parameter
+    must stay under the physical per-core VMEM
+    (:data:`filodb_tpu.lint.contracts.VMEM_BYTES`), and a chooser that
+    walks a frontier must actually TEST against its declared budget.
 """
 
 from __future__ import annotations
@@ -72,9 +65,8 @@ register_rule("oversized-transfer", "capacity",
               "suffices")
 register_rule("vmem-frontier-budget", "capacity",
               "kernel frontier arithmetic disagrees with the declared "
-              "VMEM budget: budget above physical VMEM, a chooser "
-              "that never tests its budget, or a frontier point whose "
-              "re-derived footprint does not fit")
+              "VMEM budget: budget above physical VMEM, or a chooser "
+              "that never tests its budget")
 
 # host-side constructors whose result is a device buffer under JAX
 # (jnp.* array factories; jax.device_put). np.* allocations are host
@@ -246,64 +238,10 @@ class _Escapes:
                     self.tainted_containers.add(t.value.id)
 
 
-# -- vmem frontier re-derivation ---------------------------------------------
+# -- vmem frontier budget ----------------------------------------------------
 
 
-def _ref_frontier_footprint(pk, st: int, dspan: int, hi: int, lo: int,
-                            nsteps: int, G: int, tt: int,
-                            nbuf: int) -> int:
-    """Independent re-derivation of the groupsum on-chip footprint for
-    one frontier point — the contract side of the chooser arithmetic
-    (constants read off the kernel module so a retune moves both)."""
-    lead = 1 if st == 1 else 0
-    mlen = tt + pk._GS_AL + (-(-(dspan + lead) // pk._GS_AL)) * pk._GS_AL
-    nstreams = 1 + (1 if hi != pk.GS_CUR and st != 1 else 0) \
-        + (1 if lo != pk.GS_CUR and st != 1 else 0)
-    t_pad = -(-nsteps // tt) * tt
-    accum = 2 * t_pad * G * 4
-    fixed = pk._GS_SS * G * 4 + 8 * pk._GS_SS * 4
-    scratch = nbuf * nstreams * mlen * 3 * pk._GS_SS * 4
-    return accum + scratch + fixed
-
-
-def _sweep_frontier(pk, budget: int) -> List[Tuple[str, Tuple]]:
-    """Sweep the chooser's whole admissible grid; return violations as
-    (kind, point) — 'overflow' when a returned configuration's
-    re-derived footprint exceeds ``budget``, 'premature-fallback' when
-    the chooser returns None although the minimal configuration
-    (narrow tile, double buffer) fits."""
-    bad: List[Tuple[str, Tuple]] = []
-    modes = (pk.GS_BOTH, pk.GS_CUR, pk.GS_ALT)
-    for st in (1, 2, 3, 6):
-        for dspan in (0, 1, 6, 12, 24, pk._GS_DSPAN_MAX):
-            for hi in modes:
-                for lo in modes:
-                    for nsteps in (64, 512, 2880, 8192):
-                        for G in (16, 512):
-                            pt = (st, dspan, hi, lo, nsteps, G)
-                            got = pk._gs_pipeline(st, dspan, hi, lo,
-                                                  nsteps, G,
-                                                  vmem_budget=budget)
-                            if got is not None:
-                                tt, nbuf = got
-                                fp = _ref_frontier_footprint(
-                                    pk, st, dspan, hi, lo, nsteps, G,
-                                    tt, nbuf)
-                                if fp > budget:
-                                    bad.append(("overflow",
-                                                pt + (tt, nbuf, fp)))
-                            else:
-                                fp = _ref_frontier_footprint(
-                                    pk, st, dspan, hi, lo, nsteps, G,
-                                    pk._GS_TT, 2)
-                                if fp <= budget:
-                                    bad.append(("premature-fallback",
-                                                pt + (fp,)))
-    return bad
-
-
-def _check_vmem_frontier(mods: Sequence[ModuleSource],
-                         cg: cgmod.CallGraph
+def _check_vmem_frontier(cg: cgmod.CallGraph
                          ) -> List[Tuple[Optional[str], Finding]]:
     out: List[Tuple[Optional[str], Finding]] = []
     for key, fi in sorted(cg.funcs.items()):
@@ -351,41 +289,6 @@ def _check_vmem_frontier(mods: Sequence[ModuleSource],
                          f"never compares a footprint against it — "
                          f"the frontier walk is unbudgeted"),
                 context=f"{fi.qualname}:budget-unused")))
-    # (3) symbolic sweep of the in-tree groupsum frontier against the
-    # kernel contract, when the kernel module is being linted
-    krel = "filodb_tpu/query/pallas_kernels.py"
-    if any(m.relpath == krel for m in mods):
-        import importlib
-        pk = importlib.import_module("filodb_tpu.query.pallas_kernels")
-        contract = contracts_mod.CONTRACTS.get(
-            ("filodb_tpu.query.pallas_kernels", "counter_groupsum"))
-        budget = min(
-            contract.vmem_budget if contract and contract.vmem_budget
-            else contracts_mod.VMEM_BYTES, contracts_mod.VMEM_BYTES)
-        line = 1
-        for m in mods:
-            if m.relpath == krel:
-                for i, ln in enumerate(m.lines, start=1):
-                    if "def _gs_pipeline" in ln:
-                        line = i
-                        break
-        for kind, pt in _sweep_frontier(pk, budget)[:8]:
-            if kind == "overflow":
-                st, dspan, hi, lo, nsteps, G, tt, nbuf, fp = pt
-                msg = (f"_gs_pipeline admits (tt={tt}, nbuf={nbuf}) at "
-                       f"(st={st}, dspan={dspan}, hi={hi}, lo={lo}, "
-                       f"nsteps={nsteps}, G={G}) but the re-derived "
-                       f"footprint {fp} exceeds the contract budget "
-                       f"{budget}")
-            else:
-                st, dspan, hi, lo, nsteps, G, fp = pt
-                msg = (f"_gs_pipeline falls back to host at (st={st}, "
-                       f"dspan={dspan}, hi={hi}, lo={lo}, "
-                       f"nsteps={nsteps}, G={G}) although the minimal "
-                       f"configuration fits ({fp} <= {budget})")
-            out.append((krel, Finding(
-                rule="vmem-frontier-budget", path=krel, line=line,
-                message=msg, context=f"gs-frontier:{kind}:{pt[:6]}")))
     return out
 
 
@@ -700,5 +603,5 @@ def check_project(mods: Sequence[ModuleSource],
     # (3) oversized-transfer
     out.extend(_check_transfers(cg, mods, ann))
     # (4) vmem-frontier-budget
-    out.extend(_check_vmem_frontier(mods, cg))
+    out.extend(_check_vmem_frontier(cg))
     return out

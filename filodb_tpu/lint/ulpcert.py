@@ -435,77 +435,6 @@ def _h_epilogue():
     return prod, ref, 0.0
 
 
-@precision_harness("fixed-point-split")
-def _h_fixed_split():
-    """The 61-bit hi/lo split + the kernel's f32 recombine
-    (dh*2^(31-s) + dl*2^-s) vs the direct f64 boundary delta, with the
-    declared span*2^-59 quantization floor."""
-    import numpy as np
-
-    from filodb_tpu.query.tilestore import AlignedTiles
-    rng = np.random.default_rng(_SEED + 2)
-    N, S = 64, 8
-    dt = 10_000
-    base = 0
-    ts = (np.arange(N, dtype=np.int64)[:, None] * dt
-          + np.zeros((1, S), np.int64)).T * 1.0      # [S, N] exact grid
-    # mixed magnitudes: huge counters, small gauges, negatives
-    scales = np.array([1e12, 1e6, 1.0, 1e-3, 5e8, 42.0, 1e10, 7.0])
-    vals = (scales[:, None]
-            * (1.0 + np.cumsum(rng.uniform(0, 1e-4, (S, N)), axis=1)))
-    vals[2] = rng.uniform(-50, 50, N)                # sign-mixed gauge
-    valid = np.ones((S, N), dtype=bool)
-    tiles = AlignedTiles([{"i": str(i)} for i in range(S)], base, dt,
-                         valid, ts, vals)
-    fx = tiles._fixed_channels("v")
-    assert fx is not None
-    hi, lo, _mid, s = (np.asarray(x) for x in fx)    # [N, S], [S]
-    c1 = np.ldexp(np.float32(1.0), 31 - s).astype(np.float32)
-    c2 = np.ldexp(np.float32(1.0), -s).astype(np.float32)
-    i, j = 10, 50                                    # boundary pair
-    dh = (hi[j] - hi[i]).astype(np.float32)
-    dl = (lo[j] - lo[i]).astype(np.float32)
-    prod = dh * c1 + dl * c2                         # [S] f32
-    ref = (vals[:, j] - vals[:, i])                  # [S] f64
-    span = vals.max(axis=1) - vals.min(axis=1)
-    floor = span * 2.0 ** -59
-    return prod, ref, floor
-
-
-@precision_harness("groupsum-recombine-f32")
-def _h_groupsum_recombine():
-    """The group-sum kernel's recombine (pallas_kernels._groupsum_kernel
-    lines around `delta = dh * c1 + dl * c2`): exact int32 hi/lo deltas
-    over FULL-SPAN boundary pairs (dl wide enough to round in f32),
-    recombined in f32, vs the direct f64 delta."""
-    import numpy as np
-
-    from filodb_tpu.query.tilestore import AlignedTiles
-    rng = np.random.default_rng(_SEED + 6)
-    N, S = 64, 8
-    dt = 10_000
-    ts = (np.arange(N, dtype=np.int64)[None, :] * dt
-          + np.zeros((S, 1), np.int64)) * 1.0
-    scales = np.array([1e12, 1e6, 1.0, 1e-3, 5e8, 42.0, 1e10, 7.0])
-    vals = (scales[:, None]
-            * (1.0 + np.cumsum(rng.uniform(0, 0.2, (S, N)), axis=1)))
-    valid = np.ones((S, N), dtype=bool)
-    tiles = AlignedTiles([{"i": str(i)} for i in range(S)], 0, dt,
-                         valid, ts, vals)
-    fx = tiles._fixed_channels("v")
-    assert fx is not None
-    hi, lo, _mid, s = (np.asarray(x) for x in fx)
-    c1 = np.ldexp(np.float32(1.0), 31 - s).astype(np.float32)
-    c2 = np.ldexp(np.float32(1.0), -s).astype(np.float32)
-    i, j = 0, N - 1                 # widest boundary pair in the tile
-    dh = (hi[j] - hi[i]).astype(np.float32)
-    dl = (lo[j] - lo[i]).astype(np.float32)
-    prod = dh * c1 + dl * c2
-    ref = vals[:, j] - vals[:, i]
-    span = vals.max(axis=1) - vals.min(axis=1)
-    return prod, ref, span * 2.0 ** -59
-
-
 @precision_harness("extrapolated-rate-f64")
 def _h_extrapolated_rate():
     """tilestore._extrapolated_rate (the shared f64 formula) vs the

@@ -739,8 +739,8 @@ class ShardedTiles:
                           num_groups: int, offset_ms: int = 0
                           ) -> Tuple[np.ndarray, np.ndarray]:
         """Fused `sum by (g)` contract off the resident store ->
-        (sums [T, G], counts [T, G]) numpy, matching the Pallas
-        group-sum kernel's return shape (TpuBackend.fused_groupsum)."""
+        (sums [T, G], counts [T, G]) numpy, matching the one-chip
+        group-sum's return shape (TpuBackend.fused_groupsum)."""
         out = np.asarray(self.dispatch_grouped_pair(
             func, steps, window_ms, gids, num_groups, offset_ms))
         T = steps.size

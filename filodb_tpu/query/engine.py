@@ -1461,8 +1461,8 @@ class QueryEngine:
     def _try_fused_agg(self, plan) -> Optional[GridResult]:
         """`sum/avg/count by (g) (rate/increase/delta(sel[w]))` fused
         end-to-end on device: grouping happens inside one device
-        program (the Pallas group-sum kernel over dense tiles, the
-        grouped non-dense evaluator over tiles with holes) and the
+        program (the grouped f32-hybrid evaluator over dense tiles and
+        tiles with holes alike, or the mesh store's) and the
         [S, T] per-series intermediate never leaves the chip
         (exec/AggrOverRangeVectors map-reduce, fused).
 

@@ -43,7 +43,6 @@ from filodb_tpu.lint.capacity import capacity
 from filodb_tpu.lint.contracts import kernel_contract
 from filodb_tpu.lint.numerics import order_insensitive, precision  # noqa: F401
 from filodb_tpu.memory import histogram as bh
-from filodb_tpu.query import pallas_kernels as pk
 from filodb_tpu.query.cumsum import cumsum_f64
 from filodb_tpu.query.model import RawSeries
 
@@ -113,7 +112,7 @@ class AlignedTiles:
         self._tbf: Dict[str, jnp.ndarray] = {}
         self._tps: Dict[str, jnp.ndarray] = {}
         self._tperm: Dict[Tuple[str, int], jnp.ndarray] = {}
-        self._jitter = None
+        self._f32_safe: Dict[str, bool] = {}
         self._jl = None
         self._jf = None
         self._dense = bool(np.asarray(valid).all())
@@ -356,154 +355,32 @@ class AlignedTiles:
             self._tperm[key] = c
         return c
 
-    def t_perm_tiled(self, name: str, st: int, src: jnp.ndarray
-                     ) -> jnp.ndarray:
-        """Stride-permuted AND s-tile-major channel for the Pallas
-        group-sum kernel: [n_s, st, G, SS] with SS = kernel lane tile.
-        Within one (s-tile, residue) plane, consecutive G rows are
-        CONTIGUOUS in HBM, so each kernel DMA is one large linear read
-        (the plain [st, G, S] layout would make per-s-tile blocks
-        strided 4KB chunks). S is padded to a multiple of SS; G is
-        padded past the kernel's tail tile like t_perm."""
-        key = (name + "#tiled", st)
-        c = self._tperm.get(key)
-        if c is None:
-            N = src.shape[0]
-            S = src.shape[1]
-            # pad the permuted G axis past every tail tile: the kernel's
-            # merged kc/kl stream reads up to dspan (<= _GS_DSPAN_MAX)
-            # + alignment rows past the last window-end row — sized for
-            # the WIDEST step tile the pipeline chooser can pick
-            G = (-(-N // st) + pk._GS_TT_WIDE + 2 * pk._GS_AL
-                 + pk._GS_DSPAN_MAX)
-            padn = G * st - N
-            if padn:
-                src = jnp.concatenate(
-                    [src, jnp.zeros((padn, S), src.dtype)], axis=0)
-            S_pad = -(-S // pk._GS_SS) * pk._GS_SS
-            if S_pad != S:
-                src = jnp.concatenate(
-                    [src, jnp.zeros((G * st, S_pad - S), src.dtype)],
-                    axis=1)
-            c = jnp.asarray(
-                src.reshape(G, st, S_pad // pk._GS_SS, pk._GS_SS)
-                .transpose(2, 1, 0, 3))
-            self._tperm[key] = c
-        return c
+    def f32_safe(self, vch: str) -> bool:
+        """Whether the fused counter program can carry value channel
+        ``vch`` (``"cv"`` or ``"v"``): every value finite, and every
+        series' span (its largest less its smallest value, which bounds
+        each boundary delta) small enough that a rate, and a sum of the
+        tiles' rates, stays inside f32. ``_f32_epilogue``'s factor is at
+        most 3.2 (each edge extrapolates by under 1.1 average intervals,
+        an average at most the sampled span) and a rate divides it by a
+        window of at least 1 ms, so a rate is at most 3,200 x span and a
+        group sum S times that. A channel that fails takes the exact f64
+        host path. Cached per channel."""
+        ok = self._f32_safe.get(vch)
+        if ok is None:
+            c = self.channel(vch)                 # 0 at invalid slots
+            hi = jnp.max(jnp.where(self.valid, c, -jnp.inf), axis=1)
+            lo = jnp.min(jnp.where(self.valid, c, jnp.inf), axis=1)
+            span = jnp.max(jnp.where(hi >= lo, hi - lo, 0.0))
+            ok = bool(jnp.isfinite(c).all()
+                      & (span * len(self.keys) <= _F32_SPAN_SUM_MAX))
+            self._f32_safe[vch] = ok
+        return ok
 
-    @precision(
-        "fixed-point-split", bits=61, rel_ulps=4,
-        reason="exact int32 hi/lo split: |v - mid| * 2**s <= 2**60, so "
-               "boundary subtractions in the group-sum kernel are "
-               "exact integer ops; only the final f32 recombine "
-               "rounds, relative to the delta, with a fixed-point "
-               "quantization floor of span * 2**-59 — certified "
-               "against the direct f64 delta")
-    def _fixed_channels(self, vch: str):
-        """Per-series 61-bit fixed-point encoding of a value channel for
-        the group-sum kernel: each series is rebased to its in-tile
-        midpoint and scaled by a per-series power of two 2^s chosen so
-        |v - mid| * 2^s <= 2^60, then split as hi*2^31 + lo with lo in
-        [0, 2^31). Integer boundary subtractions in the kernel are then
-        EXACT; only the final f32 recombine rounds, relative to the
-        delta — the same noise floor as the reference's f64 arithmetic
-        (rangefn/RateFunctions.scala:23).
 
-        Returns (hi [N,S] i32, lo [N,S] i32, mid_f32 [S], s [S] i32) or
-        None when the channel has non-finite values."""
-        key = (vch, "#fixed")
-        c = self._tperm.get(key)
-        if c is None:
-            v = self.t_channel(vch)                      # [N, S] f64
-            vmax = jnp.max(v, axis=0)
-            vmin = jnp.min(v, axis=0)
-            if not bool(jnp.isfinite(vmax).all()
-                        & jnp.isfinite(vmin).all()):
-                self._tperm[key] = (None,)
-                return None
-            mid = (vmax + vmin) * 0.5
-            # host-side scale selection ([S]-sized; f64 frexp has no TPU
-            # lowering): span2 <= 2^e with frexp's m in [0.5, 1)
-            span2 = np.maximum(np.asarray(vmax - vmin) * 0.5, 2.0 ** -130)
-            _, e = np.frexp(span2)
-            if np.any(60 - e < -96):
-                # a span this wide (> 2^156) cannot be represented in
-                # the 61-bit fixed-point channel at any in-range scale:
-                # clipping the exponent would silently WRAP int64 and
-                # corrupt results — take the exact f64 fallback instead
-                self._tperm[key] = (None,)
-                return None
-            s_np = np.clip(60 - e, -96, 126).astype(np.int32)
-            s = jnp.asarray(s_np)
-            scale = jnp.asarray(np.ldexp(1.0, s_np))
-            fixed = jnp.rint(
-                (v - mid[None, :]) * scale[None, :]
-            ).astype(jnp.int64)
-            hi64 = fixed >> 31
-            lo = (fixed - (hi64 << 31)).astype(jnp.int32)
-            c = (hi64.astype(jnp.int32), lo,
-                 mid.astype(jnp.float32), s)
-            self._tperm[key] = c
-        return None if c == (None,) else c
-
-    def t_perm_fixed_tiled(self, vch: str, st: int) -> jnp.ndarray:
-        """The Pallas group-sum kernel's packed channel: s-tile-major
-        stride-permuted [n_s, st, G, 3*SS] i32 where plane 0 is the
-        int32 relative timestamp and planes 1-2 are the per-series
-        fixed-point hi/lo split of the value channel (_fixed_channels).
-        One kernel DMA per boundary stream fetches timestamps + values
-        as a single contiguous read (see t_perm_tiled)."""
-        key = (vch + "#fixed_tiled", st)
-        c = self._tperm.get(key)
-        if c is None:
-            fx = self._fixed_channels(vch)
-            assert fx is not None, "dispatcher must gate on finiteness"
-            hi, lo = fx[0], fx[1]
-            parts = [self.t_perm_tiled(f"{vch}#fx{i}", st, ch)
-                     for i, ch in enumerate(
-                         (self.t_tsr_i32(), hi, lo))]
-            c = jnp.asarray(jnp.concatenate(parts, axis=3))
-            for i in range(3):
-                self._tperm.pop((f"{vch}#fx{i}" + "#tiled", st), None)
-            self._tperm[key] = c
-        return c
-
-    def t_fixed_base(self, vch: str) -> jnp.ndarray:
-        """[n_s, 8, SS] f32 companion of t_perm_fixed_tiled: row 0 =
-        per-series rebase midpoint (f32, used only by the counter-zero
-        extrapolation limiter), row 1 = 2^(31-s), row 2 = 2^-s."""
-        key = (vch + "#fixed_base", 0)
-        c = self._tperm.get(key)
-        if c is None:
-            fx = self._fixed_channels(vch)
-            assert fx is not None
-            mid, s = fx[2], fx[3]
-            c1 = jnp.ldexp(jnp.float32(1.0), 31 - s)
-            c2 = jnp.ldexp(jnp.float32(1.0), -s)
-            S = mid.shape[0]
-            S_pad = -(-S // pk._GS_SS) * pk._GS_SS
-            rows = jnp.zeros((3, S_pad), jnp.float32)
-            rows = rows.at[0, :S].set(mid).at[1, :S].set(c1)
-            rows = rows.at[2, :S].set(c2)
-            rows = jnp.pad(rows, ((0, 5), (0, 0)))
-            c = jnp.asarray(
-                rows.reshape(8, S_pad // pk._GS_SS, pk._GS_SS)
-                .transpose(1, 0, 2))
-            self._tperm[key] = c
-        return c
-
-    def jitter_ms(self) -> float:
-        """Max |ts - nominal slot tick| over valid slots: the bound the
-        group-sum dispatcher uses to elide jitter-fallback families
-        when the query grid phase statically clears it."""
-        if self._jitter is None:
-            ticks = (self.base_ms
-                     + jnp.arange(self.num_slots, dtype=jnp.float64)
-                     * self.dt_ms)
-            d = jnp.where(self.valid,
-                          jnp.abs(self.ts - ticks[None, :]), 0.0)
-            self._jitter = float(jnp.max(d))
-        return self._jitter
+# a series' span times the cohort's series count that f32_safe admits:
+# the largest f32 over the largest rate per unit of span (3.2 / 1 ms)
+_F32_SPAN_SUM_MAX = float(np.finfo(np.float32).max) / 3200.0
 
 
 @capacity(
@@ -1062,7 +939,7 @@ def _tiles_arrays_fast(tiles: AlignedTiles, func: str
 @precision(
     "counter-fast-hybrid", bits=31, rel_ulps=16,
     reason="the int31 span-guard idiom: the dispatcher "
-           "(_slide_eligible / ShardedTiles.query_fits) proves the "
+           "(_grid_fits_i32 / ShardedTiles.query_fits) proves the "
            "whole query grid fits int32 ms relative to the tile base "
            "before the i64->i32 timestamp narrowing; boundary deltas "
            "stay exact f64 and only the extrapolation epilogue runs "
@@ -1417,21 +1294,25 @@ def executable_cache_stats() -> Dict[str, int]:
     return out
 
 
+def _grid_fits_i32(tiles: AlignedTiles, w0s: int, last_ms: int) -> bool:
+    """The span guard of the f32-hybrid evaluators: every time from the
+    first window's start ``w0s`` to the last step ``last_ms``, and every
+    slot of the tiles, is int32 ms from the tile base."""
+    return (_SENT_LO < w0s - tiles.base_ms
+            and last_ms - tiles.base_ms < _SENT_HI
+            and tiles.num_slots * tiles.dt_ms + tiles.dt_ms < _SENT_HI)
+
+
 def _slide_eligible(tiles: AlignedTiles, nsteps: int, w0s: int, w0e: int,
                     last_ms: int, step: int):
-    """Shared dispatch guard for the slide evaluator AND the Pallas
-    group-sum kernel: a REGULAR grid (step % dt == 0) over dense tiles,
-    entirely interior (no index clipping: kp = kc-1 >= 0 ... kn =
-    kcl+1 <= N-1), with every relative time in int32 ms. Returns
-    (st, k_c0, k_l0) or None. Both consumers MUST dispatch off this one
-    predicate so they agree on the in-bounds proof."""
+    """Dispatch guard of the slide evaluator: a REGULAR grid (step % dt
+    == 0) over dense tiles, entirely interior (no index clipping: kp =
+    kc-1 >= 0 ... kn = kcl+1 <= N-1), with every relative time in int32
+    ms. Returns the stride in slots, step // dt, or None."""
     N, dt = tiles.num_slots, tiles.dt_ms
     if nsteps < 2 or not tiles._dense or step % dt != 0:
         return None
-    lo_rel = w0s - tiles.base_ms
-    hi_rel = last_ms - tiles.base_ms
-    if not (_SENT_LO < lo_rel and hi_rel < _SENT_HI
-            and N * dt + dt < _SENT_HI):
+    if not _grid_fits_i32(tiles, w0s, last_ms):
         return None
     st = step // dt
     k_c0 = int(np.floor((w0e - tiles.base_ms + dt / 2.0) / dt))
@@ -1440,7 +1321,7 @@ def _slide_eligible(tiles: AlignedTiles, nsteps: int, w0s: int, w0e: int,
     if not (st >= 1 and k_c0 >= 1 and k_l0 >= 0
             and k_c0 + span <= N - 1 and k_l0 + 1 + span <= N - 1):
         return None
-    return st, k_c0, k_l0
+    return st
 
 
 @kernel_contract(
@@ -1453,22 +1334,19 @@ def evaluate_counters_t(tiles: AlignedTiles, func: str, steps: np.ndarray,
                         window_ms: int, offset_ms: int = 0) -> jnp.ndarray:
     """rate/increase/delta on the transposed fast path → [T, S].
 
-    Dispatch: the f32-hybrid evaluator (f32 output) when the query grid
-    and tile span fit int32 ms relative to the tile base (~24.8 days);
-    the exact all-f64 evaluator (f64 output) otherwise."""
+    Dispatch (``counters_batch_family``): the f32-hybrid evaluators (f32
+    output) when the query grid and tile span fit int32 ms relative to
+    the tile base (~24.8 days) and the value channel passes
+    ``AlignedTiles.f32_safe``; the exact all-f64 evaluator (f64 output)
+    otherwise."""
     assert func in ("rate", "increase", "delta")
     nsteps = steps.size
     w0e = np.int64(steps[0] - offset_ms)
     w0s = np.int64(w0e - window_ms)
     step = np.int64(steps[1] - steps[0]) if nsteps > 1 else np.int64(1)
-    lo_rel = int(w0s) - tiles.base_ms
-    hi_rel = int(steps[-1] - offset_ms) - tiles.base_ms
-    fits_i32 = (_SENT_LO < lo_rel and hi_rel < _SENT_HI
-                and tiles.num_slots * tiles.dt_ms + tiles.dt_ms < _SENT_HI)
-    el = _slide_eligible(tiles, nsteps, int(w0s), int(w0e),
-                         int(steps[-1] - offset_ms), int(step))
-    if el is not None:
-        st, _, _ = el
+    family = counters_batch_family(tiles, func, steps, window_ms, offset_ms)
+    if family[0] == "slide":
+        st = family[1]
         arrs = _tiles_arrays_slide(tiles, func, st)
         key = ("slide", func, nsteps, st)
         args = (arrs, np.int64(tiles.num_slots),
@@ -1478,7 +1356,7 @@ def evaluate_counters_t(tiles: AlignedTiles, func: str, steps: np.ndarray,
             _bind(_eval_counter_slide, func, nsteps, st)),
             cost_args=args)
         return fn(*args)
-    if fits_i32:
+    if family == ("fast",):
         arrs = _tiles_arrays_fast(tiles, func)
         key = ("fast", func, nsteps)
         build = lambda: jax.jit(_bind(
@@ -1497,63 +1375,39 @@ def evaluate_counters_t(tiles: AlignedTiles, func: str, steps: np.ndarray,
 
 def _group_onehot(ids, G: int):
     """int32 group ids [S] -> f32 one-hot [S, G], built on the device;
-    an id outside [0, G) (the padding's -1) gives an all-zero row."""
+    an id outside [0, G) (such as -1) gives an all-zero row."""
     return (ids[:, None] == jnp.arange(G, dtype=jnp.int32)[None, :]
             ).astype(jnp.float32)
 
 
-def _ids_arg(gids, n: int):
-    """Tile-order group ids as a fused program takes them: int32[n], -1
-    (no group) past the series. A device array is taken as it is:
-    ``fused_group_ids`` made it so."""
+def _ids_arg(gids):
+    """Tile-order group ids as a fused program takes them: int32 [S]. A
+    device array is taken as it is: ``fused_group_ids`` made it so."""
     if isinstance(gids, jax.Array):
         return gids
-    ids = np.full(n, -1, np.int32)
-    ids[:len(gids)] = gids
-    return ids
+    return np.asarray(gids, np.int32)
 
 
 def fused_group_ids(tiles: AlignedTiles, gvec) -> jax.Array:
-    """``gvec`` (tile order) padded for the tiles' fused program (to the
-    Pallas kernel's lane tile over dense counter tiles, not at all over
-    holes and histograms) and put on the device: the backend's tile entry
-    keeps it per grouping, so that a request of the grouping sends no
-    ids."""
-    S = len(tiles.keys)
-    n = (-(-S // pk._GS_SS) * pk._GS_SS
-         if tiles._dense and not isinstance(tiles, HistTiles) else S)
-    return jax.device_put(_ids_arg(gvec, n))
+    """``gvec`` (tile order) as the tiles' fused program takes it, put on
+    the device: the backend's tile entry keeps it per grouping, so that a
+    request of the grouping sends no ids."""
+    return jax.device_put(_ids_arg(gvec))
 
 
-def _groupsum_program(func: str, st: int, dspan: int, hi_mode: int,
-                      lo_mode: int, exact_branch: bool, nsteps: int,
-                      G: int, interpret: bool, v_p, base, params, ids):
+def _groupsum_program(func: str, nsteps: int, G: int, arrs, consts, grid,
+                      ids):
     """The fused group-sum as ONE traceable program (jitted once per
-    static tuple by groupsum_counters): the [S_pad, G] f32 one-hot from
-    the int32 group ids (an id that names no group, the padding's -1,
-    gives an all-zero row) and the Pallas kernel, whose one output holds
-    the sums and the counts, f32 [2, nsteps, G], so that the host pulls
-    one buffer. Every input is explicitly typed, so the program is the
-    same under x64 on and off."""
-    return pk.groupsum_call(
-        func, st, dspan, hi_mode, lo_mode, exact_branch, nsteps,
-        v_p, base, _group_onehot(ids, G), params, interpret=interpret)
-
-
-def _groupsum_holes_program(func: str, nsteps: int, G: int, arrs, consts,
-                            grid, ids):
-    """The fused group-sum over tiles with holes as ONE traceable program
-    (jitted once per static tuple by groupsum_counters): the non-dense
-    f32-hybrid evaluator's [T, S] rates, NaN where a window holds fewer
-    than two samples, then the Pallas kernel's own epilogue — the masked
-    one-hot matmul to [T, G] sums and counts, f32 at HIGHEST precision
-    (the MXU's default bf16 input truncation is another answer), so a
-    selection sums the same way with holes and without. ``consts`` is
-    the tiles' int64[3] on the device (``t_consts``: num_slots, base_ms,
-    dt_ms); ``grid`` the request's int64[3]: w0s, w0e, step; ``ids``
-    int32 [S] in tile order, an id outside [0, G) names no group (on the
-    device where the backend's tile entry keeps them). Sums and counts
-    leave stacked, ONE f32 [2, nsteps, G]."""
+    static tuple by groupsum_counters): the f32-hybrid evaluator's [T, S]
+    rates, NaN where a window holds fewer than two samples, then the
+    masked one-hot matmul to [T, G] sums and counts, f32 at HIGHEST
+    precision (the MXU's default bf16 input truncation is another
+    answer). ``consts`` is the tiles' int64[3] on the device
+    (``t_consts``: num_slots, base_ms, dt_ms); ``grid`` the request's
+    int64[3]: w0s, w0e, step; ``ids`` int32 [S] in tile order, an id
+    outside [0, G) names no group (on the device where the backend's tile
+    entry keeps them). Sums and counts leave stacked, ONE f32
+    [2, nsteps, G]."""
     num_slots, base, dt = consts[0], consts[1], consts[2]
     w0s, w0e, step = grid[0], grid[1], grid[2]
     out = _eval_counter_fast(func, nsteps, arrs, num_slots, base, dt,
@@ -1568,143 +1422,57 @@ def _groupsum_holes_program(func: str, nsteps: int, G: int, arrs, consts,
 
 @kernel_contract(
     "groupsum_dispatch", kind="dispatch",
-    vmem_budget=14 << 20,
-    rel_time_bits=31, span_guard="_slide_eligible",
-    notes="host-side gate and dispatcher of the fused group-sum, two "
-          "programs behind it. Dense tiles, the Pallas kernel: regular "
-          "interior grid via _slide_eligible, merged-stream window/step "
-          "divisibility, dspan cap, full VMEM re-budget (accumulators + "
-          "DMA scratch + onehot + base) all decide BEFORE the executable "
-          "table is asked; then one cached executable (site groupsum) "
-          "per static tuple takes the query's five scalars as one "
-          "int32[5] host vector, and its group ids, which the backend's "
-          "tile entry keeps on the device. Tiles with holes: "
-          "_groupsum_holes")
+    rel_time_bits=31, span_guard="_grid_fits_i32",
+    notes="host-side gate and dispatcher of the fused group-sum: the "
+          "f32-hybrid evaluator and the masked one-hot matmul where the "
+          "whole grid fits int32 ms relative to the tile base and the "
+          "value channel passes AlignedTiles.f32_safe; "
+          "_eval_counter_fast clips its own indices, so no shape "
+          "condition of the grid is asked. One cached executable (site "
+          "groupsum) per (func, nsteps, G, channel shape, dense or not) "
+          "takes the query's int64[3] grid as its one host array: the "
+          "tiles' constants and the group ids are on the device")
 def groupsum_counters(tiles: AlignedTiles, func: str, steps: np.ndarray,
-                      window_ms: int, gids, G: int, offset_ms: int = 0,
-                      interpret: bool = False):
+                      window_ms: int, gids, G: int, offset_ms: int = 0):
     """`sum by (g) (rate/increase/delta(sel[w]))` fused on device ->
     ONE device array f32 [2, T, G], the sums at [0] and the counts at
     [1] (a sum is meaningful where its count > 0), so that the caller
-    syncs one buffer; or None when the preconditions don't hold (caller
-    falls back to evaluate_counters_t + host/XLA grouping). One gate,
-    two programs, chosen from ``tiles._dense``:
-    the Pallas group-sum kernel over dense tiles, the grouped non-dense
-    f32-hybrid evaluator (``_groupsum_holes``) over tiles with holes.
-    Both are boundary samples -> f32 extrapolation -> masked group
-    matmul, over two layouts of the same samples.
+    syncs one buffer; or None when the preconditions don't hold (the
+    caller serves the query on the host). One program over any aligned
+    tiles, dense or with holes: ``_groupsum_program`` over the channels
+    of ``_tiles_arrays_fast`` (the two dense ones, or the seven filled
+    ones over holes), which the aligned path holds resident already.
 
     ``gids``: the group id in [0, G) of every series of the tiles, in
-    tile order, or ``fused_group_ids`` of them (on the device, padded:
-    what the backend's tile entry hands over). One cached executable per
-    (func, grid statics, tile shapes, G) serves every query of that
-    shape: a query sends one small integer vector, nothing is traced or
-    compiled again (``_jit_lookup``: exec-cache hits/misses,
-    ``kernel-build``).
+    tile order, or ``fused_group_ids`` of them (on the device: what the
+    backend's tile entry hands over). One cached executable per (func,
+    nsteps, G, channel shape, dense or not) serves every query of that
+    shape: a query sends one int64[3] grid, nothing is traced or compiled
+    again (``_jit_lookup``: exec-cache hits/misses, ``kernel-build``).
 
-    Preconditions of the kernel: dense tiles; regular grid with
-    step % dt == 0 fully interior to the tile; a whole number of steps
-    a window; finite values; a pipeline within VMEM; span fits int32 ms
-    relative to the tile base. The ids are padded to the kernel's lane
-    tile with -1, which names no group, so any S works. Over holes only
-    the last of them is asked (``_groupsum_holes``)."""
+    Refused (None): a grid wider than int32 ms from the tile base
+    (``_grid_fits_i32``: the exact all-f64 ``("t",)`` family), or a
+    value channel the f32 epilogue and sums cannot carry
+    (``AlignedTiles.f32_safe``: a non-finite value, or a span past
+    f32)."""
     assert func in ("rate", "increase", "delta")
-    if not tiles._dense:
-        return _groupsum_holes(tiles, func, steps, window_ms, gids, G,
-                               offset_ms)
     nsteps = steps.size
-    if nsteps < 2:
+    if nsteps < 1:
         return None
     w0e = int(steps[0] - offset_ms)
-    w0s = w0e - window_ms
-    step = int(steps[1] - steps[0])
-    el = _slide_eligible(tiles, nsteps, w0s, w0e,
-                         int(steps[-1] - offset_ms), step)
-    if el is None:
-        return None
-    st, k_c0, k_l0 = el
-    # merged-stream contract: the window must span a whole number of
-    # steps so the kc/kl families share a stride-residue plane
-    d = k_c0 - k_l0
-    if d % st != 0 or not (0 <= d // st <= pk._GS_DSPAN_MAX):
-        return None
-    dspan = d // st
-    if st == 1 and k_l0 < 1:
-        return None              # the merged block reads one lead row
-    S = len(tiles.keys)
     vch = "cv" if func in ("rate", "increase") else "v"
-    if tiles._fixed_channels(vch) is None:
-        return None              # non-finite values: exact f64 fallback
-    # static jitter-phase elision: when the grid phase clears the
-    # tile's max |ts - tick|, the boundary-sample choice is the same
-    # for every series and step, and the fallback family is never read
-    dt = tiles.dt_ms
-    J = tiles.jitter_ms()
-    phase_e = (w0e - tiles.base_ms) - k_c0 * dt
-    phase_s = k_l0 * dt - (w0s - tiles.base_ms)
-    hi_mode = (pk.GS_CUR if phase_e >= J else
-               pk.GS_ALT if phase_e < -J else pk.GS_BOTH)
-    lo_mode = (pk.GS_CUR if phase_s >= J else
-               pk.GS_ALT if phase_s < -J else pk.GS_BOTH)
-    # full VMEM budget, not just the accumulators: the pipeline chooser
-    # (pk._gs_pipeline) walks the (step-tile width, DMA pipeline depth)
-    # frontier — accumulators + nbuf x nstreams x mlen scratch + the
-    # onehot/base input blocks — and an oversized query must fall back
-    # to the general path HERE, not explode at Mosaic compile time
-    if pk._gs_pipeline(st, dspan, hi_mode, lo_mode, nsteps, G) is None:
-        return None              # no admissible (tt, nbuf) within VMEM
-    S_pad = -(-S // pk._GS_SS) * pk._GS_SS
-    v_p = tiles.t_perm_fixed_tiled(vch, st)
-    base = tiles.t_fixed_base(vch)
-    ids = _ids_arg(gids, S_pad)
-    params = np.array([k_l0, w0e - tiles.base_ms, window_ms, step, nsteps],
-                      np.int32)
-    exact_branch = pk.groupsum_exact_branch(window_ms, st, dspan)
-    static = (func, st, dspan, hi_mode, lo_mode, exact_branch, nsteps, G,
-              interpret)
-    key = ("groupsum",) + static + (tuple(v_p.shape), tuple(base.shape))
-    args = (v_p, base, params, ids)
-    # a kernel the chip's compiler refuses fails the query with the
-    # compiler's message: the decided-in-advance route to the general
-    # path is the _gs_pipeline budget check above, never an except
-    fn = _jit_lookup(_EVAL_T_JIT, key, lambda: jax.jit(
-        _bind(_groupsum_program, *static)), site="groupsum",
-        cost_args=args)
-    return fn(*args)
-
-
-@kernel_contract(
-    "groupsum_holes_dispatch", kind="dispatch",
-    rel_time_bits=31, span_guard="counters_batch_family",
-    notes="the fused gate's branch over tiles with holes: the grouped "
-          "non-dense f32-hybrid evaluator where counters_batch_family "
-          "says ('fast',), i.e. the whole grid fits int32 ms relative "
-          "to the tile base; _eval_counter_fast clips its own indices, "
-          "so none of the kernel's shape conditions is asked. One "
-          "cached executable (site groupsum) per (func, nsteps, G, "
-          "channel shape) takes the query's int64[3] grid as its one "
-          "host array: the tiles' constants and the group ids are on "
-          "the device")
-def _groupsum_holes(tiles: AlignedTiles, func: str, steps: np.ndarray,
-                    window_ms: int, gids, G: int, offset_ms: int):
-    """``groupsum_counters`` over tiles that are not dense -> sums and
-    counts stacked, f32 [2, T, G], from ``_groupsum_holes_program`` over
-    the seven cached channels of ``_tiles_arrays_fast`` (what the aligned
-    path holds resident already), or None for the exact all-f64
-    ``("t",)`` family: a grid wider than int32 ms."""
-    nsteps = steps.size
-    if nsteps < 1 or counters_batch_family(
-            tiles, func, steps, window_ms, offset_ms) != ("fast",):
+    if not (_grid_fits_i32(tiles, w0e - window_ms,
+                           int(steps[-1] - offset_ms))
+            and tiles.f32_safe(vch)):
         return None
-    w0e = int(steps[0] - offset_ms)
     step = int(steps[1] - steps[0]) if nsteps > 1 else 1
     arrs = _tiles_arrays_fast(tiles, func)
     grid = np.array([w0e - window_ms, w0e, step], np.int64)
-    args = (arrs, tiles.t_consts(), grid, _ids_arg(gids, len(tiles.keys)))
-    key = ("groupsum", "holes", func, nsteps, G,
-           tuple(arrs["tsr"].shape))
+    args = (arrs, tiles.t_consts(), grid, _ids_arg(gids))
+    key = ("groupsum", func, nsteps, G, tuple(arrs["tsr"].shape),
+           tiles._dense)
     fn = _jit_lookup(_EVAL_T_JIT, key, lambda: jax.jit(
-        _bind(_groupsum_holes_program, func, nsteps, G)),
+        _bind(_groupsum_program, func, nsteps, G)),
         site="groupsum", cost_args=args)
     return fn(*args)
 
@@ -1765,7 +1533,7 @@ def _bucket_quantile(q, les, h, xp=jnp):
 
 @precision(
     "hist-quantile", bits=31, rel_ulps=256, compensated=True,
-    reason="int32 relative timestamps under counters_batch_family's "
+    reason="int32 relative timestamps under _grid_fits_i32's "
            "span guard (exact); everything after them is f64: the "
            "boundary deltas, the extrapolation formula, the masked group "
            "sums (an f64 accumulator) and the quantile, whose interpolation divides the rank's "
@@ -1781,7 +1549,7 @@ def _hist_quantile_program(func: str, nsteps: int, G: int, arrs, consts,
     (``_eval_counter_fast``'s takes, the f64 formula: NaN where a window
     holds fewer than two samples), ``hist_group_partials`` to [T, G, B],
     then ``hist_quantile_epilogue`` to [T, G] f64. ``consts`` and ``ids``
-    as ``_groupsum_holes_program``'s, ``les`` f64 [B] and ``q`` f64 the
+    as ``_groupsum_program``'s, ``les`` f64 [B] and ``q`` f64 the
     tiles' (``t_les``, ``t_q``), all four on the device; ``grid`` the
     request's int64[3]: w0s, w0e, step. ``les`` and ``q`` are runtime
     values, so one executable serves every quantile and every scheme of B
@@ -1820,11 +1588,11 @@ def hist_quantile_epilogue(sums, cnts, les, q):
 
 @kernel_contract(
     "hist_quantile_dispatch", kind="dispatch",
-    rel_time_bits=31, span_guard="counters_batch_family",
+    rel_time_bits=31, span_guard="_grid_fits_i32",
     notes="the fused histogram quantile: the f32-hybrid evaluator's "
           "takes with a bucket axis and the f64 formula, masked group "
           "sums and the quantile in one program where "
-          "counters_batch_family says the grid fits int32 ms relative to "
+          "_grid_fits_i32 says the grid fits int32 ms relative to "
           "the tile base; _eval_counter_fast clips its own "
           "indices. One cached executable (site groupsum) per (func, "
           "nsteps, G, channel shape) takes the query's int64[3] grid "
@@ -1840,15 +1608,17 @@ def hist_quantile_groupsum(tiles: HistTiles, func: str, steps: np.ndarray,
     order, or ``fused_group_ids`` of them."""
     assert func in ("rate", "increase")
     nsteps = steps.size
-    if nsteps < 1 or counters_batch_family(
-            tiles, func, steps, window_ms, offset_ms) == ("t",):
+    if nsteps < 1:
         return None
     w0e = int(steps[0] - offset_ms)
+    if not _grid_fits_i32(tiles, w0e - window_ms,
+                          int(steps[-1] - offset_ms)):
+        return None
     step = int(steps[1] - steps[0]) if nsteps > 1 else 1
     arrs = _tiles_arrays_hist(tiles)
     grid = np.array([w0e - window_ms, w0e, step], np.int64)
     args = (arrs, tiles.t_consts(), tiles.t_les(), tiles.t_q(q), grid,
-            _ids_arg(gids, len(tiles.keys)))
+            _ids_arg(gids))
     key = ("groupsum", "hist", func, nsteps, G,
            tuple(arrs["ff_v"].shape), "ps_ones" in arrs)
     fn = _jit_lookup(_EVAL_T_JIT, key, lambda: jax.jit(
@@ -1918,20 +1688,23 @@ def counters_batch_family(tiles: AlignedTiles, func: str,
     """Hashable dispatch-family key for one counter query — two queries
     may share a batched dispatch only when their families match (the
     family fixes which compiled evaluator the scalar path would pick,
-    so batching never changes the kernel choice)."""
+    so batching never changes the kernel choice). A value channel the
+    f32 epilogue cannot carry (``AlignedTiles.f32_safe``) takes the
+    exact all-f64 ``("t",)`` family, as a grid wider than int32 ms
+    does."""
     nsteps = steps.size
     w0e = int(steps[0] - offset_ms)
     w0s = w0e - window_ms
     step = int(steps[1] - steps[0]) if nsteps > 1 else 1
-    el = _slide_eligible(tiles, nsteps, w0s, w0e,
+    if not tiles.f32_safe("cv" if func in ("rate", "increase") else "v"):
+        return ("t",)
+    st = _slide_eligible(tiles, nsteps, w0s, w0e,
                          int(steps[-1] - offset_ms), step)
-    if el is not None:
-        return ("slide", el[0])
-    lo_rel = w0s - tiles.base_ms
-    hi_rel = int(steps[-1] - offset_ms) - tiles.base_ms
-    fits_i32 = (_SENT_LO < lo_rel and hi_rel < _SENT_HI
-                and tiles.num_slots * tiles.dt_ms + tiles.dt_ms < _SENT_HI)
-    return ("fast",) if fits_i32 else ("t",)
+    if st is not None:
+        return ("slide", st)
+    if _grid_fits_i32(tiles, w0s, int(steps[-1] - offset_ms)):
+        return ("fast",)
+    return ("t",)
 
 
 def evaluate_counters_t_batch(tiles: AlignedTiles, func: str,

@@ -456,8 +456,8 @@ def _packed_window(func: str, w_bound: int, nsteps: int, ib, fb):
     return _window_endpoint(func, ts, vals, lens, w0s, w0e, step, nsteps,
                             scalar)
 
-# tests set this to exercise the fused group-sum kernel in interpret
-# mode on the CPU test mesh; production CPU nodes leave it off
+# lets a CPU node take the one-device fused programs (tests and the
+# benchmark's CPU rehearsal set it); production CPU nodes leave it off
 FUSED_GROUPSUM_INTERPRET = False
 
 
@@ -501,8 +501,8 @@ class _TileEntry:
         return self.gvecs.get(gids, self._gather)
 
     def device_ids(self, gids):
-        """``tile_order(gids)`` padded for the tiles' one-device fused
-        program and put on the device (``tst.fused_group_ids``): once per
+        """``tile_order(gids)`` put on the device for the tiles'
+        one-device fused program (``tst.fused_group_ids``): once per
         frozen ``gids``, so that a request sends its grid alone. They go
         with the entry."""
         return self.dvecs.get(gids, lambda g: tst.fused_group_ids(
@@ -589,8 +589,8 @@ class TpuBackend:
         self.tile_builds = 0    # observability: device tile (re)builds
         self.tile_hits = 0      # observability: cache hits
         self.fused_aggs = 0     # observability: fused group-sum queries
-        # those of them that the grouped non-dense program served
-        # (tiles with holes); the rest ran the Pallas kernel or the mesh
+        # those of them served over tiles with holes by the one-device
+        # program; the rest came from dense tiles or the mesh
         self.fused_holes_aggs = 0
         # fused_groupsum calls that came back None, and those of them
         # that the gate refused over tiles with holes
@@ -1131,10 +1131,10 @@ class TpuBackend:
         the order of the refusals below, the path that serves and the
         program are what they were without it; the
         program is one cached executable of the tilestore
-        table, the Pallas group-sum kernel over dense tiles or the
-        grouped non-dense evaluator over tiles with holes
-        (``filodb_fused_holes_aggs_total`` counts those apart), or the
-        mesh store's grouped collective. Each returns sums and counts
+        table, the grouped f32-hybrid evaluator over dense tiles and
+        tiles with holes alike (``filodb_fused_holes_aggs_total`` counts
+        those over holes apart), or the mesh store's grouped
+        collective. Each returns sums and counts
         stacked in ONE device array, so a request makes one
         device-to-host transfer. Returns (sums, cnts) as [T, G] numpy
         (two views of that one buffer) or None when ineligible (caller
@@ -1142,17 +1142,17 @@ class TpuBackend:
 
         Every None counts in ``filodb_fused_refused_total``. The
         reasons, in the order they are looked at: not a counter
-        function, or nothing selected; a CPU node without the
-        interpreted kernel or a mesh; series that do not share one
-        cadence grid (no tiles); a window that reaches the write-buffer
-        tail (``_fused_covered``); and the gate
-        (``tst.groupsum_counters``, or the mesh store's placement).
-        Over dense tiles: a grid that is irregular, not interior or not
-        a whole number of steps a window, non-finite values, or no
-        pipeline within VMEM. Over tiles with holes, counted apart in
-        ``filodb_fused_refused_gaps_total``: a grid wider than int32 ms
-        from the tile base (the exact all-f64 family), or a CPU node
-        whose mesh store places dense tiles only."""
+        function, or nothing selected; a CPU node without
+        ``FUSED_GROUPSUM_INTERPRET`` or a mesh; series that do not share
+        one cadence grid (no tiles); a window that reaches the
+        write-buffer tail (``_fused_covered``); and the gate
+        (``tst.groupsum_counters``, or the mesh store's placement): a
+        grid wider than int32 ms from the tile base (the exact all-f64
+        family), or a value channel the f32 program cannot carry
+        (``AlignedTiles.f32_safe``: a non-finite value, or a span past
+        f32). Those over tiles with holes count apart in
+        ``filodb_fused_refused_gaps_total``, as does a CPU node whose
+        mesh store places dense tiles only."""
         res = self._fused_groupsum(series, func, steps, window_ms,
                                    offset_ms, gids, G, facts)
         if res is None:
@@ -1166,12 +1166,8 @@ class TpuBackend:
         on_cpu = jax.default_backend() == "cpu"
         if on_cpu and not FUSED_GROUPSUM_INTERPRET \
                 and self.mesh_eval is None:
-            # interpret-mode Pallas re-traces per tile shape — with live
-            # ingest growing the tiles that is seconds per query; CPU
-            # nodes take the vectorized-numpy path instead (tests flip
-            # the flag to exercise the kernel in interpret mode; the
-            # mesh-sharded grouped collective below is XLA, not Pallas,
-            # so it serves on any backend)
+            # a CPU node serves on the host unless a mesh store (below)
+            # takes the query or tests set the flag
             return None
         if facts is None:
             facts = selection_facts(series)
@@ -1216,8 +1212,7 @@ class TpuBackend:
         else:
             with obs_trace.span("device-dispatch", path="fused"):
                 res = tst.groupsum_counters(
-                    tiles, func, steps, window_ms, gvec, G, offset_ms,
-                    interpret=on_cpu)
+                    tiles, func, steps, window_ms, gvec, G, offset_ms)
             if res is None:
                 self.fused_refused_gaps += not tiles._dense
                 return None
@@ -1253,8 +1248,8 @@ class TpuBackend:
         query on the host over the same selection). Counts in
         ``filodb_fused_hist_aggs_total``, a None in
         ``filodb_fused_hist_refused_total{reason}``: ``cpu`` (a CPU node
-        without the interpreted kernels and without a mesh store that
-        took the query), ``tiles`` (not one bucket scheme, no shared
+        without ``FUSED_GROUPSUM_INTERPRET`` and without a mesh store
+        that took the query), ``tiles`` (not one bucket scheme, no shared
         cadence, or a scheme the program cannot answer: fewer than two
         buckets, or no ``+Inf`` last), ``tail`` (a window reaches the
         write-buffer tail) and ``grid`` (wider than int32 ms from the

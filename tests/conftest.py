@@ -20,10 +20,8 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_enable_x64", True)
 
-# exercise the fused Pallas group-sum path (interpret mode) on the CPU
-# test mesh; production CPU nodes keep it off (tpu.py gate) —
-# interpret-mode re-jits per shape, which a serving node must never pay
-# per query
+# let the CPU test node take the one-device fused programs; production
+# CPU nodes keep the flag off (tpu.py gate) and serve on the host
 from filodb_tpu.query import tpu as _tpu  # noqa: E402
 
 _tpu.FUSED_GROUPSUM_INTERPRET = True

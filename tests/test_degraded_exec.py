@@ -120,7 +120,7 @@ def test_concat_hist_mismatched_les_raises():
         ex.execute()
 
 
-# -- groupsum dispatcher VMEM budget (advisor, tilestore.py) ---------------
+# -- the fused group-sum gate (tilestore.py) --------------------------------
 
 def _tiles(S=8, N=288, seed=7, span=None):
     rng = np.random.default_rng(seed)
@@ -134,34 +134,54 @@ def _tiles(S=8, N=288, seed=7, span=None):
                             np.ones((S, N), bool), ts, vals)
 
 
+STEPS = np.arange(BASE + 400_000, BASE + 2_400_000, 60_000, dtype=np.int64)
+
+
 def _gs(tiles, G, func="delta", S=8):
-    steps = np.arange(BASE + 400_000, BASE + 2_400_000, 60_000,
-                      dtype=np.int64)
-    return tst.groupsum_counters(tiles, func, steps, 300_000,
-                                 np.arange(S) % G, G, interpret=True)
+    return tst.groupsum_counters(tiles, func, STEPS, 300_000,
+                                 np.arange(S) % G, G)
+
+
+def _grouped_in_float64(tiles, func, gid, G):
+    """The per-series evaluator's rates, summed and counted by group in
+    float64 here."""
+    per = np.asarray(tst.evaluate_counters_t(tiles, func, STEPS, 300_000),
+                     np.float64)
+    ok = ~np.isnan(per)
+    sums = np.stack([np.where(ok, per, 0.0)[:, gid == g].sum(axis=1)
+                     for g in range(G)], 1)
+    cnts = np.stack([ok[:, gid == g].sum(axis=1) for g in range(G)], 1)
+    return sums, cnts
 
 
 def test_groupsum_vmem_budget_rejects_wide_group_tables():
+    """A group table of 1,500 is served by the one fused program, and
+    equals the float64 grouping; most groups hold no series."""
     tiles = _tiles()
-    # G=1500 passes the old accumulator-only check (256*1500*8 ~ 3MB
-    # < 4MB) but the DMA scratch + onehot block push the total past
-    # VMEM: the dispatcher must fall back, not die in Mosaic
-    assert _gs(tiles, 1500) is None
-    # the same tiles with a small group table still dispatch
+    G = 1500
+    gid = (np.arange(8) * 211) % G
+    res = tst.groupsum_counters(tiles, "delta", STEPS, 300_000, gid, G)
+    assert res is not None
+    sums, cnts = np.asarray(res[0]), np.asarray(res[1])
+    assert sums.shape == (STEPS.size, G)
+    want_s, want_c = _grouped_in_float64(tiles, "delta", gid, G)
+    np.testing.assert_array_equal(cnts, want_c)
+    np.testing.assert_allclose(sums, want_s, rtol=2e-6,
+                               atol=2e-6 * np.abs(want_s).max())
+    assert cnts.sum() == 8 * STEPS.size
+    # the same tiles with a small group table dispatch too
     assert _gs(tiles, 4) is not None
 
 
-# -- fixed-point scale-exponent underflow (advisor, tilestore.py) ----------
-
 def test_fixed_channels_refuse_unrepresentable_span():
-    tiles = _tiles(span=1e60)                # needs s < -96: unencodable
-    # before the fix the scale exponent was clipped to -96 and the
-    # int64 rint silently wrapped; now the packer refuses and the
-    # dispatcher takes the non-fused fallback
-    assert tiles._fixed_channels("v") is None
+    tiles = _tiles(span=1e60)
+    # a rate of a span this wide, summed over the cohort, is past f32:
+    # the gate refuses and the caller takes the exact host path
+    assert not tiles.f32_safe("v")
     assert _gs(tiles, 4) is None
 
 
 def test_fixed_channels_normal_span_still_packs():
     tiles = _tiles()
-    assert tiles._fixed_channels("v") is not None
+    assert tiles.f32_safe("v") and tiles.f32_safe("cv")
+    assert _gs(tiles, 4) is not None

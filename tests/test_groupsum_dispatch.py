@@ -1,16 +1,16 @@
 """The fused group-sum as ONE cached executable (tilestore.groupsum_counters
--> ``_jit_lookup`` -> ``_groupsum_program``): a query sends five int32
-scalars and its group ids (the served path keeps the ids on the device);
-nothing is traced or compiled again for a shape the table has. Interpret-mode Pallas under ``jit`` on the CPU: counts and
-bits, never a time.
+-> ``_jit_lookup`` -> ``_groupsum_program``, plain XLA): a query sends one
+int64[3] grid and its group ids (the served path keeps the ids on the
+device); nothing is traced or compiled again for a shape the table has.
+On the CPU: counts and bits, never a time.
 
-(a) one miss then hits over grid positions and group vectors, the kernel
-traced once, a new static its own entry; (b) bit-for-bit the kernel called
-directly with a host one-hot; (c) four threads' first call at once; (d)
-through the served engine: ``&explain=analyze`` dispositions and the
-``filodb_exec_cache_*`` counters; (e) the gate's other program, over tiles
-with holes (``_groupsum_holes_program``, plain XLA): the per-series
-evaluator's rates grouped in float64 here, and one executable a shape.
+(a) one miss then hits over grid positions and group vectors, the program
+traced once, a new static its own entry; (b) an id outside the groups;
+(c) four threads' first call at once; (d) through the served engine:
+``&explain=analyze`` dispositions and the ``filodb_exec_cache_*``
+counters; (e) over dense tiles and tiles with holes alike: the
+per-series evaluator's rates grouped in float64 here, and one executable a
+shape.
 """
 
 import json
@@ -22,7 +22,6 @@ import numpy as np
 import pytest
 
 from filodb_tpu.obs import devprof
-from filodb_tpu.query import pallas_kernels as pk
 from filodb_tpu.query import tilestore as tst
 from filodb_tpu.standalone.server import FiloServer
 
@@ -65,14 +64,15 @@ def _delta(before):
 
 @pytest.fixture
 def traces(monkeypatch):
-    """Calls of the kernel body, i.e. how often it was traced."""
+    """Calls of the program body, i.e. how often it was traced."""
     calls = []
-    real = pk._groupsum_kernel
+    real = tst._groupsum_program
 
-    def counted(*a, **kw):
+    def counted(*a):
         calls.append(1)
-        return real(*a, **kw)
-    monkeypatch.setattr(pk, "_groupsum_kernel", counted)
+        return real(*a)
+    counted.__name__ = real.__name__
+    monkeypatch.setattr(tst, "_groupsum_program", counted)
     return calls
 
 
@@ -87,7 +87,7 @@ def test_one_miss_then_hits_and_one_trace(fresh_table, traces):
     for i in range(6):
         gid = rng.integers(0, G, S)
         res = tst.groupsum_counters(tiles, "rate", _steps(T, 60_000 * i),
-                                    W, gid, G, interpret=True)
+                                    W, gid, G)
         assert res is not None
         outs.append(np.asarray(res[0]))
     assert _delta(before) == {"hits": 5, "misses": 1, "entries": 1}
@@ -97,111 +97,47 @@ def test_one_miss_then_hits_and_one_trace(fresh_table, traces):
     assert all(not np.array_equal(outs[0], o) for o in outs[1:])
 
 
-@pytest.mark.parametrize("change", ["nsteps", "G", "jitter-mode", "func"])
+@pytest.mark.parametrize("change", ["nsteps", "G", "holes", "func"])
 def test_a_new_static_is_a_second_miss_with_its_own_entry(
         fresh_table, traces, change):
     S, G, T = 96, 4, 20
     tiles = _tiles(S, jitter=500)
     gid = np.arange(S) % G
-    assert tst.groupsum_counters(tiles, "rate", _steps(T), W, gid, G,
-                                 interpret=True) is not None
+    assert tst.groupsum_counters(tiles, "rate", _steps(T), W, gid,
+                                 G) is not None
     before = tst.executable_cache_stats()
     kw = dict(func="rate", steps=_steps(T), G=G)
     if change == "nsteps":
         kw["steps"] = _steps(T + 3)
     elif change == "G":
         kw["G"] = G + 1
-    elif change == "jitter-mode":
-        # a grid phase that clears the tile's jitter elides the
-        # fallback families: other static modes, another kernel
-        kw["steps"] = _steps(T, 3000)
+    elif change == "holes":
+        # tiles of the same shape with one hole: seven channels, not two
+        valid = np.ones(tiles.valid.shape, bool)
+        valid[3, 50] = False
+        tiles = tst.AlignedTiles(tiles.keys, BASE, DT, valid,
+                                 np.asarray(tiles.ts), np.asarray(tiles.vals))
     else:
         kw["func"] = "increase"
     for _ in range(2):
         assert tst.groupsum_counters(tiles, kw["func"], kw["steps"], W, gid,
-                                     kw["G"], interpret=True) is not None
+                                     kw["G"]) is not None
     assert _delta(before) == {"hits": 1, "misses": 1, "entries": 1}
     assert len(traces) == 2
     k0, k1 = _groupsum_keys()
     assert k0 != k1
 
 
-# -- (b) bit for bit the kernel called directly --------------------------------
-
-def _direct(monkeypatch, tiles, func, steps, window, gid, G):
-    """``pk.counter_groupsum`` itself, eagerly, with the host one-hot the
-    dispatcher used to build (zero rows for the padding), at the statics
-    and scalars the dispatcher chose."""
-    seen = {}
-    real = tst._jit_lookup
-
-    def spy(cache, key, build, site="tilestore", cost_args=None):
-        seen.update(key=key, args=cost_args)
-        return real(cache, key, build, site=site, cost_args=cost_args)
-    monkeypatch.setattr(tst, "_jit_lookup", spy)
-    res = tst.groupsum_counters(tiles, func, steps, window, gid, G,
-                                interpret=True)
-    assert res is not None
-    _, func_k, st, dspan, hi, lo, exact = seen["key"][:7]
-    v_p, base, params, ids = seen["args"]
-    S = len(gid)
-    onehot = np.zeros((ids.size, G), np.float32)
-    onehot[np.arange(S), gid] = 1.0
-    kl0, w0e_rel, window_p, step, nsteps = (int(x) for x in params)
-    want = pk.counter_groupsum(func_k, st, dspan, hi, lo, v_p, base, onehot,
-                               kl0, w0e_rel, window_p, step, nsteps,
-                               interpret=True, exact_branch=exact)
-    return res, want
-
-
-@pytest.mark.parametrize("S", [100, 600])
-@pytest.mark.parametrize("func", ["rate", "increase", "delta"])
-def test_jitted_path_equals_the_direct_kernel_bit_for_bit(
-        monkeypatch, func, S):
-    """S is no multiple of the 512-lane tile (padding rows must stay out
-    of every group) and group G-1 has no series."""
-    G = 6
-    tiles = _tiles(S)
-    gid = np.arange(S) % (G - 1)
-    (sums, cnts), (want_s, want_c) = _direct(
-        monkeypatch, tiles, func, _steps(34), W, gid, G)
-    sums, cnts = np.asarray(sums), np.asarray(cnts)
-    assert sums.shape == cnts.shape == (34, G)
-    assert sums.dtype == cnts.dtype == np.float32
-    np.testing.assert_array_equal(cnts, np.asarray(want_c))
-    np.testing.assert_array_equal(sums, np.asarray(want_s))
-    # every series counted once, none of the padding, none in group G-1
-    assert cnts[:, :G - 1].sum(axis=1).tolist() == [float(S)] * 34
-    assert not cnts[:, G - 1].any() and not sums[:, G - 1].any()
-
-
-@pytest.mark.parametrize("case", ["st1", "phase+", "phase-", "wide"])
-def test_jitted_path_equals_the_direct_kernel_other_shapes(monkeypatch, case):
-    S, G = 48, 3
-    if case == "st1":
-        tiles, steps = _tiles(S, 400), _steps(100, step=10_000)
-    elif case == "wide":
-        tiles, steps = _tiles(S, 2000), _steps(300)
-    else:
-        tiles = _tiles(S, jitter=500)
-        steps = _steps(30, 3000 if case == "phase+" else -3000)
-    gid = np.arange(S) % G
-    (sums, cnts), (want_s, want_c) = _direct(
-        monkeypatch, tiles, "increase", steps, W, gid, G)
-    np.testing.assert_array_equal(np.asarray(cnts), np.asarray(want_c))
-    np.testing.assert_array_equal(np.asarray(sums), np.asarray(want_s))
-
+# -- (b) an id outside the groups ----------------------------------------------
 
 def test_an_id_outside_the_groups_is_in_no_group():
     S, G = 40, 4
     tiles = _tiles(S)
     gid = np.arange(S) % G
-    full = tst.groupsum_counters(tiles, "rate", _steps(12), W, gid, G,
-                                 interpret=True)
+    full = tst.groupsum_counters(tiles, "rate", _steps(12), W, gid, G)
     out = gid.copy()
     out[gid == 2] = -1
-    part = tst.groupsum_counters(tiles, "rate", _steps(12), W, out, G,
-                                 interpret=True)
+    part = tst.groupsum_counters(tiles, "rate", _steps(12), W, out, G)
     keep = [0, 1, 3]
     np.testing.assert_array_equal(np.asarray(part[0])[:, keep],
                                   np.asarray(full[0])[:, keep])
@@ -217,7 +153,7 @@ def test_four_threads_first_call_at_once(fresh_table, traces):
     # the race under test is the executable table's, not the tile's
     gids = [np.random.default_rng(i).integers(0, G, S) for i in range(4)]
     assert tst.groupsum_counters(tiles, "rate", _steps(T + 1), W, gids[0],
-                                 G, interpret=True) is not None
+                                 G) is not None
     n_traced = len(traces)
     before = tst.executable_cache_stats()
     gate = threading.Barrier(4)
@@ -227,7 +163,7 @@ def test_four_threads_first_call_at_once(fresh_table, traces):
         try:
             gate.wait(timeout=60)
             r = tst.groupsum_counters(tiles, "rate", _steps(T, 60_000 * i),
-                                      W, gids[i], G, interpret=True)
+                                      W, gids[i], G)
             got[i] = (np.asarray(r[0]), np.asarray(r[1]))
         except Exception as e:      # noqa: BLE001 — reported below
             errs.append(e)
@@ -244,7 +180,7 @@ def test_four_threads_first_call_at_once(fresh_table, traces):
     assert len(traces) - n_traced == 1
     for i in range(4):
         again = tst.groupsum_counters(tiles, "rate", _steps(T, 60_000 * i),
-                                      W, gids[i], G, interpret=True)
+                                      W, gids[i], G)
         np.testing.assert_array_equal(got[i][0], np.asarray(again[0]))
         np.testing.assert_array_equal(got[i][1], np.asarray(again[1]))
         per = np.asarray(tst.evaluate_counters_t(
@@ -332,7 +268,7 @@ def test_served_fused_query_builds_once_then_runs_the_compiled_object(
                                    [w[t] for t in sorted(w)], rtol=1e-5)
 
 
-# -- (e) tiles with holes: the grouped non-dense evaluator ---------------------
+# -- (e) dense tiles and tiles with holes: one program -------------------------
 
 S_H, N_H, G_H = 64, 200, 5
 
@@ -361,6 +297,22 @@ def holed():
     return _holed()
 
 
+@pytest.fixture(scope="module")
+def dense():
+    """``_holed``'s samples with every slot filled: its timestamps and
+    values, where a hole's slot takes the tick and its neighbours' mean."""
+    h = _holed()
+    valid = np.asarray(h.valid)
+    ticks = BASE + np.arange(N_H)[None, :] * DT + np.zeros((S_H, 1))
+    ts = np.where(valid, np.asarray(h.ts), ticks)
+    v = np.array(h.vals)
+    for r in range(S_H):
+        ok = np.flatnonzero(valid[r])
+        v[r] = np.interp(np.arange(N_H), ok, v[r, ok])
+    return tst.AlignedTiles([{} for _ in range(S_H)], BASE, DT,
+                            np.ones((S_H, N_H), bool), ts, v)
+
+
 # (first window end relative to BASE, step, steps): windows are W = 300 s
 GRIDS = {
     "interior": (400_000, 60_000, 20),
@@ -376,19 +328,27 @@ def _grid(name):
     return BASE + first + np.arange(n, dtype=np.int64) * step
 
 
+@pytest.mark.parametrize("kind", ["dense", "holes"])
 @pytest.mark.parametrize("grid", sorted(GRIDS))
 @pytest.mark.parametrize("op", ["sum", "count", "avg"])
 @pytest.mark.parametrize("func", ["rate", "increase", "delta"])
 def test_grouped_program_over_holes_equals_per_series_grouped_in_float64(
-        holed, func, op, grid):
+        holed, dense, func, op, grid, kind):
+    """The one program over either kind of tiles. Over dense tiles the
+    regular interior grids are the ones ``counters_batch_family`` gives
+    to the slide evaluator, which the program serves all the same."""
+    tiles = holed if kind == "holes" else dense
     steps = _grid(grid)
+    if kind == "dense" and grid in ("interior", "window-not-whole-steps"):
+        assert tst.counters_batch_family(tiles, func, steps, W)[0] \
+            == "slide"
     gid = np.arange(S_H) % (G_H - 1)        # group G_H - 1 has no series
-    res = tst.groupsum_counters(holed, func, steps, W, gid, G_H)
+    res = tst.groupsum_counters(tiles, func, steps, W, gid, G_H)
     assert res is not None
     sums, cnts = np.asarray(res[0]), np.asarray(res[1])
     assert sums.shape == cnts.shape == (steps.size, G_H)
     assert sums.dtype == cnts.dtype == np.float32
-    per = np.asarray(tst.evaluate_counters_t(holed, func, steps, W),
+    per = np.asarray(tst.evaluate_counters_t(tiles, func, steps, W),
                      np.float64)            # [T, S], NaN: under two samples
     assert per.shape == (steps.size, S_H)
     ok = ~np.isnan(per)
@@ -396,7 +356,7 @@ def test_grouped_program_over_holes_equals_per_series_grouped_in_float64(
     want_s = np.stack([np.where(ok, per, 0.0)[:, gid == g].sum(axis=1)
                        for g in range(G_H)], 1)
     np.testing.assert_array_equal(cnts, want_c)
-    if grid == "interior":
+    if grid == "interior" and kind == "holes":
         # the cases the fleet was built for are inside this grid
         assert not ok[:, 1].all() and not ok[:, 2].all()
         assert ok[:, 1].any() and ok[:, 0].all()
@@ -418,7 +378,7 @@ def test_grouped_program_over_holes_equals_per_series_grouped_in_float64(
 
 
 def _holes_keys():
-    return [k for k in _groupsum_keys() if k[1] == "holes"]
+    return [k for k in _groupsum_keys() if not k[-1]]
 
 
 def test_holes_one_miss_then_hits_over_positions_groups_and_tiles(
@@ -427,13 +387,13 @@ def test_holes_one_miss_then_hits_over_positions_groups_and_tiles(
     of the same shape (another app's tiles) all run the one executable:
     its key holds shapes, not the tiles."""
     built = []
-    real = tst._groupsum_holes_program
+    real = tst._groupsum_program
 
     def counted(*a):
         built.append(1)
         return real(*a)
     counted.__name__ = real.__name__
-    monkeypatch.setattr(tst, "_groupsum_holes_program", counted)
+    monkeypatch.setattr(tst, "_groupsum_program", counted)
     T = 20
     a, b = _holed(7), _holed(8)
     for t in (a, b):
@@ -479,8 +439,7 @@ def test_holes_send_one_int64_vector_and_the_ids(holed, monkeypatch):
     assert tst.groupsum_counters(holed, "rate", steps, W, gid, G_H,
                                  offset_ms=60_000) is not None
     assert seen["site"] == "groupsum"
-    assert seen["key"] == ("groupsum", "holes", "rate", 12, G_H,
-                           (N_H, S_H))
+    assert seen["key"] == ("groupsum", "rate", 12, G_H, (N_H, S_H), False)
     arrs, consts, grid, ids = seen["args"]
     assert type(grid) is np.ndarray and grid.dtype == np.int64
     w0e = int(steps[0]) - 60_000
@@ -507,14 +466,16 @@ def test_holes_an_id_outside_the_groups_is_in_no_group(holed):
     assert np.asarray(full[1])[:, 2].any()
 
 
-def test_grid_wider_than_int32_ms_over_holes_is_refused(holed, fresh_table):
-    """The exact all-f64 family keeps the aligned path; dense tiles are
-    not asked this question by the holes branch at all."""
+def test_grid_wider_than_int32_ms_over_holes_is_refused(holed, dense,
+                                                        fresh_table):
+    """The exact all-f64 family keeps the aligned path, over holes and
+    over dense tiles alike."""
     steps = BASE + 400_000 + np.arange(3, dtype=np.int64) * (2 ** 30)
     assert tst.counters_batch_family(holed, "rate", steps, W) == ("t",)
     before = tst.executable_cache_stats()
-    assert tst.groupsum_counters(holed, "rate", steps, W,
-                                 np.arange(S_H) % G_H, G_H) is None
+    for tiles in (holed, dense):
+        assert tst.groupsum_counters(tiles, "rate", steps, W,
+                                     np.arange(S_H) % G_H, G_H) is None
     assert _delta(before) == {"hits": 0, "misses": 0, "entries": 0}
     assert tst.groupsum_counters(holed, "rate", steps[:0], W,
                                  np.arange(S_H) % G_H, G_H) is None

@@ -67,8 +67,8 @@ def test_v5_families_enabled_at_error():
     """The four graftlint v5 capacity families + the capacity-
     certification rail ride the tier-1 gate at error severity. The
     full run above exercises them: the residency dataflow sweeps every
-    untraced function, the frontier sweep re-derives the groupsum
-    chooser grid against the kernel contract, and check_contracts=True
+    untraced function, the frontier rule holds any chooser that takes a
+    vmem_budget to it, and check_contracts=True
     certifies every @capacity claim (sharded claims at 1/2/4/8 virtual
     devices)."""
     from filodb_tpu.lint import rules
@@ -112,15 +112,13 @@ def test_shipped_baseline_is_empty():
 def test_every_pallas_call_site_has_contract():
     import importlib
     from filodb_tpu.lint.contracts import CONTRACTS
-    for m in ("filodb_tpu.query.pallas_kernels",
-              "filodb_tpu.query.tilestore", "filodb_tpu.query.tpu",
+    for m in ("filodb_tpu.query.tilestore", "filodb_tpu.query.tpu",
               "filodb_tpu.downsample.kernels",
               "filodb_tpu.parallel.mesh"):
         importlib.import_module(m)
     names = {k[1] for k in CONTRACTS}
-    # the one pallas_call wrapper, its dispatcher and the counters'
-    assert {"counter_groupsum", "groupsum_dispatch",
-            "counters_t_dispatch"} <= names
+    # the fused group-sum's dispatcher and the counters'
+    assert {"groupsum_dispatch", "counters_t_dispatch"} <= names
     # kernel entry points across the named modules
     assert {"window_endpoint", "window_gather", "downsample_gauge",
             "downsample_regular", "counter_emit_mask", "cascade_aligned",

@@ -186,7 +186,7 @@ def test_v5_families_registered_at_error():
 
 def test_claim_lookup_and_projection():
     """The certified shardstore claim exposes the per-chip projection
-    the ledger and bench emit."""
+    the ledger emits."""
     c = cmod.capacity_claim("shardstore-resident-channels")
     assert c.sharded and c.bytes_per_sample == 20.0
     assert c.claimed_total(1024, 16) == pytest.approx(

@@ -1,10 +1,10 @@
 """The served ``sum by (job)`` of ``rate[5m]`` over a store with missed
 scrapes: a scrape that failed stored no sample, so a series has holes on
 the cadence grid and its tiles are not dense. The fused gate
-(``tilestore.groupsum_counters``) then serves the selection with the
-grouped form of the non-dense f32-hybrid evaluator instead of the Pallas
-kernel: one cached executable, and only the ``[T, G]`` sums and counts
-come to the host, as over dense tiles.
+(``tilestore.groupsum_counters``) serves the selection with the one
+grouped f32-hybrid program it runs over dense tiles too, over the filled
+channels: one cached executable, and only the ``[T, G]`` sums and counts
+come to the host.
 
 Held here, through ``FiloServer``'s HTTP query path with the TPU backend
 against ``promql/refeval.py`` in float64: the answer over each kind of hole,
@@ -44,8 +44,11 @@ FIRST_SLOT, LAST_SLOT = 30, 60
 RTOL = 1e-6
 
 
-def _fleet(seed=20261003):
-    """[(labels, ts ms [TICKS], vals [TICKS])] of App-0 and App-1."""
+def _fleet(seed=20261003, poison=None):
+    """[(labels, ts ms [TICKS], vals [TICKS])] of App-0 and App-1;
+    ``poison`` puts a value the f32 program cannot carry into series 6
+    (App-0, job-0): ``"inf"`` one infinite sample, ``"span-1e60"`` every
+    value times 1e60."""
     rng = np.random.default_rng(seed)
     out = []
     for a in range(APPS):
@@ -58,6 +61,10 @@ def _fleet(seed=20261003):
                 vals = np.cumsum(rng.integers(0, 50, TICKS)).astype(float)
                 if n == 5:
                     vals[70:] -= vals[69]           # a counter reset
+                if n == 6 and poison == "inf":
+                    vals[45] = np.inf
+                if n == 6 and poison == "span-1e60":
+                    vals = vals * 1e60
                 out.append(({"_metric_": "http_requests_total",
                              "_ws_": "demo", "_ns_": f"App-{a}",
                              "job": f"job-{j}", "instance": f"i-{n:03d}"},
@@ -100,13 +107,13 @@ CASES = {
 }
 
 
-def _store(case):
+def _store(case, poison=None):
     """-> (server, [RefSeries]) with the case's scrapes taken out."""
     srv = FiloServer({"num-shards": 2, "port": 0}).start()
     producer = TestTimeseriesProducer(DEFAULT_SCHEMAS, num_shards=2)
     rng = np.random.default_rng(7)
     builders, ref = {}, []
-    for n, (labels, ts, vals) in enumerate(_fleet()):
+    for n, (labels, ts, vals) in enumerate(_fleet(poison=poison)):
         keep = np.ones(TICKS, bool)
         keep[list(CASES[case](n, rng))] = False
         b = builders.setdefault(producer.shard_for("prom-counter", labels),
@@ -257,5 +264,44 @@ def test_a_grid_wider_than_int32_ms_over_holes_is_refused_and_counted():
         assert d["filodb_fused_refused_gaps_total"] == 1
         assert d["filodb_aligned_exact_evals_total"] == 1
         assert d["filodb_aligned_fast_evals_total"] == 0
+    finally:
+        srv.stop()
+
+
+@pytest.mark.parametrize("poison", ["inf", "span-1e60"])
+@pytest.mark.parametrize("case", ["dense", "single-misses"])
+def test_a_value_f32_cannot_carry_is_refused_and_served_exactly(case,
+                                                                poison):
+    """A selection with an infinite value, or with a span whose rates
+    summed in f32 would overflow, is refused by the fused gate
+    (``AlignedTiles.f32_safe``), over dense tiles and over holes alike,
+    and the exact all-f64 aligned family and the host's ``aggregate``
+    answer it as the float64 reference does: ``+Inf`` where the reference
+    has it, 1e60-sized rates to the digit, never an f32 overflow."""
+    srv, ref = _store(case, poison)
+    try:
+        m0 = _metrics(srv)
+        got = _served(srv, "sum")
+        m1 = _metrics(srv)
+        rows = ref_eval(QUERY.format(op="sum"), ref, START, STEP, END)
+        steps = range(START, END + 1, STEP)
+        want = {dict(key)["job"]: dict(zip(steps, row))
+                for key, row in rows.items()}
+        assert set(got) == set(want)
+        for job, row in want.items():
+            assert sorted(got[job]) == sorted(row), (job, "steps")
+            np.testing.assert_allclose([got[job][t] for t in sorted(row)],
+                                       [row[t] for t in sorted(row)],
+                                       rtol=1e-9, err_msg=job)
+        if poison == "inf":
+            assert math.isinf(got["job-0"][T0 + 600])
+        else:
+            assert 1e59 < got["job-0"][T0 + 600] < 1e62
+        d = {f: m1[f] - m0[f] for f in m1 if f in m0}
+        assert d["filodb_fused_aggs_total"] == 0
+        assert d["filodb_fused_refused_total"] == 1
+        assert d["filodb_fused_refused_gaps_total"] \
+            == (0 if case == "dense" else 1)
+        assert d["filodb_aligned_exact_evals_total"] == 1
     finally:
         srv.stop()

@@ -1,6 +1,6 @@
-"""One array down: each fused counter program (the Pallas group sum over
-dense tiles, the grouped program over tiles with holes, the mesh store's
-grouped pair) returns its sums and counts stacked in ONE device array,
+"""One array down: each fused counter program (the grouped program over
+dense tiles and over tiles with holes, the mesh store's grouped pair)
+returns its sums and counts stacked in ONE device array,
 [2, T, G] in the dtype it had (f32 on one chip, f64 on the mesh), and the
 backend's ``device-sync`` pulls that one buffer: a request adds 1 to
 ``filodb_device_to_host_arrays_total`` and the bytes of two [T, G] grids to
@@ -8,11 +8,11 @@ backend's ``device-sync`` pulls that one buffer: a request adds 1 to
 already synced one array a request and still do.
 
 The (sums, cnts) the backend returns are the halves of the program's output
-to the bit, and over holes and on the mesh the bits of the programs with
-two outputs (two dots; a psum each). Through the engine the answers are
-what the existing parity tests assert, within their tolerances. The Pallas
-kernel runs in interpret mode (``FUSED_GROUPSUM_INTERPRET``,
-tests/conftest.py), the mesh on four of the virtual devices.
+to the bit, and the bits of the programs with two outputs (two dots; a
+psum each on the mesh). Through the engine the answers are what the
+existing parity tests assert, within their tolerances. The one-device
+program runs on the CPU (``FUSED_GROUPSUM_INTERPRET``, tests/conftest.py),
+the mesh on four of the virtual devices.
 """
 
 import functools
@@ -40,7 +40,7 @@ from filodb_tpu.query.tpu import TpuBackend
 BASE, DT, W, STEP = 1_600_000_000_000, 10_000, 300_000, 60_000
 S, N, G, T = 24, 200, 3, 12
 LES = (.005, .01, .025, .05, .1, .25, .5, 1, 2.5, 5, 10, np.inf)
-COUNTER_PATHS = ["pallas", "holes", "mesh"]
+COUNTER_PATHS = ["dense", "holes", "mesh"]
 
 
 def _frozen(a):
@@ -110,11 +110,10 @@ def calls(monkeypatch):
 
 
 def _two_outputs(path, args):
-    """The program's answer as it was with two outputs: over holes the two
-    dots as two results, on the mesh a psum each, from the same arguments
-    (the Pallas kernel has one output now; tests/test_groupsum_dispatch.py
-    holds it to the bit against the kernel called directly)."""
-    if path == "holes":
+    """The program's answer as it was with two outputs: on one chip the two
+    dots as two results, on the mesh a psum each, from the same
+    arguments."""
+    if path in ("dense", "holes"):
         def old(arrs, consts, grid, ids):
             out = tst._eval_counter_fast("rate", T, arrs, consts[0],
                                          consts[1], consts[2], grid[0],
@@ -176,11 +175,10 @@ def test_a_fused_request_syncs_one_array(calls, path):
         np.testing.assert_array_equal(sums, out[0])
         np.testing.assert_array_equal(cnts, out[1])
         assert (cnts > 0).any()
-        if path != "pallas":
-            want_s, want_c = _two_outputs(path, args)
-            assert sums.dtype == want_s.dtype
-            np.testing.assert_array_equal(sums, want_s)
-            np.testing.assert_array_equal(cnts, want_c)
+        want_s, want_c = _two_outputs(path, args)
+        assert sums.dtype == want_s.dtype
+        np.testing.assert_array_equal(sums, want_s)
+        np.testing.assert_array_equal(cnts, want_c)
     assert (be.mesh_dispatches > 0) == (path == "mesh")
     assert be.fused_holes_aggs == (3 if path == "holes" else 0)
 

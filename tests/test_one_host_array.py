@@ -1,18 +1,18 @@
-"""One host array a request: the four fused device programs (the Pallas
-group sum over dense tiles, the grouped program over tiles with holes, the
-histogram quantile, the mesh store's grouped pair) are handed the request's
+"""One host array a request: the fused device programs (the grouped
+counter program over dense tiles and over tiles with holes, the histogram
+quantile, the mesh store's grouped pair) are handed the request's
 grid and nothing else from the host once the selection's grouping has been
 seen. What is constant for a tile cohort (num_slots, base_ms, dt_ms, the
 bucket bounds and each quantile asked of it), a mesh placement (n_filled,
-base_ms, dt_ms) or a grouping (the padded group ids) waits on the device, and every call of a cached
+base_ms, dt_ms) or a grouping (the group ids) waits on the device, and every call of a cached
 executable adds the buffers it made from host values to
 ``filodb_host_to_device_puts_total`` (four for one array on four devices).
 
 Each case also computes its answer in the layout the programs had before,
 every scalar and the ids handed over from the host, with the scalars taken
 from the tiles and the grid here, and asks for the same bits: a constant
-that is stale, or in the wrong place, is a wrong answer. The Pallas kernel
-runs in interpret mode (``FUSED_GROUPSUM_INTERPRET``, tests/conftest.py), the
+that is stale, or in the wrong place, is a wrong answer. The one-device
+programs run on the CPU (``FUSED_GROUPSUM_INTERPRET``, tests/conftest.py), the
 mesh on four of the virtual devices.
 """
 
@@ -35,7 +35,7 @@ from filodb_tpu.query.tpu import TpuBackend
 BASE, DT, W, STEP = 1_600_000_000_000, 10_000, 300_000, 60_000
 S, N, G, T = 24, 200, 3, 12
 LES = (.005, .01, .025, .05, .1, .25, .5, 1, 2.5, 5, 10, np.inf)
-PATHS = ["pallas", "holes", "hist", "mesh"]
+PATHS = ["dense", "holes", "hist", "mesh"]
 
 
 def _frozen(a):
@@ -108,22 +108,16 @@ def calls(monkeypatch):
 def _parent(path, be, series, gids, steps, exe, args, q=0.9):
     """The answer in the parent's layout: the grid and the tiles' (or the
     placement's) scalars as host values, the ids as host int32, ``q`` as an
-    f64. The Pallas program and the mesh's are the same programs handed the
-    old arguments; over holes and histograms the program bodies are the
-    parent's as they were (the grid int64[6] unpacked in place)."""
+    f64. The mesh's is the same program handed the old arguments; the
+    counter and histogram program bodies are the parent's as they were
+    (the grid int64[6] unpacked in place)."""
     entry, _ = be._tile_entry(series, eng.selection_facts(series))
     tiles = entry.tiles
     w0e = int(steps[0])
     grid6 = np.array([w0e - W, w0e, STEP, tiles.num_slots, tiles.base_ms,
                       tiles.dt_ms], np.int64)
     gvec = np.asarray(gids)[entry.idx].astype(np.int32)
-    if path == "pallas":
-        v_p, base, params, ids = args
-        host = np.full(ids.shape, -1, np.int32)
-        host[:S] = gvec
-        sums, cnts = exe.fn(v_p, base, params, host)
-        return np.asarray(sums)[:T], np.asarray(cnts)[:T]
-    if path == "holes":
+    if path in ("dense", "holes"):
         def old(arrs, g6, ids):
             w0s, w0e, step, num_slots, base, dt = g6
             out = tst._eval_counter_fast("rate", T, arrs, num_slots, base, dt,
@@ -186,8 +180,7 @@ def test_a_request_hands_over_one_host_array(calls, path):
         _same(got, _parent(path, be, series, gids, _steps(shift), exe,
                            args))
     assert (be.mesh_dispatches > 0) == (path == "mesh")
-    if path == "holes":
-        assert be.fused_holes_aggs == 3
+    assert be.fused_holes_aggs == (3 if path == "holes" else 0)
     if path == "hist":
         assert be.fused_hist_aggs == 3
 

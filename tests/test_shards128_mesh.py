@@ -191,11 +191,11 @@ def test_mesh_answer_is_the_single_chip_answer_byte_for_byte(nodes, op,
     any group a node can hold (24 bits a rate, 53 in the sum), so it gives
     the per-series path's answer whatever the order: the same node without
     the mesh, as a CPU node runs it (the aligned f32-hybrid evaluator, then
-    the host's float64 ``aggregate``). The one-chip Pallas kernel sums a
+    the host's float64 ``aggregate``). The one-chip fused program sums a
     group in float32 and so differs in the last float32 digits."""
     meshed, plain, _ = nodes
     moved = dict(start=START + 60, end=END + 60)
-    fused = _raw(plain, op, **moved)            # conftest: interpret mode
+    fused = _raw(plain, op, **moved)            # conftest: the fused flag
     monkeypatch.setattr(tpu, "FUSED_GROUPSUM_INTERPRET", False)
     m0, _ = _metrics(plain)
     a, b = _raw(meshed, op, **moved), _raw(plain, op, **moved)

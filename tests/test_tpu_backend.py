@@ -148,8 +148,8 @@ def test_engine_with_tpu_backend_e2e():
     # steady increase of 7*(s+1) per 10s across 6 series
     expected = sum(0.7 * (s + 1) for s in range(6))
     np.testing.assert_allclose(tpu_res.values[0], expected, rtol=1e-5)
-    # the whole sum(rate(...)) ran inside the fused Pallas group-sum
-    # kernel — no [S, T] per-series intermediate
+    # the whole sum(rate(...)) ran inside the fused group-sum program —
+    # no [S, T] per-series intermediate
     assert backend.fused_aggs == 1
 
     # grouped + avg/count variants ride the same fused path

@@ -4,8 +4,8 @@ shapes only, at the sizes ``chip_smoke.py`` uses (8,192 series x 720
 samples, 16 groups).
 
 These are compiles, never chip runs: they say nothing about results or
-times. They catch what interpret mode cannot — a kernel Mosaic refuses,
-a program that does not fit, a sharding that silently replicates, and
+times. They catch what a CPU run cannot — a program the chip's compiler
+refuses, a program that does not fit, a sharding that silently replicates, and
 the f64 cumulative sum whose reduce-window form took the TPU compiler
 minutes (query/cumsum.py).
 
@@ -26,14 +26,13 @@ import jax.numpy as jnp
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
-from filodb_tpu.query import pallas_kernels as pk
 from filodb_tpu.query import tilestore as tst
 from filodb_tpu.query import tpu
 from filodb_tpu.query.cumsum import cumsum_f64
 from filodb_tpu.query.model import RawSeries
 
 S, N, G = 8192, 720, 16            # chip_smoke.py's default store
-S_HOST = pk._GS_SS                 # series of the host-side stand-in tiles
+S_HOST = 512                       # series of the host-side stand-in tiles
 BASE, DT = 1_600_000_000_000, 10_000
 W, STEP, T = 300_000, 60_000, 100  # 5m windows, 1m steps, ~100 steps
 
@@ -62,16 +61,13 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _tiles(jitter_ms):
+def _tiles():
     """Stand-in tiles built here on the CPU, only to ask the dispatchers
-    which arrays they pass: S_HOST series (one kernel lane tile) — the
+    which arrays they pass: S_HOST series, each +-2 s off the tick — the
     tests widen every series dimension to S before compiling."""
     rng = np.random.default_rng(11)
-    ts = BASE + np.arange(N, dtype=np.float64)[None, :] * DT
-    if jitter_ms:
-        ts = ts + rng.integers(-jitter_ms, jitter_ms + 1, (S_HOST, N))
-    else:
-        ts = np.broadcast_to(ts, (S_HOST, N))
+    ts = (BASE + np.arange(N, dtype=np.float64)[None, :] * DT
+          + rng.integers(-2000, 2001, (S_HOST, N)))
     vals = np.cumsum(rng.integers(0, 50, (S_HOST, N)).astype(np.float64),
                      axis=1)
     return tst.AlignedTiles([{"i": str(i)} for i in range(S_HOST)], BASE,
@@ -80,7 +76,7 @@ def _tiles(jitter_ms):
 
 @pytest.fixture(scope="module")
 def jittered():
-    return _tiles(2000)
+    return _tiles()
 
 
 def _steps():
@@ -156,47 +152,57 @@ def test_packed_endpoint_rate_compiles(one_chip, func):
 
 # -- (a) the fused group-sum program, as the dispatcher jits it ---------------
 
-@pytest.mark.parametrize("jitter_ms,nstreams", [(0, 1), (2000, 3)])
-def test_counter_groupsum_compiles(one_chip, monkeypatch, jittered,
-                                   jitter_ms, nstreams):
-    """What a fused query runs on the chip: the dispatcher's ONE jitted
-    program (one-hot from the group ids, the Pallas kernel, the slice),
-    built by the dispatcher's own ``build`` with its own statics. Sums
-    and counts leave it as ONE f32 [2, T, G] output, the kernel's own."""
-    tiles = jittered if jitter_ms else _tiles(0)
+@pytest.mark.parametrize("dense", [True, False], ids=["dense", "holes"])
+@pytest.mark.parametrize("func", ["rate", "delta"])
+def test_groupsum_over_holes_compiles(one_chip, monkeypatch, func, dense):
+    """What serves a ``sum by`` of a counter rate: the dispatcher's ONE
+    jitted program (the f32-hybrid evaluator, the one-hot from the group
+    ids, two f32 matmuls at HIGHEST), built by the dispatcher's own
+    ``build``, at the benchmark's first and third cells: 2,048 series x
+    728 slots, 16 groups, 31 steps, over dense tiles (two channels) and
+    over tiles with missed scrapes (seven)."""
+    s_cell, n_cell, t_cell = 2048, 728, 31
+    rng = np.random.default_rng(12)
+    ts = (BASE + np.arange(n_cell, dtype=np.float64)[None, :] * DT
+          + rng.integers(-2000, 2001, (S_HOST, n_cell)))
+    vals = np.cumsum(rng.integers(0, 50, (S_HOST, n_cell)).astype(
+        np.float64), axis=1)
+    valid = np.ones((S_HOST, n_cell), bool)
+    if not dense:
+        valid[3::4, 100:104] = False
+        valid[3::4, 300] = False
+    tiles = tst.AlignedTiles([{} for _ in range(S_HOST)], BASE, DT, valid,
+                             ts, vals)
     seen = {}
 
     def capture(cache, key, build, site="tilestore", cost_args=None):
         seen.update(key=key, build=build, args=cost_args, site=site)
         return lambda *a: None
     monkeypatch.setattr(tst, "_jit_lookup", capture)
-    assert tst.groupsum_counters(tiles, "rate", _steps(), W,
+    steps = BASE + 600_000 + np.arange(t_cell, dtype=np.int64) * STEP
+    assert tst.groupsum_counters(tiles, func, steps, W,
                                  np.arange(S_HOST) % G, G) is None
     monkeypatch.undo()
     assert seen["site"] == "groupsum"
-    _, func, st, dspan, hi, lo = seen["key"][:6]
-    assert pk._gs_nstreams(st, hi, lo) == nstreams
-    v_p, base, params, ids = seen["args"]
-    assert (params.dtype, params.shape) == (np.int32, (5,))
+    assert seen["key"] == ("groupsum", func, t_cell, G, (n_cell, S_HOST),
+                           dense)
+    arrs, consts, grid, ids = seen["args"]
+    assert len(arrs) == (2 if dense else 7)
+    assert (consts.dtype, consts.shape) == (np.int64, (3,))
+    assert type(grid) is np.ndarray
+    assert (grid.dtype, grid.shape) == (np.int64, (3,))
     assert (ids.dtype, ids.shape) == (np.int32, (S_HOST,))
-    # the kernel's series dimension is its grid: n_s lane tiles of _GS_SS
-    n_s = S // pk._GS_SS
-    assert v_p.shape[0] == base.shape[0] == 1
-    sh = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+
+    def sds(a):
+        shape = tuple(s_cell if d == S_HOST else d for d in a.shape)
+        return jax.ShapeDtypeStruct(shape, a.dtype, sharding=one_chip)
     compiled = seen["build"]().lower(
-        sh((n_s,) + v_p.shape[1:], v_p.dtype),
-        sh((n_s,) + base.shape[1:], base.dtype),
-        sh((5,), jnp.int32), sh((S,), jnp.int32)).compile()
+        *jax.tree_util.tree_map(sds, (arrs, consts, grid, ids))).compile()
     text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 1
-    # the five scalars arrive as one vector: no program assembles them
-    assert "concatenate" not in text
-    # one output, so one transfer to the host: the inputs' two copies in
-    # (start and done each), the one-hot, the kernel, the [:T] slice and
-    # the result's layout copy, 8 ops a launch (10 when the kernel had two
-    # outputs, each sliced and copied)
-    assert re.search(r"ENTRY[^\n]*->\s*f32\[2,100,16\]\s*\{", text)
-    assert _device_ops(text) <= 8
+    # no Pallas kernel in this program, and what leaves it is ONE f32
+    # [2, T, G]: the sums and the counts stacked, one transfer
+    assert "tpu_custom_call" not in text
+    assert re.search(r"ENTRY[^\n]*->\s*f32\[2,31,16\]\s*\{", text)
 
 
 # -- (c) the per-series aligned evaluators, as tilestore jits them ------------
@@ -218,7 +224,7 @@ def test_eval_counter_fast_compiles(one_chip, jittered):
 def holed():
     """The jittered tiles with every fourth series missing scrapes: not
     dense, so the evaluator takes the filled and prefix-count channels."""
-    t = _tiles(2000)
+    t = _tiles()
     valid = np.ones((S_HOST, N), bool)
     valid[3::4, 100:104] = False
     valid[3::4, 300] = False
@@ -241,55 +247,6 @@ def test_eval_counter_fast_over_holes_compiles(one_chip, holed, width):
         args = tuple(np.full(width, a) if ax == 0 else a
                      for a, ax in zip(args, tst._GRID_AXES))
     _compile(fn, *_shapes(args, one_chip))
-
-
-@pytest.mark.parametrize("func", ["rate", "delta"])
-def test_groupsum_over_holes_compiles(one_chip, monkeypatch, func):
-    """What serves a ``sum by`` whose selection has a missed scrape: the
-    dispatcher's ONE jitted program (the non-dense evaluator, the one-hot
-    from the group ids, two f32 matmuls at HIGHEST), built by the
-    dispatcher's own ``build``, at the benchmark's third cell: 2,048
-    series x 728 slots, 16 groups, 31 steps."""
-    s_cell, n_cell, t_cell = 2048, 728, 31
-    rng = np.random.default_rng(12)
-    ts = (BASE + np.arange(n_cell, dtype=np.float64)[None, :] * DT
-          + rng.integers(-2000, 2001, (S_HOST, n_cell)))
-    vals = np.cumsum(rng.integers(0, 50, (S_HOST, n_cell)).astype(
-        np.float64), axis=1)
-    valid = np.ones((S_HOST, n_cell), bool)
-    valid[3::4, 100:104] = False
-    valid[3::4, 300] = False
-    tiles = tst.AlignedTiles([{} for _ in range(S_HOST)], BASE, DT, valid,
-                             ts, vals)
-    seen = {}
-
-    def capture(cache, key, build, site="tilestore", cost_args=None):
-        seen.update(key=key, build=build, args=cost_args, site=site)
-        return lambda *a: None
-    monkeypatch.setattr(tst, "_jit_lookup", capture)
-    steps = BASE + 600_000 + np.arange(t_cell, dtype=np.int64) * STEP
-    assert tst.groupsum_counters(tiles, func, steps, W,
-                                 np.arange(S_HOST) % G, G) is None
-    monkeypatch.undo()
-    assert seen["site"] == "groupsum"
-    assert seen["key"] == ("groupsum", "holes", func, t_cell, G,
-                           (n_cell, S_HOST))
-    arrs, consts, grid, ids = seen["args"]
-    assert (consts.dtype, consts.shape) == (np.int64, (3,))
-    assert type(grid) is np.ndarray
-    assert (grid.dtype, grid.shape) == (np.int64, (3,))
-    assert (ids.dtype, ids.shape) == (np.int32, (S_HOST,))
-
-    def sds(a):
-        shape = tuple(s_cell if d == S_HOST else d for d in a.shape)
-        return jax.ShapeDtypeStruct(shape, a.dtype, sharding=one_chip)
-    compiled = seen["build"]().lower(
-        *jax.tree_util.tree_map(sds, (arrs, consts, grid, ids))).compile()
-    text = compiled.as_text()
-    # no Pallas kernel in this program, and what leaves it is ONE f32
-    # [2, T, G]: the sums and the counts stacked, one transfer
-    assert "tpu_custom_call" not in text
-    assert re.search(r"ENTRY[^\n]*->\s*f32\[2,31,16\]\s*\{", text)
 
 
 @pytest.mark.parametrize("dense", [True, False])

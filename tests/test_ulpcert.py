@@ -34,12 +34,11 @@ def test_every_tree_annotation_is_certified(results):
 def test_expected_claim_inventory(results):
     """The in-tree hybrid sites the issue names are all annotated —
     the counter fast/slide path, the f32 epilogue (instant division
-    chain), the fixed-point split, the donated append carry, and both
-    mesh psum collectives."""
+    chain), the donated append carry, and both mesh psum
+    collectives."""
     assert {"counter-fast-hybrid", "counter-slide-hybrid",
             "counter-epilogue-f32", "counter-exact-slot-index",
-            "fixed-point-split", "append-carry-exact",
-            "groupsum-recombine-f32", "extrapolated-rate-f64"} \
+            "append-carry-exact", "extrapolated-rate-f64"} \
         <= set(nmod.PRECISION)
     assert {"grouped-reduce-psum", "grouped-pair-psum"} \
         <= set(nmod.ORDER)
